@@ -1,0 +1,251 @@
+"""The chip's compiler, asked before any chip run: the main path's Pallas
+kernels and step programs are lowered through Mosaic and compiled for a
+DESCRIBED v5e (nothing is attached, nothing runs) at the real FM widths
+— 2^22 slots, B=65536 x 32 occurrences, K=1+10, packed storage.
+
+What interpret mode and the CPU suite cannot see shows here: a slice
+not aligned to the tiling, a kernel over its VMEM budget, a program
+that does not fit the device. A pass is a compile, never a run.
+
+The topology is described inside a fixture (never at import: only one
+process may load the TPU library, and every xdist worker imports every
+test file), and all such tests live in THIS file, so one worker loads
+the library once.
+"""
+
+import numpy as np
+import pytest
+
+LOG2_SLOTS, BATCH, NNZ, K, PACK = 22, 65536, 32, 11, 8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: nothing to test
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip (the next one warns and
+    compiles again): keep these compiles out of it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def on_tpu(monkeypatch):
+    """The op wrappers ask jax.default_backend() and would take their
+    XLA branch here; steer them from the test, not from an option."""
+    from xflow_tpu.ops import sorted_table
+
+    monkeypatch.setattr(sorted_table, "_on_tpu", lambda: True)
+
+
+def _fm_cfg():
+    from xflow_tpu.config import Config, override
+
+    return override(Config(), **{
+        "model.name": "fm", "data.log2_slots": LOG2_SLOTS,
+        "data.batch_size": BATCH, "data.max_nnz": NNZ,
+    })
+
+
+def _slots_mask():
+    rng = np.random.default_rng(0)
+    return (
+        rng.integers(0, 1 << LOG2_SLOTS, (BATCH, NNZ)).astype(np.int32),
+        np.ones((BATCH, NNZ), np.float32),
+    )
+
+
+def _shapes(tree, sharding):
+    import jax
+
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree
+    )
+
+
+def _pallas_calls(compiled) -> int:
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
+def _kernel_cases():
+    """name -> (fn, arg shapes as (shape, dtype) tuples): the six kernels
+    of the sorted engine at the FM main-path widths."""
+    from xflow_tpu.config import FTRLConfig
+    from xflow_tpu.ops import sorted_table as st
+
+    S = 1 << LOG2_SLOTS
+    n_win = S // st.WINDOW
+    n_occ = st.padded_len(BATCH * NNZ)
+    table = ((S // PACK, PACK * K), np.float32)
+    slots = ((n_occ,), np.int32)
+    win_off = ((n_win + 1,), np.int32)
+    d_occ = ((st._k8(K), n_occ), np.float32)
+    # the multi-buffer form: four source buffers of one capacity (the
+    # four-chip fullshard stream, or NS=4 sub-batches on one device)
+    nbuf = 4
+    cap = (n_occ // nbuf // st.CHUNK + 1) * st.CHUNK
+    m_slots = ((nbuf * cap,), np.int32)
+    m_off = ((nbuf, n_win + 1), np.int32)
+    m_d = ((st._k8(K), nbuf * cap), np.float32)
+    hp = FTRLConfig()
+    return {
+        "gather": (
+            lambda t, s, w: st._gather_pallas(t, s, w, False, PACK),
+            (table, slots, win_off),
+        ),
+        "gather_multi": (
+            lambda t, s, o: st._gather_pallas_multi(t, s, o, cap, False, PACK),
+            (table, m_slots, m_off),
+        ),
+        "scatter": (
+            lambda d, s, w: st._scatter_pallas(d, s, w, S, K, False, PACK),
+            (d_occ, slots, win_off),
+        ),
+        "scatter_multi": (
+            lambda d, s, o: st._scatter_pallas_multi(d, s, o, S, K, cap, False, PACK),
+            (m_d, m_slots, m_off),
+        ),
+        "scatter_ftrl": (
+            lambda d, s, w, a, b, c: st._scatter_ftrl_pallas(
+                d, s, w, a, b, c, K, hp, False, PACK
+            ),
+            (d_occ, slots, win_off, table, table, table),
+        ),
+        "rowsum": (
+            lambda v, r: st._rowsum_pallas(v, r, BATCH),
+            (((24, n_occ), np.float32), slots),
+        ),
+    }
+
+
+@pytest.mark.parametrize(
+    "kernel",
+    ["gather", "gather_multi", "scatter", "scatter_multi", "scatter_ftrl", "rowsum"],
+)
+def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache):
+    import jax
+
+    fn, arg_shapes = _kernel_cases()[kernel]
+    args = [
+        jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+        for shape, dtype in arg_shapes
+    ]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert _pallas_calls(compiled) == 1
+
+
+def test_fm_train_step_compiles_for_v5e(one_chip, no_persistent_cache, on_tpu):
+    """The whole single-device FM step `xflow train --no-mesh` runs:
+    gather + row sum + fused scatter/FTRL as Mosaic calls, donated state,
+    inside one chip's memory."""
+    from xflow_tpu.analysis.ir import _abstract_state, _capture, _CapturingRecorder
+    from xflow_tpu.models import get_model
+    from xflow_tpu.ops.sorted_table import plan_sorted_stacked
+    from xflow_tpu.optim import get_optimizer
+    from xflow_tpu.train.step import make_train_step
+
+    cfg = _fm_cfg()
+    model, opt = get_model("fm"), get_optimizer("ftrl")
+    slots, mask = _slots_mask()
+    plan = plan_sorted_stacked(slots, mask, cfg.num_slots, wire=True)
+    rows = np.zeros((BATCH,), np.float32)
+    batch = {
+        "labels": rows, "row_mask": rows, "sorted_slots": plan.sorted_slots,
+        "sorted_row": plan.sorted_row, "sorted_mask": plan.sorted_mask,
+        "win_off": plan.win_off,
+    }
+    _, step = _capture(
+        lambda: make_train_step(model, opt, cfg, recorder=_CapturingRecorder())
+    )
+    compiled = step.lower(
+        _shapes(_abstract_state(model, opt, cfg), one_chip), _shapes(batch, one_chip)
+    ).compile()
+    assert _pallas_calls(compiled) == 3
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+def test_fm_fullshard_step_compiles_for_four_chips(topo, no_persistent_cache, on_tpu):
+    """The mesh engine `xflow train` picks on more than one device: the
+    fully-sharded FM step over the 2x2 host, each chip holding a quarter
+    of the state."""
+    import jax
+
+    from xflow_tpu.analysis.ir import (
+        _abstract_state, _capture, _CapturingRecorder, _with_shardings,
+    )
+    from xflow_tpu.models import get_model
+    from xflow_tpu.ops.sorted_table import compact_plan_wire
+    from xflow_tpu.optim import get_optimizer
+    from xflow_tpu.parallel.mesh import batch_sharding, make_mesh, state_shardings
+    from xflow_tpu.parallel.sorted_fullshard import (
+        make_fullshard_train_step, plan_fullshard_batch,
+    )
+
+    cfg = _fm_cfg()
+    model, opt = get_model("fm"), get_optimizer("ftrl")
+    mesh = make_mesh(cfg, devices=topo.devices)
+    abstract = _abstract_state(model, opt, cfg)
+    state = _with_shardings(abstract, state_shardings(abstract, mesh))
+    slots, mask = _slots_mask()
+    rows = np.zeros((BATCH,), np.float32)
+    arrays = {"labels": rows, "row_mask": rows}
+    arrays.update(plan_fullshard_batch(slots, mask, cfg, mesh))
+    arrays = compact_plan_wire(
+        arrays, rows_bound=BATCH // mesh.shape["data"], fields_bound=0
+    )
+    bsh = batch_sharding(mesh)
+    batch = {
+        k: jax.ShapeDtypeStruct(v.shape, v.dtype, sharding=bsh[k])
+        for k, v in arrays.items()
+    }
+    call = make_fullshard_train_step(opt, cfg, mesh, recorder=_CapturingRecorder())
+    _, step = _capture(lambda: call(state, batch))
+    compiled = step.lower(state, batch).compile()
+    assert _pallas_calls(compiled) == 3
+    assert "all-to-all" in compiled.as_text()
+    whole = sum(
+        int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(abstract)
+    )
+    assert compiled.memory_analysis().argument_size_in_bytes < 0.3 * whole
+
+
+def test_occupancy_sweep_compiles_in_seconds(one_chip, no_persistent_cache):
+    """The end-of-fit occupancy sweep over the packed FTRL accumulator at
+    2^24 slots. Written as a reshape to [S/8, 8, 11] this one program
+    took the chip's compiler 225 s (and 156 s here); grouped by a matmul
+    it takes about one."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from xflow_tpu.train.trainer import _slot_any
+
+    n = jax.ShapeDtypeStruct(((1 << 24) // PACK, PACK * K), jnp.float32, sharding=one_chip)
+    t0 = time.perf_counter()
+    jax.jit(lambda n: jnp.mean(_slot_any(n > 0, K))).lower(n).compile()
+    assert time.perf_counter() - t0 < 60

@@ -20,9 +20,8 @@ execution). Each rule exists for a ROADMAP contract:
   is FEWER BYTES (bf16 tables, docs/PERF.md); a hidden upcast silently
   pays the f32 bytes the config opted out of.
 - **XF803 scan-carry-waste**: a ``lax.scan`` whose stacked outputs no
-  consumer reads (length× memory for nothing) or whose carry leaf the
-  body returns unchanged (the leaf rides every iteration for free —
-  usually a refactor leftover).
+  consumer reads (length× memory for nothing — usually a refactor
+  leftover).
 - **XF804 ast-ir-contract-mismatch**: donation or in/out-sharding
   contracts declared at the AST tier (the XF7xx extraction feeding
   ``tools/engine_contracts.json``) that are absent or different in the
@@ -278,23 +277,13 @@ def _xf803(facts: dict) -> list:
         prog = facts["programs"][key]
         for sc in prog.get("scans", []):
             path, line = _split_loc(sc["src"], prog["engine"])
-            parts = []
-            if sc["dead_outputs"]:
-                parts.append(
-                    f"stacked output(s) {sc['dead_outputs']} have no "
-                    f"consumer (length={sc['length']}: the whole stack "
-                    "is materialized for nothing)")
-            if sc["identity_carries"]:
-                parts.append(
-                    f"carry leaf/leaves {sc['identity_carries']} are "
-                    "returned unchanged by the body (dead weight riding "
-                    "every iteration)")
             findings.append(Finding(
                 rule="XF803", path=path, line=line,
-                message=f"scan-carry waste in program {key}: "
-                        + "; ".join(parts),
-                hint="drop the dead output (return None from the body) "
-                     "or hoist the unchanged leaf out of the carry",
+                message=f"scan-carry waste in program {key}: stacked "
+                        f"output(s) {sc['dead_outputs']} have no "
+                        f"consumer (length={sc['length']}: the whole "
+                        "stack is materialized for nothing)",
+                hint="drop the dead output (return None from the body)",
             ))
     return findings
 

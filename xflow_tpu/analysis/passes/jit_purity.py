@@ -78,18 +78,22 @@ HOST_CALLBACK_WRAPPERS = {
 }
 
 
-def _is_jit_decorator(dec: ast.AST, aliases: dict) -> bool:
+def _is_jit_decorator(dec: ast.AST, aliases: dict,
+                      wrappers=JIT_WRAPPERS) -> bool:
+    """Whether `dec` applies one of `wrappers` — by default everything
+    that TRACES its function; XF204 passes the jit family alone (a
+    shard_map body is traced but is no compile unit of its own)."""
     name = astutil.canonical(astutil.dotted(dec), aliases)
-    if name in JIT_WRAPPERS:
+    if name in wrappers:
         return True
     if isinstance(dec, ast.Call):
         cn = astutil.canonical(astutil.call_name(dec), aliases)
-        if cn in JIT_WRAPPERS:
+        if cn in wrappers:
             return True
         # functools.partial(jax.jit, ...) as a decorator factory
         if cn in ("functools.partial", "partial") and dec.args:
             return astutil.canonical(
-                astutil.dotted(dec.args[0]), aliases) in JIT_WRAPPERS
+                astutil.dotted(dec.args[0]), aliases) in wrappers
     return False
 
 

@@ -163,7 +163,7 @@ def run(project: Project) -> list:
                 if not isinstance(node, (ast.FunctionDef,
                                          ast.AsyncFunctionDef)):
                     continue
-                if not any(_is_jit_decorator(d, aliases)
+                if not any(_is_jit_decorator(d, aliases, JIT_CALLS)
                            for d in node.decorator_list):
                     continue
                 if node.name in wrapped_names:

@@ -331,12 +331,28 @@ def _cache_batch_iterator(
     return sc.iter_batches(bs, cfg.drop_remainder, profiler=profiler)
 
 
+def _warn_python_parser(err: BaseException) -> None:
+    """The native parser could not be built or loaded: say so once,
+    with the reason — the Python parser that takes over is the ~28×
+    host gap docs/PERF.md measured, and nothing else in a run's output
+    would show it."""
+    from xflow_tpu.telemetry import warn_once
+
+    warn_once(
+        "native_parser",
+        f"native parser unavailable ({type(err).__name__}: {err}); "
+        "the Python parser runs instead (far slower)",
+    )
+
+
 def _raw_batch_iterator(
     path: str,
     cfg: DataConfig,
     batch_size: Optional[int] = None,
     profiler=None,
 ) -> Iterator[SparseBatch]:
+    from xflow_tpu.telemetry import default_registry
+
     bs = batch_size or cfg.batch_size
     cached = _cache_batch_iterator(path, cfg, bs, profiler=profiler)
     if cached is not None:
@@ -353,9 +369,10 @@ def _raw_batch_iterator(
             native_iter = native_batch_iterator(path, cfg, bs)
         except FileNotFoundError:
             raise  # a missing input is the user's error, not a fallback case
-        except (ImportError, OSError, RuntimeError, subprocess.SubprocessError):
-            native_iter = None
+        except (ImportError, OSError, RuntimeError, subprocess.SubprocessError) as e:
+            _warn_python_parser(e)
         if native_iter is not None:
+            default_registry().counter("data.parser_native_shards").inc()
             if profiler is None:
                 yield from native_iter
                 return
@@ -371,6 +388,7 @@ def _raw_batch_iterator(
                     return
                 profiler.count_batch(b.num_rows)
                 yield b
+    default_registry().counter("data.parser_python_shards").inc()
     yield from examples_to_batches(
         iter_examples(path, cfg.log2_slots, cfg.hash_salt, profiler=profiler),
         bs,
@@ -397,8 +415,8 @@ def count_batches(path: str, cfg: DataConfig, batch_size: Optional[int] = None) 
             rows = native_count_rows(path, cfg.block_bytes)
         except FileNotFoundError:
             raise
-        except (ImportError, OSError, RuntimeError, subprocess.SubprocessError):
-            rows = None  # toolchain missing: the Python parser will run
+        except (ImportError, OSError, RuntimeError, subprocess.SubprocessError) as e:
+            _warn_python_parser(e)  # toolchain missing: the Python parser will run
     if rows is None:
         from xflow_tpu.data.libffm import count_rows
 

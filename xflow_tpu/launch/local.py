@@ -112,6 +112,17 @@ def _launch_local_once(
             gen=gen,
         )
         watchdog.start()
+    # Children MUST default to CPU: inheriting an ambient accelerator
+    # platform would land every child on the same device, the world
+    # would never form, and each child would silently train shard 0 as
+    # its own rank 0. Real multi-host accelerator launches opt in via
+    # XFLOW_LAUNCH_PLATFORM; parallel/distributed.py's process-count
+    # assert catches any remaining mismatch.
+    platform = os.environ.get("XFLOW_LAUNCH_PLATFORM", "cpu")
+    print(
+        f"launch-local: {num_processes} rank(s) on JAX_PLATFORMS={platform}",
+        file=sys.stderr,
+    )
     procs = []
     for rank in range(num_processes):
         env = dict(os.environ)
@@ -129,14 +140,7 @@ def _launch_local_once(
             # rank emits (jsonl.JsonlAppender) so metrics_report.py can
             # segment the multi-generation streams of a supervised run
             XFLOW_RESTART_GEN=str(gen),
-            # Children MUST default to CPU: inheriting an ambient
-            # accelerator platform would land every child on the same
-            # device (this image pins one TPU), the world would never
-            # form, and each child would silently train shard 0 as its
-            # own rank 0. Real multi-host accelerator launches opt in
-            # via XFLOW_LAUNCH_PLATFORM; parallel/distributed.py's
-            # process-count assert catches any remaining mismatch.
-            JAX_PLATFORMS=env.get("XFLOW_LAUNCH_PLATFORM", "cpu"),
+            JAX_PLATFORMS=platform,
         )
         cmd = [
             sys.executable, "-m", "xflow_tpu", "train",

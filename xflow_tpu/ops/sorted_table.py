@@ -89,7 +89,7 @@ def compact_plan_wire(arrays: dict, rows_bound: int, fields_bound: int = 0) -> d
     """Shrink the per-batch plan arrays' host->device wire format:
     row ids to uint16, fields to uint8, the 0/1 mask to uint8 —
     14.2 -> 8.2 MB per 64k x 18 batch (plus ~3.5 MB on the MVM segment
-    path's fields), ~45% less PCIe (or tunnel) traffic per step. The
+    path's fields), ~45% less host->device traffic per step. The
     jitted forwards upcast on device (`wire_rows` / `wire_mask`), where
     the cast fuses for free.
 
@@ -275,20 +275,42 @@ def _native_planner():
     """xf_plan_sorted via ctypes (native/parser.cc): a stable O(n) radix
     sort replacing np.argsort's ~150 ms/2M-occurrence comparison sort —
     the host would otherwise wall the sorted-engine step times. Falls
-    back to numpy when the toolchain is missing. XFLOW_NO_NATIVE_PLAN=1
-    forces the numpy path (used by the parity tests)."""
+    back to numpy when the toolchain is missing, with one stderr
+    warning that says why. XFLOW_NO_NATIVE_PLAN=1 forces the numpy path
+    (used by the parity tests)."""
     global _NATIVE_PLAN
     if _NATIVE_PLAN is None:
         if os.environ.get("XFLOW_NO_NATIVE_PLAN"):
             _NATIVE_PLAN = False
         else:
-            try:
-                from xflow_tpu.data.native import native_plan_sorted
+            import subprocess
 
-                _NATIVE_PLAN = native_plan_sorted
-            except Exception:
+            try:
+                from xflow_tpu.data import native
+
+                # build + load NOW: the import alone compiles nothing, and
+                # a missing toolchain must be decided here, once — not
+                # raised from inside the first batch's plan
+                native.get_lib()
+                _NATIVE_PLAN = native.native_plan_sorted
+            except (ImportError, OSError, subprocess.SubprocessError) as e:
+                from xflow_tpu.telemetry import warn_once
+
+                warn_once(
+                    "native_planner",
+                    f"native planner unavailable ({type(e).__name__}: {e}); "
+                    "numpy's argsort builds the sorted plans instead (far "
+                    "slower)",
+                )
                 _NATIVE_PLAN = False
     return _NATIVE_PLAN
+
+
+def planner_name() -> str:
+    """Which sort builds this process's sorted plans: "native" (the C
+    radix sort) or "python" (numpy's argsort) — `xflow train` names it
+    in its summary line."""
+    return "native" if _native_planner() else "python"
 
 
 def plan_sorted_batch(

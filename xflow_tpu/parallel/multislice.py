@@ -470,6 +470,12 @@ def slice_forward_args(forward_args: list, j: int) -> list:
     return [a.replace("{slice}", str(j)) for a in forward_args]
 
 
+def _slice_platform() -> str:
+    """CPU devices by default, same reasoning as launch-local: every
+    slice landing on one ambient accelerator would serialize them."""
+    return os.environ.get("XFLOW_LAUNCH_PLATFORM", "cpu")
+
+
 def _spawn_slice(j: int, num_slices: int, forward_args: list, run_dir: str,
                  sync_dir: str, run_id: str, gen: int) -> subprocess.Popen:
     """One slice subprocess: an independent single-process
@@ -488,9 +494,7 @@ def _spawn_slice(j: int, num_slices: int, forward_args: list, run_dir: str,
         XFLOW_PROCESS_ID=str(j),
         XFLOW_RUN_ID=run_id,
         XFLOW_RESTART_GEN=str(gen),
-        # CPU devices by default, same reasoning as launch-local: every
-        # slice landing on one ambient accelerator would serialize them
-        JAX_PLATFORMS=env.get("XFLOW_LAUNCH_PLATFORM", "cpu"),
+        JAX_PLATFORMS=_slice_platform(),
     )
     cmd = [
         sys.executable, "-m", "xflow_tpu", "train",
@@ -547,6 +551,11 @@ def launch_multislice(
     os.makedirs(run_dir, exist_ok=True)
     sync_dir = os.path.join(run_dir, "sync")
     os.makedirs(sync_dir, exist_ok=True)
+    print(
+        f"launch-multislice: {num_slices} slice(s) on JAX_PLATFORMS="
+        f"{_slice_platform()}",
+        file=sys.stderr,
+    )
     run_id = resolve_launch_run_id()
     live = set(range(num_slices))
     lock = threading.Lock()

@@ -46,7 +46,7 @@ Supports fused FM, MVM, and FFM (sorted-engine models; FFM rides the
 MVM segment mode's machinery with its own channel contract —
 models/ffm.py). LR stays on the GSPMD row-major path: its 1-D table
 gather is already bandwidth-efficient (2.2× the per-chip target,
-BENCH_r02) and needs no windowed engine.
+BENCH_r03) and needs no windowed engine.
 """
 
 from __future__ import annotations
@@ -57,6 +57,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from xflow_tpu.config import Config
@@ -70,7 +71,6 @@ from xflow_tpu.ops.sorted_table import (
     row_sums_sorted,
     table_gather_sorted_multi,
 )
-from xflow_tpu.parallel.compat import shard_map
 from xflow_tpu.parallel.mesh import DATA_AXIS, TABLE_AXIS
 from xflow_tpu.train.state import TrainState
 from xflow_tpu.train.step import guard_nonfinite, health_norms, metrics_keys

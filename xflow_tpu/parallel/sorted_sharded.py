@@ -41,6 +41,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from xflow_tpu.config import Config
@@ -50,7 +51,6 @@ from xflow_tpu.ops.sorted_table import (
     row_sums_sorted,
     table_gather_sorted,
 )
-from xflow_tpu.parallel.compat import shard_map
 from xflow_tpu.parallel.mesh import DATA_AXIS, TABLE_AXIS
 from xflow_tpu.train.state import TrainState
 from xflow_tpu.train.step import guard_nonfinite, health_norms, metrics_keys

@@ -73,12 +73,16 @@ def replica_env(
         XFLOW_REPLICA=str(idx),
         XFLOW_REPLICA_PORT=str(port),
         XFLOW_RELOAD_STAGGER_S=str(idx * max(stagger_s, 0.0)),
-        # replicas default to CPU like launch-local's children: N serve
-        # processes inheriting one ambient accelerator would fight over
-        # it; real accelerator fleets opt in via XFLOW_LAUNCH_PLATFORM
-        JAX_PLATFORMS=env.get("XFLOW_LAUNCH_PLATFORM", env.get("JAX_PLATFORMS", "cpu")),
+        JAX_PLATFORMS=_replica_platform(env),
     )
     return env
+
+
+def _replica_platform(env) -> str:
+    """Replicas default to CPU like launch-local's children: N serve
+    processes inheriting one ambient accelerator would fight over it;
+    real accelerator fleets opt in via XFLOW_LAUNCH_PLATFORM."""
+    return env.get("XFLOW_LAUNCH_PLATFORM", env.get("JAX_PLATFORMS", "cpu"))
 
 
 class ReplicaSupervisor:
@@ -270,12 +274,15 @@ def fleet_main(cfg: Config, serve_args: list, run_dir: str = "",
         print("serve-fleet: need >= 1 replica", file=sys.stderr)
         return 2
     run_id = resolve_launch_run_id()
-    # the router's own appender stamps run_id/world from env like every
-    # other sink; rank is pinned to -1 (control plane) explicitly
     os.environ["XFLOW_RUN_ID"] = run_id
     os.environ["XFLOW_NUM_PROCESSES"] = str(n)
     if run_dir:
         os.makedirs(run_dir, exist_ok=True)
+    print(
+        f"serve-fleet: {n} replica(s) on JAX_PLATFORMS="
+        f"{_replica_platform(os.environ)}",
+        file=sys.stderr,
+    )
 
     ports = [_free_port(scfg.host) for _ in range(n)]
     ready_info = {}
@@ -341,9 +348,12 @@ def fleet_main(cfg: Config, serve_args: list, run_dir: str = "",
     )
     # rank -1 = control-plane stream, the launcher-watchdog
     # convention (metrics_report exempts it from rank<world); capped
-    # like the replica streams (serve.metrics_max_bytes)
+    # like the replica streams (serve.metrics_max_bytes). `world` is
+    # passed like the watchdog passes it: a stamp left to resolve it
+    # can end in jax.process_count(), which starts a backend — and this
+    # process must never hold a device its replicas need
     router_app = JsonlAppender(
-        router_jsonl, stamp={"rank": -1, "run_id": run_id},
+        router_jsonl, stamp={"rank": -1, "run_id": run_id, "world": n},
         max_bytes=scfg.metrics_max_bytes,
     )
     from xflow_tpu.tracing import Tracer

@@ -670,11 +670,14 @@ def serve_main(cfg: Config, mesh=None, ready_out=None) -> int:
         t.start()
         threads.append(t)
 
+    from xflow_tpu.telemetry import device_triple
+
     ready = {
         "serving": True,
         "step": gen.step,
         "generation": gen.gen,
         "pid": os.getpid(),
+        "device": device_triple(),
     }
     if cfg.serve.port >= 0:
         ready["host"], ready["port"] = servers[0].server_address[:2]

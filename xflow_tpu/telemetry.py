@@ -195,6 +195,24 @@ def resolve_world_size() -> int:
         return 1
 
 
+_WARNED: set = set()
+_WARNED_LOCK = threading.Lock()
+
+
+def warn_once(key: str, message: str) -> None:
+    """Print `message` to stderr the first time this process sees
+    `key`. For stand-ins that would otherwise take over in silence
+    (the Python parser and planner where the native library cannot be
+    built): one line with the reason, never one per batch."""
+    with _WARNED_LOCK:
+        if key in _WARNED:
+            return
+        _WARNED.add(key)
+    import sys
+
+    print(f"xflow: warning: {message}", file=sys.stderr)
+
+
 # ------------------------------------------------------------------ registry
 
 
@@ -367,6 +385,20 @@ def default_registry() -> Registry:
 # ------------------------------------------------------------ HBM gauges
 
 
+def device_triple() -> dict:
+    """The devices this process computes on, as JAX reports them — what
+    `xflow train`'s summary and `xflow serve`'s ready line name, so a
+    run that landed on the CPU cannot pass for one on the chip."""
+    import jax
+
+    devices = jax.devices()
+    return {
+        "platform": devices[0].platform,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+
+
 def device_memory_stats(device=None) -> dict:
     """The default (or given) device's allocator stats, as a plain dict
     of the fields worth gauging: bytes_in_use, peak_bytes_in_use,
@@ -455,9 +487,11 @@ class CompileRecorder:
     batch shape, a resharded table) is a legitimate second program, not
     a recompile.
 
-    If lower/compile itself fails (an input the AOT path cannot
-    express), the wrapper logs once and falls back to the plain jitted
-    callable — accounting must never be able to kill training.
+    A lower/compile failure propagates: what the compiler refused is
+    the error of the run, not a note on stderr followed by a second,
+    unrecorded compile under plain jit. Only the cost/memory/op-scope
+    ANALYSIS of a program that did compile stays soft (a backend
+    without it leaves those fields out).
     """
 
     OP_SCOPES_CAP = 2048  # entries per record: bounds the JSONL line
@@ -561,7 +595,10 @@ class CompileRecorder:
 
     def _op_scopes(self, compiled) -> tuple:
         """(HLO module name or None, {optimized-HLO op name ->
-        named_scope label}) from the compiled module's metadata. The
+        named_scope label}, Pallas kernel count) from the compiled
+        module's text. The kernel count is the number of Mosaic custom
+        calls the program really contains — the proof that a step runs
+        the Pallas kernels and not their XLA stand-ins. The
         op_name path looks like `jit(step)/jit(main)/grad/gather`: the
         final component is the primitive, the scope is the LAST path
         component matching a known label — so the backward's scatter
@@ -573,7 +610,7 @@ class CompileRecorder:
         try:
             text = compiled.as_text()
         except Exception:
-            return None, {}
+            return None, {}, 0
         import re
 
         mod = re.match(r"\s*HloModule\s+([\w.\-]+)", text)
@@ -588,33 +625,38 @@ class CompileRecorder:
                     break
             if len(out) >= self.OP_SCOPES_CAP:
                 break
-        return module, out
+        return module, out, text.count('custom_call_target="tpu_custom_call"')
 
     # ---------------------------------------------------------- recording
     def record(self, name: str, jitted, *args, **kwargs):
         """Lower + compile `jitted` for these args, emit the compile
-        record, and return the compiled executable (None when the AOT
-        path failed — caller falls back to the jitted callable)."""
+        record, and return the compiled executable. Raises whatever
+        lower/compile raised."""
+        import jax
+
         sig = self.signature(args, kwargs)
+        # JAX announces a persistent-compile-cache hit as a monitoring
+        # event; listening for the length of this compile tells a
+        # deserialized executable from a compiled one
+        hits: list = []
+
+        def on_event(event: str, **_) -> None:
+            if event == "/jax/compilation_cache/cache_hits":
+                hits.append(event)
+
+        jax.monitoring.register_event_listener(on_event)
         t0 = time.perf_counter()
         try:
             compiled = jitted.lower(*args, **kwargs).compile()
-        except Exception as e:  # noqa: BLE001 — accounting never kills a run
-            import sys as _sys
-
-            print(
-                f"xflow: compile recorder: AOT lower/compile failed for "
-                f"{name!r} ({type(e).__name__}: {e}); falling back to "
-                "plain jit (no compile record)",
-                file=_sys.stderr,
-            )
-            return None
+        finally:
+            jax.monitoring.unregister_event_listener(on_event)
         dt = time.perf_counter() - t0
         rec = {
             "kind": "compile",
             "program": name,
             "sig": sig,
             "compile_time_s": round(dt, 6),
+            "cache_hit": bool(hits),
         }
         rec.update(self._cost_fields(compiled))
         with self._lock:
@@ -628,7 +670,7 @@ class CompileRecorder:
         else:
             self._reg.counter("compile.programs").inc()
         self._reg.timer("compile.time").observe(dt)
-        module, scopes = self._op_scopes(compiled)
+        module, scopes, rec["pallas_calls"] = self._op_scopes(compiled)
         if module:
             rec["hlo_module"] = module
         if scopes:
@@ -644,11 +686,8 @@ class CompileRecorder:
         """Drop-in replacement for a jitted callable that compiles
         through `record` once per signature."""
         cache: dict = {}
-        fallback = {}
 
         def call(*args, **kwargs):
-            if fallback:
-                return jitted(*args, **kwargs)
             try:
                 key = self._fast_key(args, kwargs)
                 compiled = cache.get(key)
@@ -656,11 +695,7 @@ class CompileRecorder:
                 key = self.signature(args, kwargs)
                 compiled = cache.get(key)
             if compiled is None:
-                compiled = self.record(name, jitted, *args, **kwargs)
-                if compiled is None:
-                    fallback["on"] = True
-                    return jitted(*args, **kwargs)
-                cache[key] = compiled
+                compiled = cache[key] = self.record(name, jitted, *args, **kwargs)
             return compiled(*args, **kwargs)
 
         call.program_name = name  # introspection (tests)

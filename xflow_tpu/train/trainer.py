@@ -335,8 +335,7 @@ class Trainer:
             )
             self.eval_step = make_eval_step(self.model, cfg, recorder=_rec)
             # ONE async device_put for the whole dict: per-array jnp.asarray
-            # is a synchronous round trip each, which dominates on
-            # high-latency links (tunneled devices: ~9 arrays × RTT/step)
+            # is a synchronous round trip each (~9 arrays per step)
             self._shard_batch = jax.device_put
         # host dedup for row-major batches (ops/sorted_table.dedup_slots):
         # single-process only — the unique count is data-dependent and a
@@ -435,6 +434,25 @@ class Trainer:
         # num_fields would be silently dropped by the one-hot, so reject
         # it loudly
         self._validate_fields = cfg.model.name in ("mvm", "ffm")
+
+    @property
+    def engine(self) -> str:
+        """The table engine the step dispatches to: "sorted" (windowed
+        Pallas kernels on a TPU) or "row_major" (XLA gather/scatter) on
+        one device; "fullshard", "replicated" or "gspmd" on a mesh."""
+        if self.mesh is not None:
+            return self._mesh_engine or "gspmd"
+        return "sorted" if self._sorted else "row_major"
+
+    @property
+    def planner(self) -> Optional[str]:
+        """What builds the sorted plans ("native" | "python"); None on
+        the row-major engines, which plan nothing."""
+        if not self._sorted:
+            return None
+        from xflow_tpu.ops.sorted_table import planner_name
+
+        return planner_name()
 
     def _check_batch(self, batch) -> None:
         if self._validate_fields:
@@ -1588,28 +1606,17 @@ class Trainer:
         # signal; untouched slots keep their build-time init, so a
         # nonzero count would read ~1.0 for randomly-initialized v tables.
         specs = self.model.table_specs(cfg)
-
-        def slot_any(mask2d, name):
-            """Per-SLOT any over the row width — packed storage
-            ([S/pack, pack*K], ops/sorted_table.pack_table) groups pack
-            slots per stored row, and an any over the full stored row
-            would count 8-slot groups, not slots."""
-            K = specs[name][0]
-            sp, width = mask2d.shape
-            return mask2d.reshape(sp, width // K, K).any(axis=-1)
-
         for name, t in self.state.tables.items():
             st = self.state.opt_state.get(name)
             if isinstance(st, dict) and "n" in st:
-                touched = (
-                    slot_any(st["n"] > 0, name) if st["n"].ndim > 1 else st["n"] > 0
-                )
+                touched = st["n"] > 0
             else:
                 # stateless optimizer (SGD): a touched slot has moved off
                 # its build-time init (0 for scalar tables, v_init_sgd for
                 # vector tables — models/base.py init_tables)
-                init = cfg.optim.v_init_sgd if t.ndim > 1 else 0.0
-                touched = slot_any(t != init, name) if t.ndim > 1 else t != init
+                touched = t != (cfg.optim.v_init_sgd if t.ndim > 1 else 0.0)
+            if touched.ndim > 1:
+                touched = _slot_any(touched, specs[name][0])
             res.occupancy[name] = float(jnp.mean(touched))
         final_rec = {
             "final": True,
@@ -2468,6 +2475,20 @@ class Trainer:
         # inside read_data_state
         self._resume_data_state = ckpt.read_data_state(src, step, fmt=fmt)
         return True
+
+
+def _slot_any(mask2d, K: int):
+    """Per-SLOT any over the row width K — packed storage
+    ([S/pack, pack*K], ops/sorted_table.pack_table) groups pack slots
+    per stored row, and an any over the full stored row would count
+    8-slot groups, not slots. Grouped by a 0/1 matmul, NOT by a reshape
+    to [S/pack, pack, K]: a minor dimension of K=11 is a relayout the
+    TPU compiler spent 225 s on for that one op at 2^24 slots (PERF.md,
+    PR 22), and 0/1 operands with sums <= K are exact at any matmul
+    precision."""
+    width = mask2d.shape[1]
+    group = jnp.arange(width)[:, None] // K == jnp.arange(width // K)
+    return (mask2d.astype(jnp.float32) @ group.astype(jnp.float32)) > 0
 
 
 def _shard_batch_arrays(batch: dict, mesh):
