@@ -1,0 +1,13 @@
+"""The fused scatter+FTRL kernel's share of its roofline: its needed
+bytes (one gradient row read an occurrence; w, n, z read and written a
+distinct slot) over the HBM peak, against its device time a step in the
+trace."""
+
+META = {"layer": "kernels", "unit": "%", "source": "device_trace", "better": "higher"}
+KERNEL = r"^scatter_optimizer[.\d]*\[pallas\]$"
+
+
+def read(run: dict):
+    from lib import counts
+
+    return counts.kernel_roofline_pct(run, KERNEL, counts.scatter_ftrl_needs)
