@@ -1,7 +1,8 @@
 """Hot-path attribution layer (round 11): PipelineProfiler unit
 coverage, the prefetch queue counters + starvation detection under the
-fault injectors' pacing, the zero-overhead-when-off contract (no
-pipeline records/counters in an off run), trainer-integrated
+fault injectors' pacing, the no-pipeline-surface-when-off contract (no
+pipeline records/counters without train.pipeline_metrics), the step
+records' `host` window against the pipeline record's, trainer-integrated
 kind="pipeline" windows through metrics_report --check/--health,
 tools/pipeline_attrib.py's table/verdict/host-gap record, the
 bench_lab core sweep + probe-wrapper CLIs, perf_ledger's BENCH_LAB /
@@ -22,12 +23,16 @@ import pytest
 
 from xflow_tpu.config import Config, override
 from xflow_tpu.telemetry import (
+    HOST_STAGES,
     PIPELINE_CONSUMER_STAGES,
     PIPELINE_PRODUCER_STAGES,
     PIPELINE_STAGES,
     PipelineProfiler,
     Registry,
+    host_field,
+    pipeline_fields,
     pipeline_verdict,
+    span,
 )
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -54,11 +59,11 @@ def test_profiler_stages_and_window():
     prof.start()
     prof.add("parse", 0.25)
     prof.add_many({"read": 0.05, "hash": 0.1})
-    with prof.stage("plan"):
+    with span("plan", prof):
         time.sleep(0.01)
     prof.count_batch(64)
     prof.observe_queue(2, 2)
-    rec = prof.window_record()
+    rec = pipeline_fields(prof.take_window())
     for s in PIPELINE_STAGES:
         assert f"{s}_s" in rec
     assert rec["parse_s"] == pytest.approx(0.25)
@@ -68,7 +73,7 @@ def test_profiler_stages_and_window():
     assert rec["queue_depth"] == 2 and rec["queue_cap"] == 2
     assert rec["wall_s"] > 0
     # the window reset: a second flush with no activity is empty
-    assert prof.window_record() == {}
+    assert prof.take_window() == {}
     # run totals survive the window reset
     totals, elapsed = prof.totals()
     assert totals["parse"] == pytest.approx(0.25)
@@ -88,6 +93,21 @@ def test_profiler_registry_gauges():
     snap = reg.snapshot()
     assert snap["pipeline.producer_blocked_s"] == pytest.approx(1.5)
     assert snap["pipeline.queue_depth"] == 1
+
+
+def test_unpublished_profiler_accumulates_without_gauges():
+    """Armed by the metrics stream alone (publish=False): the window
+    fills, the registry stays free of pipeline.* gauges."""
+    reg = Registry()
+    prof = PipelineProfiler(registry=reg, publish=False)
+    prof.start()
+    prof.add("producer_wait", 1.5)
+    prof.observe_queue(1, 4)
+    assert reg.snapshot() == {}
+    win = prof.take_window()
+    assert win["producer_wait"] == pytest.approx(1.5)
+    assert win["queue_depth"] == 1 and win["queue_cap"] == 4
+    assert prof.take_window() == {}
 
 
 def test_pipeline_verdict_directions():
@@ -241,9 +261,9 @@ def test_trainer_pipeline_records(tmp_path):
 
 
 def test_profiler_off_stream_is_pipeline_free(tmp_path):
-    """The zero-overhead-when-off contract: an off run's stream holds
-    no pipeline records and no pipeline.* counters — byte-identical in
-    shape to a pre-profiler build."""
+    """train.pipeline_metrics off: the stream holds no pipeline records
+    and no pipeline.* counters, though the metrics stream arms the host
+    timeline (`host` in the window records)."""
     from xflow_tpu.telemetry import default_registry
 
     default_registry().reset()  # a prior profiled test must not leak gauges
@@ -253,6 +273,54 @@ def test_profiler_off_stream_is_pipeline_free(tmp_path):
     for r in recs:
         for key in r.get("counters") or {}:
             assert not key.startswith("pipeline."), f"leaked counter {key}"
+
+
+def test_armed_by_metrics_path_has_host_and_no_pipeline_surface(tmp_path):
+    """train.metrics_path alone arms the host timeline: every window
+    record carries `host` (one field a stage), the first `boundary` —
+    and train.pipeline_metrics keeps its one meaning: without it, no
+    kind="pipeline" record and no pipeline.* gauge."""
+    from xflow_tpu.telemetry import default_registry
+
+    default_registry().reset()
+    res, recs = _train_tiny(tmp_path)
+    assert res.steps == 5
+    wins = [r for r in recs if "step_time_p50_ms" in r]
+    assert len(wins) == 3  # steps 2 and 4, and the final record's tail
+    for w in wins:
+        assert set(w["host"]) == {host_field(s) for s in HOST_STAGES} | {"batches"}
+    assert sum(w["host"]["batches"] for w in wins) == 5
+    assert "fit_open_ms" in wins[0]["boundary"]
+    assert not any("boundary" in w for w in wins[1:])
+    assert not any(r.get("kind") == "pipeline" for r in recs)
+    assert not any(
+        k.startswith("pipeline.") for r in recs for k in r.get("counters") or {}
+    )
+    # and the stream passes the full --check gate with the new fields
+    r = run_tool([tool("metrics_report.py"), str(tmp_path / "run"), "--check"])
+    assert r.returncode == 0, r.stderr
+
+
+def test_host_sums_equal_pipeline_windows(tmp_path):
+    """Both carriers on: `host` and the kind="pipeline" record are two
+    formats of the same window, so stage for stage their sums agree
+    (the four consumer stages of the pipeline record fold the five the
+    loop stamps)."""
+    _, recs = _train_tiny(tmp_path, **{"train.pipeline_metrics": True})
+    hosts = [r["host"] for r in recs if "host" in r]
+    pipe = [r for r in recs if r.get("kind") == "pipeline"]
+    assert len(hosts) == len(pipe) == 3
+    host = lambda key: sum(h[key] for h in hosts)
+    legacy = lambda key: sum(p[key] for p in pipe) * 1e3
+    tol = dict(abs=1e-3 * len(pipe) * 2)  # both sides rounded
+    for s in PIPELINE_PRODUCER_STAGES + ("transfer",):
+        assert host(host_field(s)) == pytest.approx(legacy(f"{s}_s"), **tol), s
+    assert host("data_wait_ms") == pytest.approx(legacy("queue_wait_s"), **tol)
+    assert host("prev_ready_ms") == pytest.approx(legacy("device_s"), **tol)
+    assert host("dispatch_call_ms") + host("loop_other_ms") == pytest.approx(
+        legacy("dispatch_s"), **tol
+    )
+    assert sum(h["batches"] for h in hosts) == sum(p["batches"] for p in pipe) == 5
 
 
 def test_profiled_then_off_run_no_gauge_leak(tmp_path):

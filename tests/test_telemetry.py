@@ -8,6 +8,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -16,12 +17,23 @@ import pytest
 from xflow_tpu.config import Config, override
 from xflow_tpu.data.synth import generate_shards
 from xflow_tpu.jsonl import JsonlAppender, read_jsonl, read_jsonl_counted
+from xflow_tpu import telemetry
 from xflow_tpu.telemetry import (
+    HOST_CONSUMER_STAGES,
+    HOST_SPANS,
+    HOST_SPANS_FIT,
+    HOST_SPANS_PREFETCH,
+    HOST_STAGES,
+    PIPELINE_PRODUCER_STAGES,
+    PipelineProfiler,
     Registry,
     StepTimer,
     TraceWindow,
     default_registry,
+    host_field,
+    host_fields,
     resolve_run_id,
+    span,
 )
 from xflow_tpu.train.trainer import Trainer
 
@@ -305,6 +317,265 @@ def test_trainer_trace_window_mid_run(train_data, tmp_path, monkeypatch):
     assert traces, "trace window produced no profiler output"
 
 
+# ------------------------------------------------------- the host timeline
+
+
+class SpanLog:
+    """Stands in for jax.profiler.TraceAnnotation (telemetry._annotation,
+    the seam TraceWindow's `profiler` argument is for the trace): logs
+    every annotation's begin and end with the thread that made it."""
+
+    def __init__(self):
+        self.events = []  # (thread name, "B" | "E", annotation name)
+
+    def __call__(self, name):
+        events = self.events
+
+        class _Ann:
+            def __enter__(self):
+                events.append((threading.current_thread().name, "B", name))
+                return self
+
+            def __exit__(self, *exc):
+                events.append((threading.current_thread().name, "E", name))
+                return False
+
+        return _Ann()
+
+    def tree(self, thread):
+        """[(depth, name)] of the thread's spans in opening order;
+        raises where a span closes out of turn."""
+        stack, out = [], []
+        for th, kind, name in self.events:
+            if th != thread:
+                continue
+            if kind == "B":
+                out.append((len(stack), name))
+                stack.append(name)
+            else:
+                assert stack and stack[-1] == name, f"{name} closed over {stack}"
+                stack.pop()
+        assert not stack, f"left open: {stack}"
+        return out
+
+
+@pytest.fixture
+def span_log(monkeypatch):
+    log = SpanLog()
+    monkeypatch.setattr(telemetry, "_annotation", log)
+    return log
+
+
+def test_span_names_and_host_fields_come_from_one_tuple():
+    """The annotation's name and the records' field are one string: the
+    per-step stages the profiler accumulates are spans of their thread,
+    and `host` holds exactly one field a stage."""
+    assert set(HOST_CONSUMER_STAGES) - {"loop_other"} <= set(HOST_SPANS_FIT)
+    assert set(HOST_SPANS_PREFETCH) <= set(PIPELINE_PRODUCER_STAGES)
+    assert telemetry._SPAN_NAMES == {s: "xflow:" + s for s in HOST_SPANS}
+    assert len(set(HOST_SPANS)) == len(HOST_SPANS)
+    prof = PipelineProfiler(registry=Registry())
+    prof.add("parse", 0.002)
+    got = host_fields(prof.take_window())
+    assert set(got) == {host_field(s) for s in HOST_STAGES} | {"batches"}
+    assert got["parse_ms"] == 2.0
+    # the train_step call's field steps aside for the StepTimer's
+    # wider dispatch_ms, one level up in the same record
+    assert host_field("dispatch") == "dispatch_call_ms"
+    assert host_field("prev_ready") == "prev_ready_ms"
+    with pytest.raises(KeyError):
+        span("not_a_stage")
+
+
+def test_span_annotates_always_and_accumulates_when_armed(span_log):
+    prof = PipelineProfiler(registry=Registry())
+    with span("plan") as unarmed:
+        time.sleep(0.002)
+    with span("plan", prof) as armed:
+        time.sleep(0.002)
+    with span("parse", prof):
+        pass
+    assert [e[1:] for e in span_log.events] == [
+        ("B", "xflow:plan"), ("E", "xflow:plan"),
+        ("B", "xflow:plan"), ("E", "xflow:plan"),
+        ("B", "xflow:parse"), ("E", "xflow:parse"),
+    ]
+    assert unarmed.seconds >= 0.002 and armed.seconds >= 0.002
+    totals, _ = prof.totals()
+    assert totals["plan"] == pytest.approx(armed.seconds)  # the armed one alone
+    assert totals["parse"] > 0
+
+
+def test_fit_opens_the_vocabulary_nested_on_its_threads(
+    train_data, tmp_path, monkeypatch, span_log
+):
+    """One fit() opens exactly the spans of the table in
+    docs/OBSERVABILITY.md "The host timeline": the fit loop's under
+    xflow:fit in loop order, the producer's on the prefetch thread —
+    with nobody listening (no metrics, no profile: the spans are the
+    loop's own, not the profiler's)."""
+    monkeypatch.chdir(tmp_path)
+    main = threading.current_thread().name
+    trainer = Trainer(_train_cfg(train_data, **{"train.log_every": 0}))
+    assert trainer.pipeline_prof is None
+    assert span_log.tree(main) == [(0, "xflow:init_state")]
+    del span_log.events[:]
+    res = trainer.fit()
+    n = res.steps
+    assert n == 30
+    tree = span_log.tree(main)
+    assert tree[0] == (0, "xflow:fit")
+    per_step = ["xflow:data_wait", "xflow:transfer", "xflow:dispatch",
+                "xflow:prev_ready"]
+    assert [name for depth, name in tree if depth == 1] == (
+        ["xflow:fit_open"] + per_step * n
+        + ["xflow:data_wait", "xflow:fit_flush", "xflow:occupancy",
+           "xflow:fit_close"]
+    )
+    # deeper: the first dispatch compiles the step, and the pass's
+    # terminating next() holds the prefetch teardown
+    deeper = [name for depth, name in tree if depth >= 2]
+    assert deeper[:2] == ["xflow:lower", "xflow:compile"]
+    assert deeper.count("xflow:iter_end") == 1
+    last_wait = max(i for i, t in enumerate(tree) if t == (1, "xflow:data_wait"))
+    assert tree[last_wait + 1] == (2, "xflow:iter_end")
+    # the producer's, on its own thread, and nothing of the loop's
+    prod = [name for _, name in span_log.tree("xflow-prefetch")]
+    assert prod.count("xflow:parse") == n + 1  # one a batch, and the EOF
+    assert prod.count("xflow:plan") == n
+    assert prod.count("xflow:producer_wait") == n
+    assert set(prod) == {"xflow:parse", "xflow:plan", "xflow:producer_wait"}
+    # everything opened is in the one tuple, and all of it turned up
+    # but the cache's reader (text shards here)
+    opened = {e[2] for e in span_log.events} | {"xflow:init_state"}
+    assert opened == {"xflow:" + s for s in HOST_SPANS} - {"xflow:cache_read"}
+
+
+def test_unarmed_run_builds_no_profiler_and_its_stream_is_what_it_was(
+    train_data, tmp_path, monkeypatch
+):
+    """metrics_path, profile_dir, pipeline_metrics all unset: no
+    profiler object, no record, no pipeline gauge; each alone arms."""
+    monkeypatch.chdir(tmp_path)
+    default_registry().reset()
+    trainer = Trainer(_train_cfg(train_data))
+    assert trainer.pipeline_prof is None
+    assert not trainer.metrics.enabled
+    res = trainer.fit()
+    assert res.steps == 30 and trainer._prev_fit is None
+    assert not any(k.startswith("pipeline.") for k in default_registry().snapshot())
+    for key, value in (
+        ("train.metrics_path", str(tmp_path / "m.jsonl")),
+        ("train.profile_dir", str(tmp_path / "prof")),
+        ("train.pipeline_metrics", True),
+    ):
+        armed = Trainer(_train_cfg(train_data, **{key: value}))
+        assert armed.pipeline_prof is not None, key
+        assert armed.pipeline_prof.publish == (key == "train.pipeline_metrics")
+
+
+def _two_fits(train_data, tmp_path, monkeypatch, **kw):
+    """Two fit() calls on one Trainer, a record a step; returns the
+    two fits' records and the perf_counter stamps an outsider can take:
+    just before the first fit(), and each fit()'s last step ready (the
+    return of StepTimer.flush)."""
+    monkeypatch.chdir(tmp_path)
+    ready = []
+
+    class Stamped(StepTimer):
+        def flush(self):
+            super().flush()
+            ready.append(time.perf_counter())
+
+    import xflow_tpu.train.trainer as trainer_mod
+
+    monkeypatch.setattr(trainer_mod, "StepTimer", Stamped)
+    mpath = tmp_path / "run" / "metrics_rank0.jsonl"
+    cfg = _train_cfg(train_data, **{
+        "train.metrics_path": str(mpath), "train.log_every": 1, **kw})
+    trainer = Trainer(cfg)
+    entered = time.perf_counter()
+    trainer.fit()
+    n_first = len(read_jsonl(str(mpath)))
+    time.sleep(0.03)  # the caller's own time between the fits
+    trainer.fit()
+    recs = read_jsonl(str(mpath))
+    return recs[:n_first], recs[n_first:], entered, ready
+
+
+def _steps(recs):
+    return [r for r in recs if "step_time_p50_ms" in r and not r.get("kind")]
+
+
+def test_boundary_tiles_the_wall_between_two_fits(train_data, tmp_path, monkeypatch):
+    """Step intervals plus the out-of-step parts of `boundary` add up to
+    the wall from the first `_fit` entry to the second's last step
+    ready: nothing between two passes is outside the records."""
+    first, second, entered, ready = _two_fits(train_data, tmp_path, monkeypatch)
+    s1, s2 = _steps(first), _steps(second)
+    assert len(s1) == 30 and len(s2) == 30  # a record a step
+    b1, b2 = s1[0]["boundary"], s2[0]["boundary"]
+    assert not any("boundary" in r for r in s1[1:] + s2[1:])
+    for b in (b1, b2):
+        assert all(v >= 0 for v in b.values()), b
+    assert set(b2) == {"fit_tail_ms", "occupancy_ms", "close_ms", "between_fits_ms",
+                       "fit_open_ms", "first_batch_ms", "first_dispatch_ms"}
+    # the tail's named parts fit inside it, and the caller's sleep is
+    # in between_fits_ms, not in the program's tail or open
+    assert b2["occupancy_ms"] + b2["close_ms"] <= b2["fit_tail_ms"] + 1e-3
+    assert b2["between_fits_ms"] >= 30.0
+    # the first step's interval holds its own cold start
+    assert s2[0]["step_time_p50_ms"] >= b2["first_batch_ms"] + b2["first_dispatch_ms"] - 1e-2
+    intervals = sum(r["step_time_p50_ms"] for r in s1 + s2)
+    outside = (b1["fit_open_ms"] + b2["fit_tail_ms"] + b2["between_fits_ms"]
+               + b2["fit_open_ms"])
+    wall_ms = (ready[1] - entered) * 1e3
+    assert intervals + outside == pytest.approx(wall_ms, abs=5.0)
+
+
+def test_first_fit_of_a_trainer_has_no_tail_behind_it(train_data, tmp_path, monkeypatch):
+    first, _, _, _ = _two_fits(train_data, tmp_path, monkeypatch)
+    assert set(_steps(first)[0]["boundary"]) == {
+        "fit_open_ms", "first_batch_ms", "first_dispatch_ms"}
+
+
+def test_final_record_carries_its_own_fit_end(train_data, tmp_path, monkeypatch):
+    """`final`: the terminating next(), the wait for the last step and
+    the occupancy sweep of its own fit(); and with a log cadence longer
+    than the pass, the pass's `host` window and `boundary` too."""
+    first, second, _, _ = _two_fits(
+        train_data, tmp_path, monkeypatch, **{"train.log_every": 100})
+    for recs in (first, second):
+        assert not [r for r in _steps(recs) if not r.get("final")]
+        final = next(r for r in recs if r.get("final"))
+        for key in ("iter_end_ms", "fit_flush_ms", "occupancy_ms"):
+            assert final[key] >= 0, key
+        assert final["host"]["batches"] == 30
+        assert "fit_open_ms" in final["boundary"]
+    assert "fit_tail_ms" in next(r for r in second if r.get("final"))["boundary"]
+    # the second fit()'s boundary names the first's sweep
+    f1 = next(r for r in first if r.get("final"))
+    f2 = next(r for r in second if r.get("final"))
+    assert f2["boundary"]["occupancy_ms"] == pytest.approx(f1["occupancy_ms"], abs=2e-3)
+
+
+def test_cache_reader_opens_one_span_a_batch(tmp_path, span_log):
+    from xflow_tpu.data.shardcache import (
+        build_cache, cache_path_for, open_shard_cache,
+    )
+
+    generate_shards(str(tmp_path / "t"), 1, 200, num_fields=6, ids_per_field=40, seed=1)
+    cfg = _train_cfg(tmp_path).data
+    build_cache(str(tmp_path / "t"), cfg)
+    cache = cache_path_for(str(tmp_path / "t-00000"), cfg.cache_dir)
+    del span_log.events[:]  # the build parsed the text: its spans are not the reader's
+    prof = PipelineProfiler(registry=Registry())
+    batches = list(open_shard_cache(cache).iter_batches(64, profiler=prof))
+    assert [b.num_rows for b in batches] == [64, 64, 64, 8]
+    assert [e[2] for e in span_log.events if e[1] == "B"] == ["xflow:cache_read"] * 4
+    assert prof.rows == 200 and prof.totals()[0]["cache_read"] > 0
+
+
 def test_quarantine_records_are_stamped(tmp_path):
     """Quarantine and metrics streams must be joinable: both stamped
     with ts/rank/run_id by the shared appender."""
@@ -509,6 +780,47 @@ def test_metrics_report_check_flags_bad_schema(tmp_path):
     r = _report([str(bad), "--check"])
     assert r.returncode != 0
     assert "FAIL" in r.stderr
+
+
+def test_metrics_report_check_admits_and_gates_the_host_timeline(tmp_path):
+    """--check's copy of the `host` / `boundary` / compile-split key
+    sets is the writer's, and a drifted or negative field fails."""
+    sys.path.insert(0, os.path.join(REPO_ROOT, "tools"))
+    import metrics_report
+
+    assert set(metrics_report.HOST_KEYS) == (
+        {host_field(s) for s in HOST_STAGES} | {"batches"})
+    stamp = {"ts": 1.0, "rank": 0, "run_id": "r", "gen": 0}
+    window = {"steps_per_s": 1.0, "rows_per_s": 64.0, "step_time_p50_ms": 9.0,
+              "step_time_p99_ms": 9.0, "data_wait_ms": 1.0, "dispatch_ms": 1.0,
+              "device_ms": 7.0}
+    host = {k: 0.5 for k in metrics_report.HOST_KEYS}
+    opened = {k: 0.25 for k in metrics_report.BOUNDARY_OPEN_KEYS}
+    tail = {k: 0.25 for k in metrics_report.BOUNDARY_TAIL_KEYS}
+    comp = {"kind": "compile", "program": "train_step", "sig": "abc",
+            "compile_time_s": 0.3, "flops": 1.0, "bytes_accessed": 2.0}
+
+    def check(*recs):
+        path = tmp_path / "m.jsonl"
+        path.write_text("".join(json.dumps({**stamp, **r}) + "\n" for r in recs))
+        return _report([str(path), "--check"])
+
+    good = check(
+        {"step": 1, **window, "host": host, "boundary": opened},
+        {"step": 2, **window, "host": host, "boundary": {**opened, **tail}},
+        {**comp, "lower_s": 0.2, "xla_compile_s": 0.1},
+    )
+    assert good.returncode == 0, good.stderr
+    for bad, said in (
+        ({"step": 1, **window, "host": {**host, "parse_ms": -1.0}}, "negative host"),
+        ({"step": 1, **window, "host": {"parse_ms": 1.0}}, "host that is not"),
+        ({"step": 1, **window, "boundary": {**opened, "fit_tail_ms": 1.0}},
+         "boundary that is not"),
+        ({**comp, "lower_s": 0.2, "xla_compile_s": 0.2}, "do not add up"),
+        ({**comp, "lower_s": 0.3}, "do not add up"),
+    ):
+        r = check(bad)
+        assert r.returncode == 2 and said in r.stderr, (bad, r.stderr)
 
 
 def test_metrics_report_empty_dir(tmp_path):

@@ -69,6 +69,19 @@ WINDOW_KEYS = (
     "dispatch_ms",
     "device_ms",
 )
+# the host timeline's share of a window record (docs/OBSERVABILITY.md
+# "The host timeline"; telemetry.host_fields and Trainer._boundary are
+# the writers): `host` is all-or-none like the window keys, every value
+# a non-negative number; `boundary` holds the open's three parts always
+# and the previous fit()'s four together or not at all. Both are absent
+# from an unarmed run's and from a pre-upgrade writer's records.
+HOST_KEYS = (
+    "read_ms", "parse_ms", "hash_ms", "batch_ms", "pad_ms", "cache_read_ms",
+    "plan_ms", "producer_wait_ms", "data_wait_ms", "transfer_ms",
+    "dispatch_call_ms", "prev_ready_ms", "loop_other_ms", "batches",
+)
+BOUNDARY_OPEN_KEYS = ("fit_open_ms", "first_batch_ms", "first_dispatch_ms")
+BOUNDARY_TAIL_KEYS = ("fit_tail_ms", "occupancy_ms", "close_ms", "between_fits_ms")
 # the health keys a health-enabled window record carries (telemetry
 # .HealthMonitor.window_record); --check enforces all-or-none too
 HEALTH_KEYS = ("grad_norm", "update_norm", "param_norm", "loss_ema")
@@ -79,6 +92,10 @@ STAMP_KEYS = ("ts", "rank", "run_id")
 # exactly-once rule: the same (program, sig) never compiles twice in
 # one stream (a recompile means a jit cache is thrashing)
 COMPILE_KEYS = ("program", "sig", "compile_time_s", "flops", "bytes_accessed")
+# the compile time's two parts (tracing + lowering; XLA's compile or a
+# cache read), added after runs were archived: present they come
+# together and add up to compile_time_s
+COMPILE_SPLIT_KEYS = ("lower_s", "xla_compile_s")
 # the key set every kind="serve" window record carries (serve/metrics
 # .ServeMetrics.maybe_flush — SERVE_WINDOW_KEYS there is the writer's
 # copy); --check enforces all-or-none plus monotone model generation
@@ -130,7 +147,7 @@ AUTOTUNE_KEYS = (
 # writer's copy) — an unknown name means a forged or drifted record
 AUTOTUNE_KNOB_NAMES = ("window_ms", "rung")
 # the key set every kind="pipeline" window record carries (telemetry
-# .PipelineProfiler.window_record + the trainer's step stamp —
+# .pipeline_fields + the trainer's step stamp —
 # docs/OBSERVABILITY.md "Input-pipeline attribution"); --check enforces
 # all-or-none, a positive wall, and the CONCURRENCY invariant: the
 # producer (prefetch thread) and consumer (fit loop) stage groups each
@@ -705,6 +722,25 @@ def check_streams(streams: dict, files: list[str]) -> list[str]:
                         f"{tag}: record {i} has window keys {present} but "
                         f"lacks {missing}"
                     )
+            for group, want in (
+                ("host", (HOST_KEYS,)),
+                ("boundary", (BOUNDARY_OPEN_KEYS, BOUNDARY_OPEN_KEYS + BOUNDARY_TAIL_KEYS)),
+            ):
+                if group not in rec:
+                    continue
+                got = rec[group]
+                if not isinstance(got, dict) or not any(
+                    set(got) == set(keys) for keys in want
+                ):
+                    problems.append(
+                        f"{tag}: record {i} has a {group} that is not one "
+                        f"of the key sets {[list(k) for k in want]}"
+                    )
+                elif not all(_finite(v) and v >= 0 for v in got.values()):
+                    problems.append(
+                        f"{tag}: record {i} has a non-numeric or negative "
+                        f"{group} value"
+                    )
             # health fields are all-or-none per record (null allowed for
             # a not-yet-available value, absence is the violation)
             h_present = [k for k in HEALTH_KEYS if k in rec]
@@ -737,6 +773,17 @@ def check_streams(streams: dict, files: list[str]) -> list[str]:
                     problems.append(
                         f"{tag}: record {i} ({rec['program']!r}) has "
                         "non-positive compile_time_s"
+                    )
+                split = [rec.get(k) for k in COMPILE_SPLIT_KEYS if k in rec]
+                if split and (
+                    len(split) != len(COMPILE_SPLIT_KEYS)
+                    or not all(_finite(v) and v >= 0 for v in split)
+                    or abs(sum(split) - rec["compile_time_s"]) > 1e-5
+                ):
+                    problems.append(
+                        f"{tag}: record {i} ({rec['program']!r}) has "
+                        f"{list(COMPILE_SPLIT_KEYS)} that do not add up to "
+                        "compile_time_s"
                     )
                 prog_key = (rec["program"], rec["sig"])
                 if prog_key in seen_programs:

@@ -6,7 +6,9 @@
 #   1. kind="compile" records for every compiled program, with nonzero
 #      compile_time/flops/bytes and the op->scope map, gated by
 #      metrics_report --check (schema + the exactly-once recompile rule);
-#   2. roofline gauges (achieved_flops_per_s) in the window records;
+#   2. the host timeline in the window records (`host`: one field a
+#      stage; `boundary` on the first) and the compile record's
+#      lower/compile split;
 #   3. tools/trace_attrib.py producing a per-scope device-time table
 #      from the run's TraceWindow trace;
 #   4. the round's BENCH_r09.json datapoint rendered through
@@ -36,7 +38,7 @@ fi
 
 export JAX_PLATFORMS=cpu
 
-# ---- 1. instrumented run: compile accounting + roofline + trace window
+# ---- 1. instrumented run: compile accounting + host timeline + trace window
 # 3200 rows / batch 64 = 50 steps; the trace window [10, 20) sits in
 # the steady state, after the train program compiled
 python -m xflow_tpu gen-data "$WORK/train" --shards 1 --rows 3200 \
@@ -68,10 +70,19 @@ for c in comp:
     assert c["bytes_accessed"] and c["bytes_accessed"] > 0, \
         f"no bytes: {c['program']}"
     assert c.get("op_scopes"), f"no op_scopes map: {c['program']}"
-wins = [r for r in recs if "achieved_flops_per_s" in r]
-assert wins, "no roofline gauges in any window record"
+    assert abs(c["lower_s"] + c["xla_compile_s"] - c["compile_time_s"]) < 1e-5, \
+        f"lower_s + xla_compile_s != compile_time_s: {c['program']}"
+wins = [r for r in recs if "step_time_p50_ms" in r and not r.get("kind")]
+assert wins, "no window records in the run"
+for w in wins:
+    host = w.get("host")
+    assert host, f"window record at step {w.get('step')} has no host fields"
+    for key in ("parse_ms", "plan_ms", "producer_wait_ms", "transfer_ms",
+                "dispatch_call_ms", "prev_ready_ms", "batches"):
+        assert key in host, f"host lacks {key}"
+assert "fit_open_ms" in wins[0].get("boundary", {}), "first window has no boundary"
 print(f"smoke_perf: {len(comp)} compile record(s), "
-      f"roofline gauges in {len(wins)} window(s)")
+      f"host timeline in {len(wins)} window(s)")
 EOF
 
 # ---- 3. trace attribution from the run's own trace window -----------------

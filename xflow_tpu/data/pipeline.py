@@ -251,8 +251,8 @@ def batch_iterator(
     first `skip` batches (checkpointed data_state resume,
     `skip_batches`) — skipped batches are neither monitored nor
     quarantined; they were already, in the run being resumed.
-    `profiler` (telemetry.PipelineProfiler) attributes per-stage wall
-    time; None = the exact historical path."""
+    `profiler` (an armed run's telemetry.PipelineProfiler) accumulates
+    per-stage wall time; the `xflow:` spans open with or without it."""
     raw = _raw_batch_iterator(path, cfg, batch_size, profiler=profiler)
     if skip > 0:
         raw = skip_batches(raw, skip)
@@ -351,7 +351,7 @@ def _raw_batch_iterator(
     batch_size: Optional[int] = None,
     profiler=None,
 ) -> Iterator[SparseBatch]:
-    from xflow_tpu.telemetry import default_registry
+    from xflow_tpu.telemetry import default_registry, span
 
     bs = batch_size or cfg.batch_size
     cached = _cache_batch_iterator(path, cfg, bs, profiler=profiler)
@@ -373,20 +373,16 @@ def _raw_batch_iterator(
             _warn_python_parser(e)
         if native_iter is not None:
             default_registry().counter("data.parser_native_shards").inc()
-            if profiler is None:
-                yield from native_iter
-                return
             # the C parser does read+parse+hash+assembly+pad inside one
-            # next_batch call — attributed as "parse", the honest
+            # next_batch call — one "parse" span a batch, the honest
             # resolution this path offers (docs/OBSERVABILITY.md)
-            pc = time.perf_counter
             while True:
-                t0 = pc()
-                b = next(native_iter, None)
-                profiler.add("parse", pc() - t0)
+                with span("parse", profiler):
+                    b = next(native_iter, None)
                 if b is None:
                     return
-                profiler.count_batch(b.num_rows)
+                if profiler is not None:
+                    profiler.count_batch(b.num_rows)
                 yield b
     default_registry().counter("data.parser_python_shards").inc()
     yield from examples_to_batches(
@@ -438,29 +434,27 @@ def prefetch(
     forever, leaking one thread (and pinning its batch buffers) per
     abandoned epoch.
 
-    `profiler` (telemetry.PipelineProfiler) exposes the queue's
-    counters: time the WORKER spends blocked in `q.put` is
-    `producer_wait` (the consumer/device is the bottleneck —
-    cumulative in the `pipeline.producer_blocked_s` gauge), and both
-    sides sample `q.qsize()` into the `pipeline.queue_depth` gauge.
-    The CONSUMER-side starvation signal (`queue_wait`) is attributed by
-    the fit loop as the batch's full data-wait — not here — so the
-    consumer stages tile the loop with nothing counted twice. None =
-    the exact historical path."""
+    Time the WORKER spends blocked in `q.put` is the `producer_wait`
+    span (the consumer/device is the bottleneck), and the consumer's
+    teardown — drain, the worker's `join` — is `iter_end`, inside the
+    pass's terminating `next()`. `profiler` (an armed run's
+    telemetry.PipelineProfiler) accumulates the former (cumulative in
+    the `pipeline.producer_blocked_s` gauge where it publishes) and
+    takes both sides' `q.qsize()` samples. The CONSUMER-side starvation
+    signal (`data_wait`) is the fit loop's — not here — so the consumer
+    stages tile the loop with nothing counted twice."""
+    from xflow_tpu.telemetry import span
+
     q: queue.Queue = queue.Queue(maxsize=depth)
     _END = object()
     stop = threading.Event()
 
     def worker() -> None:
-        pc = time.perf_counter
         try:
             for item in iterator:
-                if profiler is None:
+                with span("producer_wait", profiler):
                     q.put(item)
-                else:
-                    t0 = pc()
-                    q.put(item)
-                    profiler.add("producer_wait", pc() - t0)
+                if profiler is not None:
                     profiler.observe_queue(q.qsize(), depth)
                 if stop.is_set():
                     return
@@ -486,16 +480,18 @@ def prefetch(
                 raise item
             yield item
     finally:
-        stop.set()
-        # unblock a worker stuck in q.put: after the drain there is at
-        # least one free slot, so its pending put completes, it sees the
-        # flag, and exits (putting at most one more item, which fits)
-        while True:
-            try:
-                q.get_nowait()
-            except queue.Empty:
-                break
-        t.join(timeout=10.0)
+        with span("iter_end"):
+            stop.set()
+            # unblock a worker stuck in q.put: after the drain there is
+            # at least one free slot, so its pending put completes, it
+            # sees the flag, and exits (putting at most one more item,
+            # which fits)
+            while True:
+                try:
+                    q.get_nowait()
+                except queue.Empty:
+                    break
+            t.join(timeout=10.0)
 
 
 # --------------------------------------------------------------- streaming

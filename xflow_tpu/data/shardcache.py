@@ -394,8 +394,11 @@ class ShardCache:
         — the whole point); the final partial batch is the one copy,
         padded exactly like `make_batch` pads it (zeros beyond the real
         rows), so cache batches are bitwise-equal to text batches.
-        `profiler` attributes slice construction to the `cache_read`
-        stage (telemetry.PIPELINE_PRODUCER_STAGES)."""
+        Each batch's construction is one `cache_read` span
+        (telemetry.HOST_SPANS); `profiler`, an armed run's
+        PipelineProfiler, accumulates them."""
+        from xflow_tpu.telemetry import span
+
         mms = self.arrays()
         slots, fields, mask, labels = (
             mms["slots"], mms["fields"], mms["mask"], mms["labels"],
@@ -403,28 +406,16 @@ class ShardCache:
         B = int(batch_size)
         full, rem = self.rows // B, self.rows % B
         ones = np.ones((B,), np.float32)
-        if profiler is None:
-            for i in range(full):
-                s = slice(i * B, (i + 1) * B)
-                yield SparseBatch(slots[s], fields[s], mask[s], labels[s], ones)
-            if rem and not drop_remainder:
-                yield self._tail_batch(B, full * B, rem)
-            return
-        import time
-
-        pc = time.perf_counter
-        for i in range(full):
-            t0 = pc()
-            s = slice(i * B, (i + 1) * B)
-            b = SparseBatch(slots[s], fields[s], mask[s], labels[s], ones)
-            profiler.add("cache_read", pc() - t0)
-            profiler.count_batch(B)
-            yield b
-        if rem and not drop_remainder:
-            t0 = pc()
-            b = self._tail_batch(B, full * B, rem)
-            profiler.add("cache_read", pc() - t0)
-            profiler.count_batch(rem)
+        tail = bool(rem) and not drop_remainder
+        for i in range(full + tail):
+            with span("cache_read", profiler):
+                if i < full:
+                    s = slice(i * B, (i + 1) * B)
+                    b = SparseBatch(slots[s], fields[s], mask[s], labels[s], ones)
+                else:
+                    b = self._tail_batch(B, full * B, rem)
+            if profiler is not None:
+                profiler.count_batch(B if i < full else rem)
             yield b
 
     def _tail_batch(self, B: int, start: int, n: int) -> SparseBatch:

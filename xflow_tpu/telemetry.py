@@ -19,6 +19,15 @@ aggregate. This module is the first-class instrumentation layer:
   current step's dispatch, the same hide-under-device-time trick the
   non-finite guard's flag read uses (train/trainer.py check_pending),
   so the instrumentation adds no sync bubble to the pipeline.
+- `span`: the ONE seam the host side of `xflow train` is timed
+  through. `with span("parse", profiler):` opens a
+  `jax.profiler.TraceAnnotation("xflow:parse")` — which puts the span
+  on the profiler's own clock, beside the device's operations, whenever
+  a trace session is open, and is a flag test when none is — and, when
+  a `PipelineProfiler` is given (the run is armed: somebody listens),
+  adds the `perf_counter` duration to its window. `HOST_SPANS` is the
+  vocabulary; the step records' `host` fields come from the same
+  strings.
 - `TraceWindow`: a programmatic xprof trace window
   (`train.trace_start_step` / `train.trace_num_steps`) captured mid-run
   after compilation settles, replacing the blunt whole-run
@@ -27,9 +36,10 @@ aggregate. This module is the first-class instrumentation layer:
   the four engine builders (train/step.py, parallel/train_step.py,
   parallel/sorted_sharded.py, parallel/sorted_fullshard.py) and the
   serve runner routes its jit through `wrap()`, which lowers and
-  compiles explicitly (`.lower().compile()`), times the compile, and
-  extracts XLA's cost analysis (FLOPs, bytes accessed) and memory
-  analysis (argument/output/temp/generated bytes) into one stamped
+  compiles explicitly (`.lower()`, then `.compile()`, timed apart as
+  `lower_s` / `xla_compile_s`), and extracts XLA's cost analysis
+  (FLOPs, bytes accessed) and memory analysis
+  (argument/output/temp/generated bytes) into one stamped
   `kind="compile"` JSONL record per program — plus the {HLO op ->
   named_scope} map tools/trace_attrib.py joins against an xprof trace.
 - `device_memory_stats` / `hbm_window_fields`: runtime HBM gauges
@@ -468,8 +478,7 @@ class CompileRecorder:
     and compiles EXPLICITLY — the same executable jit would have built
     lazily on that call — timing the compile and extracting
 
-    - `cost_analysis()`: model FLOPs and bytes accessed per execution
-      (the roofline numerators the StepTimer's window gauges consume),
+    - `cost_analysis()`: model FLOPs and bytes accessed per execution,
     - `memory_analysis()`: argument/output/temp/generated code bytes
       (the compile-time HBM footprint),
     - `op_scopes`: {optimized-HLO op name -> named_scope label} scraped
@@ -645,17 +654,23 @@ class CompileRecorder:
                 hits.append(event)
 
         jax.monitoring.register_event_listener(on_event)
-        t0 = time.perf_counter()
         try:
-            compiled = jitted.lower(*args, **kwargs).compile()
+            # tracing and lowering are Python's and happen on every run;
+            # the compile is XLA's, or a read from the persistent cache
+            with span("lower") as lowering:
+                lowered = jitted.lower(*args, **kwargs)
+            with span("compile") as compiling:
+                compiled = lowered.compile()
         finally:
             jax.monitoring.unregister_event_listener(on_event)
-        dt = time.perf_counter() - t0
+        dt = lowering.seconds + compiling.seconds
         rec = {
             "kind": "compile",
             "program": name,
             "sig": sig,
             "compile_time_s": round(dt, 6),
+            "lower_s": round(lowering.seconds, 6),
+            "xla_compile_s": round(compiling.seconds, 6),
             "cache_hit": bool(hits),
         }
         rec.update(self._cost_fields(compiled))
@@ -706,18 +721,6 @@ class CompileRecorder:
         with self._lock:
             return self._latest.get(name)
 
-    def latest_cost(self, prefix: str = "train_step") -> Optional[dict]:
-        """{"flops", "bytes"} per execution of the newest compiled
-        program whose name starts with `prefix` — what the trainer
-        feeds the StepTimer's roofline gauges. None until such a
-        program compiled with usable cost analysis."""
-        with self._lock:
-            recs = list(self.records)
-        for rec in reversed(recs):
-            if rec["program"].startswith(prefix) and rec.get("flops"):
-                return {"flops": rec["flops"], "bytes": rec.get("bytes_accessed") or 0}
-        return None
-
     @property
     def recompiles(self) -> int:
         with self._lock:
@@ -759,13 +762,18 @@ class StepTimer:
       - data_wait_s: time spent inside the iterator's next()
       - dispatch_s:  fetch end -> dispatch return (plan resolve, host
         transfer, async dispatch)
-      - device_s:    dispatch return -> metrics ready. When the device
-        is the bottleneck this is the device step time; when the host
-        is, the block returns immediately and it degrades to the
-        pipeline interval — an upper bound, never an undercount.
+      - device_s:    dispatch return -> metrics ready. HOST time: when
+        the device is the bottleneck it is the device step time; when
+        the host is, the block returns immediately and it degrades to
+        the pipeline interval — an upper bound, never an undercount.
       - step_s:      completion-to-completion interval. These telescope,
         so their sum over a run equals the elapsed wall time (the
         decomposition tests' invariant).
+
+    One timer lives for one `fit()`. What lies outside its intervals —
+    `_fit` entry to `first_fetch`, and `last_ready` to `fit()`'s return
+    — the trainer stamps itself and reports as the step records'
+    `boundary` (docs/OBSERVABILITY.md "The pass boundary").
     """
 
     def __init__(self, registry: Optional[Registry] = None):
@@ -779,23 +787,35 @@ class StepTimer:
         self._win_start = time.perf_counter()
         self.steps = 0
         self.rows = 0
+        # perf_counter instant the iterator's first next() was called:
+        # where the first step's interval starts
+        self.first_fetch: Optional[float] = None
+        # seconds inside the terminating next() calls (one an epoch):
+        # prefetch teardown and join, parser close
+        self.iter_end_s = 0.0
 
     def batches(self, iterable: Iterable) -> Iterator:
         """Wrap the batch iterator so time spent INSIDE next() — and
-        only that — is the step's data-wait. Abandonment (an early
-        break / exception in the consuming loop) closes the wrapped
-        iterator promptly, preserving the prefetch worker's
-        close-cascade contract (data/pipeline.py prefetch)."""
+        only that — is the step's data-wait (`xflow:data_wait`; a
+        pass's first is the pipeline's cold start, its last the
+        terminating call, which yields no step and counts as
+        `iter_end_s`). Abandonment (an early break / exception in the
+        consuming loop) closes the wrapped iterator promptly, preserving
+        the prefetch worker's close-cascade contract (data/pipeline.py
+        prefetch)."""
         it = iter(iterable)
+        end = object()
         try:
             while True:
-                t0 = time.perf_counter()
-                try:
-                    item = next(it)
-                except StopIteration:
+                with span("data_wait") as wait:
+                    item = next(it, end)
+                if self.first_fetch is None:
+                    self.first_fetch = wait.t0
+                if item is end:
+                    self.iter_end_s += wait.seconds
                     return
-                self._wait_end = time.perf_counter()
-                self._last_wait = self._wait_end - t0
+                self._wait_end = wait.t1
+                self._last_wait = wait.seconds
                 yield item
         finally:
             close = getattr(it, "close", None)
@@ -805,8 +825,7 @@ class StepTimer:
     @property
     def last_wait(self) -> float:
         """The just-fetched batch's data-wait seconds (time inside the
-        iterator's next()) — the pipeline profiler re-attributes it as
-        its consumer-side queue_wait stage."""
+        iterator's next())."""
         return self._last_wait
 
     @property
@@ -814,6 +833,13 @@ class StepTimer:
         """perf_counter timestamp of the just-fetched batch's fetch end
         (None before the first fetch)."""
         return self._wait_end
+
+    @property
+    def last_ready(self) -> Optional[float]:
+        """perf_counter instant the newest finished step's metrics were
+        ready (None before a step has finished): where the step
+        intervals end."""
+        return self._last_ready
 
     def dispatched(self, metrics, rows: int) -> None:
         """Call right after the step's async dispatch returns. Finishes
@@ -857,19 +883,10 @@ class StepTimer:
         self._reg.timer("step.time").observe(t_ready - base)
         self._reg.timer("step.data_wait").observe(wait_s)
 
-    def window_record(self, cost: Optional[dict] = None) -> dict:
+    def window_record(self) -> dict:
         """Stats over the steps finished since the last call, then reset
         the window. Empty dict when no step has finished yet (the very
-        first log tick under log_every=1 — timing runs one behind).
-
-        `cost` = {"flops", "bytes"} per step execution (the train
-        program's cost analysis, CompileRecorder.latest_cost): when
-        given, the record additionally carries the measured roofline
-        gauges — achieved model FLOP/s and HBM GB/s over the window's
-        DEVICE time (docs/PERF.md "Measured roofline"). On host-bound
-        runs device_s degrades to the pipeline interval (an upper
-        bound), so these gauges are LOWER bounds on what the device
-        sustains — never flattery."""
+        first log tick under log_every=1 — timing runs one behind)."""
         w = self._win
         n = len(w["step"])
         if n == 0:
@@ -886,14 +903,6 @@ class StepTimer:
             "dispatch_ms": round(float(np.mean(w["dispatch"])) * 1e3, 3),
             "device_ms": round(float(np.mean(w["device"])) * 1e3, 3),
         }
-        if cost:
-            device_s = float(np.sum(w["device"]))
-            flops = cost.get("flops") or 0
-            byts = cost.get("bytes") or 0
-            if device_s > 0 and flops:
-                rec["achieved_flops_per_s"] = round(n * flops / device_s, 1)
-            if device_s > 0 and byts:
-                rec["achieved_hbm_gbps"] = round(n * byts / device_s / 1e9, 4)
         self._win = {"step": [], "wait": [], "dispatch": [], "device": []}
         self._win_rows = 0
         self._win_start = now
@@ -904,9 +913,9 @@ class StepTimer:
         return rec
 
 
-# ---------------------------------------------------------- PipelineProfiler
+# ------------------------------------------- host timeline: spans + profiler
 
-# the stage vocabulary of the input-pipeline profiler
+# the stage vocabulary of the kind="pipeline" window record
 # (train.pipeline_metrics, docs/OBSERVABILITY.md "Input-pipeline
 # attribution"). Two CONCURRENT timelines — the prefetch/producer
 # thread and the fit-loop/consumer thread — so the schema invariant
@@ -927,13 +936,39 @@ PIPELINE_CONSUMER_STAGES = (
     "queue_wait",  # the batch's full data-wait (time inside next(); the
     # prefetch get() is the starved part — producer starvation signal)
     "transfer",  # host->device staging (trainer._shard_batch)
-    "dispatch",  # fetch end -> async-dispatch return, minus transfer
+    "dispatch",  # every other host-side slice of the step: the
+    # train_step call plus the loop's bookkeeping between spans, so the
+    # four consumer stages TILE the fit loop
     "device",  # block on the PREVIOUS step's metrics (one-step-behind)
 )
 PIPELINE_STAGES = PIPELINE_PRODUCER_STAGES + PIPELINE_CONSUMER_STAGES
 
-# the host-side stages a bottleneck verdict can name (producer work;
-# producer_wait is the device-bound signal, not a host cost).
+# the `xflow:` spans, by the thread that opens them (docs/OBSERVABILITY.md
+# "The host timeline" says what each covers). The annotation's name and
+# the step records' `host` field come from the same string (`span`,
+# `host_field`), so the trace and the records cannot drift.
+HOST_SPANS_FIT = (
+    "fit", "fit_open", "data_wait", "transfer", "dispatch", "prev_ready",
+    "iter_end", "fit_flush", "occupancy", "fit_close",
+)
+HOST_SPANS_PREFETCH = ("parse", "cache_read", "plan", "producer_wait")
+HOST_SPANS_INIT = ("init_state", "lower", "compile")
+HOST_SPANS = HOST_SPANS_FIT + HOST_SPANS_PREFETCH + HOST_SPANS_INIT
+_SPAN_NAMES = {s: "xflow:" + s for s in HOST_SPANS}
+
+# what an armed profiler's window accumulates: the producer's stages
+# (the per-batch prefetch spans and the Python parser's per-line sums,
+# which have no annotation) and the fit loop's per-step spans, plus
+# `loop_other` — the loop's own bookkeeping between them (health reads,
+# guard checks, log writes), by subtraction, so the five tile the loop
+HOST_CONSUMER_STAGES = (
+    "data_wait", "transfer", "dispatch", "prev_ready", "loop_other",
+)
+HOST_STAGES = PIPELINE_PRODUCER_STAGES + HOST_CONSUMER_STAGES
+
+# the host-side stages a bottleneck verdict can name, and the
+# benchmark's producer_busy_pct sums (producer work; producer_wait is
+# the device-bound signal, not a host cost).
 # cache_read is here on purpose: a run whose producer wall is dominated
 # by cache slicing is cache-bound — the verdict should say so, both as
 # the healthy after-picture of the packed-shard-cache path and as the
@@ -941,6 +976,87 @@ PIPELINE_STAGES = PIPELINE_PRODUCER_STAGES + PIPELINE_CONSUMER_STAGES
 _PIPELINE_HOST_STAGES = (
     "read", "parse", "hash", "batch", "pad", "cache_read", "plan",
 )
+
+# jax.profiler.TraceAnnotation, looked up on the first span; a test puts
+# a recorder here (the seam TraceWindow's `profiler` argument is for it)
+_annotation = None
+
+
+def _resolve_annotation():
+    global _annotation
+    if _annotation is None:
+        import jax
+
+        _annotation = jax.profiler.TraceAnnotation
+    return _annotation
+
+
+class span:
+    """`with span("plan", profiler):` — one occurrence of a named stage
+    of the host timeline.
+
+    Always: a `jax.profiler.TraceAnnotation("xflow:<stage>")`, which
+    lands in the profiler's host plane on the same clock as the
+    device's operations whenever a trace session is open
+    (`TraceWindow`, or anybody's `jax.profiler.start_trace`), and is a
+    flag test otherwise; and the two `perf_counter` stamps `t0` / `t1`
+    (`seconds` after exit), taken inside the annotation. With a
+    `profiler` (an armed run's `PipelineProfiler`) the duration is also
+    added to its window. Never hold one open across a `yield`: the
+    annotation stack is the thread's."""
+
+    __slots__ = ("stage", "_prof", "_ann", "t0", "t1")
+
+    def __init__(self, stage: str, profiler: "Optional[PipelineProfiler]" = None):
+        self.stage = stage
+        self._prof = profiler
+        self._ann = (_annotation or _resolve_annotation())(_SPAN_NAMES[stage])
+
+    def __enter__(self) -> "span":
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        if self._prof is not None:
+            self._prof.add(self.stage, self.t1 - self.t0)
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.t1 - self.t0
+
+
+def host_field(stage: str) -> str:
+    """The step records' `host` field of a stage. The train_step call's
+    is `dispatch_call_ms`: `dispatch_ms` is taken, one level up, by the
+    StepTimer's wider interval (fetch end -> dispatch return)."""
+    return ("dispatch_call" if stage == "dispatch" else stage) + "_ms"
+
+
+def host_fields(win: dict) -> dict:
+    """A profiler window as the step records' `host` payload: the
+    window's sum of every stage, in ms, and its batches."""
+    out = {host_field(s): round(win[s] * 1e3, 3) for s in HOST_STAGES}
+    out["batches"] = win["batches"]
+    return out
+
+
+def pipeline_fields(win: dict) -> dict:
+    """A profiler window as the kind="pipeline" payload: the consumer's
+    stages folded into the four that tile the fit loop."""
+    rec = {"wall_s": round(win["wall_s"], 6)}
+    for s in PIPELINE_PRODUCER_STAGES:
+        rec[f"{s}_s"] = round(win[s], 6)
+    rec["queue_wait_s"] = round(win["data_wait"], 6)
+    rec["transfer_s"] = round(win["transfer"], 6)
+    rec["dispatch_s"] = round(win["dispatch"] + win["loop_other"], 6)
+    rec["device_s"] = round(win["prev_ready"], 6)
+    for k in ("batches", "rows", "queue_depth", "queue_cap"):
+        rec[k] = win[k]
+    return rec
 
 
 def pipeline_verdict(stages: dict, wall_s: float) -> str:
@@ -976,38 +1092,40 @@ def pipeline_verdict(stages: dict, wall_s: float) -> str:
 
 
 class PipelineProfiler:
-    """Per-stage wall-time attribution for the input pipeline
-    (train.pipeline_metrics, docs/OBSERVABILITY.md "Input-pipeline
-    attribution").
+    """The window an armed run's spans accumulate into: per-stage wall
+    time of the input pipeline and the fit loop (docs/OBSERVABILITY.md
+    "Input-pipeline attribution").
 
-    The profiler is the OPT-IN seam the pipeline threads its timing
-    through: libffm reading/parsing/hashing and batch assembly/padding
-    (data/libffm.py, data/pipeline.py), the prefetch queue's two
-    starvation signals + depth gauge (data/pipeline.prefetch), the
-    sorted-plan build (trainer._with_arrays), and the consumer side's
-    transfer/dispatch/device split (the fit loop). With the profiler
-    off (`None` everywhere) every instrumented call site takes its
-    exact pre-profiler code path, so profiler-off JSONL streams are
-    byte-identical to a pre-profiler build — the zero-overhead-when-off
-    contract tests/test_hotpath.py pins.
+    The trainer builds one when somebody listens — `train.metrics_path`
+    (the step records' `host`), `train.profile_dir`, or
+    `train.pipeline_metrics` — and hands it to the seams: `span(stage,
+    profiler)` on both threads, `add_many` from the Python parser's
+    per-line loop (data/libffm.py, data/pipeline.py), `count_batch` /
+    `observe_queue` from the parser and the prefetch queue. Unarmed
+    there is no profiler, the same spans run with `None`, and nothing
+    accumulates. `publish` (train.pipeline_metrics) is the one switch
+    for the stream's pipeline surface: the `pipeline.*` registry gauges
+    here and the trainer's kind="pipeline" window records; without it a
+    run has neither.
 
     Same one-step-behind philosophy as the StepTimer: nothing here ever
-    blocks the device — the `device` stage is the fit loop's existing
-    block on the PREVIOUS step's metrics, re-attributed, and all other
-    stages time host work that was already on the critical path.
+    blocks the device — `prev_ready` is the fit loop's existing block
+    on the PREVIOUS step's metrics, and all other stages time host work
+    that was already on the critical path.
 
     Threading: producer stages are fed from the prefetch thread,
-    consumer stages from the fit loop; `add`/`window_record` take one
+    consumer stages from the fit loop; `add`/`take_window` take one
     lock, and the hot per-line Python-parser path batches its
     accumulations (`add_many` every few hundred lines) so the lock is
     touched per batch, not per row.
     """
 
-    def __init__(self, registry: Optional[Registry] = None):
+    def __init__(self, registry: Optional[Registry] = None, publish: bool = True):
         self._reg = registry or default_registry()
+        self.publish = bool(publish)
         self._lock = threading.Lock()
-        self._win = {s: 0.0 for s in PIPELINE_STAGES}
-        self._total = {s: 0.0 for s in PIPELINE_STAGES}
+        self._win = {s: 0.0 for s in HOST_STAGES}
+        self._total = {s: 0.0 for s in HOST_STAGES}
         self._win_batches = 0
         self._win_rows = 0
         self.batches = 0
@@ -1020,21 +1138,22 @@ class PipelineProfiler:
     def start(self) -> None:
         """Re-anchor the clock at fit() start (the profiler is built at
         Trainer construction; state init must not count as pipeline
-        wall) and pre-register the prefetch gauges so a profiled run's
+        wall) and pre-register the prefetch gauges so a published run's
         counters snapshot carries them even before the first sample."""
         with self._lock:
             now = time.perf_counter()
             self._start = now
             self._win_start = now
-        self._reg.gauge("pipeline.queue_depth").set(0)
-        self._reg.gauge("pipeline.producer_blocked_s").set(0.0)
+        if self.publish:
+            self._reg.gauge("pipeline.queue_depth").set(0)
+            self._reg.gauge("pipeline.producer_blocked_s").set(0.0)
 
     # ------------------------------------------------------- accumulation
     def add(self, stage: str, seconds: float) -> None:
         with self._lock:
             self._win[stage] += seconds
             total = self._total[stage] = self._total[stage] + seconds
-        if stage == "producer_wait":
+        if stage == "producer_wait" and self.publish:
             # the producer-blocked run total as a live gauge: the
             # consumer-is-the-bottleneck signal an operator can watch
             # without waiting for a window record
@@ -1046,21 +1165,6 @@ class PipelineProfiler:
                 self._win[stage] += seconds
                 self._total[stage] += seconds
 
-    def stage(self, name: str):
-        """Context manager timing one stage occurrence."""
-        prof = self
-
-        class _Ctx:
-            def __enter__(self):
-                self._t0 = time.perf_counter()
-                return self
-
-            def __exit__(self, *exc):
-                prof.add(name, time.perf_counter() - self._t0)
-                return False
-
-        return _Ctx()
-
     def count_batch(self, rows: int) -> None:
         with self._lock:
             self._win_batches += 1
@@ -1070,10 +1174,10 @@ class PipelineProfiler:
 
     def close(self) -> None:
         """Drop this profiler's registry gauges (fit() teardown): the
-        zero-overhead-when-off contract is per-RUN, not per-process —
-        a later profiler-off fit in the same process must snapshot no
+        no-pipeline-surface contract is per-RUN, not per-process — a
+        later unpublished fit in the same process must snapshot no
         pipeline.* metrics. start() re-registers them on the next
-        profiled fit."""
+        published fit."""
         self._reg.discard("pipeline.queue_depth")
         self._reg.discard("pipeline.producer_blocked_s")
 
@@ -1084,7 +1188,8 @@ class PipelineProfiler:
         with self._lock:
             self._queue_depth = int(depth)
             self._queue_cap = int(cap)
-        self._reg.gauge("pipeline.queue_depth").set(int(depth))
+        if self.publish:
+            self._reg.gauge("pipeline.queue_depth").set(int(depth))
 
     # --------------------------------------------------------- reporting
     def totals(self) -> tuple[dict, float]:
@@ -1094,30 +1199,29 @@ class PipelineProfiler:
         with self._lock:
             return dict(self._total), time.perf_counter() - self._start
 
-    def window_record(self) -> dict:
-        """The kind="pipeline" window payload: wall + per-stage seconds
-        since the last call, then reset. Empty dict when the window saw
-        no activity at all (nothing to attribute — the trainer skips
-        the record rather than emitting zeros)."""
+    def take_window(self) -> dict:
+        """Per-stage seconds since the last call, with `wall_s`,
+        `batches`, `rows` and the queue's last sample, then reset:
+        what `host_fields` and `pipeline_fields` format. Empty dict
+        when the window saw no activity at all (nothing to attribute —
+        the trainer skips the fields rather than writing zeros)."""
         with self._lock:
             if self._win_batches == 0 and not any(
                 v > 0.0 for v in self._win.values()
             ):
                 return {}
             now = time.perf_counter()
-            wall = max(now - self._win_start, 1e-9)
-            rec = {"wall_s": round(wall, 6)}
-            for s in PIPELINE_STAGES:
-                rec[f"{s}_s"] = round(self._win[s], 6)
-                self._win[s] = 0.0
-            rec["batches"] = self._win_batches
-            rec["rows"] = self._win_rows
-            rec["queue_depth"] = self._queue_depth
-            rec["queue_cap"] = self._queue_cap
+            win = dict(self._win)
+            win["wall_s"] = max(now - self._win_start, 1e-9)
+            win["batches"] = self._win_batches
+            win["rows"] = self._win_rows
+            win["queue_depth"] = self._queue_depth
+            win["queue_cap"] = self._queue_cap
+            self._win = {s: 0.0 for s in HOST_STAGES}
             self._win_batches = 0
             self._win_rows = 0
             self._win_start = now
-        return rec
+        return win
 
 
 # ------------------------------------------------------------- HealthMonitor

@@ -11,6 +11,7 @@ one jitted SPMD step; then an eval pass that dumps
 
 from __future__ import annotations
 
+import contextlib
 import os
 import sys
 import time
@@ -39,9 +40,12 @@ from xflow_tpu.telemetry import (
     TraceWindow,
     default_registry,
     hbm_window_fields,
+    host_fields,
     install_stack_dump_handler,
+    pipeline_fields,
     resolve_restart_gen,
     resolve_run_id,
+    span,
 )
 from xflow_tpu.optim import get_optimizer
 from xflow_tpu.train.state import TrainState, init_state
@@ -255,9 +259,10 @@ class Trainer:
 
                 # shard_state's default layout IS the fullshard layout:
                 # every table/opt leaf P(('data','table')) on the slot axis
-                self.state = shard_state(
-                    init_state(self.model, self.optimizer, cfg), mesh
-                )
+                with span("init_state"):
+                    self.state = shard_state(
+                        init_state(self.model, self.optimizer, cfg), mesh
+                    )
                 fullshard_step = make_fullshard_train_step(
                     self.optimizer, cfg, mesh, recorder=_rec
                 )
@@ -291,16 +296,18 @@ class Trainer:
                     shard_sorted_state,
                 )
 
-                self.state = shard_sorted_state(
-                    init_state(self.model, self.optimizer, cfg), mesh
-                )
+                with span("init_state"):
+                    self.state = shard_sorted_state(
+                        init_state(self.model, self.optimizer, cfg), mesh
+                    )
                 self.train_step = make_sorted_sharded_train_step(
                     self.optimizer, cfg, mesh, recorder=_rec
                 )
             else:
-                self.state = shard_state(
-                    init_state(self.model, self.optimizer, cfg), mesh
-                )
+                with span("init_state"):
+                    self.state = shard_state(
+                        init_state(self.model, self.optimizer, cfg), mesh
+                    )
                 self.train_step = make_sharded_train_step(
                     self.model, self.optimizer, cfg, mesh, recorder=_rec
                 )
@@ -329,7 +336,8 @@ class Trainer:
                 self.eval_step = gspmd_eval
             self._shard_batch = lambda b: _shard_batch_arrays(b, mesh)
         else:
-            self.state = init_state(self.model, self.optimizer, cfg)
+            with span("init_state"):
+                self.state = init_state(self.model, self.optimizer, cfg)
             self.train_step = make_train_step(
                 self.model, self.optimizer, cfg, recorder=_rec
             )
@@ -368,16 +376,25 @@ class Trainer:
             ema_decay=cfg.train.health_ema_decay,
             num_slots=cfg.num_slots,
         )
-        # input-pipeline stage profiler (train.pipeline_metrics,
-        # docs/OBSERVABILITY.md "Input-pipeline attribution"): threaded
-        # through the TRAINING stream only (fit passes profiled=True to
-        # _coordinated_batches; eval streams stay unprofiled so a
-        # mid-run holdout pass never muddies the training attribution).
-        # None when off — every instrumented seam then takes its exact
-        # pre-profiler path, keeping off-runs byte-identical.
+        # the host timeline's window (docs/OBSERVABILITY.md "The host
+        # timeline"): armed when somebody listens — the metrics stream
+        # (step records' `host` and `boundary`), a profile, or the
+        # opt-in kind="pipeline" surface, which alone publishes
+        # pipeline records and gauges. None otherwise: the same spans
+        # run and nothing accumulates. Threaded through the TRAINING
+        # stream only (fit passes profiled=True to _coordinated_batches;
+        # eval streams stay unprofiled so a mid-run holdout pass never
+        # muddies the training attribution).
         self.pipeline_prof = (
-            PipelineProfiler() if cfg.train.pipeline_metrics else None
+            PipelineProfiler(publish=cfg.train.pipeline_metrics)
+            if cfg.train.pipeline_metrics
+            or cfg.train.metrics_path
+            or cfg.train.profile_dir
+            else None
         )
+        # the previous fit()'s tail — last step ready, its parts, its
+        # return instant — for the next fit()'s `boundary`
+        self._prev_fit: Optional[dict] = None
         # liveness heartbeat (train.heartbeat_path): tiny {step} records
         # the launcher watchdog and metrics_report --health read to flag
         # dead ranks and stragglers; kind="heartbeat" keeps the stream
@@ -776,14 +793,12 @@ class Trainer:
         overlaps device compute instead of serializing with dispatch.
         Training batches also feed the health monitor's touched-slot
         bitmap here (same overlap argument; eval passes skip it).
-        `profiler` attributes the whole conversion — validation, sorted
-        plan, dedup, array build — as the "plan" stage."""
+        The array build — sorted plan, dedup — is the "plan" span, which
+        `profiler` (armed runs) accumulates."""
         self._check_batch(batch)
         if track_health:
             self._health.observe_batch(batch.slots, batch.mask)
-        if profiler is None:
-            return batch, self._batch_arrays(batch, with_plan=with_plan)
-        with profiler.stage("plan"):
+        with span("plan", profiler):
             arrays = self._batch_arrays(batch, with_plan=with_plan)
         return batch, arrays
 
@@ -938,39 +953,42 @@ class Trainer:
 
         return flag, restore
 
-    def _step_cost(self) -> Optional[dict]:
-        """{"flops", "bytes"} per train-step execution from the newest
-        compiled train program's cost analysis — the roofline numerators
-        the StepTimer's window gauges consume. None until a train
-        program compiled (or with compile accounting off)."""
-        rec = self.compile_recorder
-        return rec.latest_cost("train_step") if rec is not None else None
-
     def fit(self, train_path: Optional[str] = None) -> TrainResult:
-        try:
-            return self._fit(train_path)
-        finally:
-            # drain + stop the async checkpoint writer BEFORE the
-            # metrics sink closes: its final kind="ckpt" records must
-            # land, and fit() returning implies the last submitted save
-            # is durable (or its failure logged)
-            if self._ckpt_writer is not None:
-                self._ckpt_writer.close()
-                self._ckpt_writer = None
-            # release the metrics/heartbeat handles even on abnormal
-            # exit; a later log() on this Trainer transparently reopens
-            # in append mode
-            self.metrics.close()
-            self.heartbeat.close()
-            if self.pipeline_prof is not None:
-                # drop the pipeline.* gauges from the (process-global)
-                # registry so a later profiler-off fit in this process
-                # snapshots no pipeline metrics (per-run zero-overhead
-                # contract); the next profiled fit's start() re-arms
-                self.pipeline_prof.close()
+        entered = time.perf_counter()
+        with span("fit"):
+            try:
+                return self._fit(train_path, entered)
+            finally:
+                # abnormal exits land here with the sinks still open
+                # (the normal path closed them inside its fit_close span)
+                self._close_sinks()
+                if self._prev_fit is not None:
+                    self._prev_fit["returned"] = time.perf_counter()
 
-    def _fit(self, train_path: Optional[str] = None) -> TrainResult:
+    def _close_sinks(self) -> None:
+        """End-of-fit teardown; a second call is a no-op."""
+        # drain + stop the async checkpoint writer BEFORE the metrics
+        # sink closes: its final kind="ckpt" records must land, and
+        # fit() returning implies the last submitted save is durable (or
+        # its failure logged)
+        if self._ckpt_writer is not None:
+            self._ckpt_writer.close()
+            self._ckpt_writer = None
+        # release the metrics/heartbeat handles even on abnormal exit;
+        # a later log() on this Trainer transparently reopens in append
+        # mode
+        self.metrics.close()
+        self.heartbeat.close()
+        if self.pipeline_prof is not None:
+            # drop the pipeline.* gauges from the (process-global)
+            # registry so a later unpublished fit in this process
+            # snapshots no pipeline metrics; the next published fit's
+            # start() re-registers them
+            self.pipeline_prof.close()
+
+    def _fit(self, train_path: Optional[str], entered: float) -> TrainResult:
         cfg = self.cfg
+        prev_fit, self._prev_fit = self._prev_fit, None
         if cfg.data.stream not in ("off", "tail"):
             raise ValueError(
                 f"data.stream={cfg.data.stream!r}: expected 'off' or 'tail'"
@@ -983,6 +1001,10 @@ class Trainer:
             # every existing stream stays byte-identical (the PR 9
             # zero-overhead discipline; pinned by tests/test_freshness).
             return self._fit_tail(train_path)
+        # `_fit` entry -> the batch iterator's first next(): closed where
+        # the first epoch's loop starts
+        fit_open = contextlib.ExitStack()
+        fit_open.enter_context(span("fit_open"))
         res = TrainResult()
         # perf_counter for every DURATION (monotonic — wall clock jumps
         # under NTP slew); the records' `ts` field (JsonlAppender) is the
@@ -997,14 +1019,16 @@ class Trainer:
         steptimer = StepTimer()
         registry = default_registry()
         health = self._health
-        # input-pipeline attribution (train.pipeline_metrics): re-anchor
-        # the profiler clock at fit start so Trainer construction (state
-        # init) never reads as pipeline wall; None when off — the
-        # profiled branches below are then never taken and the record
-        # stream is byte-identical to a pre-profiler build
+        # the host timeline's window: re-anchor its clock at fit start
+        # so Trainer construction (state init) never reads as pipeline
+        # wall. None when nobody listens: the loop below runs the same
+        # spans and stamps, and only the accumulation falls away
         prof = self.pipeline_prof
         if prof is not None:
             prof.start()
+        # the out-of-step parts of this fit(), for its records: `boundary`
+        # rides the first record that carries step timings
+        boundary: Optional[dict] = None
         # operator stack dumps: `kill -USR1 <pid>` prints every thread's
         # stack (main-thread-only; restored in the finally), and the
         # optional no-progress watchdog dumps them automatically when no
@@ -1037,6 +1061,27 @@ class Trainer:
         halted = False
         pending_ok = None  # (metrics, step index) awaiting the flag check
         pending_rec = None  # a log-cadence step's payload, written one behind
+
+        def log_window(rec: dict, at_step: int, win: Optional[dict] = None) -> None:
+            """Write a record that carries step timings, with the host
+            timeline's share of it: the profiler's window (taken now,
+            unless the caller took it earlier) as `host` (armed runs),
+            this fit()'s `boundary` on the first such record, and —
+            where train.pipeline_metrics publishes — the same window
+            again as its OWN kind="pipeline" record
+            (docs/OBSERVABILITY.md "Input-pipeline attribution")."""
+            nonlocal boundary
+            if win is None:
+                win = prof.take_window() if prof is not None else {}
+            if win:
+                rec["host"] = host_fields(win)
+            if boundary is not None and "step_time_p50_ms" in rec:
+                rec["boundary"], boundary = boundary, None
+            self.metrics.log(rec)
+            if win and prof.publish:
+                self.metrics.log(
+                    {"kind": "pipeline", "step": at_step, **pipeline_fields(win)}
+                )
 
         def emit_pending_record() -> None:
             """Write the staged metrics-JSONL record for the last
@@ -1077,10 +1122,8 @@ class Trainer:
             # window stats: rows/s, steps/s, p50/p99 step time,
             # data-wait/dispatch/device decomposition (telemetry.
             # StepTimer) — emitted one step behind, the window now
-            # covers exactly the cadence's finished steps — plus the
-            # measured roofline gauges when the compile recorder knows
-            # the step's cost
-            rec.update(steptimer.window_record(cost=self._step_cost()))
+            # covers exactly the cadence's finished steps
+            rec.update(steptimer.window_record())
             # live HBM gauges (guarded: CPU allocators report nothing
             # and the fields simply stay out)
             rec.update(hbm_window_fields(registry))
@@ -1089,16 +1132,7 @@ class Trainer:
             rec.update(health.window_record())
             if counters:
                 rec["counters"] = counters
-            self.metrics.log(rec)
-            if prof is not None:
-                # the pipeline window rides the same log cadence as its
-                # OWN kind="pipeline" record (schema: docs/
-                # OBSERVABILITY.md "Input-pipeline attribution")
-                prec = prof.window_record()
-                if prec:
-                    self.metrics.log(
-                        {"kind": "pipeline", "step": at_step, **prec}
-                    )
+            log_window(rec, at_step)
 
         def check_pending() -> bool:
             """Consume the PREVIOUS step's update_ok flag. Called right
@@ -1265,11 +1299,12 @@ class Trainer:
                     idx: max(int(skips.get(idx, 0)), 0) for idx, _ in epoch_shards
                 }
                 steps_in_epoch = max(self._shard_pos.values(), default=0)
-                # profiled consumer tiling: the end-of-iteration mark the
-                # next step's dispatch attribution continues from (None =
-                # no gap to claim: epoch start, or a checkpoint/eval just
-                # spent wall that is NOT per-step host work)
-                prof_mark = None
+                # consumer tiling: the end-of-iteration mark the next
+                # step's `loop_other` continues from (None = no gap to
+                # claim: epoch start, or a checkpoint/eval just spent
+                # wall that is NOT per-step host work)
+                lap_mark = None
+                fit_open.close()  # a no-op from the second epoch on
                 # quarantine on the FIRST pass only: later epochs see the
                 # same bad rows again (still counted/enforced), and one
                 # record per bad row beats epochs× duplicates
@@ -1286,50 +1321,45 @@ class Trainer:
                     if step_delay_s:  # drill injector (testing/faults.py)
                         time.sleep(step_delay_s)
                     arrays = self._resolve_fullshard_overflow(batch, arrays)
-                    if prof is None:
+                    with span("transfer", prof) as moved:
                         arrays = self._shard_batch(arrays)
+                    with span("dispatch", prof) as called:
                         self.state, m = self.train_step(self.state, arrays)
-                        # finish the PREVIOUS step's timing: the block on
-                        # its metrics overlaps this step's device
-                        # execution, so neither the timer, the health
-                        # read, nor the guard below adds a bubble
+                    # finish the PREVIOUS step's timing: the block on its
+                    # metrics overlaps this step's device execution, so
+                    # neither the timer, the health read, nor the guard
+                    # below adds a bubble
+                    with span("prev_ready", prof) as ready:
                         steptimer.dispatched(m, batch.num_rows)
-                    else:
-                        # the consumer-side stage split — the SAME calls
-                        # as above with their boundaries stamped (no
-                        # extra sync), TILING the fit loop under the
-                        # StepTimer's own definitions: queue_wait = the
-                        # batch's full data-wait (time inside next()),
-                        # dispatch = every other host-side slice of the
-                        # step (fetch end -> dispatch return minus the
-                        # transfer refinement, plus the previous
-                        # iteration's tail bookkeeping: health reads,
-                        # guard checks, log writes — claimed via
-                        # prof_mark), device = the one-behind metrics
-                        # block. Tiling is what makes the attribution
-                        # coverage hit its >= 95% bar.
-                        t0 = time.perf_counter()
-                        arrays = self._shard_batch(arrays)
-                        t1 = time.perf_counter()
-                        self.state, m = self.train_step(self.state, arrays)
-                        t2 = time.perf_counter()
-                        steptimer.dispatched(m, batch.num_rows)
-                        t3 = time.perf_counter()
-                        wait_end = steptimer.last_wait_end or t0
-                        fetch_start = wait_end - steptimer.last_wait
-                        gap = (
-                            max(fetch_start - prof_mark, 0.0)
-                            if prof_mark is not None
-                            else 0.0
+                    if prof is not None:
+                        # the spans TILE the fit loop under the
+                        # StepTimer's own definitions: data_wait = the
+                        # time inside next(), loop_other = every host-
+                        # side slice between the spans (fetch end ->
+                        # transfer, and the previous iteration's tail
+                        # bookkeeping: health reads, guard checks, log
+                        # writes — claimed via lap_mark). Tiling is what
+                        # makes the attribution coverage hit its >= 95%
+                        # bar.
+                        waited = steptimer.last_wait
+                        lap_start = (
+                            lap_mark
+                            if lap_mark is not None
+                            else steptimer.last_wait_end - waited
                         )
                         prof.add_many({
-                            "queue_wait": steptimer.last_wait,
-                            "transfer": t1 - t0,
-                            "dispatch": (t2 - t1)
-                            + max(t0 - wait_end, 0.0) + gap,
-                            "device": t3 - t2,
+                            "data_wait": waited,
+                            "loop_other": max(
+                                (ready.t1 - lap_start) - waited - moved.seconds
+                                - called.seconds - ready.seconds,
+                                0.0,
+                            ),
                         })
-                        prof_mark = t3
+                        if res.steps == 0:
+                            boundary = self._boundary(
+                                prev_fit, entered, steptimer, called.t1
+                            )
+                    lap_mark = ready.t1
                     # the previous step's metrics are ready now — the
                     # health scalars (norms, loss for the EMA) read free
                     health.collect()
@@ -1407,8 +1437,8 @@ class Trainer:
                         hang.tick()  # a slow collective save is progress
                         # a (possibly minutes-long) save is NOT per-step
                         # host work: drop the tiling mark so the next
-                        # step's dispatch never claims it
-                        prof_mark = None
+                        # step's loop_other never claims it
+                        lap_mark = None
                     if (
                         self._syncer is not None
                         and cfg.sync.every_steps
@@ -1420,7 +1450,7 @@ class Trainer:
                         # leaves a boundary-committed checkpoint behind)
                         run_sync_round()
                         # a bounded wait is not per-step host work either
-                        prof_mark = None
+                        lap_mark = None
                     if kill_step and res.steps == kill_step:
                         # elastic-recovery drill (testing/faults.py):
                         # SIGKILL AFTER the checkpoint cadence above, so
@@ -1510,6 +1540,17 @@ class Trainer:
                         file=sys.stderr,
                     )
                     break
+            # the final step's timing is still in flight (one behind);
+            # this block is the single end-of-data sync the timer adds —
+            # the guard's flag, the loss and the health monitor's tail
+            # collect below read ready buffers behind it
+            fit_open.close()  # still open after a resume past the last epoch
+            with span("fit_flush") as flushed:
+                steptimer.flush()
+            if prof is not None:
+                # the last step's metrics block is a wait for the device
+                # like every step's before it
+                prof.add("prev_ready", flushed.seconds)
             # the last step's flag is still pending after the data ends
             if not halted and check_pending():
                 halted = True
@@ -1567,30 +1608,15 @@ class Trainer:
             dump_restore()
             hang.close()
             trace.close()
-        # the final step's timing is still in flight (one behind); this
-        # block is the single end-of-data sync the timer adds — the
-        # health monitor's tail collect rides the same block
-        if prof is None:
-            steptimer.flush()
-            health.flush()
-            # a record staged on the run's final step has no successor
-            # dispatch to hide behind; the flush above just paid its
-            # one end-of-data sync, so these reads are free too
-            emit_pending_record()
-        else:
-            t0 = time.perf_counter()
-            steptimer.flush()
-            health.flush()
-            # the last step's metrics block belongs to its device stage
-            prof.add("device", time.perf_counter() - t0)
-            emit_pending_record()  # consumes the tail pipeline window too
-            prec = prof.window_record()
-            if prec:
-                # the tail pipeline window, BEFORE the occupancy sweep
-                # below — post-loop host work is not pipeline wall
-                self.metrics.log(
-                    {"kind": "pipeline", "step": res.steps, **prec}
-                )
+        health.flush()
+        # a record staged on the run's final step has no successor
+        # dispatch to hide behind; the flush above paid the one
+        # end-of-data sync, so these reads are free too
+        emit_pending_record()
+        # what the window holds past the last log tick, for the final
+        # record: taken BEFORE the occupancy sweep below — post-loop host
+        # work is not pipeline wall
+        tail_win = prof.take_window() if prof is not None else {}
         res.seconds = time.perf_counter() - start
         # final sync boundary: publish the tail block's delta and fold
         # in whatever peers have landed, so the state this fit returns
@@ -1606,38 +1632,76 @@ class Trainer:
         # signal; untouched slots keep their build-time init, so a
         # nonzero count would read ~1.0 for randomly-initialized v tables.
         specs = self.model.table_specs(cfg)
-        for name, t in self.state.tables.items():
-            st = self.state.opt_state.get(name)
-            if isinstance(st, dict) and "n" in st:
-                touched = st["n"] > 0
-            else:
-                # stateless optimizer (SGD): a touched slot has moved off
-                # its build-time init (0 for scalar tables, v_init_sgd for
-                # vector tables — models/base.py init_tables)
-                touched = t != (cfg.optim.v_init_sgd if t.ndim > 1 else 0.0)
-            if touched.ndim > 1:
-                touched = _slot_any(touched, specs[name][0])
-            res.occupancy[name] = float(jnp.mean(touched))
-        final_rec = {
-            "final": True,
-            "steps": res.steps,
-            "examples": res.examples,
-            "elapsed_s": round(res.seconds, 3),
-            "occupancy": res.occupancy,
-        }
-        # tail window (steps since the last log tick) + run-total counters
-        final_rec.update(steptimer.window_record(cost=self._step_cost()))
-        final_rec.update(hbm_window_fields(registry))
-        final_rec.update(health.window_record())
-        counters = registry.snapshot()
-        if counters:
-            final_rec["counters"] = counters
-        self.metrics.log(final_rec)
-        self.heartbeat.append({"event": "final", "step": res.steps})
-        if cfg.train.checkpoint_dir:
-            # the run's terminal state must be durable when fit returns
-            self.save_checkpoint(wait=True)
+        with span("occupancy") as swept:
+            for name, t in self.state.tables.items():
+                st = self.state.opt_state.get(name)
+                if isinstance(st, dict) and "n" in st:
+                    touched = st["n"] > 0
+                else:
+                    # stateless optimizer (SGD): a touched slot has moved
+                    # off its build-time init (0 for scalar tables,
+                    # v_init_sgd for vector tables — models/base.py
+                    # init_tables)
+                    touched = t != (cfg.optim.v_init_sgd if t.ndim > 1 else 0.0)
+                if touched.ndim > 1:
+                    touched = _slot_any(touched, specs[name][0])
+                res.occupancy[name] = float(jnp.mean(touched))
+        with span("fit_close") as closed:
+            final_rec = {
+                "final": True,
+                "steps": res.steps,
+                "examples": res.examples,
+                "elapsed_s": round(res.seconds, 3),
+                "occupancy": res.occupancy,
+            }
+            # tail window (steps since the last log tick) + run-total counters
+            final_rec.update(steptimer.window_record())
+            final_rec.update(hbm_window_fields(registry))
+            final_rec.update(health.window_record())
+            if prof is not None:
+                # this fit()'s own end: the terminating next(), the wait
+                # for the last step, the sweep (the close is still
+                # running; the next fit()'s `boundary` has it)
+                final_rec["iter_end_ms"] = round(steptimer.iter_end_s * 1e3, 3)
+                final_rec["fit_flush_ms"] = round(flushed.seconds * 1e3, 3)
+                final_rec["occupancy_ms"] = round(swept.seconds * 1e3, 3)
+            counters = registry.snapshot()
+            if counters:
+                final_rec["counters"] = counters
+            log_window(final_rec, res.steps, tail_win)
+            self.heartbeat.append({"event": "final", "step": res.steps})
+            if cfg.train.checkpoint_dir:
+                # the run's terminal state must be durable when fit returns
+                self.save_checkpoint(wait=True)
+            self._close_sinks()
+        if prof is not None:
+            self._prev_fit = {
+                "ready": steptimer.last_ready or flushed.t1,
+                "occupancy_ms": swept.seconds * 1e3,
+                "close_ms": closed.seconds * 1e3,
+            }
         return res
+
+    @staticmethod
+    def _boundary(prev_fit: Optional[dict], entered: float, steptimer: StepTimer,
+                  dispatched: float) -> dict:
+        """The step records' `boundary` (docs/OBSERVABILITY.md "The pass
+        boundary"): the parts, in ms, that tile the stretch from the
+        previous fit()'s last step ready to this one's first step
+        dispatched. `fit_tail_ms` (with its parts `occupancy_ms`,
+        `close_ms`) and `between_fits_ms` need a previous fit() on this
+        Trainer; `first_batch_ms` and `first_dispatch_ms` lie inside the
+        first step's interval, the others outside every interval."""
+        out: dict = {}
+        if prev_fit is not None and "returned" in prev_fit:
+            out["fit_tail_ms"] = (prev_fit["returned"] - prev_fit["ready"]) * 1e3
+            out["occupancy_ms"] = prev_fit["occupancy_ms"]
+            out["close_ms"] = prev_fit["close_ms"]
+            out["between_fits_ms"] = (entered - prev_fit["returned"]) * 1e3
+        out["fit_open_ms"] = (steptimer.first_fetch - entered) * 1e3
+        out["first_batch_ms"] = steptimer.last_wait * 1e3
+        out["first_dispatch_ms"] = (dispatched - steptimer.last_wait_end) * 1e3
+        return {k: round(v, 3) for k, v in out.items()}
 
     # ---------------------------------------------------------- streaming fit
     def _fit_tail(self, train_path: Optional[str] = None) -> TrainResult:
@@ -1707,7 +1771,7 @@ class Trainer:
                 "examples": at_examples,
                 "elapsed_s": at_elapsed,
             }
-            rec.update(steptimer.window_record(cost=self._step_cost()))
+            rec.update(steptimer.window_record())
             rec.update(hbm_window_fields(registry))
             rec.update(health.window_record())
             if counters:
@@ -1903,7 +1967,7 @@ class Trainer:
             "elapsed_s": round(res.seconds, 3),
             "occupancy": res.occupancy,
         }
-        final_rec.update(steptimer.window_record(cost=self._step_cost()))
+        final_rec.update(steptimer.window_record())
         final_rec.update(hbm_window_fields(registry))
         final_rec.update(health.window_record())
         counters = registry.snapshot()
