@@ -157,35 +157,75 @@ def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache):
     assert _pallas_calls(compiled) == 1
 
 
-def test_fm_train_step_compiles_for_v5e(one_chip, no_persistent_cache, on_tpu):
-    """The whole single-device FM step `xflow train --no-mesh` runs:
-    gather + row sum + fused scatter/FTRL as Mosaic calls, donated state,
-    inside one chip's memory."""
-    from xflow_tpu.analysis.ir import _abstract_state, _capture, _CapturingRecorder
+def _single_device_step(cfg, one_chip):
+    """(jitted single-device train step, abstract state, abstract batch)
+    as `xflow train --no-mesh` builds it: FM on its flat sorted plan, LR
+    on the row-major arrays."""
+    from xflow_tpu.analysis.ir import (
+        _abstract_state, _capture, _CapturingRecorder, _rowmajor_batch,
+    )
     from xflow_tpu.models import get_model
     from xflow_tpu.ops.sorted_table import plan_sorted_stacked
     from xflow_tpu.optim import get_optimizer
     from xflow_tpu.train.step import make_train_step
 
-    cfg = _fm_cfg()
-    model, opt = get_model("fm"), get_optimizer("ftrl")
-    slots, mask = _slots_mask()
-    plan = plan_sorted_stacked(slots, mask, cfg.num_slots, wire=True)
-    rows = np.zeros((BATCH,), np.float32)
-    batch = {
-        "labels": rows, "row_mask": rows, "sorted_slots": plan.sorted_slots,
-        "sorted_row": plan.sorted_row, "sorted_mask": plan.sorted_mask,
-        "win_off": plan.win_off,
-    }
+    model, opt = get_model(cfg.model.name), get_optimizer("ftrl")
+    if cfg.model.name == "fm":
+        slots, mask = _slots_mask()
+        plan = plan_sorted_stacked(slots, mask, cfg.num_slots, wire=True)
+        rows = np.zeros((BATCH,), np.float32)
+        batch = {
+            "labels": rows, "row_mask": rows, "sorted_slots": plan.sorted_slots,
+            "sorted_row": plan.sorted_row, "sorted_mask": plan.sorted_mask,
+            "win_off": plan.win_off,
+        }
+    else:
+        batch = _rowmajor_batch(cfg)
     _, step = _capture(
         lambda: make_train_step(model, opt, cfg, recorder=_CapturingRecorder())
     )
-    compiled = step.lower(
-        _shapes(_abstract_state(model, opt, cfg), one_chip), _shapes(batch, one_chip)
-    ).compile()
+    return step, _shapes(_abstract_state(model, opt, cfg), one_chip), _shapes(batch, one_chip)
+
+
+def test_fm_train_step_compiles_for_v5e(one_chip, no_persistent_cache, on_tpu):
+    """The whole single-device FM step `xflow train --no-mesh` runs:
+    gather + row sum + fused scatter/FTRL as Mosaic calls, donated state,
+    inside one chip's memory."""
+    step, state, batch = _single_device_step(_fm_cfg(), one_chip)
+    compiled = step.lower(state, batch).compile()
     assert _pallas_calls(compiled) == 3
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+
+
+@pytest.mark.parametrize("model_name", ["fm", "lr"])
+def test_guarded_step_keeps_no_second_state(model_name, one_chip, no_persistent_cache, on_tpu):
+    """The non-finite guard decides before the write, so the pre-step
+    w, n, z need not outlive the update and are not read again: compiled
+    with `skip` (the default) the fused FM step and the LR two-pass step
+    take no more temporary memory than with `off`, and touch no more
+    bytes than one gradient-sized read on top. Selecting old against new
+    kept 1.5 whole leaves more in FM at this size (3.2 GB) and touched 26
+    more; in LR the compiler recomputed the FTRL sweep for the check
+    instead of keeping its result, and touched four more."""
+    from xflow_tpu.config import override
+
+    log2 = 24  # at 2^22 the row-side temporaries hide a kept FM leaf
+    temp, touched = {}, {}
+    for guard in ("off", "skip"):
+        cfg = override(_fm_cfg(), **{
+            "model.name": model_name, "data.log2_slots": log2,
+            "train.nonfinite_guard": guard,
+        })
+        step, state, batch = _single_device_step(cfg, one_chip)
+        compiled = step.lower(state, batch).compile()
+        temp[guard] = compiled.memory_analysis().temp_size_in_bytes
+        cost = compiled.cost_analysis()
+        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+        touched[guard] = cost["bytes accessed"]
+    leaf = (1 << log2) * (K if model_name == "fm" else 1) * 4
+    assert temp["skip"] <= temp["off"] + leaf // 8, temp
+    assert touched["skip"] <= touched["off"] + 1.5 * leaf, touched
 
 
 def test_fm_fullshard_step_compiles_for_four_chips(topo, no_persistent_cache, on_tpu):

@@ -1,6 +1,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from xflow_tpu.config import Config, override
 from xflow_tpu.data.pipeline import examples_to_batches
@@ -207,7 +208,6 @@ def test_fused_scatter_on_fails_loudly_when_ineligible():
     """optim.fused_scatter=on is a hard assertion, not a hint: config
     ineligibility (wrong optimizer/model, sharded builder) and
     non-flat-plan batches raise instead of silently running two-pass."""
-    import pytest
 
     from xflow_tpu.train.step import _fused_scatter_eligible
 
@@ -253,7 +253,6 @@ def test_fused_scatter_on_rejected_on_mesh_at_startup():
     """optim.fused_scatter=on on a mesh must fail at Trainer
     construction (the mesh engines run two-pass; a lazily-built
     overflow-fallback step raising mid-run would be far worse)."""
-    import pytest
 
     from xflow_tpu.parallel.mesh import make_mesh
     from xflow_tpu.train.trainer import Trainer
@@ -264,3 +263,214 @@ def test_fused_scatter_on_rejected_on_mesh_at_startup():
     })
     with pytest.raises(ValueError, match="single-device"):
         Trainer(cfg, mesh=make_mesh(cfg))
+
+
+# ---------------------------------------------------- non-finite guard
+#
+# The guard decides BEFORE the write (train/step.py guard_nonfinite): a
+# bad step hands the optimizer a zero gradient, and a zero gradient is
+# the identity (optim/base.py's contract). These tests hold every step
+# builder and every optimizer to that.
+
+GUARD_B, GUARD_F, GUARD_LOG2 = 64, 8, 14  # 16384 slots = 8 windows
+GUARD_BUILDERS = ("single_lr", "sorted_fm", "gspmd", "fullshard", "replicated_sorted")
+
+
+def _guard_rig(builder, optim, guard):
+    """(step, state, place) for one step builder at a tiny size: `place`
+    turns a row-major numpy batch into that builder's step input."""
+    from xflow_tpu.ops.sorted_table import plan_sorted_batch, plan_sorted_stacked
+    from xflow_tpu.parallel.mesh import batch_sharding, make_mesh
+    from xflow_tpu.parallel.sorted_fullshard import (
+        fullshard_batch_sharding, make_fullshard_train_step, plan_fullshard_batch,
+    )
+    from xflow_tpu.parallel.sorted_sharded import (
+        make_sorted_sharded_train_step, shard_sorted_state,
+    )
+    from xflow_tpu.parallel.train_step import make_sharded_train_step, shard_state
+
+    d, t = 4, 2
+    cfg = override(Config(), **{
+        "model.name": "lr" if builder in ("single_lr", "gspmd") else "fm",
+        "model.num_fields": 5, "data.log2_slots": GUARD_LOG2,
+        "data.batch_size": GUARD_B, "data.max_nnz": GUARD_F,
+        "optim.name": optim, "train.nonfinite_guard": guard,
+        # SGD's published step (1e-3) moves a float32 table by under an
+        # ulp here; a visible step keeps "discarded" distinct from "tiny"
+        "optim.sgd.lr": 0.5,
+        **({} if builder in ("single_lr", "sorted_fm") else {"mesh.data": d, "mesh.table": t}),
+    })
+    model, opt = get_model(cfg.model.name), get_optimizer(optim)
+    state = init_state(model, opt, cfg)
+    S = cfg.num_slots
+    rows = lambda b: {"labels": b["labels"], "row_mask": b["row_mask"]}
+
+    def sorted_arrays(plan, b):
+        return {**rows(b), "sorted_slots": plan.sorted_slots, "sorted_row": plan.sorted_row,
+                "sorted_mask": plan.sorted_mask, "win_off": plan.win_off}
+
+    if builder == "single_lr":
+        step = make_train_step(model, opt, cfg)
+        place = lambda b: {k: jnp.asarray(v) for k, v in b.items()}
+    elif builder == "sorted_fm":
+        # FTRL takes the fused scatter+FTRL form, SGD the two-pass sorted one
+        step = make_train_step(model, opt, cfg)
+        place = lambda b: {
+            k: jnp.asarray(v)
+            for k, v in sorted_arrays(plan_sorted_batch(b["slots"], b["mask"], S), b).items()
+        }
+    else:
+        mesh = make_mesh(cfg, devices=jax.devices()[: d * t])
+        if builder == "gspmd":
+            step = make_sharded_train_step(model, opt, cfg, mesh)
+            state = shard_state(state, mesh)
+            bsh = batch_sharding(mesh)
+            place = lambda b: {k: jax.device_put(jnp.asarray(v), bsh[k]) for k, v in b.items()}
+        elif builder == "fullshard":
+            step = make_fullshard_train_step(opt, cfg, mesh)
+            state = shard_state(state, mesh)
+            bsh = fullshard_batch_sharding(mesh, with_fields=False)
+            place = lambda b: {
+                k: jax.device_put(jnp.asarray(v), bsh[k])
+                for k, v in {**plan_fullshard_batch(b["slots"], b["mask"], cfg, mesh), **rows(b)}.items()
+            }
+        else:
+            step = make_sorted_sharded_train_step(opt, cfg, mesh)
+            state = shard_sorted_state(state, mesh)
+            place = lambda b: {
+                k: jnp.asarray(v)
+                for k, v in sorted_arrays(
+                    plan_sorted_stacked(b["slots"], b["mask"], S, num_sub=d, always_stack=True), b
+                ).items()
+            }
+    return step, state, place
+
+
+def _guard_batches(n, seed=7):
+    rng = np.random.default_rng(seed)
+    return [
+        {
+            "slots": rng.integers(0, 1 << GUARD_LOG2, (GUARD_B, GUARD_F)).astype(np.int32),
+            "fields": rng.integers(0, 5, (GUARD_B, GUARD_F)).astype(np.int32),
+            "mask": (rng.random((GUARD_B, GUARD_F)) < 0.8).astype(np.float32),
+            "labels": (rng.random(GUARD_B) < 0.4).astype(np.float32),
+            "row_mask": np.ones((GUARD_B,), np.float32),
+        }
+        for _ in range(n)
+    ]
+
+
+def _host_leaves(state):
+    # copies: the step donates its state
+    return [np.array(x) for x in jax.tree.leaves((state.tables, state.opt_state))]
+
+
+@pytest.mark.parametrize("builder,optim", [
+    (b, o) for b in GUARD_BUILDERS for o in ("ftrl", "sgd")
+    # the replicated sorted engine's state shardings are FTRL's {n, z}
+    if (b, o) != ("replicated_sorted", "sgd")
+])
+def test_nonfinite_guard_discards_exactly_and_costs_good_steps_nothing(builder, optim):
+    good = _guard_batches(4)
+    runs = {}
+    for guard in ("off", "skip"):  # the guarded rig stays in hand below
+        step, state, place = _guard_rig(builder, optim, guard)
+        losses = []
+        for b in good[:3]:
+            state, m = step(state, place(b))
+            assert guard == "off" or bool(m["update_ok"])
+            losses.append(np.asarray(m["loss"]).tobytes())
+        runs[guard] = (losses, _host_leaves(state))
+    # on good batches the guarded step is bit-identical to the unguarded
+    assert runs["skip"][0] == runs["off"][0]
+    assert [x.tobytes() for x in runs["skip"][1]] == [x.tobytes() for x in runs["off"][1]]
+
+    # a NaN-label batch after the good steps: flagged, step counted,
+    # every table and optimizer leaf VALUE-equal to the state before it
+    # (-0.0 + 0.0 may flip a zero's sign, so values, not bytes)
+    before = runs["skip"][1]
+    assert any(np.any(x != 0) for x in before)
+    state, m = step(state, place({**good[3], "labels": np.full((GUARD_B,), np.nan, np.float32)}))
+    assert not bool(m["update_ok"])
+    assert int(state.step) == 4
+    after = _host_leaves(state)
+    assert len(after) == len(before)
+    for x, y in zip(before, after):
+        np.testing.assert_array_equal(x, y)
+    # ... and the discarded step poisons nothing: the next good step lands
+    state, m = step(state, place(good[3]))
+    assert bool(m["update_ok"]) and np.isfinite(float(m["loss"]))
+    assert any(np.any(x != y) for x, y in zip(after, _host_leaves(state)))
+
+
+def test_optimizer_zero_gradient_is_the_identity():
+    """The contract the guard's discard rests on (optim/base.py): for
+    every registered optimizer, apply(tables, state, zeros) after real
+    updates returns tables and state unchanged."""
+    from xflow_tpu.optim.base import _REGISTRY
+
+    assert {"ftrl", "sgd"} <= set(_REGISTRY)
+    cfg = small_cfg()
+    rng = np.random.default_rng(3)
+    shapes = {"w": (4096,), "v": (4096, 4)}
+    for name, opt in sorted(_REGISTRY.items()):
+        tables = {k: jnp.asarray(rng.normal(0, 0.01, s).astype(np.float32)) for k, s in shapes.items()}
+        state = opt.init_state(tables)
+        apply = jax.jit(lambda t, s, g, opt=opt: opt.apply(t, s, g, cfg))
+        for _ in range(3):
+            # half of the slots never see a gradient (FTRL's lazy-init rule)
+            grads = {
+                k: jnp.asarray((rng.normal(0, 1, s) * (rng.random(s) < 0.5)).astype(np.float32))
+                for k, s in shapes.items()
+            }
+            tables, state = apply(tables, state, grads)
+        zeros = {k: jnp.zeros(s, jnp.float32) for k, s in shapes.items()}
+        new_tables, new_state = apply(tables, state, zeros)
+        for x, y in zip(jax.tree.leaves((tables, state)), jax.tree.leaves((new_tables, new_state))):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y), err_msg=name)
+
+
+def _table_shaped_counts(builder_row):
+    """(select_n, is_finite) equations of one step program whose operand
+    has the shape of a table or optimizer-state leaf."""
+    from xflow_tpu.analysis.ir import _build_program, _iter_eqns
+
+    _, fn, args, _ = _build_program(*builder_row)
+    leaf_shapes = {tuple(x.shape) for x in jax.tree.leaves((args[0].tables, args[0].opt_state))}
+    counts = {"select_n": 0, "is_finite": 0}
+    for eqn in _iter_eqns(fn.trace(*args).jaxpr.jaxpr):
+        if eqn.primitive.name in counts and any(
+            tuple(getattr(v.aval, "shape", ())) in leaf_shapes for v in eqn.invars
+        ):
+            counts[eqn.primitive.name] += 1
+    return counts["select_n"], counts["is_finite"]
+
+
+def _train_program_keys():
+    from xflow_tpu.analysis.ir import PROGRAMS
+
+    return [p[0] for p in PROGRAMS if p[2].endswith("_train")]
+
+
+@pytest.mark.parametrize("key", _train_program_keys())
+def test_guarded_step_has_no_table_wide_select_or_sweep(key):
+    """Structure, from the jaxpr (nothing runs), for every train program
+    of the analyzer's matrix (all step builders): the guard adds no
+    select between old and new state and no isfinite over a state leaf.
+    What is table-shaped with the guard off — the optimizer's own
+    selects, FTRL's shrink and lazy-init rules — is all a guarded step
+    holds besides one select and one isfinite per GRADIENT leaf, where
+    the parent held three of each per table (w, n, z). The fused sorted
+    step's gradient is the batch-sized cotangent, so there the guard
+    adds nothing table-shaped at all."""
+    from xflow_tpu.analysis.ir import PROGRAMS
+
+    row = next(p for p in PROGRAMS if p[0] == key)
+    with_guard = lambda g: (row[0], row[1], row[2], {**row[3], "train.nonfinite_guard": g}, row[4])
+    sel_on, fin_on = _table_shaped_counts(with_guard("skip"))
+    sel_off, fin_off = _table_shaped_counts(with_guard("off"))
+    assert fin_off == 0
+    # every program of the matrix has ONE table ("w" or "wv"), so one
+    # gradient leaf — none on the fused path
+    added = 0 if key == "train_step[fm.sorted]" else 1
+    assert (sel_on - sel_off, fin_on) == (added, added)

@@ -340,14 +340,16 @@ class TrainConfig:
     signal_sync_every: int = 100
     # non-finite guard (docs/ROBUSTNESS.md): every train step also
     # returns an `update_ok` flag — one jnp.isfinite reduction over the
-    # loss and the updated table/optimizer leaves, computed INSIDE the
-    # SPMD program so multi-process ranks agree for free (the flag is
-    # replicated; no new host collectives). "skip" (default): a bad
-    # step's state update is discarded on device (jnp.where on the
-    # flag — no recompute), counted, and training continues; "halt":
-    # abort on the first bad step, after committing a checkpoint;
-    # "off": no check (a NaN batch silently poisons the tables — the
-    # reference behavior).
+    # loss and the GRADIENT as the optimizer is about to receive it,
+    # decided before the write and computed INSIDE the SPMD program so
+    # multi-process ranks agree for free (the flag is replicated; no
+    # new host collectives). "skip" (default): a bad step hands the
+    # optimizer a zero gradient — the identity for FTRL and SGD, so the
+    # update is discarded on device with no second copy of the state,
+    # no table-wide select and no table-wide sweep — is counted, and
+    # training continues; "halt": abort on the first bad step, after
+    # committing a checkpoint; "off": no check (a NaN batch silently
+    # poisons the tables — the reference behavior).
     nonfinite_guard: str = "skip"
     # under "skip", this many CONSECUTIVE discarded steps abort anyway
     # (after a committed checkpoint): a stream of bad steps means the

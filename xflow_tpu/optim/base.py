@@ -22,6 +22,20 @@ from xflow_tpu.config import Config
 
 @dataclass(frozen=True)
 class Optimizer:
+    """A registered optimizer.
+
+    Contract: a zero gradient is the identity —
+    `apply(tables, state, zeros, cfg)` returns `tables` and `state`
+    unchanged in value. The dense sweep leans on it for every slot a
+    batch does not touch, and the non-finite guard
+    (`train/step.py guard_nonfinite`) leans on it to discard a bad step:
+    it hands `apply` a zero gradient and keeps no copy of the old state.
+    An optimizer for which that does not hold (momentum, weight decay)
+    must not be registered without giving the guard another way to
+    discard; `tests/test_train_step.py` holds every registered optimizer
+    to it.
+    """
+
     name: str
     # tables -> opt_state pytree (dict per table)
     init_state: Callable

@@ -554,11 +554,16 @@ def make_fullshard_train_step(
                 (loss, rows), grads = jax.value_and_grad(
                     loss_for_grad, has_aux=True
                 )(state.tables[tname], batch)
+            metrics = {"loss": loss, "rows": rows}
+            # non-finite guard: update_ok computed from the replicated
+            # loss + the sharded gradient (the isfinite reduction GSPMDs
+            # to shard-local alls + one psum) — every rank/device sees
+            # the same flag, so the zeroed gradient stays rank-symmetric
+            safe_grads, metrics = guard_nonfinite(cfg, {tname: grads}, metrics)
             with jax.named_scope("optimizer"):
                 new_tables, new_opt = optimizer.apply(
-                    {tname: state.tables[tname]}, state.opt_state, {tname: grads}, cfg
+                    {tname: state.tables[tname]}, state.opt_state, safe_grads, cfg
                 )
-            metrics = {"loss": loss, "rows": rows}
             # health norms ride the same replicated-scalar contract as
             # the guard flag (shared helper, train/step.py): sharded
             # reductions + one psum, identical values on every rank
@@ -567,14 +572,7 @@ def make_fullshard_train_step(
                     cfg, state.tables, new_tables, grads={tname: grads}
                 )
             )
-            # non-finite guard: update_ok computed from replicated loss +
-            # the sharded updated leaves (the isfinite reduction GSPMDs to
-            # shard-local alls + one psum) — every rank/device sees the
-            # same flag, so the jnp.where discard stays rank-symmetric
-            return guard_nonfinite(
-                cfg, state, TrainState(new_tables, new_opt, state.step + 1),
-                metrics,
-            )
+            return TrainState(new_tables, new_opt, state.step + 1), metrics
 
         return train_step, fullshard_batch_sharding(mesh, with_fields=with_fields)
 

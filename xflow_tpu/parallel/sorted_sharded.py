@@ -197,23 +197,19 @@ def make_sorted_sharded_train_step(
             (loss, rows), grads = jax.value_and_grad(loss_for_grad, has_aux=True)(
                 state.tables["wv"], batch
             )
-        with jax.named_scope("optimizer"):
-            new_tables, new_opt = optimizer.apply(
-                {"wv": state.tables["wv"]},
-                state.opt_state,
-                {"wv": grads},
-                cfg,
-            )
         metrics = {"loss": loss, "rows": rows}
-        # health norms + non-finite guard: the shared helpers every
+        # non-finite guard + health norms: the shared helpers every
         # engine uses (train/step.py) — reductions over the sharded
         # leaves lower to shard-local sums + one psum, outputs replicated
+        safe_grads, metrics = guard_nonfinite(cfg, {"wv": grads}, metrics)
+        with jax.named_scope("optimizer"):
+            new_tables, new_opt = optimizer.apply(
+                {"wv": state.tables["wv"]}, state.opt_state, safe_grads, cfg
+            )
         metrics.update(
             health_norms(cfg, state.tables, new_tables, grads={"wv": grads})
         )
-        return guard_nonfinite(
-            cfg, state, TrainState(new_tables, new_opt, state.step + 1), metrics
-        )
+        return TrainState(new_tables, new_opt, state.step + 1), metrics
 
     table_sh = NamedSharding(mesh, P(TABLE_AXIS, None))
     opt_sh = {"wv": {"n": table_sh, "z": table_sh}}

@@ -225,11 +225,12 @@ def check_kernel_parity(
     # the DISPATCHING wrapper: Pallas on TPU, the two-pass composition
     # elsewhere — so this gate keeps running (trivially) off-TPU, per
     # the module contract
-    got_f = jax.jit(
+    fused = jax.jit(
         lambda d, s, w_, n_, z_: scatter_ftrl_sorted(
             d, s, wo, w_, n_, z_, k, hp, False, 8
         )
-    )(jnp.asarray(d_f), ss, jnp.asarray(w0), jnp.asarray(n0), jnp.asarray(z0))
+    )
+    got_f = fused(jnp.asarray(d_f), ss, jnp.asarray(w0), jnp.asarray(n0), jnp.asarray(z0))
     g_ref = jax.jit(
         lambda d, s: _scatter_xla(d, s, None, S, k, 8)
     )(jnp.asarray(d_f), ss)
@@ -242,6 +243,18 @@ def check_kernel_parity(
         checks[name] = _rel_err(
             np.asarray(got_f[i]), np.asarray(want_f[i]), floor=1e-4
         )
+
+    # --- a zero cotangent is the identity: the non-finite guard
+    # (train/step.py guard_nonfinite) discards a bad step by handing this
+    # kernel zeros, and keeps no copy of the old state. Applied to a
+    # state the kernel itself wrote (so every stored w is f(z, n) or a
+    # never-touched init), the window pass must return w, n, z unchanged
+    # in value, exactly
+    again = fused(jnp.zeros(d_f.shape, jnp.float32), ss, *got_f)
+    checks["scatter_ftrl_zero_grad"] = max(
+        float(np.max(np.abs(np.asarray(a) - np.asarray(b))))
+        for a, b in zip(again, got_f)
+    )
 
     # --- row-sum kernel (the FM forward's occurrence->row reduction)
     ch = 24
@@ -277,6 +290,7 @@ def check_kernel_parity(
         "scatter_ftrl_w": 1e-3,
         "scatter_ftrl_n": 1e-3,
         "scatter_ftrl_z": 1e-3,
+        "scatter_ftrl_zero_grad": 0.0,
         "rowsum": 1e-4,
     }
     ok = all(checks[name] <= tol[name] for name in tol)

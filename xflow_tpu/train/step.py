@@ -73,35 +73,44 @@ def nonfinite_guard_on(cfg: Config) -> bool:
     return g != "off"
 
 
-def guard_nonfinite(cfg: Config, state: TrainState, new_state: TrainState, metrics: dict):
-    """Fold the non-finite update guard into one step's result.
+def guard_nonfinite(cfg: Config, grads, metrics: dict):
+    """Decide the non-finite guard BEFORE the write: returns the
+    gradient the optimizer is to receive, and the step's metrics.
 
-    `update_ok` = the loss AND every updated table/optimizer leaf are
-    finite, as ONE isfinite reduction per leaf fused into the step (the
-    optimizer sweep already touches every element, so the extra HBM
-    traffic is ~zero on the two-pass paths). On a bad step the whole
-    update is discarded by `jnp.where` on the flag — no recompute, the
-    previous state rides through. The step counter still advances, so
-    checkpoint names stay monotonic.
+    `update_ok` = the loss AND every gradient leaf, as the optimizer is
+    about to receive it, are finite (one isfinite reduction per gradient
+    leaf). On a bad step every gradient is replaced by zeros — a
+    scalar-predicate select on the gradient only, which fuses into its
+    consumer — and the optimizer then runs unconditionally, in place, on
+    the donated state. A zero gradient IS the discard: it is the
+    optimizer contract (`optim/base.py`) that `apply(tables, state, 0)`
+    leaves tables and state unchanged, the identity every step already
+    leans on for the slots its batch does not touch. So nothing reads
+    the pre-step state after the update: no second copy of w, n, z, no
+    table-wide select, no table-wide isfinite sweep. The step counter
+    still advances, so checkpoint names stay monotonic.
+
+    What the flag promises: a step whose loss or gradient is non-finite
+    never lands; given a finite state it leaves a finite state — except
+    through float32 overflow inside the optimizer itself (g² with
+    |g| > 1.8e19, or an accumulator within one step of 3.4e38), which
+    the flag does not see (docs/ROBUSTNESS.md). A state poisoned that
+    way is caught by the next step that gathers the slot.
 
     Shared by all four step builders (single-device, GSPMD, fullshard,
-    replicated sorted) so their guard semantics cannot drift. The flag
-    is computed inside the SPMD program from replicated values, so every
-    multi-process rank sees the same bit with no host collective — the
-    trainer's skip/halt bookkeeping stays rank-symmetric for free.
+    replicated sorted) so their guard semantics cannot drift. Under a
+    mesh the reduction over a sharded gradient is shard-local plus one
+    psum, so the flag is replicated and every multi-process rank sees
+    the same bit with no host collective — the trainer's skip/halt
+    bookkeeping stays rank-symmetric for free.
     """
     if not nonfinite_guard_on(cfg):
-        return new_state, metrics
+        return grads, metrics
     ok = jnp.isfinite(metrics["loss"])
-    for leaf in jax.tree.leaves((new_state.tables, new_state.opt_state)):
-        ok = ok & jnp.isfinite(leaf).all()
-    keep = lambda new, old: jnp.where(ok, new, old)
-    guarded = TrainState(
-        tables=jax.tree.map(keep, new_state.tables, state.tables),
-        opt_state=jax.tree.map(keep, new_state.opt_state, state.opt_state),
-        step=new_state.step,
-    )
-    return guarded, dict(metrics, update_ok=ok)
+    for g in jax.tree.leaves(grads):
+        ok = ok & jnp.isfinite(g).all()
+    grads = jax.tree.map(lambda g: jnp.where(ok, g, jnp.zeros_like(g)), grads)
+    return grads, dict(metrics, update_ok=ok)
 
 
 def health_mode(cfg: Config) -> str:
@@ -141,10 +150,11 @@ def health_norms(cfg: Config, old_tables, new_tables, grads=None, grad_sq=None) 
     Reductions are plain sums, so under GSPMD/shard_map-produced sharded
     leaves they lower to shard-local reductions + one psum and every
     rank sees identical replicated values — no host collective, same
-    cost model as the non-finite guard's isfinite sweep. Norms are taken
-    on the PROPOSED update, before the guard's discard select: a
-    discarded step's exploding grad norm is exactly the diagnostic the
-    health stream exists to show."""
+    cost model as the non-finite guard's isfinite reduction. Callers
+    pass the gradient as it was BEFORE the guard zeroed it: a discarded
+    step's exploding grad norm is exactly the diagnostic the health
+    stream exists to show. `update_norm` of a discarded step reads 0 —
+    nothing was proposed to the table."""
     mode = health_mode(cfg)
     if mode == "off":
         return {}
@@ -228,7 +238,10 @@ def _fused_sorted_step(state: TrainState, batch: dict, cfg: Config):
     Bit-equal to value_and_grad + ftrl.apply (same kernels, same
     elementwise math on each window's complete gradient block); the
     difference is that the [S, K] gradient never exists in HBM and the
-    dense optimizer sweep is gone."""
+    dense optimizer sweep is gone. The non-finite guard decides on the
+    occurrence cotangent before the kernel runs (`guard_nonfinite`), so
+    the kernel's aliased w, n, z are the only copy: with
+    train.health_metrics=off nothing reads the pre-step table after it."""
     from xflow_tpu.ops.sorted_table import pack_of, scatter_ftrl_sorted, table_gather_sorted
 
     mvm = cfg.model.name == "mvm"
@@ -276,22 +289,26 @@ def _fused_sorted_step(state: TrainState, batch: dict, cfg: Config):
         loss, vjp = jax.vjp(row_loss, occ_t)
     with jax.named_scope("grad"):
         (d_occ,) = vjp(jnp.ones_like(loss))
+    metrics = {"loss": loss, "rows": batch["row_mask"].sum()}
+    # the guard decides on the cotangent the kernel is about to receive
+    # ([K8, Np], batch-sized): zeroed on a bad step, the window write
+    # leaves every slot as it leaves an untouched one
+    g_occ, metrics = guard_nonfinite(cfg, d_occ, metrics)
     st = state.opt_state[tname]
     # the fused kernel IS scatter + optimizer in one window write
     with jax.named_scope("scatter_optimizer"):
         w_new, n_new, z_new = scatter_ftrl_sorted(
-            d_occ, batch["sorted_slots"], batch["win_off"], table, st["n"], st["z"],
+            g_occ, batch["sorted_slots"], batch["win_off"], table, st["n"], st["z"],
             K, cfg.optim.ftrl, cfg.data.sorted_bf16, pack,
         )
     new_state = TrainState(
         {tname: w_new}, {tname: {"n": n_new, "z": z_new}}, state.step + 1
     )
-    metrics = {"loss": loss, "rows": batch["row_mask"].sum()}
     # the table gradient never materializes on this path (that is the
-    # point of the fusion) — the occurrence-space cotangent's norm
-    # stands in for the grad norm (equal when the batch's occurrences
-    # hit distinct slots; a divergence signal either way). update/param
-    # norms keep the pre-step table live, same price the guard pays.
+    # point of the fusion) — the occurrence-space cotangent's norm, taken
+    # before the guard zeroes it, stands in for the grad norm (equal when
+    # the batch's occurrences hit distinct slots; a divergence signal
+    # either way). update/param norms keep the pre-step table live.
     metrics.update(
         health_norms(
             cfg, state.tables, new_state.tables,
@@ -331,13 +348,7 @@ def make_train_step(model: Model, optimizer: Optimizer, cfg: Config, jit: bool =
             )
         )
         if fuse and fusable:
-            new_state, metrics = _fused_sorted_step(state, batch, cfg)
-            # guard note: selecting against the pre-step table forces XLA
-            # to keep it live across the fused scatter, giving back the
-            # table-sized transient the fusion removed — the price of
-            # discardable updates (docs/ROBUSTNESS.md); set
-            # train.nonfinite_guard=off to reclaim it
-            return guard_nonfinite(cfg, state, new_state, metrics)
+            return _fused_sorted_step(state, batch, cfg)
         if fuse and cfg.optim.fused_scatter == "on":
             raise ValueError(
                 "optim.fused_scatter=on but this batch has no flat "
@@ -351,15 +362,14 @@ def make_train_step(model: Model, optimizer: Optimizer, cfg: Config, jit: bool =
         # (the gather's transpose) shows up here in an xprof trace
         with jax.named_scope("grad"):
             loss, grads = jax.value_and_grad(loss_fn)(state.tables, batch, model, cfg)
+        metrics = {"loss": loss, "rows": batch["row_mask"].sum()}
+        safe_grads, metrics = guard_nonfinite(cfg, grads, metrics)
         with jax.named_scope("optimizer"):
             new_tables, new_opt = optimizer.apply(
-                state.tables, state.opt_state, grads, cfg
+                state.tables, state.opt_state, safe_grads, cfg
             )
-        metrics = {"loss": loss, "rows": batch["row_mask"].sum()}
         metrics.update(health_norms(cfg, state.tables, new_tables, grads=grads))
-        return guard_nonfinite(
-            cfg, state, TrainState(new_tables, new_opt, state.step + 1), metrics
-        )
+        return TrainState(new_tables, new_opt, state.step + 1), metrics
 
     if jit:
         # donate the state: tables and optimizer state update in place in HBM
