@@ -275,4 +275,46 @@ def test_trainer_fullshard_overflow_falls_back_single_process(tmp_path):
     res = t.fit()
     assert res.steps == 1
     assert t._fullshard_overflow_warned
+    assert res.fullshard_overflow_batches == 1
     assert np.isfinite(res.last_loss)
+
+
+@pytest.mark.parametrize("hot,want", [(4, [1, 1, 1]), (0, [0, 0, 0])])
+def test_final_record_counts_fullshard_overflow_batches(tmp_path, hot, want):
+    """How often a run fell back to the GSPMD step shows in every
+    fit()'s `final` record: the count of THAT fit()'s train batches,
+    not a once-a-run flag (two batches a pass here, one of them skewed
+    when `hot` of a row's 8 occurrences are one feature)."""
+    import json
+
+    from xflow_tpu.train.trainer import Trainer
+
+    rng = np.random.default_rng(0)
+    with open(tmp_path / "train-00000", "w") as f:
+        for i in range(4096):
+            skew = hot if i < 2048 else 0
+            feats = " ".join(
+                ["0:0:1.0"] * skew
+                + [f"{fg}:{rng.integers(0, 5000)}:1.0" for fg in range(1, 9 - skew)]
+            )
+            f.write(f"{i % 2}\t{feats}\n")
+    mpath = tmp_path / "metrics.jsonl"
+    cfg = cfg_for(
+        "fm", 4, 2,
+        **{
+            "data.train_path": str(tmp_path / "train"),
+            "data.batch_size": 2048,
+            "data.max_nnz": 8,
+            "model.num_fields": 9,
+            "train.epochs": 1,
+            "train.pred_dump": False,
+            "data.fullshard_slack": 1.5,
+            "train.metrics_path": str(mpath),
+        },
+    )
+    t = Trainer(cfg, mesh=make_mesh(cfg))
+    got = [t.fit().fullshard_overflow_batches for _ in range(3)]
+    assert got == want
+    finals = [r for r in map(json.loads, open(mpath)) if r.get("final")]
+    assert [r["fullshard_overflow_batches"] for r in finals] == want
+    assert all(r["steps"] == 2 for r in finals)

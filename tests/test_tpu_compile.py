@@ -61,19 +61,19 @@ def on_tpu(monkeypatch):
     monkeypatch.setattr(sorted_table, "_on_tpu", lambda: True)
 
 
-def _fm_cfg():
+def _fm_cfg(log2_slots=LOG2_SLOTS):
     from xflow_tpu.config import Config, override
 
     return override(Config(), **{
-        "model.name": "fm", "data.log2_slots": LOG2_SLOTS,
+        "model.name": "fm", "data.log2_slots": log2_slots,
         "data.batch_size": BATCH, "data.max_nnz": NNZ,
     })
 
 
-def _slots_mask():
+def _slots_mask(log2_slots=LOG2_SLOTS):
     rng = np.random.default_rng(0)
     return (
-        rng.integers(0, 1 << LOG2_SLOTS, (BATCH, NNZ)).astype(np.int32),
+        rng.integers(0, 1 << log2_slots, (BATCH, NNZ)).astype(np.int32),
         np.ones((BATCH, NNZ), np.float32),
     )
 
@@ -171,7 +171,7 @@ def _single_device_step(cfg, one_chip):
 
     model, opt = get_model(cfg.model.name), get_optimizer("ftrl")
     if cfg.model.name == "fm":
-        slots, mask = _slots_mask()
+        slots, mask = _slots_mask(cfg.data.log2_slots)
         plan = plan_sorted_stacked(slots, mask, cfg.num_slots, wire=True)
         rows = np.zeros((BATCH,), np.float32)
         batch = {
@@ -228,10 +228,56 @@ def test_guarded_step_keeps_no_second_state(model_name, one_chip, no_persistent_
     assert touched["skip"] <= touched["off"] + 1.5 * leaf, touched
 
 
-def test_fm_fullshard_step_compiles_for_four_chips(topo, no_persistent_cache, on_tpu):
+def test_fm_2_26_does_not_fit_one_chip(one_chip, no_persistent_cache, on_tpu):
+    """Why the benchmark's larger FM table takes four chips: the
+    single-device step at 2^26 slots (8.9 GB of w, n, z) is refused by
+    the chip's compiler for its memory, so nothing between one chip's
+    2^25 and the host's 2^27 can be a one-chip cell."""
+    step, state, batch = _single_device_step(_fm_cfg(26), one_chip)
+    with pytest.raises(Exception, match="Ran out of memory in memory space hbm"):
+        step.lower(state, batch).compile()
+
+
+def test_state_is_born_sharded_at_2_27_on_four_chips(topo, no_persistent_cache):
+    """The init program of `fm-v10-s27-x4` (train/state.py build_state:
+    `init_state` under one jit with `out_shardings`): 17.7 GB of w, n, z
+    that no chip holds come out a quarter a chip, the sampler's
+    temporaries stay under one whole leaf, and the program fits a
+    chip's 15.75 GB. Built eagerly on one device and sharded afterwards
+    the same state died with RESOURCE_EXHAUSTED before the first step."""
+    import jax
+
+    from xflow_tpu.models import get_model
+    from xflow_tpu.optim import get_optimizer
+    from xflow_tpu.parallel.mesh import make_mesh, state_shardings
+    from xflow_tpu.train.state import init_state
+
+    cfg = _fm_cfg(27)
+    model, opt = get_model("fm"), get_optimizer("ftrl")
+    mesh = make_mesh(cfg, devices=topo.devices)
+
+    def init():
+        return init_state(model, opt, cfg)
+
+    abstract = jax.eval_shape(init)
+    compiled = jax.jit(init, out_shardings=state_shardings(abstract, mesh)).lower().compile()
+    assert not any(
+        c in compiled.as_text() for c in ("all-gather", "all-reduce", "all-to-all", "collective-permute")
+    )
+    mem = compiled.memory_analysis()
+    whole = sum(int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(abstract))
+    leaf = (1 << 27) * K * 4
+    assert whole > 16e9 and mem.output_size_in_bytes <= whole // 4 + (1 << 20)
+    assert mem.temp_size_in_bytes < leaf
+    assert mem.output_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("log2_slots", [LOG2_SLOTS, 27])
+def test_fm_fullshard_step_compiles_for_four_chips(log2_slots, topo, no_persistent_cache, on_tpu):
     """The mesh engine `xflow train` picks on more than one device: the
     fully-sharded FM step over the 2x2 host, each chip holding a quarter
-    of the state."""
+    of the state — at 2^27 slots (`fm-v10-s27-x4`) a quarter of a state
+    no chip holds whole, with its temporaries inside a chip's 15.75 GB."""
     import jax
 
     from xflow_tpu.analysis.ir import (
@@ -245,12 +291,12 @@ def test_fm_fullshard_step_compiles_for_four_chips(topo, no_persistent_cache, on
         make_fullshard_train_step, plan_fullshard_batch,
     )
 
-    cfg = _fm_cfg()
+    cfg = _fm_cfg(log2_slots)
     model, opt = get_model("fm"), get_optimizer("ftrl")
     mesh = make_mesh(cfg, devices=topo.devices)
     abstract = _abstract_state(model, opt, cfg)
     state = _with_shardings(abstract, state_shardings(abstract, mesh))
-    slots, mask = _slots_mask()
+    slots, mask = _slots_mask(log2_slots)
     rows = np.zeros((BATCH,), np.float32)
     arrays = {"labels": rows, "row_mask": rows}
     arrays.update(plan_fullshard_batch(slots, mask, cfg, mesh))
@@ -270,7 +316,9 @@ def test_fm_fullshard_step_compiles_for_four_chips(topo, no_persistent_cache, on
     whole = sum(
         int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(abstract)
     )
-    assert compiled.memory_analysis().argument_size_in_bytes < 0.3 * whole
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes < 0.3 * whole
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
 def test_occupancy_sweep_compiles_in_seconds(one_chip, no_persistent_cache):

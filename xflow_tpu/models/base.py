@@ -89,7 +89,13 @@ def init_tables(model: Model, cfg: Config, key: jax.Array) -> Dict[str, jax.Arra
         if cfg.optim.name == "sgd":
             t = jnp.full(shape, cfg.optim.v_init_sgd, dtype=jnp.float32)
         else:
-            t = jax.random.normal(sub, shape, dtype=jnp.float32) * cfg.optim.v_init_scale
+            # the barrier keeps the scale a multiply of its own: inside a
+            # jit (train/state.py build_state) XLA would fold it into the
+            # sampler's constants and move values by an ulp, and a state
+            # built sharded must carry the eager init's bits
+            t = jax.lax.optimization_barrier(
+                jax.random.normal(sub, shape, dtype=jnp.float32)
+            ) * cfg.optim.v_init_scale
         if tname == "wv":
             # fused FM layout: logical column 0 is the linear w (zero-init
             # like a scalar w-table) — every pack*K-row position j with

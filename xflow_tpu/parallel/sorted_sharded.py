@@ -239,13 +239,18 @@ def make_sorted_sharded_train_step(
     return call
 
 
-def shard_sorted_state(state: TrainState, mesh: Mesh) -> TrainState:
-    """Place state onto the table-axis-only sharding this path uses."""
+def sorted_state_shardings(state: TrainState, mesh: Mesh):
+    """A pytree of NamedShardings matching a TrainState: the
+    table-axis-only layout this path uses."""
     table_sh = NamedSharding(mesh, P(TABLE_AXIS, None))
+    scalar_sh = NamedSharding(mesh, P())
+    return jax.tree.map(
+        lambda x: table_sh if getattr(x, "ndim", 0) >= 1 else scalar_sh, state
+    )
 
-    def put(x):
-        if getattr(x, "ndim", 0) >= 1:
-            return jax.device_put(x, table_sh)
-        return jax.device_put(x, NamedSharding(mesh, P()))
 
-    return jax.tree.map(put, state)
+def shard_sorted_state(state: TrainState, mesh: Mesh) -> TrainState:
+    """Place a finished state (a restore's or a test's host data) onto
+    this path's sharding; a Trainer's own state is born there
+    (train/state.py build_state)."""
+    return jax.tree.map(jax.device_put, state, sorted_state_shardings(state, mesh))

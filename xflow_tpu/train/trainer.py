@@ -12,6 +12,7 @@ one jitted SPMD step; then an eval pass that dumps
 from __future__ import annotations
 
 import contextlib
+import math
 import os
 import sys
 import time
@@ -48,7 +49,7 @@ from xflow_tpu.telemetry import (
     span,
 )
 from xflow_tpu.optim import get_optimizer
-from xflow_tpu.train.state import TrainState, init_state
+from xflow_tpu.train.state import TrainState, build_state
 from xflow_tpu.train.step import (
     batch_to_arrays,
     make_eval_step,
@@ -76,6 +77,9 @@ class TrainResult:
     occupancy: dict = field(default_factory=dict)
     interrupted: int = 0  # signal number when preempted mid-run (A3)
     bad_steps: int = 0  # non-finite updates discarded by the guard
+    # train batches the fullshard engine handed to the GSPMD row-major
+    # step (too skewed for data.fullshard_slack)
+    fullshard_overflow_batches: int = 0
 
     @property
     def examples_per_sec(self) -> float:
@@ -250,19 +254,17 @@ class Trainer:
                     "step; mesh engines run the two-pass form — use auto "
                     "(fuses where eligible) or off"
                 )
-            from xflow_tpu.parallel.train_step import make_sharded_train_step, make_sharded_eval_step, shard_state
+            from xflow_tpu.parallel.mesh import state_shardings
+            from xflow_tpu.parallel.train_step import make_sharded_train_step, make_sharded_eval_step
 
             if self._mesh_engine == "fullshard":
                 from xflow_tpu.parallel.sorted_fullshard import (
                     make_fullshard_train_step,
                 )
 
-                # shard_state's default layout IS the fullshard layout:
-                # every table/opt leaf P(('data','table')) on the slot axis
-                with span("init_state"):
-                    self.state = shard_state(
-                        init_state(self.model, self.optimizer, cfg), mesh
-                    )
+                # state_shardings' layout IS the fullshard layout: every
+                # table/opt leaf P(('data','table')) on the slot axis
+                self._build_state(lambda s: state_shardings(s, mesh))
                 fullshard_step = make_fullshard_train_step(
                     self.optimizer, cfg, mesh, recorder=_rec
                 )
@@ -293,21 +295,15 @@ class Trainer:
                 # routing rank-symmetric)
                 from xflow_tpu.parallel.sorted_sharded import (
                     make_sorted_sharded_train_step,
-                    shard_sorted_state,
+                    sorted_state_shardings,
                 )
 
-                with span("init_state"):
-                    self.state = shard_sorted_state(
-                        init_state(self.model, self.optimizer, cfg), mesh
-                    )
+                self._build_state(lambda s: sorted_state_shardings(s, mesh))
                 self.train_step = make_sorted_sharded_train_step(
                     self.optimizer, cfg, mesh, recorder=_rec
                 )
             else:
-                with span("init_state"):
-                    self.state = shard_state(
-                        init_state(self.model, self.optimizer, cfg), mesh
-                    )
+                self._build_state(lambda s: state_shardings(s, mesh))
                 self.train_step = make_sharded_train_step(
                     self.model, self.optimizer, cfg, mesh, recorder=_rec
                 )
@@ -336,8 +332,7 @@ class Trainer:
                 self.eval_step = gspmd_eval
             self._shard_batch = lambda b: _shard_batch_arrays(b, mesh)
         else:
-            with span("init_state"):
-                self.state = init_state(self.model, self.optimizer, cfg)
+            self._build_state()
             self.train_step = make_train_step(
                 self.model, self.optimizer, cfg, recorder=_rec
             )
@@ -451,6 +446,25 @@ class Trainer:
         # num_fields would be silently dropped by the one-hot, so reject
         # it loudly
         self._validate_fields = cfg.model.name in ("mvm", "ffm")
+
+    def _build_state(self, shardings=None) -> None:
+        """The run's first state, born in its shardings (`build_state`),
+        under `xflow:init_state`; the span's kind="init_state" record
+        says how large the state is, whole and on each device."""
+        with span("init_state") as built:
+            self.state = build_state(self.model, self.optimizer, self.cfg, shardings)
+        if self.metrics.enabled:
+            leaves = jax.tree.leaves(self.state)
+            self.metrics.log({
+                "kind": "init_state",
+                "dur_ms": round(built.seconds * 1e3, 3),
+                "state_bytes_total": sum(x.nbytes for x in leaves),
+                # every device holds one shard of every leaf
+                "state_bytes_per_device": sum(
+                    math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+                    for x in leaves
+                ),
+            })
 
     @property
     def engine(self) -> str:
@@ -591,7 +605,6 @@ class Trainer:
                         "batches (raise the slack to keep the fast path)",
                         file=sys.stderr,
                     )
-                    self.metrics.log({"fullshard_overflow_fallback": True})
                 # row-major: the GSPMD step handles it — THROUGH dedup if
                 # enabled (overflow batches are the most skewed = exactly
                 # where the cross-chip dedup win lives). Multi-process: the
@@ -709,6 +722,12 @@ class Trainer:
         elif mine_dup is not None and not got[1]:
             arrays.pop("fs_fields", None)  # all-clear: product mode
         return arrays
+
+    def _fell_back(self, arrays: dict) -> bool:
+        """A train batch of the fullshard engine that goes to the GSPMD
+        row-major step: it carries no fullshard plan (its own overflow,
+        or a peer's in a multi-process run)."""
+        return self._mesh_engine == "fullshard" and "fs_slots" not in arrays
 
     def _maybe_dedup(self, arrays: dict, batch) -> dict:
         """Attach the deduped gather arrays to a row-major batch when the
@@ -1321,6 +1340,7 @@ class Trainer:
                     if step_delay_s:  # drill injector (testing/faults.py)
                         time.sleep(step_delay_s)
                     arrays = self._resolve_fullshard_overflow(batch, arrays)
+                    res.fullshard_overflow_batches += self._fell_back(arrays)
                     with span("transfer", prof) as moved:
                         arrays = self._shard_batch(arrays)
                     with span("dispatch", prof) as called:
@@ -1654,6 +1674,8 @@ class Trainer:
                 "elapsed_s": round(res.seconds, 3),
                 "occupancy": res.occupancy,
             }
+            if self._mesh_engine == "fullshard":
+                final_rec["fullshard_overflow_batches"] = res.fullshard_overflow_batches
             # tail window (steps since the last log tick) + run-total counters
             final_rec.update(steptimer.window_record())
             final_rec.update(hbm_window_fields(registry))
@@ -1819,6 +1841,7 @@ class Trainer:
                 ):
                     arrays.pop("_shard", None)
                     arrays = self._resolve_fullshard_overflow(batch, arrays)
+                    res.fullshard_overflow_batches += self._fell_back(arrays)
                     arrays = self._shard_batch(arrays)
                     self.state, m = self.train_step(self.state, arrays)
                     steptimer.dispatched(m, batch.num_rows)
@@ -1967,6 +1990,8 @@ class Trainer:
             "elapsed_s": round(res.seconds, 3),
             "occupancy": res.occupancy,
         }
+        if self._mesh_engine == "fullshard":
+            final_rec["fullshard_overflow_batches"] = res.fullshard_overflow_batches
         final_rec.update(steptimer.window_record())
         final_rec.update(hbm_window_fields(registry))
         final_rec.update(health.window_record())
