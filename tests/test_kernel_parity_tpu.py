@@ -24,3 +24,5 @@ def test_kernel_parity_on_device():
 
     res = check_kernel_parity()
     assert res["ok"], res
+    # the fullshard step's merged stream is checked beside the multi case
+    assert {"gather_merged_exact", "scatter_merged_exact"} <= set(res["checks"])

@@ -181,6 +181,13 @@ def test_fullshard_validation_messages():
         validate_sorted_fullshard(
             cfg_for("fm", 4, 2, **{"model.fm_fused": False}), mesh
         )
+    # (global row, field, mask) must fold into the merge's one int32 word
+    with pytest.raises(ValueError, match="one int32"):
+        validate_sorted_fullshard(
+            cfg_for("mvm", 4, 2, **{"data.batch_size": 1 << 24, "model.num_fields": 100}),
+            mesh,
+        )
+    validate_sorted_fullshard(cfg_for("fm", 4, 2, **{"data.batch_size": 1 << 24}), mesh)
     cap = fullshard_capacity(cfg_for("fm", 4, 2), mesh)
     assert cap % 512 == 0 and cap >= 512
 
@@ -318,3 +325,111 @@ def test_final_record_counts_fullshard_overflow_batches(tmp_path, hot, want):
     finals = [r for r in map(json.loads, open(mpath)) if r.get("final")]
     assert [r["fullshard_overflow_batches"] for r in finals] == want
     assert all(r["steps"] == 2 for r in finals)
+
+
+def _received_buffers(kind, with_fields, D=4, T=2, rows=32, nf=5, seed=0):
+    """What device (d=1, t=1) holds after the exchange: every source
+    shard's buffer for ITS owner block, as `fullshard_buffers` cuts them
+    — (r_slots, r_row, r_mask, r_fields or None, r_off), each [D, ...]."""
+    from xflow_tpu.ops.sorted_table import plan_sorted_batch
+    from xflow_tpu.parallel.sorted_fullshard import fullshard_buffers
+
+    rng = np.random.default_rng(seed)
+    s_local = S // (D * T)
+    d, t = 1, 1
+    o = d * T + t
+    cap = 1024
+    out = {k: [] for k in ("fs_slots", "fs_row", "fs_mask", "fs_fields", "fs_off")}
+    for src in range(D):
+        if kind == "uniform":
+            slots = rng.integers(0, S, (rows, F))
+        elif kind == "skewed":
+            # a power law inside every owner block: a few hot slots take
+            # most occurrences, most windows of the block few or none
+            hot = np.minimum(rng.zipf(1.3, (rows, F)) - 1, s_local - 1)
+            slots = rng.integers(0, D * T, (rows, F)) * s_local + hot
+        else:  # "one_empty": source 2 sends this block nothing but pads
+            slots = rng.integers(0, S, (rows, F))
+            if src == 2:
+                slots = slots % s_local  # all in block 0
+        slots = slots.astype(np.int32)
+        mask = (rng.random((rows, F)) < 0.8).astype(np.float32)
+        fields = rng.integers(0, nf, (rows, F)).astype(np.int32)
+        plan = plan_sorted_batch(slots, mask, S, fields=fields if with_fields else None)
+        bufs = fullshard_buffers(
+            plan, D, T, cap, s_local, 8.0, with_fields, n_real=rows * F
+        )
+        for k in out:
+            if k in bufs:
+                out[k].append(bufs[k][t, d])
+    got = {k: np.stack(v) for k, v in out.items() if v}
+    if kind == "one_empty":
+        assert got["fs_mask"][2].sum() == 0 and (got["fs_slots"][2] == s_local - 1).all()
+    return got, s_local, cap, rows
+
+
+@pytest.mark.parametrize("with_fields", [False, True], ids=["plain", "fields"])
+@pytest.mark.parametrize("kind", ["uniform", "skewed", "one_empty"])
+def test_merge_received_is_a_slot_sorted_permutation(kind, with_fields):
+    """The on-device merge of the D received buffers: the same multiset
+    of (slot, global row, mask[, field]) — the last three folded into
+    the step's one payload word — in non-decreasing slot order,
+    and the summed buffer offsets ARE the merged stream's window offsets
+    (last entry D*cap: pads ride in the last window) — the single-stream
+    kernels' contract, with no search on the device."""
+    from xflow_tpu.parallel.sorted_fullshard import merge_received
+
+    nf = 5
+    bufs, s_local, cap, rows = _received_buffers(kind, with_fields, nf=nf)
+    D = bufs["fs_slots"].shape[0]
+    # the step's payload word: [global row (* nf + field) | mask bit]
+    seg = bufs["fs_row"] + np.arange(D, dtype=np.int32)[:, None] * rows
+    if with_fields:
+        seg = seg * nf + bufs["fs_fields"]
+    word = (seg * 2 + bufs["fs_mask"].astype(np.int32)).astype(np.int32)
+    slots_m, win_off, word_m = map(np.asarray, jax.jit(merge_received)(
+        jnp.asarray(bufs["fs_slots"]), jnp.asarray(bufs["fs_off"]), jnp.asarray(word)
+    ))
+    assert slots_m.shape == word_m.shape == (D * cap,) and word_m.dtype == np.int32
+    assert np.all(np.diff(slots_m) >= 0)
+
+    def occurrences(slots, word):
+        seg, mask = word >> 1, word & 1
+        cols = (seg // nf, seg % nf, mask) if with_fields else (seg, mask)
+        return sorted(zip(slots.ravel().tolist(), *(c.ravel().tolist() for c in cols)))
+
+    before = occurrences(bufs["fs_slots"], word)
+    assert before == occurrences(slots_m, word_m)
+    # ... and the word decodes to what the planner wrote
+    raw = [bufs["fs_row"] + np.arange(D)[:, None] * rows]
+    raw += [bufs["fs_fields"]] if with_fields else []
+    raw += [bufs["fs_mask"].astype(np.int64)]
+    assert before == sorted(zip(bufs["fs_slots"].ravel().tolist(),
+                                *(c.ravel().tolist() for c in raw)))
+    wpo = s_local // WINDOW
+    want = np.searchsorted(slots_m, np.arange(wpo) * WINDOW, side="left")
+    np.testing.assert_array_equal(win_off[:wpo], want)
+    assert win_off[wpo] == D * cap and win_off.shape == (wpo + 1,)
+    # every position lies in the window whose span owns it
+    for j in range(wpo):
+        seg = slots_m[win_off[j]:win_off[j + 1]]
+        assert np.all((seg >= j * WINDOW) & (seg < (j + 1) * WINDOW))
+
+
+def test_fullshard_compile_record_counts_table_spans():
+    """The counter that says the merge engaged: the fullshard step's
+    compile record carries `table_spans_per_step` = the local windows
+    (one span each in the gather and in its transpose), not windows
+    times source buffers."""
+    from xflow_tpu.telemetry import CompileRecorder
+
+    cfg = cfg_for("fm", 4, 2)
+    mesh = make_mesh(cfg)
+    model, opt = get_model("fm"), get_optimizer("ftrl")
+    rec = CompileRecorder()
+    step = make_fullshard_train_step(opt, cfg, mesh, recorder=rec)
+    state = shard_state(init_state(model, opt, cfg), mesh)
+    step(state, _place_fullshard(rand_batch(np.random.default_rng(0)), cfg, mesh, False))
+    latest = rec.latest("train_step.fullshard.fm")
+    assert latest["table_spans_per_step"] == S // 8 // WINDOW
+    assert latest["pallas_calls"] == 0  # CPU: the kernels' XLA stand-ins

@@ -490,3 +490,105 @@ def test_native_plan_rejects_out_of_range_slots():
             native.native_plan_sorted(
                 slots, mask, None, S, WINDOW, padded_len(slots.size)
             )
+
+
+def _four_buffers(rng, cap=1024, nbuf=4):
+    """`nbuf` slot-sorted buffers over the same [S, K] table, each padded
+    to `cap` with slot S-1 and carrying its buffer-local window offsets
+    (last entry `cap`): the fullshard engine's received stream
+    (parallel/sorted_fullshard.fullshard_buffers' contract)."""
+    slots, offs = [], []
+    for i in range(nbuf):
+        n = int(rng.integers(cap // 4, cap - CHUNK))
+        real = np.sort(rng.integers(0, S, n)).astype(np.int32)
+        buf = np.concatenate([real, np.full(cap - n, S - 1, np.int32)])
+        off = np.searchsorted(real, np.arange(0, S + 1, WINDOW)).astype(np.int32)
+        off[-1] = cap
+        slots.append(buf)
+        offs.append(off)
+    return np.stack(slots), np.stack(offs)
+
+
+@pytest.mark.parametrize("engine", ["ops", "pallas_interpret"])
+@pytest.mark.parametrize("pack", [1, 8])
+def test_merged_stream_matches_multi_buffer(pack, engine):
+    """One slot-sorted stream merged from four buffers, through the
+    single-stream gather and its VJP, equals the multi-buffer op over
+    the buffers — position for position once the permutation is undone
+    (forward), and slot for slot (the table gradient). `ops`: the public
+    custom-VJP ops as the step calls them; `pallas_interpret`: the TPU
+    kernels themselves in interpreter mode, where the offsets decide
+    which positions a window's span reads and writes."""
+    from contextlib import nullcontext
+
+    from xflow_tpu.ops.sorted_table import (
+        _gather_pallas_multi,
+        _scatter_pallas_multi,
+        pack_table,
+        table_gather_sorted_multi,
+        unpack_table,
+    )
+    from xflow_tpu.parallel.sorted_fullshard import merge_received
+
+    pallas = engine == "pallas_interpret"
+    ctx = nullcontext
+    if pallas:
+        pltpu = pytest.importorskip("jax.experimental.pallas.tpu")
+        if not hasattr(pltpu, "force_tpu_interpret_mode"):
+            pytest.skip("pallas TPU interpret mode unavailable in this jax build")
+        ctx = pltpu.force_tpu_interpret_mode
+    rng = np.random.default_rng(7 + pack)
+    b_slots, b_off = _four_buffers(rng)
+    nbuf, cap = b_slots.shape
+    n = nbuf * cap
+    table = rng.normal(size=(S, K)).astype(np.float32)
+    jt = jnp.asarray(pack_table(table) if pack > 1 else table)
+    flat = jnp.asarray(b_slots.reshape(-1))
+    joff = jnp.asarray(b_off)
+    # the permutation rides through the merge as a payload
+    m_slots, m_off, perm = merge_received(
+        jnp.asarray(b_slots), joff, jnp.arange(n, dtype=jnp.int32).reshape(nbuf, cap)
+    )
+    perm = np.asarray(perm)
+    d_multi = rng.normal(size=(K8, n)).astype(np.float32)
+    d_multi[K:] = 0.0
+    d_merged = jnp.asarray(d_multi[:, perm])
+    with ctx():
+        if pallas:
+            occ_multi = _gather_pallas_multi(jt, flat, joff, cap, False, pack)
+            occ_merged = _gather_pallas(jt, m_slots, m_off, False, pack)
+            g_multi = _scatter_pallas_multi(
+                jnp.asarray(d_multi), flat, joff, S, K, cap, False, pack
+            )
+            g_merged = _scatter_pallas(d_merged, m_slots, m_off, S, K, False, pack)
+        else:
+            occ_multi, vjp_multi = jax.vjp(
+                lambda t: table_gather_sorted_multi(t, flat, joff, False, pack), jt
+            )
+            occ_merged, vjp_merged = jax.vjp(
+                lambda t: table_gather_sorted(t, m_slots, m_off, False, pack), jt
+            )
+            (g_multi,) = vjp_multi(jnp.asarray(d_multi))
+            (g_merged,) = vjp_merged(d_merged)
+    # forward: merged position j holds what buffer position perm[j] held
+    # (interpret mode emulates the MXU's bf16 terms: rtol as above)
+    np.testing.assert_allclose(
+        np.asarray(occ_merged), np.asarray(occ_multi)[:, perm],
+        rtol=5e-5 if pallas else 0,
+    )
+    np.testing.assert_allclose(
+        np.asarray(occ_merged)[:K].T, table[np.asarray(m_slots)],
+        rtol=5e-5 if pallas else 0,
+    )
+    # VJP: the same table gradient, in the table's own layout; only the
+    # order of the float32 adds inside a slot differs
+    assert g_merged.shape == g_multi.shape == jt.shape
+    np.testing.assert_allclose(
+        np.asarray(g_merged), np.asarray(g_multi), rtol=5e-5, atol=2e-5
+    )
+    want = np.zeros((S, K), np.float32)
+    np.add.at(want, b_slots.reshape(-1), d_multi[:K].T)
+    got = np.asarray(g_merged)
+    np.testing.assert_allclose(
+        unpack_table(got, K) if pack > 1 else got, want, rtol=5e-5, atol=2e-5
+    )

@@ -277,7 +277,9 @@ def test_fm_fullshard_step_compiles_for_four_chips(log2_slots, topo, no_persiste
     """The mesh engine `xflow train` picks on more than one device: the
     fully-sharded FM step over the 2x2 host, each chip holding a quarter
     of the state — at 2^27 slots (`fm-v10-s27-x4`) a quarter of a state
-    no chip holds whole, with its temporaries inside a chip's 15.75 GB."""
+    no chip holds whole, with its temporaries inside a chip's 15.75 GB —
+    with the exchange, the on-device merge (a sort) and three kernels
+    that each walk one span a table window."""
     import jax
 
     from xflow_tpu.analysis.ir import (
@@ -311,8 +313,20 @@ def test_fm_fullshard_step_compiles_for_four_chips(log2_slots, topo, no_persiste
     call = make_fullshard_train_step(opt, cfg, mesh, recorder=_CapturingRecorder())
     _, step = _capture(lambda: call(state, batch))
     compiled = step.lower(state, batch).compile()
+    text = compiled.as_text()
     assert _pallas_calls(compiled) == 3
-    assert "all-to-all" in compiled.as_text()
+    assert "all-to-all" in text
+    # the four received buffers are merged into one slot-sorted stream on
+    # the device (one sort), and the kernels walk ONE span a window: no
+    # Mosaic call takes the buffers' [D, wpo+1] offset table
+    assert " sort(" in text
+    from xflow_tpu.ops.sorted_table import WINDOW
+
+    d, wpo1 = arrays["fs_off"].shape[-2:]
+    assert (d, wpo1) == (4, (1 << log2_slots) // 4 // WINDOW + 1)
+    kernels = [ln for ln in text.splitlines() if 'custom_call_target="tpu_custom_call"' in ln]
+    assert not any(f"s32[{d},{wpo1}]" in ln for ln in kernels), kernels
+    assert sum(f"s32[{wpo1}]" in ln for ln in kernels) == 2  # gather, transpose
     whole = sum(
         int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(abstract)
     )

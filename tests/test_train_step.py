@@ -247,6 +247,10 @@ def test_kernel_parity_runs_off_tpu():
 
     par = check_kernel_parity(log2_slots=13, n_occ=1 << 12, batch=256)
     assert par["ok"], par["checks"]
+    # every stream the steps hand the kernels is in the gate: one plan,
+    # the multi-buffer form, and the buffers merged on the device
+    for form in ("exact", "multi_exact", "merged_exact"):
+        assert f"gather_{form}" in par["checks"] and f"scatter_{form}" in par["checks"]
 
 
 def test_fused_scatter_on_rejected_on_mesh_at_startup():

@@ -126,7 +126,7 @@ class _Captured(Exception):
 
 
 class _CapturingRecorder:
-    def wrap(self, name, fn):
+    def wrap(self, name, fn, **static_fields):
         raise _Captured(name, fn)
 
 

@@ -768,9 +768,8 @@ def _gather_kernel_multi(off_ref, slots_ref, table_ref, out_ref, slc, old, sem_s
     WINDOW-MAJOR: grid step j owns table window j and walks every
     buffer's matching span, so each table block is DMA'd into VMEM
     exactly ONCE per call instead of once per buffer — the source-major
-    order read the whole table nbuf times (nbuf = D source shards in
-    the fullshard engine, NS sub-batches on one device; measured 2×+ on
-    the MVM segment path at NS=4). `off_ref` is [nbuf, wpo+1]
+    order read the whole table nbuf times (nbuf = NS sub-batches on one
+    device; measured 2×+ on the MVM segment path at NS=4). `off_ref` is [nbuf, wpo+1]
     buffer-local window offsets, the `_scatter_kernel_multi` contract."""
     from jax.experimental import pallas as pl
 
@@ -990,8 +989,8 @@ def _scatter_kernel_multi(off_ref, slots_ref, d_ref, out_ref, slc, dch, sem_s, s
                           *, bf16, nbuf, cap, pack):
     """Windowed scatter over `nbuf` concatenated per-source buffers.
 
-    The fully-sharded engine's cotangent stream is nbuf buffers of `cap`
-    positions each, all targeting the SAME local table shard; grid step j
+    The cotangent stream is nbuf buffers of `cap` positions each (stacked
+    sub-batch plans), all targeting the SAME table; grid step j
     owns table window j and accumulates the matching span of every
     buffer before one [W, K] block write — each output block is visited
     exactly once, so no cross-step revisit semantics are needed.
@@ -1323,28 +1322,35 @@ def _gather_bwd(bf16, pack, res, d_occ_t):
 table_gather_sorted.defvjp(_gather_fwd, _gather_bwd)
 
 
-# ------------------------------------------- multi-buffer op (fullshard)
+# --------------------------------- multi-buffer op (stacked sub-batches)
 
 
 @partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def table_gather_sorted_multi(table, sorted_slots, loc_off, bf16=False, pack=1):
     """`table_gather_sorted` over a concatenated multi-buffer stream: the
     per-call input is `nbuf` fixed-capacity buffers, each slot-sorted
-    over the SAME table (the fullshard engine's per-source-shard buffers
-    over the local shard, pads at slot S_local-1 / mask 0; a single
-    device's NS row-contiguous sub-batch plans over the whole table).
+    over the SAME table. Its one caller is `sorted_gather_map`: a single
+    device's NS row-contiguous sub-batch plans (`plan_sorted_stacked`)
+    over the whole table, whose row side needs the occurrences kept
+    sub-batch-major. (The fullshard engine's per-source-shard buffers
+    have the same shape but no such need: it merges them into one
+    slot-sorted stream on the device and calls `table_gather_sorted` —
+    parallel/sorted_fullshard.py `merge_received`.)
     Both directions are WINDOW-MAJOR — grid step j owns table window j
     and walks every buffer's matching span — so the table crosses
     HBM→VMEM exactly ONCE per call regardless of nbuf (the source-major
     order read it nbuf times; measured 2×+ on the MVM segment path at
-    NS=4). The VJP accumulates every buffer's span into one [W, K]
-    block write per window (`_scatter_kernel_multi`); in the fullshard
-    engine the table-shard gradient never leaves the device.
+    NS=4). What grows with nbuf is the SPANS: one per (window, buffer),
+    each at least one CHUNK-wide one-hot pass however few occurrences
+    it holds (PERF.md §5: the same ~1 us a chunk visit as the
+    single-stream kernels, nbuf times the visits on sparse windows).
+    The VJP accumulates every buffer's span into one [W, K] block write
+    per window (`_scatter_kernel_multi`).
 
     `loc_off` [nbuf, wpo+1]: buffer-local window offsets, last entry
     extended to `cap`. Capacity = sorted_slots.size // nbuf, a CHUNK
-    multiple (host contract: parallel/sorted_fullshard.py buffers, or
-    `plan_sorted_stacked` sub-batch plans via `sorted_gather_map`).
+    multiple (host contract: `plan_sorted_stacked` sub-batch plans via
+    `sorted_gather_map`; pads at the table's last slot, mask 0).
     `pack` as in `table_gather_sorted` (the table stored
     [S/pack, pack*K])."""
     if _on_tpu():

@@ -22,12 +22,19 @@ Data flow per step, device (d, t), owner block o = d*T + t:
    traffic (~12 B/occurrence · slack), the synchronous analog of every
    worker Pulling from the server that owns each key
    (`lr_worker.cc:170`), batched into one collective.
-3. The Pallas sorted-window kernels run on the local ``[S/(D*T), K]``
-   table shard over the concatenated buffer stream
-   (`table_gather_sorted_multi`: WINDOW-MAJOR in both directions —
-   each grid step owns one table window and walks every source
-   buffer's span, so the shard crosses HBM→VMEM once per call; the
-   VJP accumulates all buffers into one block write per local window).
+3. The D received buffers — each slot-sorted over the SAME local
+   ``[S/(D*T), K]`` shard — are merged ON THE DEVICE into one
+   slot-sorted stream (`merge_received`: one `lax.sort` keyed on the
+   slot, the global row id, field and mask riding folded into one int32
+   payload word; the merged window offsets are the column sums of the
+   buffers' offsets),
+   and the single-stream Pallas sorted-window kernels the one-chip step
+   uses run over it (`table_gather_sorted`: one span a table window;
+   its VJP one ``[W, K]`` block write a window). Every consumer of the
+   stream is per occurrence and the rows are summed by global row id,
+   so the merged order is the same mathematics; what it saves is the
+   kernels' cost per (window, buffer) span — at least one CHUNK-wide
+   one-hot pass each, D times the windows for the same occurrences.
 4. Per-row partial sums for ALL source shards are reduced to their row
    owners by ONE `psum_scatter` over 'data' + ONE `psum` over 'table'
    (~B·ch·4 B each) — aggregated rows cross the wire, never table rows.
@@ -69,7 +76,7 @@ from xflow_tpu.ops.sorted_table import (
     map_host_parallel,
     plan_sorted_batch,
     row_sums_sorted,
-    table_gather_sorted_multi,
+    table_gather_sorted,
 )
 from xflow_tpu.parallel.mesh import DATA_AXIS, TABLE_AXIS
 from xflow_tpu.train.state import TrainState
@@ -129,6 +136,15 @@ def validate_sorted_fullshard(cfg: Config, mesh: Mesh) -> None:
         raise ValueError(
             f"data.sorted_sub_batches={cfg.data.sorted_sub_batches} conflicts "
             f"with the fullshard plan count (= {d // p} per process); leave it 0"
+        )
+    segs = cfg.data.batch_size * p * (
+        1 if cfg.model.name == "fm" else max(cfg.model.num_fields, 1)
+    )
+    if 2 * segs > 1 << 31:
+        raise ValueError(
+            f"fullshard layout folds (global row, field, mask) into one int32 "
+            f"an occurrence: global batch {cfg.data.batch_size * p} x fields "
+            f"needs {segs} ids, over 2^30"
         )
     if cfg.data.fullshard_slack < 1.0:
         raise ValueError(
@@ -270,6 +286,32 @@ def fullshard_batch_sharding(mesh: Mesh, with_fields: bool = False) -> dict:
     return {k: full[k] for k in keys}
 
 
+def merge_received(r_slots, r_off, word):
+    """The D received buffers ``[D, cap]`` -> ONE slot-sorted stream
+    ``[D*cap]`` with its window offsets ``[wpo+1]`` and the payload word
+    in the same order: ``(slots, win_off, word)``.
+
+    Every buffer is slot-sorted over the same local shard, so one
+    `lax.sort` keyed on the slot merges them; what else is per
+    occurrence (global row id, field, mask) rides folded into ONE int32
+    `word` an occurrence, because every operand of the sort costs the
+    chip's compiler seconds on a cold start (about twelve a payload
+    operand at the four-chip cell's 1,050,624 positions) and the step
+    most of a millisecond (PERF.md §6). The merged offsets need no search: window j of the merged stream starts
+    after every buffer's occurrences of the windows before it, the
+    column sum of the buffer-local offsets; each buffer's last entry is
+    `cap`, so the last entry is the stream's length
+    (`table_gather_sorted`'s contract). Pads (slot s_local-1, mask 0)
+    sort to the tail of the last window, where each buffer carried its
+    own. The order among equal slots is left to the sort
+    (`is_stable=False`): nothing reads it, and a stable sort carries one
+    more operand to break ties by position."""
+    slots, word = jax.lax.sort(
+        (r_slots.reshape(-1), word.reshape(-1)), num_keys=1, is_stable=False
+    )
+    return slots, r_off.sum(axis=0), word
+
+
 def _local_logits(mode, tbl_local, fs_slots, fs_row, fs_mask, fs_off, fs_fields,
                   R, cfg, D, K, nf, bf16, plus):
     """Device (d, t) forward body, shared by the train and eval steps.
@@ -283,32 +325,38 @@ def _local_logits(mode, tbl_local, fs_slots, fs_row, fs_mask, fs_off, fs_fields,
     Steps (the numbers refer to the module docstring's data flow):
     2. exchange: my buffer for dest d' -> device (d', t); receive every
        source's buffer for MY block — ONE all_to_all over 'data'.
-    3. local windowed gather (+ shard-local scatter in the VJP).
+    3. merge the D buffers into one slot-sorted stream, then the local
+       windowed gather over it (+ shard-local scatter in the VJP).
     4. per-row aggregates return to their row owners: psum_scatter over
        'data' + psum over 'table' (owner_reduce).
     """
-    from xflow_tpu.ops.sorted_table import pack_of, wire_mask, wire_rows
+    from xflow_tpu.ops.sorted_table import pack_of, wire_rows
 
     def a2a(x):
         return jax.lax.all_to_all(x, DATA_AXIS, 0, 0, tiled=True)
 
+    with_fields = mode in ("ffm", "mvm_segment")
     r_slots = a2a(fs_slots)  # [D_src, cap]
-    # compacted wire dtypes (compact_plan_wire) ride through the
-    # all_to_all — less ICI traffic too — and upcast after
-    r_row = wire_rows(a2a(fs_row))
-    r_mask = wire_mask(a2a(fs_mask))
     r_off = a2a(fs_off)  # [D_src, wpo+1]
-    slots_flat = r_slots.reshape(-1)
-    mask_flat = jax.lax.stop_gradient(r_mask.reshape(-1))
+    # rows arrive shard-local [0, R); globalize by source index so one
+    # segment space covers all D source shards' rows. The compacted wire
+    # dtypes (compact_plan_wire) ride through the all_to_all — less ICI
+    # traffic — and are folded here into the merge's one payload word,
+    # [seg | mask bit]: seg = global row, times nf plus the field where
+    # the mode has fields (validate_sorted_fullshard bounds it to 31 bits)
+    seg = wire_rows(a2a(fs_row)) + jnp.arange(D, dtype=jnp.int32)[:, None] * R
+    if with_fields:
+        seg = seg * nf + wire_rows(a2a(fs_fields))
+    word = seg * 2 + a2a(fs_mask).astype(jnp.int32)
+    slots_flat, win_off, word = merge_received(r_slots, r_off, word)
+    seg = word >> 1
+    mask_flat = jax.lax.stop_gradient((word & 1).astype(jnp.float32))
+    grow, fields_flat = (seg // nf, seg % nf) if with_fields else (seg, None)
 
-    occ_t = table_gather_sorted_multi(
-        tbl_local, slots_flat, r_off, bf16, pack_of(tbl_local, K)
+    occ_t = table_gather_sorted(
+        tbl_local, slots_flat, win_off, bf16, pack_of(tbl_local, K)
     )
     occm_t = occ_t[:K] * mask_flat[None, :]
-
-    # rows arrive shard-local [0, R); globalize by source index so one
-    # segment space covers all D source shards' rows
-    grow = (r_row + jnp.arange(D, dtype=jnp.int32)[:, None] * R).reshape(-1)
 
     def owner_reduce(partials):
         mine = jax.lax.psum_scatter(
@@ -321,7 +369,6 @@ def _local_logits(mode, tbl_local, fs_slots, fs_row, fs_mask, fs_off, fs_fields,
         from xflow_tpu.ops.sorted_table import segment_sum_channels
 
         k_lat = cfg.model.v_dim
-        fields_flat = wire_rows(a2a(fs_fields)).reshape(-1)
         # FFM channel contract + exact-at-zeros hand VJP
         # (models/ffm.py make_ffm_row_op): one segment-sum into the
         # per-(row, field) space, owner_reduce row return like the
@@ -344,8 +391,6 @@ def _local_logits(mode, tbl_local, fs_slots, fs_row, fs_mask, fs_off, fs_fields,
     if mode == "mvm_segment":
         from xflow_tpu.ops.sorted_table import segment_sum_channels
 
-        r_fields = wire_rows(a2a(fs_fields))
-        seg = grow * nf + r_fields.reshape(-1)
         # mask rides as an extra channel: its segment-sum is the
         # per-(row, field) occurrence count => `present` (models/mvm.py)
         stacked = jnp.concatenate([occm_t, mask_flat[None, :]], axis=0)
@@ -485,6 +530,10 @@ def make_fullshard_train_step(
     """
     validate_sorted_fullshard(cfg, mesh)
     D, tname, K, nf, bf16, plus = _mode_statics(cfg, mesh)
+    # the compile record's `table_spans_per_step`: one span a local table
+    # window in the gather and in its transpose, whatever the number of
+    # source buffers (the merge, `merge_received`)
+    wpo = cfg.num_slots // (D * mesh.shape[TABLE_AXIS]) // WINDOW
 
     def local_logits(mode, tbl_local, fs_slots, fs_row, fs_mask, fs_off,
                      fs_fields, R):
@@ -593,7 +642,9 @@ def make_fullshard_train_step(
                 donate_argnums=(0,),
             )
             if recorder is not None:
-                fn = recorder.wrap(f"train_step.fullshard.{mode}", fn)
+                fn = recorder.wrap(
+                    f"train_step.fullshard.{mode}", fn, table_spans_per_step=wpo
+                )
             jitted[mode] = (fn, bsh)
         fn, bsh = jitted[mode]
         return fn(state, {k: batch[k] for k in bsh})

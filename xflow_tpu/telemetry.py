@@ -637,10 +637,12 @@ class CompileRecorder:
         return module, out, text.count('custom_call_target="tpu_custom_call"')
 
     # ---------------------------------------------------------- recording
-    def record(self, name: str, jitted, *args, **kwargs):
+    def record(self, name: str, jitted, *args, static_fields=None, **kwargs):
         """Lower + compile `jitted` for these args, emit the compile
         record, and return the compiled executable. Raises whatever
-        lower/compile raised."""
+        lower/compile raised. `static_fields`: what the builder knows
+        about the program and the compiled module does not say
+        (`wrap`'s keywords), copied into the record."""
         import jax
 
         sig = self.signature(args, kwargs)
@@ -686,6 +688,7 @@ class CompileRecorder:
             self._reg.counter("compile.programs").inc()
         self._reg.timer("compile.time").observe(dt)
         module, scopes, rec["pallas_calls"] = self._op_scopes(compiled)
+        rec.update(static_fields or {})
         if module:
             rec["hlo_module"] = module
         if scopes:
@@ -697,9 +700,10 @@ class CompileRecorder:
             self._sink.append(rec)
         return compiled
 
-    def wrap(self, name: str, jitted):
+    def wrap(self, name: str, jitted, **static_fields):
         """Drop-in replacement for a jitted callable that compiles
-        through `record` once per signature."""
+        through `record` once per signature; keywords are fields the
+        builder adds to each of the program's compile records."""
         cache: dict = {}
 
         def call(*args, **kwargs):
@@ -710,7 +714,9 @@ class CompileRecorder:
                 key = self.signature(args, kwargs)
                 compiled = cache.get(key)
             if compiled is None:
-                compiled = cache[key] = self.record(name, jitted, *args, **kwargs)
+                compiled = cache[key] = self.record(
+                    name, jitted, *args, static_fields=static_fields, **kwargs
+                )
             return compiled(*args, **kwargs)
 
         call.program_name = name  # introspection (tests)

@@ -6,8 +6,9 @@ structurally blind to: the kernels are only *lowered through Mosaic* on
 a real chip, and the MXU's default operand rounding only exists there.
 This module re-checks, on whatever backend is live:
 
-- `table_gather_sorted` (single-stream and multi-buffer) is BIT-exact
-  against the XLA gather oracle — the 3-term bf16 decomposition's
+- `table_gather_sorted` (single-stream, multi-buffer, and single-stream
+  over the buffers merged on the device as the fullshard step merges
+  them) is BIT-exact against the XLA gather oracle — the 3-term bf16 decomposition's
   selection property (`_dot_f32`), not a tolerance;
 - the windowed scatter VJPs match `jax.ops.segment_sum` within the
   reduction-reorder class (≤ ~1 ulp per accumulated term);
@@ -106,7 +107,7 @@ def check_kernel_parity(
     )
     checks["scatter_exact"] = _rel_err(got_s, want_s, floor=1e-2)
 
-    # --- multi-buffer gather/scatter (fullshard engine): split the
+    # --- multi-buffer gather/scatter (stacked sub-batch plans): split the
     # sorted stream in two, pad each buffer to a fixed capacity with
     # slot S-1 per the host contract (each half of a sorted stream is
     # itself sorted, so no re-sort is needed)
@@ -142,6 +143,28 @@ def check_kernel_parity(
         )(jnp.asarray(d_m), mslots)
     )
     checks["scatter_multi_exact"] = _rel_err(got_ms, want_ms, floor=1e-2)
+
+    # --- the fullshard step's stream: the same buffers merged into ONE
+    # slot-sorted stream on the device (parallel/sorted_fullshard.py
+    # merge_received: a sort keyed on the slot, the summed offsets) and
+    # run through the single-stream kernels — bit-exact against the XLA
+    # gather at the merged positions AND against the multi-buffer kernel
+    # at the positions the merge took them from
+    from xflow_tpu.parallel.sorted_fullshard import merge_received
+
+    g_slots, g_off, g_perm = jax.jit(merge_received)(
+        mslots.reshape(2, cap), moff, jnp.arange(2 * cap, dtype=jnp.int32).reshape(2, cap)
+    )
+    got_g = np.asarray(
+        jax.jit(lambda t, s, w: table_gather_sorted(t, s, w, False))(tbl, g_slots, g_off)
+    )
+    want_g = np.asarray(jax.jit(_gather_xla)(tbl, g_slots, g_off))
+    checks["gather_merged_exact"] = max(
+        _rel_err(got_g, want_g), _rel_err(got_g, got_m[:, np.asarray(g_perm)])
+    )
+    d_g = jnp.asarray(d_m[:, np.asarray(g_perm)])
+    got_gs = np.asarray(jax.jit(scat)(tbl, g_slots, g_off, d_g))
+    checks["scatter_merged_exact"] = _rel_err(got_gs, want_ms, floor=1e-2)
 
     # --- packed storage ([S/8, 8K], pack_table): gather BIT-exact vs
     # the logical-layout kernel, scatter equal to the packed logical
@@ -274,6 +297,7 @@ def check_kernel_parity(
     tol = {
         "gather_exact": 0.0,
         "gather_multi_exact": 0.0,
+        "gather_merged_exact": 0.0,
         "gather_bf16": 2.0 ** -7,
         # scatters sum duplicate-slot terms in kernel order, segment_sum
         # in its own — absolute reorder noise is ~1e-6 on unit-scale
@@ -281,6 +305,7 @@ def check_kernel_parity(
         # <=1e-4, while a routing bug moves O(1) mass (err >= ~1)
         "scatter_exact": 1e-4,
         "scatter_multi_exact": 1e-4,
+        "scatter_merged_exact": 1e-4,
         "gather_packed": 0.0,
         "scatter_packed": 1e-4,
         "gather_aligned_k": 0.0,
