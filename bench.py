@@ -115,7 +115,7 @@ def measure_e2e(args, model: str, rows: int, use_cache: bool = False) -> float:
         rate = res.examples / secs
         print(
             f"# e2e[{model}]: rows={rows} gen={gen_s:.1f}s warm={res_warm.seconds:.1f}s "
-            f"timed_epoch={secs:.2f}s steps={res.steps} sorted={trainer._sorted} "
+            f"timed_epoch={secs:.2f}s steps={res.steps} engine={trainer.engine} "
             f"parser_threads=auto({os.cpu_count()} cores)",
             file=sys.stderr,
         )

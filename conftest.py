@@ -22,3 +22,17 @@ import jax
 
 jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
 jax.config.update("jax_num_cpu_devices", 8)
+
+import pytest
+
+
+@pytest.fixture(autouse=True)
+def _quarantine_counter_starts_at_zero():
+    """`data.quarantined_rows` lives in the process-wide registry and
+    every checkpoint's data_state records its run total: a test that
+    quarantined rows would otherwise show up in the data_state of
+    whichever test the worker runs next (tests/test_elastic.py and
+    tests/test_topology.py compare that dict whole)."""
+    from xflow_tpu.telemetry import default_registry
+
+    default_registry().discard("data.quarantined_rows")

@@ -90,13 +90,19 @@ def test_sigterm_checkpoints_and_resumes(tmp_path):
     p = subprocess.Popen(args, cwd=tmp_path, env=env, stdout=subprocess.PIPE,
                          stderr=subprocess.PIPE, text=True)
     # wait until training has demonstrably taken steps (per-step metrics)
+    def stepped():
+        # a step record: the kind="init_state" / "compile" records of the
+        # trainer's construction come before fit() installs the handler
+        if not metrics.exists():
+            return False
+        with open(metrics) as f:
+            return any('"loss"' in line and '"kind"' not in line for line in f)
+
     deadline = time.time() + 300
-    while time.time() < deadline:
-        if metrics.exists() and metrics.stat().st_size > 0:
-            break
+    while time.time() < deadline and not stepped():
         assert p.poll() is None, (p.stdout.read(), p.stderr.read())
         time.sleep(0.2)
-    assert metrics.exists() and metrics.stat().st_size > 0, "training never started"
+    assert stepped(), "training never started"
     p.send_signal(signal.SIGTERM)
     out, err = p.communicate(timeout=120)
     assert p.returncode == 0, (out, err)

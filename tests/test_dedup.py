@@ -113,21 +113,21 @@ def test_trainer_first_batch_decides(tmp_path):
         )
 
     # dedup default is OFF (measured single-chip loss; docs/PERF.md)
-    assert Trainer(cfg)._dedup_cap == 0
+    skewed = sb(np.zeros((B, F), np.int32))
+    assert "unique_slots" not in Trainer(cfg)._engine.batch_arrays(skewed)
     cfg = override(cfg, **{"data.dedup": "auto"})
     # skewed first batch -> dedup on and attached
     tr = Trainer(cfg)
-    assert tr._dedup_cap > 0
-    arrays = tr._batch_arrays(sb(np.zeros((B, F), np.int32)))
-    assert "unique_slots" in arrays and tr._dedup_on is True
+    arrays = tr._engine.batch_arrays(skewed)
+    assert "unique_slots" in arrays and "slots" not in arrays
     # near-uniform FIRST batch -> decided off for the run: later batches
     # skip the host sort entirely (even skewed ones)
     tr2 = Trainer(cfg)
     distinct = np.arange(B * F, dtype=np.int32).reshape(B, F)
-    arrays = tr2._batch_arrays(sb(distinct))
-    assert "unique_slots" not in arrays and tr2._dedup_on is False
-    arrays = tr2._batch_arrays(sb(np.zeros((B, F), np.int32)))
+    arrays = tr2._engine.batch_arrays(sb(distinct))
+    assert "unique_slots" not in arrays
+    arrays = tr2._engine.batch_arrays(skewed)
     assert "unique_slots" not in arrays
     # explicit off disables entirely
     tr3 = Trainer(override(cfg, **{"data.dedup": "off"}))
-    assert tr3._dedup_cap == 0
+    assert "unique_slots" not in tr3._engine.batch_arrays(skewed)

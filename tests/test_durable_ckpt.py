@@ -151,7 +151,7 @@ def copy_tiers(src_root, tmp_path):
     return primary, replica
 
 
-ENGINES = ("single", "gspmd", "replicated", "fullshard")
+ENGINES = ("single", "gspmd", "fullshard")
 
 
 def engine_trainer(cfg, engine):
@@ -164,17 +164,9 @@ def engine_trainer(cfg, engine):
     if engine == "gspmd":
         # sorted engines off -> the generic GSPMD mesh path
         cfg = override(cfg, **{"data.sorted_layout": "off"})
-    elif engine == "replicated":
-        cfg = override(cfg, **{"data.sorted_layout": "on",
-                               "data.sorted_mesh": "replicated"})
     mesh = make_mesh(cfg, np.array(jax.devices()[:2]))
     t = Trainer(cfg, mesh=mesh)
-    if engine == "fullshard":
-        assert t._mesh_engine == "fullshard"
-    elif engine == "replicated":
-        assert t._mesh_engine == "replicated"
-    else:
-        assert t._mesh_engine is None
+    assert t.engine == engine
     return t
 
 

@@ -180,7 +180,7 @@ def test_nan_batch_skipped_on_mesh(dataset):
         mesh = make_mesh(cfg)
         t = Trainer(cfg, mesh=mesh)
         if model == "fm":
-            assert t._mesh_engine == "fullshard"
+            assert t.engine == "fullshard"
         poison_nan_batches(t, steps=[2])
         res = t.fit()
         assert res.bad_steps == 1, model

@@ -267,12 +267,12 @@ def test_trainer_routes_ffm_sorted_and_falls_back_on_dup(tmp_path):
     cfg = ffm_cfg(**{"data.batch_size": 16, "data.max_nnz": NF,
                      "train.metrics_path": str(tmp_path / "m.jsonl")})
     t = Trainer(cfg)
-    assert t._sorted, "FFM auto should select the sorted hybrid now"
+    assert t.engine == "sorted", "FFM auto should select the sorted hybrid now"
     rng = np.random.default_rng(3)
     b = aligned_batch(rng, B=16)
     sb = SparseBatch(slots=b["slots"], fields=b["fields"], mask=b["mask"],
                      labels=b["labels"], row_mask=b["row_mask"])
-    arrays = t._batch_arrays(sb)
+    arrays = t._engine.batch_arrays(sb)
     assert "ffm_invperm" in arrays and "sorted_slots" in arrays
     dup = dict(b)
     dup["fields"] = dup["fields"].copy()
@@ -280,9 +280,9 @@ def test_trainer_routes_ffm_sorted_and_falls_back_on_dup(tmp_path):
     dup["mask"] = np.ones_like(dup["mask"])
     sbd = SparseBatch(slots=dup["slots"], fields=dup["fields"], mask=dup["mask"],
                       labels=dup["labels"], row_mask=dup["row_mask"])
-    arrays_dup = t._batch_arrays(sbd)
+    arrays_dup = t._engine.batch_arrays(sbd)
     assert "sorted_slots" not in arrays_dup and "slots" in arrays_dup
 
     t_on = Trainer(override(cfg, **{"data.sorted_layout": "on"}))
     with pytest.raises(ValueError, match="aligned"):
-        t_on._batch_arrays(sbd)
+        t_on._engine.batch_arrays(sbd)

@@ -256,13 +256,22 @@ def test_coordinated_preemption_two_process(tmp_path):
         cwd=tmp_path, env=_clean_env(), stdout=subprocess.PIPE,
         stderr=subprocess.PIPE, text=True,
     )
+    def stepped():
+        # a STEP record, not a non-empty file: the stream opens with the
+        # kind="init_state" and kind="compile" records of the trainer's
+        # construction, before fit() installs the signal handler — a
+        # SIGTERM sent then is swallowed and the run never stops. A step
+        # is collective, so once rank 0 logged one, rank 1 is inside fit().
+        if not metrics.exists():
+            return False
+        with open(metrics) as f:
+            return any('"loss"' in line and '"kind"' not in line for line in f)
+
     deadline = time.time() + 300
-    while time.time() < deadline:
-        if metrics.exists() and metrics.stat().st_size > 0:
-            break
+    while time.time() < deadline and not stepped():
         assert p.poll() is None, (p.stdout.read(), p.stderr.read())
         time.sleep(0.2)
-    assert metrics.exists() and metrics.stat().st_size > 0, "training never started"
+    assert stepped(), "training never started"
     kids = _children_by_rank(p.pid)
     assert 1 in kids, f"children found: {kids}"
     os.kill(kids[1], signal.SIGTERM)  # NOT rank 0 — coordination must spread it

@@ -319,21 +319,18 @@ def test_launch_local_supervised_auto_restart(tmp_path):
     assert chk.returncode == 0, chk.stderr
 
 
-@pytest.mark.parametrize("engine", ["fullshard", "replicated"])
-def test_launch_local_two_process_sorted_engine(tmp_path, engine):
-    """Multi-process sorted engines: 2 processes × 1 device, mesh
+def test_launch_local_two_process_sorted_engine(tmp_path):
+    """The multi-process sorted engine: 2 processes × 1 device, mesh
     (data=2, table=1), fused FM with sorted_layout=on — final tables
-    match a single-process sorted run on the batch-composed data.
-    Covers BOTH mesh engines: fullshard (table sharded over the whole
-    mesh, occurrence all_to_all crossing the process boundary) and
-    replicated (table on the 'table' axis only)."""
+    match a single-process sorted run on the batch-composed data
+    (fullshard: table sharded over the whole mesh, the occurrence
+    all_to_all crossing the process boundary)."""
     require_multiproc_cpu()
     B, rows = 32, 96
     fm_args = [
         "--model", "fm", "--epochs", "2", "--log2-slots", "13",
         "--set", "model.num_fields=4", "--set", "data.max_nnz=8",
         "--set", "train.pred_dump=false", "--set", "data.sorted_layout=on",
-        "--set", f"data.sorted_mesh={engine}",
         # exact eval on both sides: this is an equality gate, and the
         # multi-process default (bucketed) differs by tie quantization
         # on a 64-row test set
@@ -377,7 +374,7 @@ def test_launch_local_two_process_sorted_engine(tmp_path, engine):
     d1 = np.load(tmp_path / "ckpt1p" / f"step_{s1['steps']}" / "state.npz")
     np.testing.assert_allclose(
         d2["tables/wv"], d1["tables/wv"], rtol=1e-5, atol=1e-6,
-        err_msg="2-process sorted-sharded tables != single-process sorted tables",
+        err_msg="2-process fullshard tables != single-process sorted tables",
     )
     np.testing.assert_allclose(d2["opt/wv/n"], d1["opt/wv/n"], rtol=1e-5, atol=1e-6)
 
@@ -394,7 +391,6 @@ def test_launch_local_two_process_fullshard_ffm(tmp_path):
         "--set", "model.num_fields=4", "--set", "model.v_dim=3",
         "--set", "data.max_nnz=8",
         "--set", "train.pred_dump=false", "--set", "data.sorted_layout=on",
-        "--set", "data.sorted_mesh=fullshard",
     ]
     generate_shards(str(tmp_path / "train"), 2, rows, num_fields=4, ids_per_field=50)
     r2 = run_cli(
@@ -460,7 +456,6 @@ def test_launch_local_two_process_mvm_auto_dup_coordination(tmp_path):
         "--model", "mvm", "--epochs", "1", "--log2-slots", "13",
         "--set", "model.num_fields=4", "--set", "data.max_nnz=8",
         "--set", "train.pred_dump=false", "--set", "data.sorted_layout=on",
-        "--set", "data.sorted_mesh=fullshard",
         "--set", "model.mvm_exclusive=auto",
     ]
     r2 = run_cli(
@@ -524,7 +519,6 @@ def test_launch_local_two_process_fullshard_hot_key_fallback(tmp_path):
         "--model", "fm", "--epochs", "1", "--log2-slots", "13",
         "--set", "model.num_fields=4", "--set", "data.max_nnz=8",
         "--set", "train.pred_dump=false", "--set", "data.sorted_layout=on",
-        "--set", "data.sorted_mesh=fullshard",
         "--set", "data.fullshard_slack=1.25",
     ]
     r2 = run_cli(
@@ -569,7 +563,6 @@ def test_launch_local_two_process_fullshard_mvm_product(tmp_path):
         "--model", "mvm", "--epochs", "2", "--log2-slots", "13",
         "--set", "model.num_fields=4", "--set", "data.max_nnz=8",
         "--set", "train.pred_dump=false", "--set", "data.sorted_layout=on",
-        "--set", "data.sorted_mesh=fullshard",
     ]
     generate_shards(str(tmp_path / "train"), 2, rows, num_fields=4, ids_per_field=50)
     r2 = run_cli(

@@ -222,20 +222,20 @@ def test_trainer_routes_exclusive_to_product_path():
         labels=b["labels"], row_mask=b["row_mask"],
     )
     tr = Trainer(cfg)
-    assert tr._sorted
-    arrays = tr._batch_arrays(sb)
+    assert tr.engine == "sorted"
+    arrays = tr._engine.batch_arrays(sb)
     assert "sorted_fields" not in arrays  # product path
     # duplicate fields in one row -> auto falls back to the segment path
     dup = SparseBatch(
         slots=b["slots"], fields=np.zeros_like(b["fields"]), mask=b["mask"],
         labels=b["labels"], row_mask=b["row_mask"],
     )
-    arrays = tr._batch_arrays(dup)
+    arrays = tr._engine.batch_arrays(dup)
     assert "sorted_fields" in arrays
     # forcing exclusivity raises on the same batch
     tr_on = Trainer(_cfg(**{"model.mvm_exclusive": "on"}))
     with pytest.raises(ValueError, match="mvm_exclusive=off"):
-        tr_on._batch_arrays(dup)
+        tr_on._engine.batch_arrays(dup)
 
 
 # ------------------------------------------------------ fullshard engine

@@ -7,9 +7,7 @@ the one-shot floor pin — then the ladder (parse/pick, exactly-once
 compile per rung through the CompileRecorder, runner dispatch), the
 coalescer's release-rung seam, the byte-identical-when-off pin, the
 metrics_report kind="autotune" schema gate + fleet stamp separation,
-the serve_bench SLO-attainment gate, the perf_ledger p99 leg, and the
-CI smoke gate (tools/smoke_autotune.sh: mis-tuned start -> converges
--> BENCH_SERVE_r17.json).
+the serve_bench SLO-attainment gate and the perf_ledger p99 leg.
 """
 
 import json
@@ -547,27 +545,3 @@ def test_serve_bench_attainment_rides_the_record(tmp_path):
     legs = {e["metric"] for e in ent}
     assert "serve_qps_p99_ms" in legs
     assert "serve_qps_slo_attainment_pct" in legs
-
-
-# ----------------------------------------------------------- CI smoke gate
-def test_smoke_autotune_script(tmp_path):
-    """The autotuning CI gate end to end (tools/smoke_autotune.sh):
-    train -> serve mis-tuned with the controller on -> converge under
-    load (decision trail + /stats + spans) -> headline bench >= 2x the
-    round-9 baseline at equal-or-better p99 -> metrics_report --check/
-    --health -> perf_ledger --regress -> BENCH_SERVE_r17.json."""
-    env = dict(os.environ)
-    env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
-    r = subprocess.run(
-        ["bash", os.path.join(REPO_ROOT, "tools", "smoke_autotune.sh"),
-         str(tmp_path)],
-        capture_output=True, text=True, timeout=570, env=env,
-    )
-    assert r.returncode == 0, f"stdout:\n{r.stdout}\nstderr:\n{r.stderr}"
-    assert "smoke_autotune: OK" in r.stdout
-    assert "converged OK" in r.stdout
-    assert "headline OK" in r.stdout
-    bench = json.load(open(tmp_path / "BENCH_SERVE_r17.json"))
-    assert bench["metric"] == "serve_qps" and bench["round"] == 17
-    assert bench["errors"] == 0
-    assert bench["slo_attainment_pct"] >= 99.0

@@ -214,7 +214,7 @@ def test_trainer_fullshard_auto(model_name, tmp_path):
     cfg = cfg_for(model_name, 4, 2, **over)
     mesh = make_mesh(cfg)
     t_mesh = Trainer(cfg, mesh=mesh)
-    assert t_mesh._mesh_engine == "fullshard"
+    assert t_mesh.engine == "fullshard"
     res_mesh = t_mesh.fit()
     auc_mesh, ll_mesh = t_mesh.evaluate(dump=False)
 
@@ -242,11 +242,10 @@ def test_trainer_auto_falls_back_to_gspmd_when_invalid(tmp_path):
     cfg = cfg_for("fm", 4, 2, **{"data.log2_slots": 12})
     mesh = make_mesh(cfg)
     t = Trainer(cfg, mesh=mesh)
-    assert t._mesh_engine is None
-    assert not t._sorted
+    assert t.engine == "gspmd" and t.planner is None
 
 
-def test_trainer_fullshard_overflow_falls_back_single_process(tmp_path):
+def test_trainer_fullshard_overflow_falls_back_single_process(tmp_path, capsys):
     """A batch too skewed for the buffer capacity must NOT abort a
     single-process run: the trainer falls back to the GSPMD row-major
     step for that batch (state sharding is identical) and warns once."""
@@ -278,10 +277,10 @@ def test_trainer_fullshard_overflow_falls_back_single_process(tmp_path):
     )
     mesh = make_mesh(cfg)
     t = Trainer(cfg, mesh=mesh)
-    assert t._mesh_engine == "fullshard"
+    assert t.engine == "fullshard"
     res = t.fit()
     assert res.steps == 1
-    assert t._fullshard_overflow_warned
+    assert "falling back to the GSPMD row-major step" in capsys.readouterr().err
     assert res.fullshard_overflow_batches == 1
     assert np.isfinite(res.last_loss)
 

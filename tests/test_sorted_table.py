@@ -239,7 +239,7 @@ def test_trainer_sorted_layout_matches_off(tmp_path, model_name, table):
             },
         )
         t = Trainer(cfg)
-        assert t._sorted == (sorted_layout == "on")
+        assert (t.engine == "sorted") == (sorted_layout == "on")
         t.fit()
         return t
 

@@ -16,7 +16,6 @@ from xflow_tpu.config import Config, override
 from xflow_tpu.models import get_model
 from xflow_tpu.optim import get_optimizer
 from xflow_tpu.parallel.mesh import make_mesh, state_shardings
-from xflow_tpu.parallel.sorted_sharded import sorted_state_shardings
 from xflow_tpu.train.state import build_state, init_state
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -40,7 +39,6 @@ def _layouts(mesh):
     return {
         "fullshard": lambda s: state_shardings(s, mesh),
         "gspmd": lambda s: state_shardings(s, mesh),
-        "replicated": lambda s: sorted_state_shardings(s, mesh),
         "one_device": None,
     }
 
@@ -61,7 +59,7 @@ def _assert_same_bits(got, want):
 
 @pytest.mark.parametrize("layout,model_name", [
     ("fullshard", "fm"), ("gspmd", "mvm"), ("gspmd", "lr"),
-    ("replicated", "fm"),  # the replicated engine runs packed [S/8, 8K] tables only
+    ("fullshard", "ffm"),  # the widest packed row
     ("one_device", "fm"), ("one_device", "lr"),
 ])
 def test_state_born_sharded_has_the_eager_init_bits(layout, model_name):
@@ -83,8 +81,7 @@ def test_state_born_sharded_has_the_eager_init_bits(layout, model_name):
             assert leaf.sharding.is_equivalent_to(sh, leaf.ndim)
 
 
-@pytest.mark.parametrize("layout,share", [("fullshard", 4), ("replicated", 2)])
-def test_sharded_init_program_holds_a_share_per_device(layout, share):
+def test_sharded_init_program_holds_a_share_per_device():
     """No leaf, and no temporary of a leaf's size, whole on one device:
     the compiled init program's output and temporaries a device are the
     unsharded program's over the number of shards (the sampler's words
@@ -97,20 +94,19 @@ def test_sharded_init_program_holds_a_share_per_device(layout, share):
     def init():
         return init_state(model, opt, cfg)
 
-    out = _layouts(mesh)[layout](jax.eval_shape(init))
+    out = _layouts(mesh)["fullshard"](jax.eval_shape(init))
     whole = jax.jit(init).lower().compile().memory_analysis()
     part = jax.jit(init, out_shardings=out).lower().compile().memory_analysis()
     slack = 4096
-    assert part.output_size_in_bytes <= whole.output_size_in_bytes / share + slack
-    assert part.temp_size_in_bytes <= whole.temp_size_in_bytes / share + slack
-    if share == 4:  # the eager path held the whole leaf here
-        assert part.temp_size_in_bytes < (1 << 16) * 11 * 4
+    assert part.output_size_in_bytes <= whole.output_size_in_bytes / 4 + slack
+    assert part.temp_size_in_bytes <= whole.temp_size_in_bytes / 4 + slack
+    # the eager path held the whole leaf here
+    assert part.temp_size_in_bytes < (1 << 16) * 11 * 4
 
 
 def _trainer_cfg(engine, tmp_path, **extra):
     base = {
         "fullshard": ("fm", {}),
-        "replicated": ("fm", {"data.sorted_mesh": "replicated", "data.sorted_layout": "on"}),
         "gspmd": ("lr", {}),
         "one_device": ("fm", {}),
     }[engine]
@@ -118,9 +114,9 @@ def _trainer_cfg(engine, tmp_path, **extra):
     return _cfg(base[0], log2_slots=14, **{**base[1], "train.pred_dump": False, **extra})
 
 
-@pytest.mark.parametrize("engine", ["fullshard", "replicated", "gspmd", "one_device"])
+@pytest.mark.parametrize("engine", ["fullshard", "gspmd", "one_device"])
 def test_trainer_builds_its_state_through_the_one_helper(engine, tmp_path):
-    """All four sites of Trainer.__init__: the state is the eager
+    """Every engine of train/engine.py: the state is the eager
     init's, lives in the engine's layout, and the `xflow:init_state`
     span's kind="init_state" record carries its size, whole and on the
     fullest device."""
@@ -138,7 +134,7 @@ def test_trainer_builds_its_state_through_the_one_helper(engine, tmp_path):
     leaves = jax.tree.leaves(t.state)
     total = sum(x.nbytes for x in leaves)
     assert rec["state_bytes_total"] == total
-    shards = {"one_device": 1, "replicated": 2}.get(engine, 4)
+    shards = 1 if engine == "one_device" else 4
     # every table leaf split `shards` ways, the int32 step on every device
     assert rec["state_bytes_per_device"] == (total - 4) // shards + 4
     fullest = max(

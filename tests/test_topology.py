@@ -9,7 +9,7 @@ Two properties are pinned here:
    layout, every leaf lands on the live sharding, and the data_state's
    per-SHARD offsets re-assign the record set to the new world with
    exact coverage (no record trained twice, none dropped). The mesh
-   matrix (1<->2<->4 devices, GSPMD / sorted replicated / fullshard /
+   matrix (1<->2<->4 devices, GSPMD / fullshard /
    single-device engines) runs in-process on the conftest's 8-CPU-device
    fake cluster; the true multi-PROCESS shrink drill is
    tools/smoke_topology.sh (probe-gated like every 2-proc drill).
@@ -233,32 +233,31 @@ def test_restore_reshards_gspmd_mesh_sizes(dataset, tmp_path):
 
 @pytest.mark.skipif(len(jax.devices()) < 4, reason="needs 4 CPU devices")
 def test_restore_reshards_across_sorted_engines(dataset, tmp_path):
-    """Fused FM across ALL FOUR engines: a fullshard-engine checkpoint
+    """Fused FM across the engines: a fullshard-engine checkpoint
     (2-device mesh) restores into the 4-device fullshard mesh, the
-    sorted REPLICATED engine, and the single-device sorted step — the
-    canonical logical npz layout makes the engine irrelevant."""
+    2-device GSPMD row-major engine, and the single-device sorted step
+    — the canonical logical npz layout makes the engine irrelevant."""
     base = {"train.checkpoint_dir": str(tmp_path / "ck"),
             "data.log2_slots": 14, "data.batch_size": 128,
             "model.name": "fm"}
     cfg = make_cfg(dataset, **base)
     t = Trainer(cfg, mesh=mesh_of(cfg, 2))
-    assert t._mesh_engine == "fullshard"
+    assert t.engine == "fullshard"
     t.fit()
     wv = np.asarray(jax.device_get(t.state.tables["wv"]))
     step = int(t.state.step)
 
     # 4-device fullshard
     t4 = Trainer(cfg, mesh=mesh_of(cfg, 4))
-    assert t4._mesh_engine == "fullshard"
+    assert t4.engine == "fullshard"
     assert t4.maybe_restore() and int(t4.state.step) == step
     np.testing.assert_array_equal(
         np.asarray(jax.device_get(t4.state.tables["wv"])), wv
     )
-    # 2-device sorted REPLICATED engine
-    cfg_r = make_cfg(dataset, **{**base, "data.sorted_layout": "on",
-                                 "data.sorted_mesh": "replicated"})
+    # 2-device GSPMD row-major engine
+    cfg_r = make_cfg(dataset, **{**base, "data.sorted_layout": "off"})
     tr = Trainer(cfg_r, mesh=mesh_of(cfg_r, 2))
-    assert tr._mesh_engine == "replicated"
+    assert tr.engine == "gspmd"
     assert tr.maybe_restore() and int(tr.state.step) == step
     np.testing.assert_array_equal(
         np.asarray(jax.device_get(tr.state.tables["wv"])), wv

@@ -277,19 +277,16 @@ def test_fused_scatter_on_rejected_on_mesh_at_startup():
 # builder and every optimizer to that.
 
 GUARD_B, GUARD_F, GUARD_LOG2 = 64, 8, 14  # 16384 slots = 8 windows
-GUARD_BUILDERS = ("single_lr", "sorted_fm", "gspmd", "fullshard", "replicated_sorted")
+GUARD_BUILDERS = ("single_lr", "sorted_fm", "gspmd", "fullshard")
 
 
 def _guard_rig(builder, optim, guard):
     """(step, state, place) for one step builder at a tiny size: `place`
     turns a row-major numpy batch into that builder's step input."""
-    from xflow_tpu.ops.sorted_table import plan_sorted_batch, plan_sorted_stacked
+    from xflow_tpu.ops.sorted_table import plan_sorted_batch
     from xflow_tpu.parallel.mesh import batch_sharding, make_mesh
     from xflow_tpu.parallel.sorted_fullshard import (
         fullshard_batch_sharding, make_fullshard_train_step, plan_fullshard_batch,
-    )
-    from xflow_tpu.parallel.sorted_sharded import (
-        make_sorted_sharded_train_step, shard_sorted_state,
     )
     from xflow_tpu.parallel.train_step import make_sharded_train_step, shard_state
 
@@ -330,22 +327,13 @@ def _guard_rig(builder, optim, guard):
             state = shard_state(state, mesh)
             bsh = batch_sharding(mesh)
             place = lambda b: {k: jax.device_put(jnp.asarray(v), bsh[k]) for k, v in b.items()}
-        elif builder == "fullshard":
+        else:
             step = make_fullshard_train_step(opt, cfg, mesh)
             state = shard_state(state, mesh)
             bsh = fullshard_batch_sharding(mesh, with_fields=False)
             place = lambda b: {
                 k: jax.device_put(jnp.asarray(v), bsh[k])
                 for k, v in {**plan_fullshard_batch(b["slots"], b["mask"], cfg, mesh), **rows(b)}.items()
-            }
-        else:
-            step = make_sorted_sharded_train_step(opt, cfg, mesh)
-            state = shard_sorted_state(state, mesh)
-            place = lambda b: {
-                k: jnp.asarray(v)
-                for k, v in sorted_arrays(
-                    plan_sorted_stacked(b["slots"], b["mask"], S, num_sub=d, always_stack=True), b
-                ).items()
             }
     return step, state, place
 
@@ -371,8 +359,6 @@ def _host_leaves(state):
 
 @pytest.mark.parametrize("builder,optim", [
     (b, o) for b in GUARD_BUILDERS for o in ("ftrl", "sgd")
-    # the replicated sorted engine's state shardings are FTRL's {n, z}
-    if (b, o) != ("replicated_sorted", "sgd")
 ])
 def test_nonfinite_guard_discards_exactly_and_costs_good_steps_nothing(builder, optim):
     good = _guard_batches(4)

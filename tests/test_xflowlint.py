@@ -554,7 +554,7 @@ def test_cli_unknown_rule_is_usage_error():
 
 
 def test_contract_artifact_checked_in_and_byte_stable():
-    """tools/engine_contracts.json: covers all four engine builders,
+    """tools/engine_contracts.json: covers every engine builder,
     the AST sections match a fresh extraction, two consecutive
     extractions render byte-identically (ISSUE 14 acceptance), and the
     v2 jaxpr section (ISSUE 15) is present and program-complete."""
@@ -593,8 +593,8 @@ def test_contract_artifact_checked_in_and_byte_stable():
 def test_contract_matrix_covers_known_invariants():
     """Spot-check the matrix against facts the builders guarantee
     today: every train program donates the state, every engine covers
-    the core trace scopes, the sorted-sharded table rides
-    P('table', None)."""
+    the core trace scopes, the fullshard builder names both mesh
+    axes."""
     data = json.load(open(os.path.join(REPO_ROOT, "tools",
                                        "engine_contracts.json")))
     train_programs = 0
@@ -603,10 +603,9 @@ def test_contract_matrix_covers_known_invariants():
             if name.startswith("train_step"):
                 train_programs += 1
                 assert prog["donate_argnums"] == [0], (rel, name)
-    assert train_programs == 4  # one train program per builder
-    ss = data["engines"]["xflow_tpu/parallel/sorted_sharded.py"]
-    assert ss["leaf_specs"]["wv"] == ["NamedSharding(P('table', None))"]
-    assert ss["leaf_specs"]["wv.n"] == ss["leaf_specs"]["wv.z"]
+    assert train_programs == 3  # one train program per builder
+    fs = data["engines"]["xflow_tpu/parallel/sorted_fullshard.py"]
+    assert fs["axes_referenced"] == ["data", "table"]
     for rel, eng in data["engines"].items():
         if rel == "xflow_tpu/parallel/train_step.py":
             continue  # inherits the shared step's scopes by delegation
@@ -620,7 +619,6 @@ def test_cli_check_contracts_green_then_drift_exits_4(tmp_path):
     root = tmp_path / "tree"
     for rel in ("xflow_tpu/train/step.py", "xflow_tpu/parallel/mesh.py",
                 "xflow_tpu/parallel/train_step.py",
-                "xflow_tpu/parallel/sorted_sharded.py",
                 "xflow_tpu/parallel/sorted_fullshard.py",
                 "tools/engine_contracts.json"):
         dst = root / rel
@@ -629,7 +627,7 @@ def test_cli_check_contracts_green_then_drift_exits_4(tmp_path):
     r = run_cli("--root", str(root), "--check-contracts")
     assert r.returncode == 0, (r.stdout, r.stderr)
     # drop the donation from one builder: contract drift, exit 4
-    sf = root / "xflow_tpu/parallel/sorted_sharded.py"
+    sf = root / "xflow_tpu/parallel/sorted_fullshard.py"
     sf.write_text(sf.read_text().replace("donate_argnums=(0,),", ""))
     r = run_cli("--root", str(root), "--check-contracts")
     assert r.returncode == 4 and "CONTRACT DRIFT" in r.stderr
@@ -641,20 +639,19 @@ def test_xf704_scope_drift_across_builders(tmp_path):
     root = tmp_path / "tree"
     for rel in ("xflow_tpu/train/step.py", "xflow_tpu/parallel/mesh.py",
                 "xflow_tpu/parallel/train_step.py",
-                "xflow_tpu/parallel/sorted_sharded.py",
                 "xflow_tpu/parallel/sorted_fullshard.py"):
         dst = root / rel
         dst.parent.mkdir(parents=True, exist_ok=True)
         shutil.copy(os.path.join(REPO_ROOT, rel), dst)
     project = Project.load(str(root))
     assert [f for f in run_passes(project) if f.rule == "XF704"] == []
-    sf = root / "xflow_tpu/parallel/sorted_sharded.py"
+    sf = root / "xflow_tpu/parallel/sorted_fullshard.py"
     sf.write_text(sf.read_text().replace(
         'named_scope("optimizer")', 'named_scope("optimzer")'))
     findings = [f for f in run_passes(Project.load(str(root)))
                 if f.rule == "XF704"]
     assert len(findings) == 1
-    assert findings[0].path == "xflow_tpu/parallel/sorted_sharded.py"
+    assert findings[0].path == "xflow_tpu/parallel/sorted_fullshard.py"
     assert "'optimizer'" in findings[0].message
 
 
@@ -676,7 +673,7 @@ def test_xf704_partial_scan_matches_full_tree_verdict():
     findings = lint(
         os.path.join(REPO_ROOT, "xflow_tpu", "train", "step.py"),
         os.path.join(REPO_ROOT, "xflow_tpu", "parallel",
-                     "sorted_sharded.py"))
+                     "sorted_fullshard.py"))
     assert [f for f in findings if f.rule == "XF704"] == []
 
 
@@ -703,15 +700,15 @@ def test_xf704_intra_builder_leaf_spec_disagreement(tmp_path):
     root = tmp_path / "tree"
     for rel in ("xflow_tpu/train/step.py", "xflow_tpu/parallel/mesh.py",
                 "xflow_tpu/parallel/train_step.py",
-                "xflow_tpu/parallel/sorted_sharded.py",
                 "xflow_tpu/parallel/sorted_fullshard.py"):
         dst = root / rel
         dst.parent.mkdir(parents=True, exist_ok=True)
         shutil.copy(os.path.join(REPO_ROOT, rel), dst)
-    sf = root / "xflow_tpu/parallel/sorted_sharded.py"
+    sf = root / "xflow_tpu/parallel/sorted_fullshard.py"
     sf.write_text(
         sf.read_text()
-        + "\n\n_drifted = {\"wv\": NamedSharding(None, P(None, None))}\n"
+        + "\n\n_declared = {\"wv\": NamedSharding(None, P(\"table\", None))}\n"
+        + "_drifted = {\"wv\": NamedSharding(None, P(None, None))}\n"
     )
     findings = [f for f in run_passes(Project.load(str(root)))
                 if f.rule == "XF704"]
@@ -1185,7 +1182,6 @@ def test_checked_in_worklist_names_lr_and_fm_chains():
     # every sorted engine contributes a chain (the per-shard kernel
     # targets the mesh programs lower)
     programs = {e["program"] for e in data["entries"]}
-    assert "train_step.replicated[fm]" in programs
     assert "train_step.fullshard.fm[fm]" in programs
     assert "train_step.gspmd[lr]" in programs
 

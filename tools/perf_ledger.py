@@ -174,7 +174,7 @@ def normalize_multichip(path: str, data) -> list[dict]:
         "n_devices": data.get("n_devices"),
         "skipped": bool(data.get("skipped")),
     }] if isinstance(data, dict) else []
-    # multi-slice records (MULTICHIP_r16+, tools/smoke_multislice.sh)
+    # multi-slice records (MULTICHIP_r06.json and later)
     # also carry the measured aggregate: the N-slice throughput and its
     # speedup over one slice. The `ok` flag above already folds the
     # >= 1.8x acceptance gate (the script computes it); these entries

@@ -203,7 +203,7 @@ class _LintSeededBranch:
             if m["update_ok"]:  # SEED
                 break
 EOF
-seed XF701 xflow_tpu/parallel/sorted_sharded.py <<'EOF'
+seed XF701 xflow_tpu/parallel/sorted_fullshard.py <<'EOF'
 
 
 def _lint_seeded_axis(mesh):
@@ -228,24 +228,24 @@ def _lint_seeded_nodonate():
 EOF
 echo "smoke_lint: seeded-violation drill OK (10 rule classes, exact file:line)"
 
-# ---- 4b. XF704 cross-engine drift needs all four builders in one root ----
+# ---- 4b. XF704 cross-engine drift needs every builder in one root ---------
 DRIFT="$WORK/drift_tree"
 mkdir -p "$DRIFT/xflow_tpu/train" "$DRIFT/xflow_tpu/parallel"
 cp xflow_tpu/train/step.py "$DRIFT/xflow_tpu/train/"
-cp xflow_tpu/parallel/train_step.py xflow_tpu/parallel/sorted_sharded.py \
+cp xflow_tpu/parallel/train_step.py \
    xflow_tpu/parallel/sorted_fullshard.py xflow_tpu/parallel/mesh.py \
    "$DRIFT/xflow_tpu/parallel/"
 python tools/xflowlint.py --root "$DRIFT" --no-baseline >/dev/null 2>&1 \
     || { echo "smoke_lint: faithful builder copies must lint clean"; exit 1; }
 # rename one builder's "optimizer" scope: every OTHER builder covers it
 sed -i 's/named_scope("optimizer")/named_scope("optimzer")/' \
-    "$DRIFT/xflow_tpu/parallel/sorted_sharded.py"
-line=$(grep -n 'jax.named_scope' "$DRIFT/xflow_tpu/parallel/sorted_sharded.py" \
+    "$DRIFT/xflow_tpu/parallel/sorted_fullshard.py"
+line=$(grep -n 'jax.named_scope' "$DRIFT/xflow_tpu/parallel/sorted_fullshard.py" \
     | head -1 | cut -d: -f1)
 out=$(python tools/xflowlint.py --root "$DRIFT" --no-baseline 2>/dev/null || true)
-grep -qE "sorted_sharded.py:$line: XF704" <<<"$out" || {
+grep -qE "sorted_fullshard.py:$line: XF704" <<<"$out" || {
     echo "smoke_lint: seeded XF704 scope drift not caught at" \
-         "sorted_sharded.py:$line"; echo "$out"; exit 1; }
+         "sorted_fullshard.py:$line"; echo "$out"; exit 1; }
 echo "smoke_lint: XF704 cross-engine scope-drift drill OK"
 
 # ---- 5. engine-contract matrix: checked in, byte-stable, drift-gated ------
@@ -256,7 +256,7 @@ CONTRACT="$WORK/contract_tree"
 mkdir -p "$CONTRACT/xflow_tpu/train" "$CONTRACT/xflow_tpu/parallel" \
          "$CONTRACT/tools"
 cp xflow_tpu/train/step.py "$CONTRACT/xflow_tpu/train/"
-cp xflow_tpu/parallel/train_step.py xflow_tpu/parallel/sorted_sharded.py \
+cp xflow_tpu/parallel/train_step.py \
    xflow_tpu/parallel/sorted_fullshard.py xflow_tpu/parallel/mesh.py \
    "$CONTRACT/xflow_tpu/parallel/"
 cp tools/engine_contracts.json "$CONTRACT/tools/"
@@ -277,7 +277,7 @@ cmp -s "$WORK/contracts_r1.json" tools/engine_contracts.json || {
 # drift gate: change a builder's contract (drop the donation) without
 # regenerating -> exit 4, distinct from finding growth (1) / stale (2)
 sed -i 's/donate_argnums=(0,),//' \
-    "$CONTRACT/xflow_tpu/parallel/sorted_sharded.py"
+    "$CONTRACT/xflow_tpu/parallel/sorted_fullshard.py"
 rc=0; python tools/xflowlint.py --root "$CONTRACT" --check-contracts \
     >/dev/null 2>&1 || rc=$?
 [ "$rc" -eq 4 ] || {

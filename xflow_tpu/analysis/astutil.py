@@ -3,7 +3,25 @@
 from __future__ import annotations
 
 import ast
+import os
 from typing import Iterator, Optional
+
+
+def engine_modules() -> dict:
+    """{engine name: builder module (repo-relative)} — the literal
+    `ENGINE_MODULES` of xflow_tpu/train/engine.py, the one place that
+    lists the table engines. Read from its source: the AST tier never
+    imports the code it checks (engine.py imports jax)."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "train", "engine.py")
+    with open(path, encoding="utf-8") as f:
+        tree = ast.parse(f.read())
+    for node in tree.body:
+        if isinstance(node, ast.Assign) and any(
+                isinstance(t, ast.Name) and t.id == "ENGINE_MODULES"
+                for t in node.targets):
+            return ast.literal_eval(node.value)
+    raise LookupError(f"no ENGINE_MODULES literal in {path}")
 
 
 def dotted(node: ast.AST) -> Optional[str]:

@@ -23,7 +23,7 @@ xflow_tpu.analysis.ir --root R``) so that
   "unavailable" verdict (exit 5) the AST tier can report and continue
   past — scratch-copy AST-only linting keeps working.
 
-What it extracts, per program in ``PROGRAMS`` (the four engine
+What it extracts, per program in ``PROGRAMS`` (the engine
 builders' train/eval/predict programs across the model variants the
 ROADMAP's kernel arc targets):
 
@@ -55,6 +55,8 @@ import math
 import os
 import sys
 
+from xflow_tpu.analysis.astutil import engine_modules
+
 # primitives whose operand-0 is a table being read / written sparsely
 GATHER_PRIMS = ("gather",)
 SCATTER_PRIMS = ("scatter", "scatter-add", "scatter-mul", "scatter-max",
@@ -82,31 +84,34 @@ ELEMENTWISE_PRIMS = frozenset({
 # "<recorder name>[<variant>]" — recorder names repeat across configs
 # ("train_step" serves both the LR and FM variants), the bracket makes
 # them unique and greppable.
-PROGRAMS = (
-    # key, engine module (repo-relative), builder, config overrides, batch
-    ("train_step[lr]", "xflow_tpu/train/step.py", "single_train",
+# The engine column names an engine of train/engine.py, which says
+# where its builder lives.
+_PROGRAMS = (
+    # key, engine, builder, config overrides, batch
+    ("train_step[lr]", "row_major", "single_train",
      {"model.name": "lr"}, "rowmajor"),
-    ("predict[lr]", "xflow_tpu/train/step.py", "single_eval",
+    ("predict[lr]", "row_major", "single_eval",
      {"model.name": "lr"}, "rowmajor"),
-    ("train_step[fm]", "xflow_tpu/train/step.py", "single_train",
+    ("train_step[fm]", "row_major", "single_train",
      {"model.name": "fm"}, "rowmajor"),
     # the kernel arc's marquee target: the sorted fused path (on CPU the
     # scatter+FTRL fusion falls back to gather/scatter + elementwise XLA
     # ops — exactly the chain the Pallas kernel replaces)
-    ("train_step[fm.sorted]", "xflow_tpu/train/step.py", "single_train",
+    ("train_step[fm.sorted]", "sorted", "single_train",
      {"model.name": "fm"}, "sorted_flat"),
-    ("train_step.gspmd[lr]", "xflow_tpu/parallel/train_step.py",
-     "gspmd_train", {"model.name": "lr"}, "rowmajor"),
-    ("predict.gspmd[lr]", "xflow_tpu/parallel/train_step.py",
-     "gspmd_eval", {"model.name": "lr"}, "rowmajor"),
-    ("train_step.replicated[fm]", "xflow_tpu/parallel/sorted_sharded.py",
-     "sorted_sharded_train", {"model.name": "fm"}, "sorted_stacked"),
-    ("train_step.fullshard.fm[fm]",
-     "xflow_tpu/parallel/sorted_fullshard.py", "fullshard_train",
+    ("train_step.gspmd[lr]", "gspmd", "gspmd_train",
+     {"model.name": "lr"}, "rowmajor"),
+    ("predict.gspmd[lr]", "gspmd", "gspmd_eval",
+     {"model.name": "lr"}, "rowmajor"),
+    ("train_step.fullshard.fm[fm]", "fullshard", "fullshard_train",
      {"model.name": "fm"}, "fullshard"),
-    ("predict.fullshard.fm[fm]",
-     "xflow_tpu/parallel/sorted_fullshard.py", "fullshard_eval",
+    ("predict.fullshard.fm[fm]", "fullshard", "fullshard_eval",
      {"model.name": "fm"}, "fullshard"),
+)
+# key, engine module (repo-relative), builder, config overrides, batch
+_MODULE_OF = engine_modules()
+PROGRAMS = tuple(
+    (key, _MODULE_OF[engine], *rest) for key, engine, *rest in _PROGRAMS
 )
 
 # mesh shape every sharded program lowers against (forced host devices)
@@ -202,32 +207,6 @@ def _sorted_flat_batch(cfg):
     }
 
 
-def _sorted_stacked_batch(cfg, mesh):
-    """Stacked per-data-shard plans [D, Np_l] (sorted_sharded path)."""
-    import jax
-    import jax.numpy as jnp
-
-    from xflow_tpu.ops.sorted_table import CHUNK, WINDOW
-    from xflow_tpu.parallel.mesh import DATA_AXIS, batch_sharding
-
-    sds = jax.ShapeDtypeStruct
-    sh = batch_sharding(mesh)
-    B, F = cfg.data.batch_size, cfg.data.max_nnz
-    D = mesh.shape[DATA_AXIS]
-    rows = B // D
-    npad = (rows * F // CHUNK + 2) * CHUNK
-    n_win = cfg.num_slots // WINDOW
-    mk = lambda k, shape, dt: sds(shape, dt, sharding=sh[k])
-    return {
-        "sorted_slots": mk("sorted_slots", (D, npad), jnp.int32),
-        "sorted_row": mk("sorted_row", (D, npad), jnp.int32),
-        "sorted_mask": mk("sorted_mask", (D, npad), jnp.float32),
-        "win_off": mk("win_off", (D, n_win + 1), jnp.int32),
-        "labels": mk("labels", (B,), jnp.float32),
-        "row_mask": mk("row_mask", (B,), jnp.float32),
-    }
-
-
 def _fullshard_batch(cfg, mesh):
     import jax
     import jax.numpy as jnp
@@ -260,9 +239,6 @@ def _fullshard_batch(cfg, mesh):
 
 def _build_program(key, engine, builder, overrides, batch_kind):
     """-> (recorder name, jit object, (arg pytrees...), cfg)."""
-    import jax
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
     from xflow_tpu.config import Config, override
     from xflow_tpu.models import get_model
     from xflow_tpu.optim import get_optimizer
@@ -311,23 +287,6 @@ def _build_program(key, engine, builder, overrides, batch_kind):
         call = make_sharded_eval_step(model, cfg, mesh, recorder=cap)
         name, fn = _capture(lambda: call(st.tables, batch))
         return name, fn, (st.tables, batch), cfg
-    if builder == "sorted_sharded_train":
-        from xflow_tpu.parallel.mesh import TABLE_AXIS
-        from xflow_tpu.parallel.sorted_sharded import (
-            make_sorted_sharded_train_step,
-        )
-
-        tsh = NamedSharding(mesh, P(TABLE_AXIS, None))
-        rep = NamedSharding(mesh, P())
-        st = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(
-                x.shape, x.dtype,
-                sharding=tsh if getattr(x, "ndim", 0) >= 1 else rep),
-            state)
-        batch = _sorted_stacked_batch(cfg, mesh)
-        name, fn = _capture(lambda: make_sorted_sharded_train_step(
-            opt, cfg, mesh, recorder=cap))
-        return name, fn, (st, batch), cfg
     if builder == "fullshard_train":
         from xflow_tpu.parallel.sorted_fullshard import (
             make_fullshard_train_step,

@@ -42,11 +42,7 @@ JIT_CALLS = {"jax.jit", "jit", "pjit", "jax.pjit"}
 
 # modules where PR 7's recorder contract applies: every jitted program
 # must be wrapped so compile accounting sees it
-RECORDER_SCOPED = (
-    "xflow_tpu/train/step.py",
-    "xflow_tpu/parallel/train_step.py",
-    "xflow_tpu/parallel/sorted_sharded.py",
-    "xflow_tpu/parallel/sorted_fullshard.py",
+RECORDER_SCOPED = tuple(dict.fromkeys(astutil.engine_modules().values())) + (
     "xflow_tpu/models/predict.py",
     "xflow_tpu/serve/",
 )

@@ -62,13 +62,15 @@ from xflow_tpu.analysis.passes.recompile import _static_spec
 
 RULES = ("XF701", "XF702", "XF703", "XF704")
 
-ENGINE_MODULES = (
-    "xflow_tpu/train/step.py",
-    "xflow_tpu/parallel/train_step.py",
-    "xflow_tpu/parallel/sorted_sharded.py",
-    "xflow_tpu/parallel/sorted_fullshard.py",
-)
+# the step builders' modules, each once, in train/engine.py's order
+ENGINE_MODULES = tuple(dict.fromkeys(astutil.engine_modules().values()))
 SHARED_STEP_MODULE = "xflow_tpu/train/step.py"
+# XF704(a)'s vocabulary: the stages every engine's trace attributes to
+# (docs/OBSERVABILITY.md). A scope beyond it is one builder's own — the
+# single-device step's fused "scatter_optimizer" kernel has no
+# counterpart on a mesh engine, whose two-pass form reads as "grad" +
+# "optimizer"
+STAGE_SCOPES = frozenset({"gather", "loss", "grad", "optimizer"})
 MESH_MODULE = "xflow_tpu/parallel/mesh.py"
 ARTIFACT_REL = "tools/engine_contracts.json"
 
@@ -683,7 +685,7 @@ def _analyze(project: Project) -> tuple:
                       if r != rel and effective[r] is not None]
             if not others:
                 continue
-            everywhere_else = set.intersection(*others)
+            everywhere_else = set.intersection(*others) & STAGE_SCOPES
             for scope in sorted(everywhere_else - effective[rel]):
                 line = min(mc.scope_lines) if mc.scope_lines else 1
                 findings.append(Finding(

@@ -99,7 +99,7 @@ class ModelConfig:
     # batches to the segment path. Single-process routes locally; the
     # multi-process fullshard engine coordinates the per-batch choice
     # through a rank-symmetric flag allgather
-    # (trainer._resolve_fullshard_overflow) so every rank picks the
+    # (train/engine.py `Engine.agree`) so every rank picks the
     # same mode; other multi-process engines raise on duplicates (no
     # coordination point — models/mvm.py resolve_mvm_product). "on":
     # require exclusive fields (raise on duplicates). "off": always
@@ -171,17 +171,6 @@ class DataConfig:
     # smallest power of two keeping B/NS·nf·(k+1)·4B under 16 MiB — the
     # measured sweet spot on v5e, docs/PERF.md).
     sorted_sub_batches: int = 0
-    # which sorted engine runs on a device mesh:
-    # - "fullshard" (default): table + optimizer state sharded over the
-    #   WHOLE mesh, P(('data','table')) — each device owns S/(D*T) slots,
-    #   occurrences travel to their slot owners by one all_to_all, row
-    #   aggregates return by one psum_scatter + psum, and the table
-    #   gradient never leaves its device (parallel/sorted_fullshard.py).
-    #   The 1B-feature regime (12 GB+ FTRL state) requires this layout.
-    # - "replicated": table sharded on the 'table' axis only, replicated
-    #   across 'data' (D× table memory; parallel/sorted_sharded.py) —
-    #   fewer collectives, viable when the table fits per-device HBM.
-    sorted_mesh: str = "fullshard"
     # host-side batch dedup for the ROW-MAJOR paths (reference analog:
     # per-minibatch unique-key Pull, lr_worker.cc:150-165): ship
     # (unique_slots, inverse) so the table gather moves U rows instead

@@ -50,7 +50,7 @@ side only, where the no-replication layout requires it — the round-4
 single-device forced-sorted segment path (and the XLA compiler crash
 it hit at nf·k = 128, B = 64k) no longer exists: `sorted_layout=on`
 now means the hybrid, and rejects non-aligned batches with a clear
-error (trainer._resolve_ffm_aligned).
+error (train/engine.py _ffm_aligned).
 """
 
 from __future__ import annotations

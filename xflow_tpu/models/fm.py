@@ -73,8 +73,8 @@ def stack_channels(occm_t, K):
 
 def fm_logits_from_sums(sums, K, cfg):
     """[rows, ch] per-row channel sums -> [rows] logits. Shared by the
-    single-device sorted path and the sharded engine
-    (parallel/sorted_sharded.py) so the second-order math cannot drift."""
+    single-device sorted path and the fullshard engine
+    (parallel/sorted_fullshard.py) so the second-order math cannot drift."""
     nch = 2 * K - 1
     wx = sums[:, 0]
     s, q = sums[:, 1:K], sums[:, K:nch]  # [rows, k] each

@@ -81,7 +81,7 @@ def resolve_mvm_product(mvm_exclusive: str, has_dup: bool, num_processes: int) -
     everywhere. The multi-process fullshard engine does NOT call this
     under `auto` — it plans with fields and coordinates the per-batch
     choice through a rank-symmetric flag allgather
-    (trainer._resolve_fullshard_overflow), so a local data-dependent
+    (train/engine.py `Engine.agree`), so a local data-dependent
     raise can never strand peer ranks in their collectives. Under `on`
     duplicates raise by contract (the user asserted exclusive fields).
     """

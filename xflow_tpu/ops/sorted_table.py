@@ -408,7 +408,6 @@ def plan_sorted_stacked(
     num_slots: int,
     fields: Optional[np.ndarray] = None,
     num_sub: int = 1,
-    always_stack: bool = False,
     wire: bool = False,
 ) -> SortedPlan:
     """Per-sub-batch sorted plans, stacked on a leading [NS] axis.
@@ -419,21 +418,11 @@ def plan_sorted_stacked(
     [B/NS, ...] — small enough to stay cache-resident for models whose
     row-side state is large (MVM's [B·nf, k]); XLA accumulates the table
     gradient across sub-batches. `B % num_sub == 0` is required (the
-    planner's callers pick a divisor). `num_sub=1` returns FLAT arrays
-    unless `always_stack` (the sharded engine wants [1, Np] at D=1).
+    planner's callers pick a divisor). `num_sub=1` returns FLAT arrays.
     """
     B = slots.shape[0]
     if num_sub <= 1:
-        p = plan_sorted_batch(slots, mask, num_slots, fields=fields, wire=wire)
-        if not always_stack:
-            return p
-        return SortedPlan(
-            sorted_slots=p.sorted_slots[None],
-            sorted_row=p.sorted_row[None],
-            sorted_mask=p.sorted_mask[None],
-            win_off=p.win_off[None],
-            sorted_fields=None if p.sorted_fields is None else p.sorted_fields[None],
-        )
+        return plan_sorted_batch(slots, mask, num_slots, fields=fields, wire=wire)
     if B % num_sub:
         raise ValueError(f"batch {B} not divisible by num_sub {num_sub}")
     bs = B // num_sub

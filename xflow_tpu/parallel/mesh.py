@@ -54,11 +54,7 @@ def replicated(mesh: Mesh) -> NamedSharding:
 
 
 def batch_sharding(mesh: Mesh) -> dict:
-    """Batch arrays split on the leading (row) axis over the data axis.
-
-    The sorted-plan entries ([D, Np_l] stacked per-data-shard plans,
-    parallel/sorted_sharded.py) shard their leading axis the same way.
-    """
+    """Batch arrays split on the leading (row) axis over the data axis."""
     row2d = NamedSharding(mesh, P(DATA_AXIS, None))
     row1d = NamedSharding(mesh, P(DATA_AXIS))
     # fullshard buffers [D_src, T, D_dst, cap]: source shard on 'data',
@@ -70,11 +66,6 @@ def batch_sharding(mesh: Mesh) -> dict:
         "mask": row2d,
         "labels": row1d,
         "row_mask": row1d,
-        "sorted_slots": row2d,
-        "sorted_row": row2d,
-        "sorted_mask": row2d,
-        "sorted_fields": row2d,
-        "win_off": row2d,
         "fs_slots": fs4,
         "fs_row": fs4,
         "fs_mask": fs4,

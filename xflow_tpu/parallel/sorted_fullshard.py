@@ -5,9 +5,7 @@ The table AND its optimizer state shard over the WHOLE mesh —
 ``wpo`` whole windows — with NO replication anywhere (the 1B-feature /
 12 GB-FTRL-state north-star regime only fits HBM this way; SURVEY.md §7
 hard part d). This is the direct analog of ps-lite sharding the uint64
-key space across *all* servers with no replication (SURVEY.md §2 C13),
-where `parallel/sorted_sharded.py` replicates the table across 'data'
-(D× memory) to save collectives.
+key space across *all* servers with no replication (SURVEY.md §2 C13).
 
 Data flow per step, device (d, t), owner block o = d*T + t:
 
@@ -93,7 +91,7 @@ class FullshardOverflowError(ValueError):
     coordinates the fallback rank-symmetrically — every rank contributes
     its overflow flag to one per-batch allgather and ALL ranks run the
     row-major step when any overflowed
-    (trainer._resolve_fullshard_overflow), so the collective programs
+    (train/engine.py `Engine.agree`), so the collective programs
     never desync."""
 
 
@@ -105,7 +103,7 @@ def _dims(cfg: Config, mesh: Mesh):
 
 def validate_sorted_fullshard(cfg: Config, mesh: Mesh) -> None:
     """Reject configs the fully-sharded engine cannot run, with the
-    specific reason (mirrors validate_sorted_sharded)."""
+    specific reason."""
     d, t, p = _dims(cfg, mesh)
     S = cfg.num_slots
     if S % (d * t * WINDOW) != 0:
@@ -520,7 +518,7 @@ def make_fullshard_train_step(
     """FM/MVM train step with everything sharded over ('data','table').
 
     MVM runs in one of two row-side modes, chosen PER BATCH by the
-    planner (trainer._mvm_wants_fields): "mvm_product" (no fs_fields —
+    planner (train/engine.py _mvm_wants_fields): "mvm_product" (no fs_fields —
     exclusive fields verified on the host; the row side is the same
     [R, ~24] row-sum + psum_scatter as FM, models/mvm.py) or
     "mvm_segment" (general multi-valued fields through the [R·nf]

@@ -33,8 +33,8 @@ aggregate. This module is the first-class instrumentation layer:
   after compilation settles, replacing the blunt whole-run
   start/stop-trace (which buried the steady state under compile noise).
 - `CompileRecorder`: compile accounting. Every step/predict program in
-  the four engine builders (train/step.py, parallel/train_step.py,
-  parallel/sorted_sharded.py, parallel/sorted_fullshard.py) and the
+  the engine builders (train/step.py, parallel/train_step.py,
+  parallel/sorted_fullshard.py) and the
   serve runner routes its jit through `wrap()`, which lowers and
   compiles explicitly (`.lower()`, then `.compile()`, timed apart as
   `lower_s` / `xla_compile_s`), and extracts XLA's cost analysis
@@ -941,7 +941,7 @@ PIPELINE_PRODUCER_STAGES = (
 PIPELINE_CONSUMER_STAGES = (
     "queue_wait",  # the batch's full data-wait (time inside next(); the
     # prefetch get() is the starved part — producer starvation signal)
-    "transfer",  # host->device staging (trainer._shard_batch)
+    "transfer",  # host->device staging (train/engine.py `Engine.shard_batch`)
     "dispatch",  # every other host-side slice of the step: the
     # train_step call plus the loop's bookkeeping between spans, so the
     # four consumer stages TILE the fit loop

@@ -435,7 +435,7 @@ def sync_faults_from_env() -> tuple[int, float]:
     multi-slice chaos injectors (parallel/multislice.SliceSyncer
     resolves them ONCE at construction, zero per-round cost unset).
 
-    Env contract (tools/smoke_multislice.sh exports these):
+    Env contract (tests/test_multislice.py::test_sync_fault_env_parsing):
     - XFLOW_FAULT_SLICE_KILL_ROUND: SIGKILL this slice the moment it
       ENTERS that 1-based sync round, before publishing its delta — the
       slice-loss drill: survivors must drop it from the sync group and

@@ -5,7 +5,7 @@ exchange buffers as ``slack x uniform-hash expectation + one spare
 CHUNK`` (parallel/sorted_fullshard.fullshard_capacity). On skewed data
 a hot key concentrates occurrences in ONE owner block, and when any
 buffer overflows, the whole batch falls back — rank-symmetrically —
-to the GSPMD row-major step (trainer._resolve_fullshard_overflow). A
+to the GSPMD row-major step (train/engine.py `Engine.agree`). A
 v5e-64 run should know its expected fallback rate BEFORE production,
 not discover it; this tool plans synthetic Zipf batches against
 virtual owner-block grids and reports overflow rates per slack.

@@ -1,0 +1,501 @@
+"""The table engine: which step program runs a batch, decided once.
+
+Four step builders exist — the single-device step over row-major
+arrays or over a sorted-window plan (train/step.py), the GSPMD step
+(parallel/train_step.py) and the fully-sharded sorted step
+(parallel/sorted_fullshard.py). `resolve_engine` is the only place that
+knows the list: it picks one from the config and the mesh, validates
+what the pick needs, and hands the trainer an `Engine` — the step
+programs, how a SparseBatch becomes their input, and the per-batch
+fallback — so the fit and eval loops ask the engine and never branch on
+its name. The analysis tier reads `ENGINE_MODULES` from this file (by
+AST: it never imports the code it checks), so a new engine rule is one
+edit here.
+"""
+
+from __future__ import annotations
+
+import sys
+from dataclasses import dataclass
+from typing import Callable, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from xflow_tpu.config import Config
+from xflow_tpu.models.ffm import ffm_invperm, resolve_ffm_aligned
+from xflow_tpu.models.mvm import has_field_duplicates, resolve_mvm_product
+from xflow_tpu.ops.sorted_table import (
+    WINDOW,
+    compact_plan_wire,
+    dedup_slots,
+    plan_sorted_stacked,
+    planner_name,
+    resolve_sub_batches,
+)
+from xflow_tpu.parallel.mesh import batch_sharding, state_shardings
+from xflow_tpu.parallel.sorted_fullshard import (
+    FullshardOverflowError,
+    make_fullshard_eval_step,
+    make_fullshard_train_step,
+    plan_fullshard_batch,
+    validate_sorted_fullshard,
+)
+from xflow_tpu.parallel.train_step import (
+    make_sharded_eval_step,
+    make_sharded_train_step,
+)
+from xflow_tpu.train.step import batch_to_arrays, make_eval_step, make_train_step
+
+# engine name (what `Trainer.engine` and the run summary print) -> the
+# module that builds its step programs. A literal: xflowlint's AST tier
+# reads it without importing (analysis/astutil.engine_modules).
+ENGINE_MODULES = {
+    "row_major": "xflow_tpu/train/step.py",
+    "sorted": "xflow_tpu/train/step.py",
+    "gspmd": "xflow_tpu/parallel/train_step.py",
+    "fullshard": "xflow_tpu/parallel/sorted_fullshard.py",
+}
+
+
+@dataclass(frozen=True)
+class Engine:
+    """What the trainer needs of the engine a run resolved to."""
+
+    name: str  # a key of ENGINE_MODULES
+    # what builds the sorted plans ("native" | "python"); None on the
+    # row-major engines, which plan nothing
+    planner: Optional[str]
+    # eval_shape(state) -> shardings for `build_state`; None = one device
+    state_shardings: Optional[Callable]
+    train_step: Callable  # (state, arrays) -> (state, metrics)
+    eval_step: Callable  # (tables, arrays) -> pctr
+    batch_arrays: Callable  # SparseBatch -> step-input arrays (host)
+    # (batch, arrays) -> arrays every rank runs the same program on
+    agree: Callable
+    # arrays -> this train batch left the engine's own step
+    fell_back: Callable
+    shard_batch: Callable  # host arrays -> device arrays
+
+
+def _choose(cfg: Config, mesh) -> str:
+    """The selection rule, with the refusals of configs a forced choice
+    cannot run."""
+    sl = cfg.data.sorted_layout
+    if mesh is not None:
+        name = "gspmd"
+        if sl == "on":
+            # forced: reject unrunnable configs with the specific reason
+            validate_sorted_fullshard(cfg, mesh)
+            name = "fullshard"
+        elif sl == "auto":
+            # auto enables the fully-sharded engine whenever the config
+            # can run it (it IS the fast path for FM/MVM/FFM, with the
+            # same no-replication memory story as GSPMD); configs it
+            # cannot run keep the GSPMD row-major path
+            try:
+                validate_sorted_fullshard(cfg, mesh)
+                name = "fullshard"
+            except ValueError:
+                pass
+        if cfg.optim.fused_scatter == "on":
+            # fail at STARTUP, not data-dependently: the mesh engines
+            # run the two-pass form (the in-place window kernel's
+            # contract is the single-device step), and the fullshard
+            # overflow fallback builds its GSPMD step lazily — under
+            # "on" that build would raise mid-run on the first skewed
+            # batch of a long job
+            raise ValueError(
+                "optim.fused_scatter=on requires the single-device "
+                "step; mesh engines run the two-pass form — use auto "
+                "(fuses where eligible) or off"
+            )
+        return name
+    supported = (
+        cfg.model.name == "fm" and cfg.model.fm_fused
+    ) or cfg.model.name in ("mvm", "ffm")
+    if sl == "on":
+        # 'on' forces the layout, so reject configurations where it
+        # cannot work instead of failing deep inside sharding/XLA
+        # (or silently paying the host sort for an unused layout)
+        if not supported:
+            raise ValueError(
+                "sorted_layout=on requires model.name=fm with "
+                "model.fm_fused=true, model.name=mvm, or "
+                f"model.name=ffm; got model={cfg.model.name} "
+                f"fm_fused={cfg.model.fm_fused}"
+            )
+        if cfg.num_slots % WINDOW != 0:
+            raise ValueError(
+                f"sorted_layout=on needs num_slots divisible by {WINDOW}; "
+                f"got 2^{cfg.data.log2_slots}"
+            )
+        return "sorted"
+    # FFM under auto runs the ALIGNED HYBRID sorted engine (models/
+    # ffm.py: windowed gather + host placement permutation + fused
+    # scatter+FTRL). Batches with duplicate (row, field) occurrences
+    # fall back per batch to the layout-fixed row-major einsum path
+    # (_sorted_arrays); the per-(row, field) segment engine is the
+    # fullshard MESH row side only.
+    if sl == "auto" and supported and cfg.num_slots % WINDOW == 0:
+        return "sorted"
+    return "row_major"
+
+
+def _dedup(cfg: Config) -> Callable:
+    """Host dedup for row-major batches (ops/sorted_table.dedup_slots):
+    `(arrays, batch) -> arrays` with the deduped gather arrays attached
+    when the batch fits the capacity (data.dedup). Single-process only —
+    the unique count is data-dependent and a per-rank overflow fallback
+    would desync collective programs. The first batch DECIDES for the
+    run: if its unique count overflows (near-uniform data — dedup
+    unprofitable there anyway), stop paying the host np.unique sort on
+    every subsequent batch. On success the dead [B, F] slots array is
+    dropped from the transfer (batch_rows reads only
+    unique_slots/inverse)."""
+    if cfg.data.dedup not in ("auto", "off"):
+        raise ValueError(f"data.dedup={cfg.data.dedup!r}: expected auto|off")
+    cap = (
+        int(cfg.data.batch_size * cfg.data.max_nnz * cfg.data.dedup_cap_frac)
+        if cfg.data.dedup == "auto" and jax.process_count() == 1
+        else 0
+    )
+    if not cap:
+        return lambda arrays, batch: arrays
+    first = []  # [did the run's first row-major batch fit?]
+
+    def maybe_dedup(arrays: dict, batch) -> dict:
+        if first == [False]:
+            return arrays
+        got = dedup_slots(np.asarray(batch.slots), cap)
+        if not first:
+            first.append(got is not None)
+        if got is not None:
+            arrays = dict(arrays)
+            arrays["unique_slots"], arrays["inverse"] = got
+            arrays.pop("slots", None)
+        return arrays
+
+    return maybe_dedup
+
+
+def _mvm_wants_fields(cfg: Config, engine: str, batch) -> tuple[bool, Optional[bool]]:
+    """(plan with per-occurrence fields?, duplicate flag to coordinate).
+
+    fields=False = the exclusive-fields product path (models/mvm.py):
+    the host verified no row repeats a field, so the step needs
+    neither the fields array nor the [B·nf] segment space. Routing is
+    per-batch under `auto`: single-process decides locally; the
+    multi-process fullshard engine plans WITH fields unconditionally
+    and returns the local duplicate flag, which `Engine.agree`
+    allgathers so every rank picks the SAME mode for the batch (a local
+    raise — round-3 ADVICE — would leave peer ranks blocked in their
+    collectives). `on` keeps its contract: duplicates raise
+    (resolve_mvm_product)."""
+    excl = cfg.model.mvm_exclusive
+    multiproc = jax.process_count() > 1
+    if excl == "auto" and multiproc and engine == "fullshard":
+        return True, bool(has_field_duplicates(batch.fields, batch.mask))
+    dup = excl != "off" and has_field_duplicates(batch.fields, batch.mask)
+    return not resolve_mvm_product(excl, dup, jax.process_count()), None
+
+
+def _ffm_aligned(cfg: Config, batch) -> bool:
+    """Route one FFM batch: aligned hybrid (True) or the row-major
+    general path (False). Mirrors MVM's product routing contracts:
+    single-process routes per batch; multi-process (non-fullshard)
+    cannot — the two paths' collective programs differ across ranks
+    — so duplicate fields raise there; forced `sorted_layout=on`
+    raises too (the user asserted the sorted engine, and FFM's
+    sorted engine is the aligned hybrid)."""
+    if resolve_ffm_aligned(batch.fields, batch.mask):
+        return True
+    forced = cfg.data.sorted_layout == "on"
+    if forced or jax.process_count() > 1:
+        raise ValueError(
+            "FFM aligned hybrid: a row carries two masked occurrences "
+            "of the same field. "
+            + (
+                "sorted_layout=on requires aligned batches; use auto "
+                "for the per-batch row-major fallback"
+                if forced
+                else "this multi-process configuration cannot fall "
+                "back per batch (the paths' programs differ across "
+                "ranks); set data.sorted_layout=off"
+            )
+        )
+    return False
+
+
+def _sorted_arrays(cfg: Config, maybe_dedup: Callable) -> Callable:
+    """The single-device sorted engine's batch builder: the step
+    consumes ONLY the plan + labels/row_mask (+ sorted_fields for MVM's
+    segment path), so the row-major [B, F] arrays are dropped — they
+    would be dead ~24 MB host→device transfers per 64k-row batch."""
+    ffm, mvm = cfg.model.name == "ffm", cfg.model.name == "mvm"
+    # FFM's aligned hybrid has no per-(row, field) segment state to keep
+    # cache-resident, and its placement permutation is defined over the
+    # whole batch — always one flat plan
+    num_sub = 1 if ffm else resolve_sub_batches(cfg)
+    rows_bound = cfg.data.batch_size // max(num_sub, 1)
+
+    def batch_arrays(batch) -> dict:
+        arrays = batch_to_arrays(batch)
+        if ffm and not _ffm_aligned(cfg, batch):
+            # duplicate (row, field) occurrence: the aligned hybrid
+            # cannot place this batch — run the row-major general
+            # einsum path for it (single-process per-batch routing,
+            # same pattern as MVM's product fallback)
+            return maybe_dedup(arrays, batch)
+        arrays = {"labels": arrays["labels"], "row_mask": arrays["row_mask"]}
+        want_fields = ffm or (mvm and _mvm_wants_fields(cfg, "sorted", batch)[0])
+        fields_bound = cfg.model.num_fields if want_fields else 0
+        plan = plan_sorted_stacked(
+            np.asarray(batch.slots),
+            np.asarray(batch.mask),
+            cfg.num_slots,
+            fields=np.asarray(batch.fields) if want_fields else None,
+            num_sub=num_sub,
+            # CONFIG-derived (rank-symmetric) wire decision, the same
+            # rule compact_plan_wire applies — the C planner then
+            # emits uint16/uint8 directly and the compaction below
+            # passes the arrays through untouched
+            wire=rows_bound <= (1 << 16) and fields_bound <= (1 << 8),
+        )
+        arrays.update(
+            sorted_slots=plan.sorted_slots,
+            sorted_row=plan.sorted_row,
+            sorted_mask=plan.sorted_mask,
+            win_off=plan.win_off,
+        )
+        if want_fields:
+            arrays["sorted_fields"] = plan.sorted_fields
+        if ffm:
+            arrays["ffm_invperm"] = ffm_invperm(
+                plan.sorted_row, plan.sorted_fields, plan.sorted_mask,
+                int(arrays["labels"].shape[0]), cfg.model.num_fields,
+            )
+        return compact_plan_wire(
+            arrays, rows_bound=rows_bound, fields_bound=fields_bound
+        )
+
+    return batch_arrays
+
+
+def _fullshard_arrays(cfg: Config, mesh, maybe_dedup: Callable) -> Callable:
+    """The fullshard engine's batch builder: the owner-block plan
+    (train and eval consume the same one), or — for a batch too skewed
+    for data.fullshard_slack — row-major arrays for the GSPMD step."""
+    ffm, mvm = cfg.model.name == "ffm", cfg.model.name == "mvm"
+    rows_bound = cfg.data.batch_size // (mesh.shape["data"] // jax.process_count())
+    warned = []
+
+    def batch_arrays(batch) -> dict:
+        arrays = batch_to_arrays(batch)
+        if mvm:
+            want_fields, dup_flag = _mvm_wants_fields(cfg, "fullshard", batch)
+        else:
+            # FFM always consumes per-occurrence fields (its segment
+            # space is row·nf + field); FM never does
+            want_fields, dup_flag = ffm, None
+        try:
+            out = {"labels": arrays["labels"], "row_mask": arrays["row_mask"]}
+            out.update(
+                plan_fullshard_batch(
+                    np.asarray(batch.slots),
+                    np.asarray(batch.mask),
+                    cfg,
+                    mesh,
+                    fields=np.asarray(batch.fields) if want_fields else None,
+                )
+            )
+        except FullshardOverflowError:
+            if not warned:
+                warned.append(True)
+                print(
+                    "fullshard: batch too skewed for "
+                    f"data.fullshard_slack={cfg.data.fullshard_slack}; "
+                    "falling back to the GSPMD row-major step for such "
+                    "batches (raise the slack to keep the fast path)",
+                    file=sys.stderr,
+                )
+            # row-major: the GSPMD step handles it — THROUGH dedup if
+            # enabled (overflow batches are the most skewed = exactly
+            # where the cross-chip dedup win lives). Multi-process: the
+            # marker makes `agree` (fit loop, main thread) pull EVERY
+            # rank onto the row-major step for this batch — a per-rank
+            # fallback would desync the ranks' collective programs and
+            # deadlock.
+            arrays = maybe_dedup(arrays, batch)
+            if jax.process_count() > 1:
+                arrays["_fs_overflow"] = True
+            return arrays
+        out = compact_plan_wire(
+            out,
+            rows_bound=rows_bound,
+            fields_bound=cfg.model.num_fields if want_fields else 0,
+        )
+        if dup_flag is not None:
+            # multi-process auto routing: the fit loop's per-batch
+            # allgather decides product vs segment for ALL ranks
+            out["_mvm_dup"] = dup_flag
+        return out
+
+    return batch_arrays
+
+
+def _fullshard_agree(batch, arrays: dict) -> dict:
+    """Rank-symmetric per-batch engine agreement (round-3 weak #1 +
+    ADVICE: MVM auto-routing desync).
+
+    Multi-process fullshard only: every rank contributes a [2]-int32
+    flag vector — (occurrence buffers overflowed, MVM batch has
+    duplicate fields) — to ONE host allgather per batch, and all
+    ranks act on the elementwise max:
+
+    - any overflow → ALL ranks run this batch on the GSPMD row-major
+      step (identical state sharding, so the two jitted programs
+      interleave — the same dispatch the single-process fallback
+      uses). Ranks whose plan succeeded rebuild row-major arrays
+      from the still-held SparseBatch (a host reshape, no re-parse).
+      The reference never dies on a hot key — its PS just serves it
+      slowly (`/root/reference/src/optimizer/ftrl.h:54-79`).
+    - MVM under `mvm_exclusive=auto`: plans carry fields
+      unconditionally (_mvm_wants_fields); if NO rank saw duplicate
+      fields, every rank drops `fs_fields` here — before the
+      device transfer — and the batch runs the fast product mode;
+      any duplicate anywhere keeps the segment mode everywhere.
+
+    Cost: one [2]-int32 host allgather per train batch, ~100-200 µs
+    on CPU rendezvous — noise against the ≥40 ms device step at
+    bench shapes (docs/DISTRIBUTED.md "Hot keys"). Runs on the MAIN
+    thread (the prefetch thread builds plans; collectives from two
+    threads could interleave across ranks).
+    """
+    from jax.experimental import multihost_utils
+
+    mine_over = bool(arrays.pop("_fs_overflow", False))
+    mine_dup = arrays.pop("_mvm_dup", None)
+    flags = np.array([mine_over, bool(mine_dup)], np.int32)
+    got = (
+        np.asarray(multihost_utils.process_allgather(flags))
+        .reshape(-1, 2)
+        .max(axis=0)
+    )
+    if got[0]:
+        if not mine_over:
+            # a peer overflowed: drop my fullshard plan, rebuild
+            # row-major. No dedup here — multi-process forces the
+            # dedup capacity to 0 (per-batch capacity routing would
+            # give ranks different jitted programs, the exact desync
+            # this function prevents)
+            arrays = batch_to_arrays(batch)
+    elif mine_dup is not None and not got[1]:
+        arrays.pop("fs_fields", None)  # all-clear: product mode
+    return arrays
+
+
+def _mesh_shard_batch(mesh) -> Callable:
+    """host arrays -> global device arrays, split as `batch_sharding` says."""
+    sh = batch_sharding(mesh)
+    if jax.process_count() > 1:
+        # each process holds different rows (its own input shard): assemble a
+        # global array from per-process local data (device_put would demand
+        # identical values everywhere)
+        return lambda batch: {
+            k: jax.make_array_from_process_local_data(sh[k], np.asarray(v))
+            for k, v in batch.items()
+        }
+    return lambda batch: {
+        k: jax.device_put(jnp.asarray(v), sh[k]) for k, v in batch.items()
+    }
+
+
+def resolve_engine(cfg: Config, mesh, model, optimizer, recorder) -> Engine:
+    """Pick the engine for (cfg, mesh) and build what the trainer calls.
+
+    One device: "sorted" (the sorted-window layout of ops/sorted_table.py
+    — fused FM, MVM, FFM; Pallas kernels on a TPU) or "row_major" (XLA
+    gather/scatter). Mesh: "fullshard" (table + state sharded over the
+    WHOLE mesh, parallel/sorted_fullshard.py; multi-process when the
+    data axis divides across processes) or "gspmd" for configs it cannot
+    run. `data.sorted_layout` on|auto|off forces, allows or forbids the
+    sorted layouts."""
+    name = _choose(cfg, mesh)
+    maybe_dedup = _dedup(cfg)
+    identity = lambda batch, arrays: arrays
+    never = lambda arrays: False
+    row_major_arrays = lambda batch: maybe_dedup(batch_to_arrays(batch), batch)
+    if mesh is None:
+        return Engine(
+            name=name,
+            planner=planner_name() if name == "sorted" else None,
+            state_shardings=None,
+            train_step=make_train_step(model, optimizer, cfg, recorder=recorder),
+            eval_step=make_eval_step(model, cfg, recorder=recorder),
+            batch_arrays=(
+                _sorted_arrays(cfg, maybe_dedup) if name == "sorted" else row_major_arrays
+            ),
+            agree=identity,
+            fell_back=never,
+            # ONE async device_put for the whole dict: per-array
+            # jnp.asarray is a synchronous round trip each (~9 arrays
+            # per step)
+            shard_batch=jax.device_put,
+        )
+    shard_batch = _mesh_shard_batch(mesh)
+    # the fullshard layout IS state_shardings' layout: every table/opt
+    # leaf P(('data','table')) on the slot axis
+    shardings = lambda s: state_shardings(s, mesh)
+    # make_sharded_eval_step adopts the tables' LIVE sharding as its
+    # in_sharding — jit never reshards explicit in_shardings
+    gspmd_eval = make_sharded_eval_step(model, cfg, mesh, recorder=recorder)
+    if name == "gspmd":
+        return Engine(
+            name=name,
+            planner=None,
+            state_shardings=shardings,
+            train_step=make_sharded_train_step(
+                model, optimizer, cfg, mesh, recorder=recorder
+            ),
+            eval_step=gspmd_eval,
+            batch_arrays=row_major_arrays,
+            agree=identity,
+            fell_back=never,
+            shard_batch=shard_batch,
+        )
+    fullshard_step = make_fullshard_train_step(optimizer, cfg, mesh, recorder=recorder)
+    fullshard_eval = make_fullshard_eval_step(cfg, mesh, recorder=recorder)
+    # per-batch dispatch: a batch too skewed for the buffer capacity
+    # arrives as row-major arrays (the overflow fallback of
+    # _fullshard_arrays, or a peer's through `agree`) and runs the GSPMD
+    # step, built on first use — the state sharding is identical, so the
+    # two steps interleave freely
+    gspmd = {}
+
+    def train_step(state, batch):
+        if "fs_slots" in batch:
+            return fullshard_step(state, batch)
+        if "step" not in gspmd:
+            gspmd["step"] = make_sharded_train_step(
+                model, optimizer, cfg, mesh, recorder=recorder
+            )
+        return gspmd["step"](state, batch)
+
+    def eval_step(tables, arrays):
+        if "fs_slots" in arrays:
+            return fullshard_eval(tables, arrays)
+        return gspmd_eval(tables, arrays)
+
+    return Engine(
+        name=name,
+        planner=planner_name(),
+        state_shardings=shardings,
+        train_step=train_step,
+        eval_step=eval_step,
+        batch_arrays=_fullshard_arrays(cfg, mesh, maybe_dedup),
+        agree=_fullshard_agree if jax.process_count() > 1 else identity,
+        fell_back=lambda arrays: "fs_slots" not in arrays,
+        shard_batch=shard_batch,
+    )

@@ -49,13 +49,9 @@ from xflow_tpu.telemetry import (
     span,
 )
 from xflow_tpu.optim import get_optimizer
+from xflow_tpu.train.engine import resolve_engine
 from xflow_tpu.train.state import TrainState, build_state
-from xflow_tpu.train.step import (
-    batch_to_arrays,
-    make_eval_step,
-    make_train_step,
-    nonfinite_guard_on,
-)
+from xflow_tpu.train.step import nonfinite_guard_on
 
 
 class NonFiniteHalt(RuntimeError):
@@ -148,203 +144,6 @@ class Trainer:
             if cfg.train.compile_metrics
             else None
         )
-        _rec = self.compile_recorder
-        # sorted-window table layout (ops/sorted_table.py):
-        # - single device: fused-FM and MVM (Pallas kernels / XLA fallback)
-        # - mesh: fused-FM and MVM via one of two engines selected by
-        #   data.sorted_mesh — "fullshard" (default; table + state sharded
-        #   over the WHOLE mesh, parallel/sorted_fullshard.py) or
-        #   "replicated" (table on the 'table' axis only, D× memory,
-        #   parallel/sorted_sharded.py). Multi-process works when the data
-        #   axis divides across processes (2-process subprocess-tested for
-        #   both engines). Configs neither engine can run keep the GSPMD
-        #   row-major path.
-        from xflow_tpu.ops.sorted_table import WINDOW, resolve_sub_batches
-
-        sl = cfg.data.sorted_layout
-        # mesh sorted engine: None (GSPMD row-major) | "fullshard"
-        # (parallel/sorted_fullshard.py — table + state sharded over the
-        # WHOLE mesh, no replication; the 1B-feature-regime fast path) |
-        # "replicated" (parallel/sorted_sharded.py — 'table'-axis-only
-        # sharding, D× table memory, fewer collectives)
-        self._mesh_engine = None
-        if mesh is not None:
-            engine = cfg.data.sorted_mesh
-            if engine not in ("fullshard", "replicated"):
-                raise ValueError(
-                    f"data.sorted_mesh={engine!r}: expected 'fullshard' or "
-                    "'replicated'"
-                )
-            from xflow_tpu.parallel.sorted_fullshard import validate_sorted_fullshard
-            from xflow_tpu.parallel.sorted_sharded import validate_sorted_sharded
-
-            if sl == "on":
-                # forced: reject unrunnable configs with the specific reason
-                if engine == "fullshard":
-                    validate_sorted_fullshard(cfg, mesh)
-                else:
-                    validate_sorted_sharded(cfg, mesh)
-                self._mesh_engine = engine
-            elif sl == "auto" and engine == "fullshard":
-                # auto enables the fully-sharded engine whenever the config
-                # can run it (it IS the fast path for FM/MVM, with the same
-                # no-replication memory story as GSPMD); the replicated
-                # engine stays opt-in only — its D× table memory must be an
-                # explicit choice
-                try:
-                    validate_sorted_fullshard(cfg, mesh)
-                    self._mesh_engine = "fullshard"
-                except ValueError:
-                    self._mesh_engine = None
-            self._sorted = self._mesh_engine is not None
-        else:
-            supported = (
-                cfg.model.name == "fm" and cfg.model.fm_fused
-            ) or cfg.model.name in ("mvm", "ffm")
-            # FFM under auto runs the ALIGNED HYBRID sorted engine since
-            # round 5 (models/ffm.py: windowed gather + host placement
-            # permutation + fused scatter+FTRL — 512k ex/s at B=64k vs
-            # the round-4 row-major path's 193k at 16k, docs/PERF.md).
-            # Batches with duplicate (row, field) occurrences fall back
-            # per batch to the layout-fixed row-major einsum path
-            # (_batch_arrays); the old per-(row, field) segment engine
-            # remains the fullshard MESH row side only.
-            self._sorted = sl == "on" or (
-                sl == "auto" and supported and cfg.num_slots % WINDOW == 0
-            )
-            if sl == "on":
-                # 'on' forces the layout, so reject configurations where it
-                # cannot work instead of failing deep inside sharding/XLA
-                # (or silently paying the host sort for an unused layout)
-                if not supported:
-                    raise ValueError(
-                        "sorted_layout=on requires model.name=fm with "
-                        "model.fm_fused=true, model.name=mvm, or "
-                        f"model.name=ffm; got model={cfg.model.name} "
-                        f"fm_fused={cfg.model.fm_fused}"
-                    )
-                if cfg.num_slots % WINDOW != 0:
-                    raise ValueError(
-                        f"sorted_layout=on needs num_slots divisible by {WINDOW}; "
-                        f"got 2^{cfg.data.log2_slots}"
-                    )
-        self._sorted_sharded = self._sorted and mesh is not None
-        if self._sorted_sharded:
-            # one plan per LOCAL data shard; other processes build theirs
-            self._sorted_sub = mesh.shape["data"] // jax.process_count()
-        else:
-            # FFM's aligned hybrid has no per-(row, field) segment
-            # state to keep cache-resident, and its placement permutation
-            # is defined over the whole batch — always one flat plan
-            self._sorted_sub = (
-                1
-                if cfg.model.name == "ffm"
-                else resolve_sub_batches(cfg) if self._sorted else 1
-            )
-        if mesh is not None:
-            if cfg.optim.fused_scatter == "on":
-                # fail at STARTUP, not data-dependently: the mesh engines
-                # run the two-pass form (the in-place window kernel's
-                # contract is the single-device step), and the fullshard
-                # overflow fallback builds its GSPMD step lazily — under
-                # "on" that build would raise mid-run on the first skewed
-                # batch of a long job
-                raise ValueError(
-                    "optim.fused_scatter=on requires the single-device "
-                    "step; mesh engines run the two-pass form — use auto "
-                    "(fuses where eligible) or off"
-                )
-            from xflow_tpu.parallel.mesh import state_shardings
-            from xflow_tpu.parallel.train_step import make_sharded_train_step, make_sharded_eval_step
-
-            if self._mesh_engine == "fullshard":
-                from xflow_tpu.parallel.sorted_fullshard import (
-                    make_fullshard_train_step,
-                )
-
-                # state_shardings' layout IS the fullshard layout: every
-                # table/opt leaf P(('data','table')) on the slot axis
-                self._build_state(lambda s: state_shardings(s, mesh))
-                fullshard_step = make_fullshard_train_step(
-                    self.optimizer, cfg, mesh, recorder=_rec
-                )
-                # per-batch dispatch: a batch too skewed for the buffer
-                # capacity arrives as row-major arrays (single-process
-                # overflow fallback in _batch_arrays) and runs the GSPMD
-                # step — the state sharding is identical, so the two
-                # steps interleave freely
-                gspmd = {}
-
-                def _dispatch(state, batch):
-                    if "fs_slots" in batch:
-                        return fullshard_step(state, batch)
-                    if "step" not in gspmd:
-                        gspmd["step"] = make_sharded_train_step(
-                            self.model, self.optimizer, cfg, mesh,
-                            recorder=_rec,
-                        )
-                    return gspmd["step"](state, batch)
-
-                self.train_step = _dispatch
-            elif self._mesh_engine == "replicated":
-                # multi-process `mvm_exclusive=auto` here behaves like
-                # `on`: clean one-feature-per-field data takes the
-                # product path; a duplicate-field batch raises
-                # (resolve_mvm_product — only the fullshard engine has
-                # the per-batch flag allgather that makes data-dependent
-                # routing rank-symmetric)
-                from xflow_tpu.parallel.sorted_sharded import (
-                    make_sorted_sharded_train_step,
-                    sorted_state_shardings,
-                )
-
-                self._build_state(lambda s: sorted_state_shardings(s, mesh))
-                self.train_step = make_sorted_sharded_train_step(
-                    self.optimizer, cfg, mesh, recorder=_rec
-                )
-            else:
-                self._build_state(lambda s: state_shardings(s, mesh))
-                self.train_step = make_sharded_train_step(
-                    self.model, self.optimizer, cfg, mesh, recorder=_rec
-                )
-            # eval: the fullshard engine consumes the SAME host plan as
-            # training (round-3 weak #5: the row-major [B, F] arrays are
-            # dead ~24 MB/batch transfers there); overflow-fallback
-            # batches arrive row-major and run the GSPMD eval step
-            # (make_sharded_eval_step adopts the tables' LIVE sharding
-            # as its in_sharding — jit never reshards explicit
-            # in_shardings). The replicated engine keeps row-major eval.
-            gspmd_eval = make_sharded_eval_step(self.model, cfg, mesh, recorder=_rec)
-            if self._mesh_engine == "fullshard":
-                from xflow_tpu.parallel.sorted_fullshard import (
-                    make_fullshard_eval_step,
-                )
-
-                fullshard_eval = make_fullshard_eval_step(cfg, mesh, recorder=_rec)
-
-                def _eval_dispatch(tables, arrays):
-                    if "fs_slots" in arrays:
-                        return fullshard_eval(tables, arrays)
-                    return gspmd_eval(tables, arrays)
-
-                self.eval_step = _eval_dispatch
-            else:
-                self.eval_step = gspmd_eval
-            self._shard_batch = lambda b: _shard_batch_arrays(b, mesh)
-        else:
-            self._build_state()
-            self.train_step = make_train_step(
-                self.model, self.optimizer, cfg, recorder=_rec
-            )
-            self.eval_step = make_eval_step(self.model, cfg, recorder=_rec)
-            # ONE async device_put for the whole dict: per-array jnp.asarray
-            # is a synchronous round trip each (~9 arrays per step)
-            self._shard_batch = jax.device_put
-        # host dedup for row-major batches (ops/sorted_table.dedup_slots):
-        # single-process only — the unique count is data-dependent and a
-        # per-rank overflow fallback would desync collective programs
-        if cfg.data.dedup not in ("auto", "off"):
-            raise ValueError(f"data.dedup={cfg.data.dedup!r}: expected auto|off")
         # packed shard cache (data/shardcache.py, docs/DATA.md):
         # validated at CONSTRUCTION like the guard/dedup modes (identical
         # config on every rank → rank-symmetric), not on the first shard
@@ -353,12 +152,17 @@ class Trainer:
             raise ValueError(
                 f"data.cache={cfg.data.cache!r}: expected auto|on|off"
             )
-        self._dedup_cap = (
-            int(cfg.data.batch_size * cfg.data.max_nnz * cfg.data.dedup_cap_frac)
-            if cfg.data.dedup == "auto" and jax.process_count() == 1
-            else 0
+        # which step program runs a batch, how a batch becomes its input
+        # and the per-batch fallback are the engine's (train/engine.py);
+        # the state is born in the engine's shardings
+        self._engine = resolve_engine(
+            cfg, mesh, self.model, self.optimizer, self.compile_recorder
         )
-        self._dedup_on = None  # undecided until the first row-major batch
+        self._build_state(self._engine.state_shardings)
+        # the loops call the step programs through these two names:
+        # tests and the benchmark's planted faults replace them
+        self.train_step = self._engine.train_step
+        self.eval_step = self._engine.eval_step
         # model-health monitor (train.health_metrics, docs/OBSERVABILITY.md
         # "Health metrics"): consumes the step builders' fused norm
         # scalars one step behind, owns the loss EMA and the
@@ -441,7 +245,6 @@ class Trainer:
         # validate the guard mode at CONSTRUCTION (identical config on
         # every rank → rank-symmetric), not on the first bad batch
         self._guarded = nonfinite_guard_on(cfg)
-        self._fullshard_overflow_warned = False
         # MVM and FFM key their views/blocks on the field id: a field >=
         # num_fields would be silently dropped by the one-hot, so reject
         # it loudly
@@ -468,22 +271,16 @@ class Trainer:
 
     @property
     def engine(self) -> str:
-        """The table engine the step dispatches to: "sorted" (windowed
-        Pallas kernels on a TPU) or "row_major" (XLA gather/scatter) on
-        one device; "fullshard", "replicated" or "gspmd" on a mesh."""
-        if self.mesh is not None:
-            return self._mesh_engine or "gspmd"
-        return "sorted" if self._sorted else "row_major"
+        """The table engine the step dispatches to (train/engine.py):
+        "sorted" (windowed Pallas kernels on a TPU) or "row_major" (XLA
+        gather/scatter) on one device; "fullshard" or "gspmd" on a mesh."""
+        return self._engine.name
 
     @property
     def planner(self) -> Optional[str]:
         """What builds the sorted plans ("native" | "python"); None on
         the row-major engines, which plan nothing."""
-        if not self._sorted:
-            return None
-        from xflow_tpu.ops.sorted_table import planner_name
-
-        return planner_name()
+        return self._engine.planner
 
     def _check_batch(self, batch) -> None:
         if self._validate_fields:
@@ -493,263 +290,6 @@ class Trainer:
                     f"libffm field id {max_field} >= model.num_fields="
                     f"{self.cfg.model.num_fields}; raise model.num_fields"
                 )
-
-    def _mvm_wants_fields(self, batch) -> tuple[bool, Optional[bool]]:
-        """(plan with per-occurrence fields?, duplicate flag to coordinate).
-
-        fields=False = the exclusive-fields product path (models/mvm.py):
-        the host verified no row repeats a field, so the step needs
-        neither the fields array nor the [B·nf] segment space. Routing is
-        per-batch under `auto`: single-process decides locally; the
-        multi-process fullshard engine plans WITH fields unconditionally
-        and returns the local duplicate flag, which
-        `_resolve_fullshard_overflow` allgathers so every rank picks the
-        SAME mode for the batch (a local raise — round-3 ADVICE — would
-        leave peer ranks blocked in their collectives). `on` keeps its
-        contract: duplicates raise (resolve_mvm_product)."""
-        from xflow_tpu.models.mvm import has_field_duplicates, resolve_mvm_product
-
-        excl = self.cfg.model.mvm_exclusive
-        multiproc = jax.process_count() > 1
-        if excl == "auto" and multiproc and self._mesh_engine == "fullshard":
-            return True, bool(has_field_duplicates(batch.fields, batch.mask))
-        dup = excl != "off" and has_field_duplicates(batch.fields, batch.mask)
-        return not resolve_mvm_product(excl, dup, jax.process_count()), None
-
-    def _resolve_ffm_aligned(self, batch) -> bool:
-        """Route one FFM batch: aligned hybrid (True) or the row-major
-        general path (False). Mirrors MVM's product routing contracts:
-        single-process routes per batch; multi-process (non-fullshard)
-        cannot — the two paths' collective programs differ across ranks
-        — so duplicate fields raise there; forced `sorted_layout=on`
-        raises too (the user asserted the sorted engine, and FFM's
-        sorted engine is the aligned hybrid)."""
-        from xflow_tpu.models.ffm import resolve_ffm_aligned
-
-        aligned = resolve_ffm_aligned(batch.fields, batch.mask)
-        if aligned:
-            return True
-        forced = self.cfg.data.sorted_layout == "on"
-        if forced or jax.process_count() > 1:
-            raise ValueError(
-                "FFM aligned hybrid: a row carries two masked occurrences "
-                "of the same field. "
-                + (
-                    "sorted_layout=on requires aligned batches; use auto "
-                    "for the per-batch row-major fallback"
-                    if forced
-                    else "this multi-process configuration cannot fall "
-                    "back per batch (the paths' programs differ across "
-                    "ranks); set data.sorted_layout=off"
-                )
-            )
-        return False
-
-    def _batch_arrays(self, batch, with_plan: bool = True) -> dict:
-        """SparseBatch -> step input arrays (+ sorted-layout plan).
-
-        On the sorted paths the step consumes ONLY the plan +
-        labels/row_mask (+ sorted_fields for MVM's segment path), so the
-        row-major [B, F] arrays are dropped — they would be dead ~24 MB
-        host→device transfers per 64k-row batch. Eval batches build
-        plans too (single-device sorted and fullshard-mesh eval both
-        consume them); only the replicated mesh engine's eval passes
-        `with_plan=False` and keeps row-major.
-        """
-        arrays = batch_to_arrays(batch)
-        if self._sorted and with_plan and self._mesh_engine == "fullshard":
-            from xflow_tpu.parallel.sorted_fullshard import (
-                FullshardOverflowError,
-                plan_fullshard_batch,
-            )
-
-            mvm = self.cfg.model.name == "mvm"
-            if mvm:
-                want_fields, dup_flag = self._mvm_wants_fields(batch)
-            else:
-                # FFM always consumes per-occurrence fields (its segment
-                # space is row·nf + field); FM never does
-                want_fields, dup_flag = self.cfg.model.name == "ffm", None
-            try:
-                from xflow_tpu.ops.sorted_table import compact_plan_wire
-
-                out = {"labels": arrays["labels"], "row_mask": arrays["row_mask"]}
-                out.update(
-                    plan_fullshard_batch(
-                        np.asarray(batch.slots),
-                        np.asarray(batch.mask),
-                        self.cfg,
-                        self.mesh,
-                        fields=np.asarray(batch.fields) if want_fields else None,
-                    )
-                )
-                d_ax = self.mesh.shape["data"]
-                out = compact_plan_wire(
-                    out,
-                    rows_bound=self.cfg.data.batch_size
-                    // (d_ax // jax.process_count()),
-                    fields_bound=self.cfg.model.num_fields if want_fields else 0,
-                )
-                if dup_flag is not None:
-                    # multi-process auto routing: the fit loop's per-batch
-                    # allgather decides product vs segment for ALL ranks
-                    out["_mvm_dup"] = dup_flag
-                return out
-            except FullshardOverflowError:
-                if not self._fullshard_overflow_warned:
-                    self._fullshard_overflow_warned = True
-                    print(
-                        "fullshard: batch too skewed for "
-                        f"data.fullshard_slack={self.cfg.data.fullshard_slack}; "
-                        "falling back to the GSPMD row-major step for such "
-                        "batches (raise the slack to keep the fast path)",
-                        file=sys.stderr,
-                    )
-                # row-major: the GSPMD step handles it — THROUGH dedup if
-                # enabled (overflow batches are the most skewed = exactly
-                # where the cross-chip dedup win lives). Multi-process: the
-                # marker makes _resolve_fullshard_overflow (fit loop, main
-                # thread) pull EVERY rank onto the row-major step for this
-                # batch — a per-rank fallback would desync the ranks'
-                # collective programs and deadlock.
-                arrays = self._maybe_dedup(arrays, batch)
-                if jax.process_count() > 1:
-                    arrays["_fs_overflow"] = True
-                return arrays
-        if self._sorted and with_plan:
-            from xflow_tpu.ops.sorted_table import plan_sorted_stacked
-
-            if self.cfg.model.name == "ffm" and not self._resolve_ffm_aligned(batch):
-                # duplicate (row, field) occurrence: the aligned hybrid
-                # cannot place this batch — run the row-major general
-                # einsum path for it (single-process per-batch routing,
-                # same pattern as MVM's product fallback)
-                return self._maybe_dedup(arrays, batch)
-            arrays = {"labels": arrays["labels"], "row_mask": arrays["row_mask"]}
-            want_fields = self.cfg.model.name == "ffm" or (
-                self.cfg.model.name == "mvm" and self._mvm_wants_fields(batch)[0]
-            )
-            rows_bound = self.cfg.data.batch_size // max(self._sorted_sub, 1)
-            plan = plan_sorted_stacked(
-                np.asarray(batch.slots),
-                np.asarray(batch.mask),
-                self.cfg.num_slots,
-                fields=np.asarray(batch.fields) if want_fields else None,
-                num_sub=self._sorted_sub,
-                # the sharded engine wants a leading [D] axis even at D=1
-                always_stack=self._sorted_sharded,
-                # CONFIG-derived (rank-symmetric) wire decision, the same
-                # rule compact_plan_wire applies — the C planner then
-                # emits uint16/uint8 directly and the compaction below
-                # passes the arrays through untouched
-                wire=rows_bound <= (1 << 16)
-                and (not want_fields or self.cfg.model.num_fields <= (1 << 8)),
-            )
-            arrays.update(
-                sorted_slots=plan.sorted_slots,
-                sorted_row=plan.sorted_row,
-                sorted_mask=plan.sorted_mask,
-                win_off=plan.win_off,
-            )
-            if want_fields:
-                arrays["sorted_fields"] = plan.sorted_fields
-            if self.cfg.model.name == "ffm":
-                from xflow_tpu.models.ffm import ffm_invperm
-
-                arrays["ffm_invperm"] = ffm_invperm(
-                    plan.sorted_row, plan.sorted_fields, plan.sorted_mask,
-                    int(arrays["labels"].shape[0]), self.cfg.model.num_fields,
-                )
-            from xflow_tpu.ops.sorted_table import compact_plan_wire
-
-            arrays = compact_plan_wire(
-                arrays,
-                rows_bound=self.cfg.data.batch_size // max(self._sorted_sub, 1),
-                fields_bound=self.cfg.model.num_fields if want_fields else 0,
-            )
-        else:
-            arrays = self._maybe_dedup(arrays, batch)
-        return arrays
-
-    def _resolve_fullshard_overflow(self, batch, arrays: dict) -> dict:
-        """Rank-symmetric per-batch engine agreement (round-3 weak #1 +
-        ADVICE: MVM auto-routing desync).
-
-        Multi-process fullshard only: every rank contributes a [2]-int32
-        flag vector — (occurrence buffers overflowed, MVM batch has
-        duplicate fields) — to ONE host allgather per batch, and all
-        ranks act on the elementwise max:
-
-        - any overflow → ALL ranks run this batch on the GSPMD row-major
-          step (identical state sharding, so the two jitted programs
-          interleave — the same dispatch the single-process fallback
-          uses). Ranks whose plan succeeded rebuild row-major arrays
-          from the still-held SparseBatch (a host reshape, no re-parse).
-          The reference never dies on a hot key — its PS just serves it
-          slowly (`/root/reference/src/optimizer/ftrl.h:54-79`).
-        - MVM under `mvm_exclusive=auto`: plans carry fields
-          unconditionally (_mvm_wants_fields); if NO rank saw duplicate
-          fields, every rank drops `fs_fields` here — before the
-          device transfer — and the batch runs the fast product mode;
-          any duplicate anywhere keeps the segment mode everywhere.
-
-        Cost: one [2]-int32 host allgather per train batch, ~100-200 µs
-        on CPU rendezvous — noise against the ≥40 ms device step at
-        bench shapes (docs/DISTRIBUTED.md "Hot keys"). Runs on the MAIN
-        thread (the prefetch thread builds plans; collectives from two
-        threads could interleave across ranks).
-        """
-        if self._mesh_engine != "fullshard" or jax.process_count() == 1:
-            return arrays
-        from jax.experimental import multihost_utils
-
-        mine_over = bool(arrays.pop("_fs_overflow", False))
-        mine_dup = arrays.pop("_mvm_dup", None)
-        flags = np.array([mine_over, bool(mine_dup)], np.int32)
-        got = (
-            np.asarray(multihost_utils.process_allgather(flags))
-            .reshape(-1, 2)
-            .max(axis=0)
-        )
-        if got[0]:
-            if not mine_over:
-                # a peer overflowed: drop my fullshard plan, rebuild
-                # row-major. No dedup here — multi-process forces
-                # _dedup_cap off (per-batch capacity routing would give
-                # ranks different jitted programs, the exact desync this
-                # method prevents)
-                arrays = batch_to_arrays(batch)
-        elif mine_dup is not None and not got[1]:
-            arrays.pop("fs_fields", None)  # all-clear: product mode
-        return arrays
-
-    def _fell_back(self, arrays: dict) -> bool:
-        """A train batch of the fullshard engine that goes to the GSPMD
-        row-major step: it carries no fullshard plan (its own overflow,
-        or a peer's in a multi-process run)."""
-        return self._mesh_engine == "fullshard" and "fs_slots" not in arrays
-
-    def _maybe_dedup(self, arrays: dict, batch) -> dict:
-        """Attach the deduped gather arrays to a row-major batch when the
-        batch fits the capacity (data.dedup). The first batch DECIDES
-        for the run: if its unique count overflows (near-uniform data —
-        dedup unprofitable there anyway), stop paying the host np.unique
-        sort on every subsequent batch. On success the dead [B, F] slots
-        array is dropped from the transfer (batch_rows reads only
-        unique_slots/inverse)."""
-        if not self._dedup_cap or self._dedup_on is False:
-            return arrays
-        from xflow_tpu.ops.sorted_table import dedup_slots
-
-        got = dedup_slots(np.asarray(batch.slots), self._dedup_cap)
-        if got is not None:
-            arrays = dict(arrays)
-            arrays["unique_slots"], arrays["inverse"] = got
-            arrays.pop("slots", None)
-            self._dedup_on = True
-        elif self._dedup_on is None:
-            self._dedup_on = False
-        return arrays
 
     # -------------------------------------------------------- multi-process IO
     def _empty_batch(self):
@@ -803,7 +343,6 @@ class Trainer:
     def _with_arrays(
         self,
         batch,
-        with_plan: bool = True,
         track_health: bool = True,
         profiler=None,
     ):
@@ -818,13 +357,12 @@ class Trainer:
         if track_health:
             self._health.observe_batch(batch.slots, batch.mask)
         with span("plan", profiler):
-            arrays = self._batch_arrays(batch, with_plan=with_plan)
+            arrays = self._engine.batch_arrays(batch)
         return batch, arrays
 
     def _coordinated_batches(
         self,
         path: "str | list",
-        with_plan: bool = True,
         enforce_bad_rows: bool = True,
         quarantine: bool = True,
         track_health: bool = True,
@@ -845,9 +383,7 @@ class Trainer:
         shrink between epochs are picked up (`_epoch_batch_count`); the
         batch stream itself adds no host collectives (the fullshard
         overflow flag, when that engine is on, is the fit loop's, not
-        this iterator's). `with_plan` false skips sorted-plan building
-        (mesh eval runs row-major); `enforce_bad_rows`/`quarantine`
-        thread through to the bad-record monitor (eval passes count but
+        this iterator's). `enforce_bad_rows`/`quarantine` thread through to the bad-record monitor (eval passes count but
         never raise; only the first training pass quarantines).
         `skips` ({shard index -> batches}, or the legacy scalar `skip`)
         fast-forwards each shard past its stored offset (checkpointed
@@ -864,7 +400,7 @@ class Trainer:
         prof = self.pipeline_prof if profiled else None
 
         prepare = lambda b: self._with_arrays(
-            b, with_plan=with_plan, track_health=track_health, profiler=prof
+            b, track_health=track_health, profiler=prof
         )
 
         def feed():
@@ -907,8 +443,7 @@ class Trainer:
                 # in the producer group while simultaneously counting
                 # as the consumer's data-wait — double attribution)
                 pair = self._with_arrays(
-                    self._empty_batch(),
-                    with_plan=with_plan, track_health=track_health,
+                    self._empty_batch(), track_health=track_health
                 )
             else:
                 produced += 1
@@ -1339,10 +874,10 @@ class Trainer:
                     trace.before_step(res.steps + 1)
                     if step_delay_s:  # drill injector (testing/faults.py)
                         time.sleep(step_delay_s)
-                    arrays = self._resolve_fullshard_overflow(batch, arrays)
-                    res.fullshard_overflow_batches += self._fell_back(arrays)
+                    arrays = self._engine.agree(batch, arrays)
+                    res.fullshard_overflow_batches += self._engine.fell_back(arrays)
                     with span("transfer", prof) as moved:
-                        arrays = self._shard_batch(arrays)
+                        arrays = self._engine.shard_batch(arrays)
                     with span("dispatch", prof) as called:
                         self.state, m = self.train_step(self.state, arrays)
                     # finish the PREVIOUS step's timing: the block on its
@@ -1674,7 +1209,7 @@ class Trainer:
                 "elapsed_s": round(res.seconds, 3),
                 "occupancy": res.occupancy,
             }
-            if self._mesh_engine == "fullshard":
+            if self.engine == "fullshard":
                 final_rec["fullshard_overflow_batches"] = res.fullshard_overflow_batches
             # tail window (steps since the last log tick) + run-total counters
             final_rec.update(steptimer.window_record())
@@ -1840,9 +1375,9 @@ class Trainer:
                     self._coordinated_batches([(0, seg.path)], quarantine=True)
                 ):
                     arrays.pop("_shard", None)
-                    arrays = self._resolve_fullshard_overflow(batch, arrays)
-                    res.fullshard_overflow_batches += self._fell_back(arrays)
-                    arrays = self._shard_batch(arrays)
+                    arrays = self._engine.agree(batch, arrays)
+                    res.fullshard_overflow_batches += self._engine.fell_back(arrays)
+                    arrays = self._engine.shard_batch(arrays)
                     self.state, m = self.train_step(self.state, arrays)
                     steptimer.dispatched(m, batch.num_rows)
                     health.collect()
@@ -1990,7 +1525,7 @@ class Trainer:
             "elapsed_s": round(res.seconds, 3),
             "occupancy": res.occupancy,
         }
-        if self._mesh_engine == "fullshard":
+        if self.engine == "fullshard":
             final_rec["fullshard_overflow_batches"] = res.fullshard_overflow_batches
         final_rec.update(steptimer.window_record())
         final_rec.update(hbm_window_fields(registry))
@@ -2129,12 +1664,11 @@ class Trainer:
         fout = open(f"pred_{self.rank}_{block}.txt", "w") if dump else None
         pctrs, labels = [], []
         for batch, arrays in self._coordinated_batches(
-            shards, with_plan=self._mesh_engine != "replicated",
-            enforce_bad_rows=False, quarantine=False, track_health=False,
+            shards, enforce_bad_rows=False, quarantine=False, track_health=False,
         ):
             arrays.pop("_shard", None)
-            arrays = self._resolve_fullshard_overflow(batch, arrays)
-            arrays = self._shard_batch(arrays)
+            arrays = self._engine.agree(batch, arrays)
+            arrays = self._engine.shard_batch(arrays)
             p_dev = self.eval_step(self.state.tables, arrays)
             if multiproc:
                 # ONE allgather of the stacked local rows per batch
@@ -2184,12 +1718,11 @@ class Trainer:
         ll_sum, n_rows = 0.0, 0.0
         fout = open(f"pred_{self.rank}_{block}.txt", "w") if dump else None
         for batch, arrays in self._coordinated_batches(
-            shards, with_plan=self._mesh_engine != "replicated",
-            enforce_bad_rows=False, quarantine=False, track_health=False,
+            shards, enforce_bad_rows=False, quarantine=False, track_health=False,
         ):
             arrays.pop("_shard", None)
-            arrays = self._resolve_fullshard_overflow(batch, arrays)
-            arrays = self._shard_batch(arrays)
+            arrays = self._engine.agree(batch, arrays)
+            arrays = self._engine.shard_batch(arrays)
             p = self._local_pctrs(self.eval_step(self.state.tables, arrays))
             rm = np.asarray(batch.row_mask) > 0
             y = np.asarray(batch.labels)[rm]
@@ -2579,17 +2112,3 @@ def _slot_any(mask2d, K: int):
     group = jnp.arange(width)[:, None] // K == jnp.arange(width // K)
     return (mask2d.astype(jnp.float32) @ group.astype(jnp.float32)) > 0
 
-
-def _shard_batch_arrays(batch: dict, mesh):
-    from xflow_tpu.parallel.mesh import batch_sharding
-
-    sh = batch_sharding(mesh)
-    if jax.process_count() > 1:
-        # each process holds different rows (its own input shard): assemble a
-        # global array from per-process local data (device_put would demand
-        # identical values everywhere)
-        return {
-            k: jax.make_array_from_process_local_data(sh[k], np.asarray(v))
-            for k, v in batch.items()
-        }
-    return {k: jax.device_put(jnp.asarray(v), sh[k]) for k, v in batch.items()}
