@@ -432,10 +432,11 @@ def test_fit_opens_the_vocabulary_nested_on_its_threads(
         + ["xflow:data_wait", "xflow:fit_flush", "xflow:occupancy",
            "xflow:fit_close"]
     )
-    # deeper: the first dispatch compiles the step, and the pass's
-    # terminating next() holds the prefetch teardown
+    # deeper: the state is placed before the loop (inside fit_open), the
+    # first dispatch compiles the step, and the pass's terminating
+    # next() holds the prefetch teardown
     deeper = [name for depth, name in tree if depth >= 2]
-    assert deeper[:2] == ["xflow:lower", "xflow:compile"]
+    assert deeper[:3] == ["xflow:place_state", "xflow:lower", "xflow:compile"]
     assert deeper.count("xflow:iter_end") == 1
     last_wait = max(i for i, t in enumerate(tree) if t == (1, "xflow:data_wait"))
     assert tree[last_wait + 1] == (2, "xflow:iter_end")

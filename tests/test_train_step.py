@@ -426,7 +426,9 @@ def _table_shaped_counts(builder_row):
     from xflow_tpu.analysis.ir import _build_program, _iter_eqns
 
     _, fn, args, _ = _build_program(*builder_row)
-    leaf_shapes = {tuple(x.shape) for x in jax.tree.leaves((args[0].tables, args[0].opt_state))}
+    # the whole state, or (the fullshard step's gradient program) its table alone
+    packed = (args[0].tables, args[0].opt_state) if hasattr(args[0], "tables") else args[0]
+    leaf_shapes = {tuple(x.shape) for x in jax.tree.leaves(packed)}
     counts = {"select_n": 0, "is_finite": 0}
     for eqn in _iter_eqns(fn.trace(*args).jaxpr.jaxpr):
         if eqn.primitive.name in counts and any(
@@ -439,7 +441,7 @@ def _table_shaped_counts(builder_row):
 def _train_program_keys():
     from xflow_tpu.analysis.ir import PROGRAMS
 
-    return [p[0] for p in PROGRAMS if p[2].endswith("_train")]
+    return [p[0] for p in PROGRAMS if p[2].endswith(("_train", "_update"))]
 
 
 @pytest.mark.parametrize("key", _train_program_keys())
@@ -461,6 +463,7 @@ def test_guarded_step_has_no_table_wide_select_or_sweep(key):
     sel_off, fin_off = _table_shaped_counts(with_guard("off"))
     assert fin_off == 0
     # every program of the matrix has ONE table ("w" or "wv"), so one
-    # gradient leaf — none on the fused path
-    added = 0 if key == "train_step[fm.sorted]" else 1
+    # gradient leaf — none on the fused path, and none in the fullshard
+    # step's gradient program: its guard sits in the update program
+    added = 0 if key in ("train_step[fm.sorted]", "train_step.fullshard.fm[fm]") else 1
     assert (sel_on - sel_off, fin_on) == (added, added)

@@ -337,7 +337,7 @@ if [ "$HAVE_IR" -eq 1 ]; then
         "$IRS/xflow_tpu/train/step.py"
     # XF804: donation the AST tier cannot see (AST says undonated, the
     # lowered signature donates) — the contract matrix would rot
-    sed -i 's|train_step = jax.jit(train_step, donate_argnums=(0,))|train_step = jax.jit(train_step, **{"donate_argnums": (0,)})  # IR-SEED-804|' \
+    sed -i 's|train_step = jax.jit(train_step, donate_argnums=(0,), \*\*pinned)|train_step = jax.jit(train_step, **{"donate_argnums": (0,)}, **pinned)  # IR-SEED-804|' \
         "$IRS/xflow_tpu/train/step.py"
     out=$(python tools/xflowlint.py --root "$IRS" --no-baseline \
         --rules XF802,XF803,XF804 2>/dev/null || true)

@@ -103,7 +103,12 @@ _PROGRAMS = (
      {"model.name": "lr"}, "rowmajor"),
     ("predict.gspmd[lr]", "gspmd", "gspmd_eval",
      {"model.name": "lr"}, "rowmajor"),
+    # the fullshard step is two programs, cut where the state is
+    # written: the gradient (exchange, gather, scatter) and the update
+    # (guard + optimizer sweep)
     ("train_step.fullshard.fm[fm]", "fullshard", "fullshard_train",
+     {"model.name": "fm"}, "fullshard"),
+    ("update_step.fullshard.fm[fm]", "fullshard", "fullshard_update",
      {"model.name": "fm"}, "fullshard"),
     ("predict.fullshard.fm[fm]", "fullshard", "fullshard_eval",
      {"model.name": "fm"}, "fullshard"),
@@ -131,8 +136,17 @@ class _Captured(Exception):
 
 
 class _CapturingRecorder:
+    """`stop_at`: the prefix of the program's recorder name to stop at
+    (None: the first wrap); earlier wraps are kept in `seen`."""
+
+    def __init__(self, stop_at=None):
+        self.stop_at, self.seen = stop_at, {}
+
     def wrap(self, name, fn, **static_fields):
-        raise _Captured(name, fn)
+        if self.stop_at is None or name.startswith(self.stop_at):
+            raise _Captured(name, fn)
+        self.seen[name] = fn
+        return fn
 
 
 def _capture(thunk):
@@ -251,7 +265,8 @@ def _build_program(key, engine, builder, overrides, batch_kind):
     model = get_model(cfg.model.name)
     opt = get_optimizer(cfg.optim.name)
     state = _abstract_state(model, opt, cfg)
-    cap = _CapturingRecorder()
+    cap = _CapturingRecorder(
+        "update_step" if builder == "fullshard_update" else None)
 
     if builder == "single_train":
         from xflow_tpu.train.step import make_train_step
@@ -287,7 +302,9 @@ def _build_program(key, engine, builder, overrides, batch_kind):
         call = make_sharded_eval_step(model, cfg, mesh, recorder=cap)
         name, fn = _capture(lambda: call(st.tables, batch))
         return name, fn, (st.tables, batch), cfg
-    if builder == "fullshard_train":
+    if builder in ("fullshard_train", "fullshard_update"):
+        import jax
+
         from xflow_tpu.parallel.sorted_fullshard import (
             make_fullshard_train_step,
         )
@@ -298,7 +315,14 @@ def _build_program(key, engine, builder, overrides, batch_kind):
         name, fn = _capture(lambda: call(st, batch))
         keys = ("fs_slots", "fs_row", "fs_mask", "fs_off", "labels",
                 "row_mask")
-        return name, fn, (st, {k: batch[k] for k in keys}), cfg
+        (table,) = st.tables.values()
+        grad_args = (table, {k: batch[k] for k in keys})
+        if builder == "fullshard_train":
+            return name, fn, grad_args, cfg
+        # the update takes what the gradient program hands back
+        (grad_fn,) = cap.seen.values()
+        (loss, rows), grads = jax.eval_shape(grad_fn, *grad_args)
+        return name, fn, (st, grads, loss, rows), cfg
     if builder == "fullshard_eval":
         from xflow_tpu.parallel.sorted_fullshard import (
             make_fullshard_eval_step,
@@ -527,7 +551,7 @@ def extract_program(key, engine, builder, overrides, batch_kind, root):
                                          batch_kind)
     traced = fn.trace(*args)
     facts = analyze_jaxpr(traced.jaxpr.jaxpr, root, engine,
-                          _table_names(args[0]))
+                          _table_names(cfg))
     lowered = traced.lower()
     cost = None
     try:
@@ -559,14 +583,17 @@ def extract_program(key, engine, builder, overrides, batch_kind, root):
     return facts
 
 
-def _table_names(state_like):
-    """{leaf shape -> table name} for chain labeling."""
-    tables = getattr(state_like, "tables", state_like)
-    out = {}
-    if isinstance(tables, dict):
-        for name, leaf in sorted(tables.items()):
-            out[tuple(int(d) for d in leaf.shape)] = name
-    return out
+def _table_names(cfg):
+    """{leaf shape -> table name} for chain labeling, from the config's
+    own state: a program may take the whole state, its tables, or one
+    table alone."""
+    from xflow_tpu.models import get_model
+    from xflow_tpu.optim import get_optimizer
+
+    tables = _abstract_state(get_model(cfg.model.name),
+                             get_optimizer(cfg.optim.name), cfg).tables
+    return {tuple(int(d) for d in leaf.shape): name
+            for name, leaf in sorted(tables.items())}
 
 
 def _all_donated(traced, idx, n_args):

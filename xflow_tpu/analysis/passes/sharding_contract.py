@@ -350,6 +350,11 @@ class _ContractHooks(dataflow.Hooks):
                 if rec is not None and rec["name"] is None:
                     rec["name"] = nm
             return target  # wrap returns the wrapped callable unchanged
+        if callee is not None and callee.split(".")[-1] == "past_cache" \
+                and argvals:
+            # compile_cache.past_cache(jitted): the same program,
+            # compiled past the persistent cache
+            return argvals[0]
         if fval.ref is not None and fval.ref[0] == "jit":
             # invoking a locally-jitted program: donate its buffers
             rec = self.jits.get(fval.ref[1])

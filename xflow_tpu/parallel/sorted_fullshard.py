@@ -65,6 +65,7 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from xflow_tpu.compile_cache import past_cache
 from xflow_tpu.config import Config
 from xflow_tpu.metrics import binary_logloss_from_logits
 from xflow_tpu.ops.sorted_table import (
@@ -513,9 +514,13 @@ def make_fullshard_eval_step(cfg: Config, mesh: Mesh, recorder=None) -> Callable
 
 
 def make_fullshard_train_step(
-    optimizer, cfg: Config, mesh: Mesh, recorder=None
+    optimizer, cfg: Config, mesh: Mesh, recorder=None, state_formats=None
 ) -> Callable:
     """FM/MVM train step with everything sharded over ('data','table').
+
+    `state_formats` (state -> train/engine.py `state_formats`, or None)
+    adds the on-device layout the kernels take w, n, z in to the state's
+    shardings, on the way in and on the way out (`programs` below).
 
     MVM runs in one of two row-side modes, chosen PER BATCH by the
     planner (train/engine.py _mvm_wants_fields): "mvm_product" (no fs_fields —
@@ -594,13 +599,13 @@ def make_fullshard_train_step(
                 batch["row_mask"].reshape(D, -1),
             )
 
-        def train_step(state: TrainState, batch: dict):
+        def grad_part(table, batch: dict):
             # "grad" covers forward+backward: the scatter (gather's
             # transpose, staying on the owning device) lands here
             with jax.named_scope("grad"):
-                (loss, rows), grads = jax.value_and_grad(
-                    loss_for_grad, has_aux=True
-                )(state.tables[tname], batch)
+                return jax.value_and_grad(loss_for_grad, has_aux=True)(table, batch)
+
+        def update_part(state: TrainState, grads, loss, rows):
             metrics = {"loss": loss, "rows": rows}
             # non-finite guard: update_ok computed from the replicated
             # loss + the sharded gradient (the isfinite reduction GSPMDs
@@ -621,29 +626,62 @@ def make_fullshard_train_step(
             )
             return TrainState(new_tables, new_opt, state.step + 1), metrics
 
-        return train_step, fullshard_batch_sharding(mesh, with_fields=with_fields)
+        return grad_part, update_part, fullshard_batch_sharding(mesh, with_fields=with_fields)
 
     from xflow_tpu.parallel.mesh import state_shardings
 
     rep = NamedSharding(mesh, P())
     jitted: dict = {}
 
+    def programs(mode: str, grad_part, update_part, bsh, like):
+        """The step as its two programs, cut where the state is written.
+
+        On a TPU the state is pinned in the kernels' layout
+        (`state_formats`), and a program that hands back a pinned leaf
+        is compiled past the persistent cache
+        (`compile_cache.past_cache`). The whole step's compile is the
+        exchange, the merge's sort and three kernels: seconds every
+        process would pay. So the gradient program takes the table
+        pinned, hands back only default layouts — the shard's gradient
+        among them, re-laid once on each side of the cut — and is
+        cached; the update, an elementwise sweep, is the one compiled in
+        every process. Where nothing is pinned the cut buys nothing and
+        stays: one structure, so what the tests and the IR lint read is
+        what the chip runs."""
+        plain = state_shardings(like, mesh)
+        pinned = state_formats(like) if state_formats is not None else None
+        ssh = pinned or plain
+        grad_sh = plain.tables[tname]  # the gradient: sharded as the table, no layout
+        grad = jax.jit(
+            grad_part,
+            in_shardings=(ssh.tables[tname], bsh),
+            out_shardings=((rep, rep), grad_sh),
+        )
+        update = jax.jit(
+            update_part,
+            in_shardings=(ssh, grad_sh, rep, rep),
+            out_shardings=(ssh, {k: rep for k in metrics_keys(cfg)}),
+            donate_argnums=(0,),
+        )
+        if pinned is not None:
+            update = past_cache(update)
+        if recorder is not None:
+            grad = recorder.wrap(
+                f"train_step.fullshard.{mode}", grad, table_spans_per_step=wpo
+            )
+            update = recorder.wrap(f"update_step.fullshard.{mode}", update)
+
+        def train_step(state: TrainState, batch: dict):
+            (loss, rows), grads = grad(state.tables[tname], batch)
+            return update(state, grads, loss, rows)
+
+        return train_step
+
     def call(state: TrainState, batch: dict):
         mode = _batch_mode(cfg, batch)
         if mode not in jitted:
-            step, bsh = build(mode)
-            ssh = state_shardings(state, mesh)
-            fn = jax.jit(
-                step,
-                in_shardings=(ssh, bsh),
-                out_shardings=(ssh, {k: rep for k in metrics_keys(cfg)}),
-                donate_argnums=(0,),
-            )
-            if recorder is not None:
-                fn = recorder.wrap(
-                    f"train_step.fullshard.{mode}", fn, table_spans_per_step=wpo
-                )
-            jitted[mode] = (fn, bsh)
+            *parts, bsh = build(mode)
+            jitted[mode] = (programs(mode, *parts, bsh, state), bsh)
         fn, bsh = jitted[mode]
         return fn(state, {k: batch[k] for k in bsh})
 

@@ -22,6 +22,7 @@ from typing import Callable
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from xflow_tpu.compile_cache import past_cache
 from xflow_tpu.config import Config
 from xflow_tpu.models.base import Model
 from xflow_tpu.optim.base import Optimizer
@@ -37,8 +38,12 @@ def shard_state(state: TrainState, mesh: Mesh) -> TrainState:
 
 
 def make_sharded_train_step(
-    model: Model, optimizer: Optimizer, cfg: Config, mesh: Mesh, recorder=None
+    model: Model, optimizer: Optimizer, cfg: Config, mesh: Mesh, recorder=None,
+    state_formats=None,
 ) -> Callable:
+    """The GSPMD step. `state_formats` (state -> train/engine.py
+    `state_formats`, or None): the fullshard engine's fallback takes and
+    returns the state in that engine's layout."""
     step = make_train_step(model, optimizer, cfg, jit=False, allow_fused=False)
     # state shardings depend only on pytree structure; build from a spec of
     # the real state at first call via jit's lazy specialization
@@ -55,8 +60,9 @@ def make_sharded_train_step(
     # (train/step.py metrics_keys), replicated like loss/rows
     out_metrics_sh = {k: replicated(mesh) for k in metrics_keys(cfg)}
 
-    def wrap(state: TrainState, batch: dict):
-        ssh = state_shardings(state, mesh)
+    def wrap(state: TrainState, batch: dict, formats):
+        # the same shardings, the packed leaves' layout pinned
+        ssh = formats or state_shardings(state, mesh)
         return jax.jit(
             sharded,
             # subset to the batch's actual keys: jit in_shardings must
@@ -75,7 +81,10 @@ def make_sharded_train_step(
     def call(state: TrainState, batch: dict):
         key = frozenset(batch)
         if key not in cache:
-            jitted = wrap(state, batch)
+            formats = state_formats(state) if state_formats is not None else None
+            jitted = wrap(state, batch, formats)
+            if formats is not None:  # it hands back pinned leaves
+                jitted = past_cache(jitted)
             cache[key] = (
                 recorder.wrap("train_step.gspmd", jitted)
                 if recorder is not None

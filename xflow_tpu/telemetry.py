@@ -524,9 +524,11 @@ class CompileRecorder:
     @staticmethod
     def signature(args: tuple, kwargs: Optional[dict] = None) -> str:
         """Short stable hash of the call's abstract signature: pytree
-        structure + per-leaf shape/dtype/sharding. Two calls with the
-        same signature would reuse one executable; a changed signature
-        is a new program, never a recompile."""
+        structure + per-leaf shape/dtype/sharding, and the on-device
+        layout of a leaf that has one (a jit without a layout of its own
+        compiles for its argument's). Two calls with the same signature
+        would reuse one executable; a changed signature is a new
+        program, never a recompile."""
         import hashlib
 
         import jax
@@ -541,6 +543,9 @@ class CompileRecorder:
                 sh = getattr(leaf, "sharding", None)
                 if sh is not None:
                     desc += "@" + str(sh)
+                fmt = getattr(leaf, "format", None)
+                if fmt is not None and fmt.layout is not None:
+                    desc += "/" + str(fmt.layout)
             else:
                 desc = f"{type(leaf).__name__}:{leaf!r}"
             parts.append(desc)
@@ -550,14 +555,17 @@ class CompileRecorder:
     @staticmethod
     def _fast_key(args: tuple, kwargs: Optional[dict] = None):
         """Per-call cache key for `wrap`: treedef + per-leaf
-        shape/dtype/sharding tuples. Hashing a Sharding object is far
-        cheaper than the str()'d repr `signature` sha1s — that one runs
-        once per compile (for the record), never per step."""
+        shape/dtype and format (sharding and on-device layout: an
+        executable compiled for one layout refuses an array in another)
+        or, of a leaf that has none, sharding. Hashing these objects is
+        far cheaper than the str()'d repr `signature` sha1s — that one
+        runs once per compile (for the record), never per step."""
         import jax
 
         leaves, treedef = jax.tree_util.tree_flatten((args, kwargs or {}))
         return treedef, tuple(
-            (leaf.shape, leaf.dtype, getattr(leaf, "sharding", None))
+            (leaf.shape, leaf.dtype,
+             getattr(leaf, "format", None) or getattr(leaf, "sharding", None))
             if hasattr(leaf, "shape") and hasattr(leaf, "dtype")
             else (type(leaf), repr(leaf))
             for leaf in leaves
@@ -642,7 +650,9 @@ class CompileRecorder:
         record, and return the compiled executable. Raises whatever
         lower/compile raised. `static_fields`: what the builder knows
         about the program and the compiled module does not say
-        (`wrap`'s keywords), copied into the record."""
+        (`wrap`'s keywords), copied into the record. A `jitted` that
+        says `persistent_cache = False` of itself
+        (`compile_cache.past_cache`) has that in its record."""
         import jax
 
         sig = self.signature(args, kwargs)
@@ -675,6 +685,8 @@ class CompileRecorder:
             "xla_compile_s": round(compiling.seconds, 6),
             "cache_hit": bool(hits),
         }
+        if getattr(jitted, "persistent_cache", True) is False:
+            rec["persistent_cache"] = False  # compiled past it, by the builder's wrapper
         rec.update(self._cost_fields(compiled))
         with self._lock:
             seen = self._compiled.get((name, sig), 0)
@@ -958,7 +970,7 @@ HOST_SPANS_FIT = (
     "iter_end", "fit_flush", "occupancy", "fit_close",
 )
 HOST_SPANS_PREFETCH = ("parse", "cache_read", "plan", "producer_wait")
-HOST_SPANS_INIT = ("init_state", "lower", "compile")
+HOST_SPANS_INIT = ("init_state", "place_state", "lower", "compile")
 HOST_SPANS = HOST_SPANS_FIT + HOST_SPANS_PREFETCH + HOST_SPANS_INIT
 _SPAN_NAMES = {s: "xflow:" + s for s in HOST_SPANS}
 

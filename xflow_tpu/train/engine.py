@@ -22,7 +22,10 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Format, Layout
+from jax.sharding import SingleDeviceSharding
 
+from xflow_tpu.compile_cache import no_persistent_cache
 from xflow_tpu.config import Config
 from xflow_tpu.models.ffm import ffm_invperm, resolve_ffm_aligned
 from xflow_tpu.models.mvm import has_field_duplicates, resolve_mvm_product
@@ -46,6 +49,7 @@ from xflow_tpu.parallel.train_step import (
     make_sharded_eval_step,
     make_sharded_train_step,
 )
+from xflow_tpu.train.state import init_state
 from xflow_tpu.train.step import batch_to_arrays, make_eval_step, make_train_step
 
 # engine name (what `Trainer.engine` and the run summary print) -> the
@@ -59,6 +63,49 @@ ENGINE_MODULES = {
 }
 
 
+# How the sorted engines' Pallas calls take a packed [S/pack, pack·K]
+# leaf on a TPU: row-major in (8, 128) tiles. The TPU client's default
+# for such a leaf is the transposed, unpadded one, and a step program
+# whose boundary is in that layout re-lays w, n, z on the way in and on
+# the way out — six table-sized copies a step.
+KERNEL_LAYOUT = Layout(major_to_minor=(0, 1), tiling=((8, 128),))
+
+
+def kernel_layout(device) -> Optional[Layout]:
+    """The layout to pin a packed leaf in on `device`; None off the TPU,
+    where there is no tiled layout to name and the kernels' stand-ins
+    are XLA's own."""
+    return KERNEL_LAYOUT if device.platform == "tpu" else None
+
+
+def state_formats(name: str, state, shardings):
+    """What engine `name`'s step programs take each leaf of the state in.
+
+    `state` (live or abstract) and `shardings` are matching pytrees.
+    Under the sorted engines on a TPU every rank-2 leaf — a packed
+    table, or its optimizer state — is pinned: `Format(KERNEL_LAYOUT,
+    sharding)`; every other leaf keeps its plain sharding. None where
+    nothing is pinned (the row-major engines, 1-D tables, any device
+    that is not a TPU: there is no tiled layout to name there): the
+    builders then compile as they always did, and `Engine.place_state`
+    moves nothing."""
+    if name not in ("sorted", "fullshard"):
+        return None
+
+    def fmt(leaf, sh):
+        layout = kernel_layout(next(iter(sh.device_set))) if leaf.ndim == 2 else None
+        return sh if layout is None else Format(layout, sh)
+
+    out = jax.tree.map(fmt, state, shardings)
+    pinned = any(isinstance(f, Format) for f in jax.tree.leaves(out))
+    return out if pinned else None
+
+
+def _same_layout(a: Layout, b: Layout) -> bool:
+    # an array reports "untiled" as (), a Layout built by hand as None
+    return a.major_to_minor == b.major_to_minor and (a.tiling or ()) == (b.tiling or ())
+
+
 @dataclass(frozen=True)
 class Engine:
     """What the trainer needs of the engine a run resolved to."""
@@ -69,6 +116,8 @@ class Engine:
     planner: Optional[str]
     # eval_shape(state) -> shardings for `build_state`; None = one device
     state_shardings: Optional[Callable]
+    # state -> `state_formats` of this engine on the state's devices
+    state_formats: Callable
     train_step: Callable  # (state, arrays) -> (state, metrics)
     eval_step: Callable  # (tables, arrays) -> pctr
     batch_arrays: Callable  # SparseBatch -> step-input arrays (host)
@@ -77,6 +126,39 @@ class Engine:
     # arrays -> this train batch left the engine's own step
     fell_back: Callable
     shard_batch: Callable  # host arrays -> device arrays
+
+    def place_state(self, state) -> tuple:
+        """A state from outside a step (`build_state`, a restored
+        checkpoint, an adopted snapshot, leaves a caller assigned) ->
+        (the state as the step programs are compiled to take it, leaves
+        moved, bytes moved). A leaf already in its format passes through
+        on a comparison, no device work; another is re-laid by a
+        `device_put`, waited for, and its source deleted, leaf by leaf —
+        the runtime makes room for a result when the copy is enqueued,
+        and a donating `device_put` between two layouts frees nothing (the
+        sizes differ, the alias is dropped, the source lives on with its
+        last reference) — so no more than one leaf exists twice at any
+        moment and nothing of the old layout outlives the call. The
+        caller's arrays are consumed, as a donated argument is."""
+        formats = self.state_formats(state)
+        if formats is None:
+            return state, 0, 0
+        leaves, treedef = jax.tree.flatten(state)
+        # by layout alone: a sharding the step cannot take is the
+        # step's to refuse, as it always was
+        todo = [
+            (i, fmt) for i, fmt in enumerate(treedef.flatten_up_to(formats))
+            if isinstance(fmt, Format) and not _same_layout(leaves[i].format.layout, fmt.layout)
+        ]
+        if not todo:
+            return state, 0, 0
+        nbytes = sum(leaves[i].nbytes for i, _ in todo)
+        with no_persistent_cache():  # the relayout hands back a pinned leaf
+            for i, fmt in todo:
+                old = leaves[i]
+                leaves[i] = jax.block_until_ready(jax.device_put(old, fmt))
+                old.delete()
+        return treedef.unflatten(leaves), len(todo), nbytes
 
 
 def _choose(cfg: Config, mesh) -> str:
@@ -428,11 +510,20 @@ def resolve_engine(cfg: Config, mesh, model, optimizer, recorder) -> Engine:
     never = lambda arrays: False
     row_major_arrays = lambda batch: maybe_dedup(batch_to_arrays(batch), batch)
     if mesh is None:
+        # `build_state` without shardings leaves the state where jit puts
+        # a result: the default device
+        here = SingleDeviceSharding(jnp.zeros(()).devices().pop())
+        formats = lambda s: state_formats(name, s, jax.tree.map(lambda _: here, s))
+        abstract = jax.eval_shape(lambda: init_state(model, optimizer, cfg))
         return Engine(
             name=name,
             planner=planner_name() if name == "sorted" else None,
             state_shardings=None,
-            train_step=make_train_step(model, optimizer, cfg, recorder=recorder),
+            state_formats=formats,
+            train_step=make_train_step(
+                model, optimizer, cfg, recorder=recorder,
+                state_formats=formats(abstract),
+            ),
             eval_step=make_eval_step(model, cfg, recorder=recorder),
             batch_arrays=(
                 _sorted_arrays(cfg, maybe_dedup) if name == "sorted" else row_major_arrays
@@ -448,6 +539,7 @@ def resolve_engine(cfg: Config, mesh, model, optimizer, recorder) -> Engine:
     # the fullshard layout IS state_shardings' layout: every table/opt
     # leaf P(('data','table')) on the slot axis
     shardings = lambda s: state_shardings(s, mesh)
+    formats = lambda s: state_formats(name, s, shardings(s))
     # make_sharded_eval_step adopts the tables' LIVE sharding as its
     # in_sharding — jit never reshards explicit in_shardings
     gspmd_eval = make_sharded_eval_step(model, cfg, mesh, recorder=recorder)
@@ -456,6 +548,7 @@ def resolve_engine(cfg: Config, mesh, model, optimizer, recorder) -> Engine:
             name=name,
             planner=None,
             state_shardings=shardings,
+            state_formats=formats,
             train_step=make_sharded_train_step(
                 model, optimizer, cfg, mesh, recorder=recorder
             ),
@@ -465,13 +558,15 @@ def resolve_engine(cfg: Config, mesh, model, optimizer, recorder) -> Engine:
             fell_back=never,
             shard_batch=shard_batch,
         )
-    fullshard_step = make_fullshard_train_step(optimizer, cfg, mesh, recorder=recorder)
+    fullshard_step = make_fullshard_train_step(
+        optimizer, cfg, mesh, recorder=recorder, state_formats=formats
+    )
     fullshard_eval = make_fullshard_eval_step(cfg, mesh, recorder=recorder)
     # per-batch dispatch: a batch too skewed for the buffer capacity
     # arrives as row-major arrays (the overflow fallback of
     # _fullshard_arrays, or a peer's through `agree`) and runs the GSPMD
-    # step, built on first use — the state sharding is identical, so the
-    # two steps interleave freely
+    # step, built on first use — the state's sharding and layout are
+    # identical, in and out, so the two steps interleave freely
     gspmd = {}
 
     def train_step(state, batch):
@@ -479,7 +574,7 @@ def resolve_engine(cfg: Config, mesh, model, optimizer, recorder) -> Engine:
             return fullshard_step(state, batch)
         if "step" not in gspmd:
             gspmd["step"] = make_sharded_train_step(
-                model, optimizer, cfg, mesh, recorder=recorder
+                model, optimizer, cfg, mesh, recorder=recorder, state_formats=formats
             )
         return gspmd["step"](state, batch)
 
@@ -492,6 +587,7 @@ def resolve_engine(cfg: Config, mesh, model, optimizer, recorder) -> Engine:
         name=name,
         planner=planner_name(),
         state_shardings=shardings,
+        state_formats=formats,
         train_step=train_step,
         eval_step=eval_step,
         batch_arrays=_fullshard_arrays(cfg, mesh, maybe_dedup),

@@ -27,6 +27,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
+from xflow_tpu.compile_cache import past_cache
 from xflow_tpu.config import Config
 from xflow_tpu.metrics import binary_logloss_from_logits
 from xflow_tpu.models.base import Model
@@ -319,8 +320,14 @@ def _fused_sorted_step(state: TrainState, batch: dict, cfg: Config):
 
 
 def make_train_step(model: Model, optimizer: Optimizer, cfg: Config, jit: bool = True,
-                    allow_fused: bool = True, recorder=None) -> Callable:
+                    allow_fused: bool = True, recorder=None, state_formats=None) -> Callable:
     """Returns train_step(state, batch_arrays) -> (state, metrics).
+
+    `state_formats` (train/engine.py `state_formats`; None = the
+    devices' defaults) pins the state's on-device layout on the way in
+    AND on the way out: the state comes back as the next call takes it,
+    and the donation stays an alias. Such a program is compiled past
+    the persistent cache (`compile_cache.past_cache`).
 
     `allow_fused=False` (the sharded builders) disables the fused
     scatter+FTRL path regardless of config — the fusion's contract is
@@ -373,7 +380,13 @@ def make_train_step(model: Model, optimizer: Optimizer, cfg: Config, jit: bool =
 
     if jit:
         # donate the state: tables and optimizer state update in place in HBM
-        train_step = jax.jit(train_step, donate_argnums=(0,))
+        pinned = {} if state_formats is None else {
+            "in_shardings": (state_formats, None),
+            "out_shardings": (state_formats, None),
+        }
+        train_step = jax.jit(train_step, donate_argnums=(0,), **pinned)
+        if pinned:
+            train_step = past_cache(train_step)
         if recorder is not None:
             return recorder.wrap("train_step", train_step)
     return train_step

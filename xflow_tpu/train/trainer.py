@@ -76,6 +76,9 @@ class TrainResult:
     # train batches the fullshard engine handed to the GSPMD row-major
     # step (too skewed for data.fullshard_slack)
     fullshard_overflow_batches: int = 0
+    # state leaves re-laid into the engine's layout during this fit():
+    # a state that came in from outside a step, once (`_place_state`)
+    state_leaves_placed: int = 0
 
     @property
     def examples_per_sec(self) -> float:
@@ -268,6 +271,28 @@ class Trainer:
                     for x in leaves
                 ),
             })
+
+    def _place_state(self, res: Optional[TrainResult] = None) -> None:
+        """Put `self.state` into the layout the engine's step programs
+        are compiled to take it in (`Engine.place_state`). Called where
+        a state that came in from outside a step — `build_state`'s, a
+        restored checkpoint, an adopted or synced snapshot, leaves a
+        caller assigned — next meets a program: the entry of `fit()` and
+        `evaluate()`, and after a sync round. Leaves already placed cost
+        a comparison each; a placement that moved bytes writes one
+        kind="place_state" record and counts into `res`."""
+        with span("place_state") as placed:
+            self.state, moved, nbytes = self._engine.place_state(self.state)
+        if not moved:
+            return
+        if res is not None:
+            res.state_leaves_placed += moved
+        self.metrics.log({
+            "kind": "place_state",
+            "leaves_moved": moved,
+            "bytes": nbytes,
+            "dur_ms": round(placed.seconds * 1e3, 3),
+        })
 
     @property
     def engine(self) -> str:
@@ -733,6 +758,7 @@ class Trainer:
             self.heartbeat.append({"step": res.steps, "event": "sync"})
             t0_wall, t0 = time.time(), time.perf_counter()
             self.state, sync_rec = self._syncer.sync(self.state)
+            self._place_state(res)  # the synced leaves are new arrays
             if self.metrics.enabled:
                 # the GLOBAL step (restored base + this generation's
                 # progress) — checkpoint spans stamp the same counter,
@@ -843,6 +869,10 @@ class Trainer:
                         "sync_catchup", t0_wall, t0, int(self.state.step)
                     )
             self._syncer.attach(self.state)
+        # whatever handed this fit() its state (construction, a restore,
+        # the snapshot above, a caller's assignment), the steps meet it
+        # placed; from here on each step hands the next its own output
+        self._place_state(res)
         stop_sig = 0
         try:
             for epoch in range(start_epoch, cfg.train.epochs):
@@ -1208,6 +1238,7 @@ class Trainer:
                 "examples": res.examples,
                 "elapsed_s": round(res.seconds, 3),
                 "occupancy": res.occupancy,
+                "state_leaves_placed": res.state_leaves_placed,
             }
             if self.engine == "fullshard":
                 final_rec["fullshard_overflow_batches"] = res.fullshard_overflow_batches
@@ -1304,6 +1335,7 @@ class Trainer:
         pending_ok = None
         pending_rec = None
         self.heartbeat.append({"event": "start", "step": 0})
+        self._place_state(res)
         follower = TailFollower(
             train_path or cfg.data.train_path, cfg.data,
             appender=self.metrics if self.metrics.enabled else None,
@@ -1524,6 +1556,7 @@ class Trainer:
             "examples": res.examples,
             "elapsed_s": round(res.seconds, 3),
             "occupancy": res.occupancy,
+            "state_leaves_placed": res.state_leaves_placed,
         }
         if self.engine == "fullshard":
             final_rec["fullshard_overflow_batches"] = res.fullshard_overflow_batches
@@ -1644,6 +1677,7 @@ class Trainer:
         """
         cfg = self.cfg
         world = jax.process_count()
+        self._place_state()
         if test_path:
             shards: "str | list" = test_path
         else:
