@@ -376,10 +376,30 @@ def test_eval_never_enforces_bad_row_budget(tmp_path, monkeypatch):
 
 
 # ------------------------------------------------------ pipeline lifecycle
-def test_prefetch_worker_exits_when_consumer_abandons():
-    from xflow_tpu.data.pipeline import prefetch
+def _prefetch_threads():
+    return {t for t in threading.enumerate()
+            if t.name == "xflow-prefetch" and t.is_alive()}
 
+
+def _stream(kind, make_items):
+    """One pass over `make_items()` behind a producer thread: `plain` is
+    `prefetch` (the thread ends with its pass), `carried` a pass of the
+    `PassProducer` that fit() keeps between passes (the worker goes on
+    to read ahead over the same items)."""
+    from xflow_tpu.data.pipeline import PassProducer, PassSpec, prefetch
+
+    if kind == "plain":
+        return prefetch(iter(make_items()), depth=2)
+    spec = PassSpec(shards=(), skips=())
+    producer = PassProducer(lambda spec, defer: make_items(), depth=2)
+    producer.start(spec, then=spec)
+    return producer.batches()
+
+
+@pytest.mark.parametrize("kind", ["plain", "carried"])
+def test_prefetch_worker_exits_when_consumer_abandons(kind):
     started = threading.Event()
+    before = _prefetch_threads()  # other tests' trainers may be uncollected yet
 
     def slow_infinite():
         i = 0
@@ -388,34 +408,31 @@ def test_prefetch_worker_exits_when_consumer_abandons():
             yield i
             i += 1
 
-    it = prefetch(iter(slow_infinite()), depth=2)
+    it = _stream(kind, slow_infinite)
     assert next(it) == 0
     started.wait(timeout=10)
     it.close()  # the consumer walks away mid-epoch
     deadline = time.time() + 10
-    while time.time() < deadline:
-        if not any(
-            t.name == "xflow-prefetch" and t.is_alive()
-            for t in threading.enumerate()
-        ):
-            break
+    while time.time() < deadline and _prefetch_threads() - before:
         time.sleep(0.05)
-    alive = [t.name for t in threading.enumerate()
-             if t.name == "xflow-prefetch" and t.is_alive()]
-    assert not alive, "prefetch worker leaked after consumer close()"
+    assert not _prefetch_threads() - before, \
+        "prefetch worker leaked after consumer close()"
 
 
-def test_prefetch_propagates_producer_error():
-    from xflow_tpu.data.pipeline import prefetch
+@pytest.mark.parametrize("kind", ["plain", "carried"])
+def test_prefetch_propagates_producer_error(kind):
+    before = _prefetch_threads()
 
     def boom():
         yield 1
         raise OSError("disk on fire")
 
-    it = prefetch(iter(boom()))
+    it = _stream(kind, boom)
     assert next(it) == 1
     with pytest.raises(OSError, match="disk on fire"):
         next(it)
+    # the failed pass took its worker with it, read-ahead or not
+    assert not _prefetch_threads() - before
 
 
 def test_metrics_logger_reopens_after_close(tmp_path):

@@ -126,10 +126,14 @@ def test_pipeline_verdict_directions():
 # ------------------------------------------------- prefetch queue counters
 
 
-def test_prefetch_counters_slow_consumer():
+@pytest.mark.parametrize("kind", ["plain", "carried"])
+def test_prefetch_counters_slow_consumer(kind):
     """A slow consumer must show up as producer-blocked time and a full
-    queue — the starvation signature the satellite asks for."""
-    from xflow_tpu.data.pipeline import prefetch
+    queue — the starvation signature the satellite asks for. The same
+    of a pass of the producer fit() carries between passes, whose
+    read-ahead, waiting on a full queue for a pass nobody has begun,
+    adds nothing."""
+    from xflow_tpu.data.pipeline import PassProducer, PassSpec, prefetch
 
     reg = Registry()
     prof = PipelineProfiler(registry=reg)
@@ -139,8 +143,15 @@ def test_prefetch_counters_slow_consumer():
         for i in range(8):
             yield i
 
+    if kind == "plain":
+        stream = prefetch(gen(), depth=2, profiler=prof)
+    else:
+        spec = PassSpec(shards=(), skips=())
+        producer = PassProducer(lambda spec, defer: gen(), depth=2, profiler=prof)
+        producer.start(spec, then=spec)
+        stream = producer.batches()
     got = []
-    for item in prefetch(gen(), depth=2, profiler=prof):
+    for item in stream:
         time.sleep(0.02)  # artificially slow consumer
         got.append(item)
     assert got == list(range(8))
@@ -152,6 +163,15 @@ def test_prefetch_counters_slow_consumer():
         totals["producer_wait"], abs=1e-5
     )
     assert "pipeline.queue_depth" in snap
+    if kind == "carried":
+        deadline = time.time() + 10
+        while producer._head < 3 and time.time() < deadline:
+            time.sleep(0.005)
+        time.sleep(0.05)  # the read-ahead waits, full, for its adoption
+        assert prof.totals()[0]["producer_wait"] == totals["producer_wait"]
+        assert producer.adopt(spec, spec) == 3
+        assert list(producer.batches()) == list(range(8))
+        producer.close()
 
 
 def test_prefetch_without_profiler_unchanged():
@@ -241,7 +261,7 @@ def test_trainer_pipeline_records(tmp_path):
         # the --check gate allows)
         prod = sum(r[f"{s}_s"] for s in PIPELINE_PRODUCER_STAGES)
         cons = sum(r[f"{s}_s"] for s in PIPELINE_CONSUMER_STAGES)
-        assert prod <= wall * 1.25 + 0.05
+        assert prod <= wall * 1.25 + 0.05, pipe
         assert cons <= wall * 1.25 + 0.05
     # rows were counted (320 rows over the windows)
     assert sum(r["rows"] for r in pipe) == 320

@@ -371,7 +371,8 @@ def test_span_names_and_host_fields_come_from_one_tuple():
     per-step stages the profiler accumulates are spans of their thread,
     and `host` holds exactly one field a stage."""
     assert set(HOST_CONSUMER_STAGES) - {"loop_other"} <= set(HOST_SPANS_FIT)
-    assert set(HOST_SPANS_PREFETCH) <= set(PIPELINE_PRODUCER_STAGES)
+    # (xflow:read_ahead wraps the others over a pass's end: a span, no stage)
+    assert set(HOST_SPANS_PREFETCH) - {"read_ahead"} <= set(PIPELINE_PRODUCER_STAGES)
     assert telemetry._SPAN_NAMES == {s: "xflow:" + s for s in HOST_SPANS}
     assert len(set(HOST_SPANS)) == len(HOST_SPANS)
     prof = PipelineProfiler(registry=Registry())
@@ -440,12 +441,25 @@ def test_fit_opens_the_vocabulary_nested_on_its_threads(
     assert deeper.count("xflow:iter_end") == 1
     last_wait = max(i for i, t in enumerate(tree) if t == (1, "xflow:data_wait"))
     assert tree[last_wait + 1] == (2, "xflow:iter_end")
-    # the producer's, on its own thread, and nothing of the loop's
-    prod = [name for _, name in span_log.tree("xflow-prefetch")]
+    # the producer's, on its own thread, and nothing of the loop's: the
+    # pass, then — under xflow:read_ahead — the head start on the next,
+    # which ends where the queue is full (the worker waits outside it)
+    deadline = time.time() + 10
+    while trainer._read_ahead._head < 3 and time.time() < deadline:
+        time.sleep(0.01)  # depth 2 ready + one in hand: the worker waits
+    trainer._read_ahead.close()  # and its open span closes with it
+    tree = span_log.tree("xflow-prefetch")
+    ahead = tree.index((0, "xflow:read_ahead"))
+    prod = [name for _, name in tree[:ahead]]
     assert prod.count("xflow:parse") == n + 1  # one a batch, and the EOF
     assert prod.count("xflow:plan") == n
-    assert prod.count("xflow:producer_wait") == n
+    assert prod.count("xflow:producer_wait") == n + 1  # and the end-of-pass mark
     assert set(prod) == {"xflow:parse", "xflow:plan", "xflow:producer_wait"}
+    head = tree[ahead + 1:]
+    built = [name for depth, name in head if depth == 1]
+    assert built.count("xflow:plan") == 3
+    assert set(built) == {"xflow:parse", "xflow:plan", "xflow:producer_wait"}
+    assert head[-1] == (0, "xflow:producer_wait")
     # everything opened is in the one tuple, and all of it turned up
     # but the cache's reader (text shards here)
     opened = {e[2] for e in span_log.events} | {"xflow:init_state"}
@@ -520,7 +534,8 @@ def test_boundary_tiles_the_wall_between_two_fits(train_data, tmp_path, monkeypa
     for b in (b1, b2):
         assert all(v >= 0 for v in b.values()), b
     assert set(b2) == {"fit_tail_ms", "occupancy_ms", "close_ms", "between_fits_ms",
-                       "fit_open_ms", "first_batch_ms", "first_dispatch_ms"}
+                       "fit_open_ms", "first_batch_ms", "first_dispatch_ms", "adopted"}
+    assert b2["adopted"] is True and b1["adopted"] is False
     # the tail's named parts fit inside it, and the caller's sleep is
     # in between_fits_ms, not in the program's tail or open
     assert b2["occupancy_ms"] + b2["close_ms"] <= b2["fit_tail_ms"] + 1e-3
@@ -537,7 +552,7 @@ def test_boundary_tiles_the_wall_between_two_fits(train_data, tmp_path, monkeypa
 def test_first_fit_of_a_trainer_has_no_tail_behind_it(train_data, tmp_path, monkeypatch):
     first, _, _, _ = _two_fits(train_data, tmp_path, monkeypatch)
     assert set(_steps(first)[0]["boundary"]) == {
-        "fit_open_ms", "first_batch_ms", "first_dispatch_ms"}
+        "fit_open_ms", "first_batch_ms", "first_dispatch_ms", "adopted"}
 
 
 def test_final_record_carries_its_own_fit_end(train_data, tmp_path, monkeypatch):

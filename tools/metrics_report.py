@@ -73,8 +73,10 @@ WINDOW_KEYS = (
 # "The host timeline"; telemetry.host_fields and Trainer._boundary are
 # the writers): `host` is all-or-none like the window keys, every value
 # a non-negative number; `boundary` holds the open's three parts always
-# and the previous fit()'s four together or not at all. Both are absent
-# from an unarmed run's and from a pre-upgrade writer's records.
+# and the previous fit()'s four together or not at all, and since the
+# producer reads ahead over a pass's end `adopted` (whether this fit()
+# opened on its head start). Both are absent from an unarmed run's and
+# from a pre-upgrade writer's records.
 HOST_KEYS = (
     "read_ms", "parse_ms", "hash_ms", "batch_ms", "pad_ms", "cache_read_ms",
     "plan_ms", "producer_wait_ms", "data_wait_ms", "transfer_ms",
@@ -82,6 +84,7 @@ HOST_KEYS = (
 )
 BOUNDARY_OPEN_KEYS = ("fit_open_ms", "first_batch_ms", "first_dispatch_ms")
 BOUNDARY_TAIL_KEYS = ("fit_tail_ms", "occupancy_ms", "close_ms", "between_fits_ms")
+BOUNDARY_FLAGS = {"adopted"}  # a bool beside the milliseconds; older writers have none
 # the health keys a health-enabled window record carries (telemetry
 # .HealthMonitor.window_record); --check enforces all-or-none too
 HEALTH_KEYS = ("grad_norm", "update_norm", "param_norm", "loss_ema")
@@ -730,7 +733,7 @@ def check_streams(streams: dict, files: list[str]) -> list[str]:
                     continue
                 got = rec[group]
                 if not isinstance(got, dict) or not any(
-                    set(got) == set(keys) for keys in want
+                    set(got) - BOUNDARY_FLAGS == set(keys) for keys in want
                 ):
                     problems.append(
                         f"{tag}: record {i} has a {group} that is not one "

@@ -8,18 +8,21 @@ thread count (`lr_worker.cc:190-194`). Here batches are a fixed
 padded and masked rather than dropped (configurable via
 ``drop_remainder`` for strict reference emulation).
 
-`prefetch_to_device` overlaps host parsing with device compute — the
-TPU analog of the reference's double-duty IO/compute threads.
+`PassProducer` (and `prefetch`, one pass of it) overlaps host parsing
+with device compute — the TPU analog of the reference's double-duty
+IO/compute threads — and reads ahead over the end of a pass.
 """
 
 from __future__ import annotations
 
+import collections
+import contextlib
 import dataclasses
 import os
-import queue
 import subprocess
 import threading
 import time
+import weakref
 from typing import Iterable, Iterator, Optional
 
 import sys
@@ -37,6 +40,12 @@ class BadRecordError(RuntimeError):
     allows — the input is likely garbage (wrong format, truncated upload,
     corrupted shard) and training on it would silently learn nothing from
     those rows. Raised BEFORE the epoch completes (docs/ROBUSTNESS.md)."""
+
+
+def run_now(fn, *args) -> None:
+    """The `defer` of a stream whose effects are nobody's to take back:
+    run it on the spot."""
+    fn(*args)
 
 
 def bad_row_indices(batch: SparseBatch):
@@ -59,10 +68,17 @@ def monitor_bad_rows(
     path: str,
     enforce: bool = True,
     quarantine: bool = True,
+    defer=run_now,
 ) -> Iterator[SparseBatch]:
     """Count (and optionally quarantine) feature-less rows in a batch
     stream; with `enforce`, raise BadRecordError the moment the budget
     is exceeded.
+
+    Everything this leaves behind besides the batches — the counters,
+    the quarantine records, the summary line — goes through `defer(fn,
+    *args)`: run on the spot by default, and under a `PassProducer`
+    handed to the consumer with the batch, so that a batch nobody
+    consumed (a read-ahead that was discarded) leaves nothing.
 
     Bad rows are NOT dropped — dropping would break the row-counter /
     parser parity the multi-process step coordination depends on
@@ -84,8 +100,8 @@ def monitor_bad_rows(
     # pipeline counters (telemetry registry): run totals the trainer
     # snapshots into every metrics-JSONL window record, so batch/row
     # progress and bad-row counts ride the same stream the step
-    # decomposition does. Incremented HERE (the prefetch thread) —
-    # Counter is lock-protected against the fit loop's snapshot reads.
+    # decomposition does. Counter is lock-protected: eval streams
+    # increment on the prefetch thread, fit's stream where it consumes.
     reg = default_registry()
     c_batches = reg.counter("data.batches")
     c_rows = reg.counter("data.rows")
@@ -93,14 +109,15 @@ def monitor_bad_rows(
     total = 0
     try:
         for bi, batch in enumerate(batches):
-            c_batches.inc()
-            c_rows.inc(batch.num_rows)
+            defer(c_batches.inc)
+            defer(c_rows.inc, batch.num_rows)
             idx = bad_row_indices(batch)
             if idx.size:
-                c_bad.inc(int(idx.size))
-                labels = np.asarray(batch.labels)
-                for r in idx:
-                    qw.write(path, bi, int(r), float(labels[r]))
+                defer(c_bad.inc, int(idx.size))
+                if qw.enabled:
+                    labels = np.asarray(batch.labels)
+                    for r in idx:
+                        defer(qw.write, path, bi, int(r), float(labels[r]))
                 total += int(idx.size)
                 if enforce and 0 <= budget < total:
                     raise BadRecordError(
@@ -112,14 +129,17 @@ def monitor_bad_rows(
                     )
             yield batch
         if total:
-            print(
+            defer(lambda: print(
                 f"xflow: warning: {path}: {total} row(s) parsed to zero "
                 f"features (budget data.max_bad_rows={budget})"
                 + (f"; quarantined to {cfg.quarantine_path}" if qw.written else ""),
                 file=sys.stderr,
-            )
+            ))
     finally:
+        # now (an abandoned pass), and again behind the last deferred
+        # write, which reopens the file
         qw.close()
+        defer(qw.close)
 
 
 def examples_to_batches(
@@ -240,6 +260,7 @@ def batch_iterator(
     quarantine: bool = True,
     skip: int = 0,
     profiler=None,
+    defer=run_now,
 ) -> Iterator[SparseBatch]:
     """Stream padded batches from a libffm file, preferring the native
     parser. Every batch passes through the bad-record monitor
@@ -252,18 +273,20 @@ def batch_iterator(
     `skip_batches`) — skipped batches are neither monitored nor
     quarantined; they were already, in the run being resumed.
     `profiler` (an armed run's telemetry.PipelineProfiler) accumulates
-    per-stage wall time; the `xflow:` spans open with or without it."""
-    raw = _raw_batch_iterator(path, cfg, batch_size, profiler=profiler)
+    per-stage wall time; the `xflow:` spans open with or without it.
+    `defer` takes what the stream leaves behind besides its batches
+    (`monitor_bad_rows`; the counters and records of opening a shard)."""
+    raw = _raw_batch_iterator(path, cfg, batch_size, profiler=profiler, defer=defer)
     if skip > 0:
         raw = skip_batches(raw, skip)
     yield from monitor_bad_rows(
         raw, cfg, path,
-        enforce=enforce_bad_rows, quarantine=quarantine,
+        enforce=enforce_bad_rows, quarantine=quarantine, defer=defer,
     )
 
 
 def _cache_batch_iterator(
-    path: str, cfg: DataConfig, bs: int, profiler=None
+    path: str, cfg: DataConfig, bs: int, profiler=None, defer=run_now
 ) -> Optional[Iterator[SparseBatch]]:
     """The packed-shard-cache fast path (data.cache, docs/DATA.md):
     the verified cache's zero-copy batch iterator for text shard
@@ -305,10 +328,7 @@ def _cache_batch_iterator(
         # no quarantine record.
         raise
     except ShardCacheError as e:
-        section = getattr(e, "section", "?")
-        reg.counter("data.cache_fallbacks").inc()
-        qw = JsonlAppender(cfg.quarantine_path)
-        qw.append({
+        record = {
             "source": path,
             "cache": cache_path_for(path, cfg.cache_dir),
             "reason": (
@@ -316,18 +336,25 @@ def _cache_batch_iterator(
                 if isinstance(e, ShardCacheDigestError)
                 else "cache_unreadable"
             ),
-            "section": section,
-        })
-        qw.close()
-        print(
+            "section": getattr(e, "section", "?"),
+        }
+        warning = (
             f"xflow: warning: shard cache for {path!r} failed integrity "
-            f"({e}); quarantined, falling back to the text path",
-            file=sys.stderr,
+            f"({e}); quarantined, falling back to the text path"
         )
+
+        def fell_back() -> None:
+            reg.counter("data.cache_fallbacks").inc()
+            qw = JsonlAppender(cfg.quarantine_path)
+            qw.append(record)
+            qw.close()
+            print(warning, file=sys.stderr)
+
+        defer(fell_back)
         return None
     if sc is None:
         return None
-    reg.counter("data.cache_shards").inc()
+    defer(reg.counter("data.cache_shards").inc)
     return sc.iter_batches(bs, cfg.drop_remainder, profiler=profiler)
 
 
@@ -350,11 +377,12 @@ def _raw_batch_iterator(
     cfg: DataConfig,
     batch_size: Optional[int] = None,
     profiler=None,
+    defer=run_now,
 ) -> Iterator[SparseBatch]:
     from xflow_tpu.telemetry import default_registry, span
 
     bs = batch_size or cfg.batch_size
-    cached = _cache_batch_iterator(path, cfg, bs, profiler=profiler)
+    cached = _cache_batch_iterator(path, cfg, bs, profiler=profiler, defer=defer)
     if cached is not None:
         yield from cached
         return
@@ -372,7 +400,7 @@ def _raw_batch_iterator(
         except (ImportError, OSError, RuntimeError, subprocess.SubprocessError) as e:
             _warn_python_parser(e)
         if native_iter is not None:
-            default_registry().counter("data.parser_native_shards").inc()
+            defer(default_registry().counter("data.parser_native_shards").inc)
             # the C parser does read+parse+hash+assembly+pad inside one
             # next_batch call — one "parse" span a batch, the honest
             # resolution this path offers (docs/OBSERVABILITY.md)
@@ -384,7 +412,7 @@ def _raw_batch_iterator(
                 if profiler is not None:
                     profiler.count_batch(b.num_rows)
                 yield b
-    default_registry().counter("data.parser_python_shards").inc()
+    defer(default_registry().counter("data.parser_python_shards").inc)
     yield from examples_to_batches(
         iter_examples(path, cfg.log2_slots, cfg.hash_salt, profiler=profiler),
         bs,
@@ -420,78 +448,291 @@ def count_batches(path: str, cfg: DataConfig, batch_size: Optional[int] = None) 
     return rows // bs if cfg.drop_remainder else -(-rows // bs)
 
 
+@dataclasses.dataclass(frozen=True)
+class PassSpec:
+    """What one pass of the trainer's batch stream is opened with
+    (`Trainer._coordinated_batches`' arguments, normalised): equal
+    specs over unchanged shards give the same batches in the same
+    order."""
+
+    shards: tuple  # ((shard index, path), ...) in streaming order
+    skips: tuple  # ((shard index, batches to fast-forward), ...), one a shard
+    enforce_bad_rows: bool = True
+    quarantine: bool = True
+    track_health: bool = True
+    profiled: bool = False
+
+
+def shard_stats(shards: tuple) -> tuple:
+    """What `os.stat` says of each shard now — (exists, size, mtime_ns,
+    inode) — the half of a read-ahead's key that the arguments do not
+    hold: a shard that appeared, went, grew, shrank or was replaced
+    since reads differently."""
+    out = []
+    for _, path in shards:
+        try:
+            st = os.stat(path)
+            out.append((True, st.st_size, st.st_mtime_ns, st.st_ino))
+        except OSError:
+            out.append((False, 0, 0, 0))
+    return tuple(out)
+
+
+class _Deferred:
+    """A pass's `defer` under a `PassProducer`: what building an item
+    would leave behind is collected, in order, and travels with the
+    item; the consumer runs it when it takes the item."""
+
+    def __init__(self):
+        self._calls: list = []
+
+    def defer(self, fn, *args) -> None:
+        self._calls.append((fn, args))
+
+    def take(self) -> list:
+        calls, self._calls = self._calls, []
+        return calls
+
+
+class DeferredProfiler:
+    """The profiler a deferred pass's spans and parsers see: each
+    accumulation becomes one of the item's deferred calls on the real
+    `PipelineProfiler`."""
+
+    def __init__(self, profiler, defer):
+        self._prof, self._defer = profiler, defer
+
+    def add(self, stage: str, seconds: float) -> None:
+        self._defer(self._prof.add, stage, seconds)
+
+    def add_many(self, stages: dict) -> None:
+        self._defer(self._prof.add_many, stages)
+
+    def count_batch(self, rows: int) -> None:
+        self._defer(self._prof.count_batch, rows)
+
+
+_END = object()  # the end-of-pass mark in a producer's queue
+
+
+class PassProducer:
+    """One warm thread that builds the batch stream pass after pass
+    into a bounded queue, and reads ahead over the end of a pass.
+
+    `open_pass(spec, defer)` gives one pass's items. The worker builds
+    the pass the consumer is on (`start`), hands it the end-of-pass
+    mark, and — when that pass was begun with a `then` — goes straight
+    on to build `then`, the pass that comes next if nothing changes,
+    until the queue is full: `depth` items ready and one in hand, host
+    arrays only. That head start is the `xflow:read_ahead` span. The
+    consumer's next pass takes it over (`adopt`) if and only if it
+    would have opened the same stream — an equal `PassSpec`, and
+    `shard_stats` now equal to what they were before the read-ahead
+    opened its first shard — and otherwise stops the producer (`stop`:
+    a signal, no join) and builds its own.
+
+    Nothing a discarded read-ahead did stays: items reach the consumer
+    as (item, deferred calls) and the calls — the bad-row monitor's
+    counters and quarantine records, health tracking, the profiler's
+    stage sums — run on the consumer's thread as it takes the item
+    (`batches`), never before. An error raised while building travels
+    the same way and is raised where a fresh iterator would raise it.
+
+    Without a `then` the producer is a plain prefetch of one pass
+    (`prefetch`): its worker ends with the pass and `batches` joins it.
+    Abandonment-safe as before: a consumer that drops `batches()`
+    mid-pass (an exception in the fit loop, an early break) stops the
+    worker, which closes the pass's iterator — native parser handles,
+    the quarantine file — and exits. So does the death of `owner`.
+
+    Time the worker spends blocked on a full queue is the
+    `producer_wait` span; with a `profiler` it is accumulated — by the
+    consumer, whose take ends the wait, so it falls into the window it
+    ended in; not while a read-ahead waits to be adopted: nobody is
+    consuming then — as are both sides' queue-depth samples. The
+    consumer-side starvation signal (`data_wait`) is the fit loop's,
+    not here."""
+
+    def __init__(self, open_pass, depth: int = 2, profiler=None, owner=None):
+        self._open = open_pass
+        self._depth = depth
+        self._prof = profiler
+        # no thread outlives `owner` (the trainer that carries this
+        # producer between passes; `open_pass` must not hold it either)
+        self._owned = weakref.finalize(owner, self.close) if owner is not None else None
+        self._cv = threading.Condition()
+        self._buf: collections.deque = collections.deque()  # (item, deferred calls)
+        self._stopped = False
+        self._live = 0  # the pass the consumer is on
+        self._pass = 0  # the pass the worker is building: _live, or _live + 1 read ahead
+        self._then: Optional[PassSpec] = None  # what follows the live pass
+        self._key: Optional[tuple] = None  # (spec, shard_stats) the read-ahead opened on
+        self._head = 0  # batches the read-ahead has built
+        self._blocked_at: Optional[float] = None  # since when the worker waits for room
+        self._thread: Optional[threading.Thread] = None
+        # the worker's own: what the item it is building leaves behind,
+        # and the open xflow:read_ahead span
+        self._fx = _Deferred()
+        self._ahead = contextlib.ExitStack()
+
+    # ------------------------------------------------------- the consumer's
+    def start(self, spec: Optional[PassSpec], then: Optional[PassSpec] = None) -> None:
+        with self._cv:
+            self._then = then
+        self._thread = threading.Thread(
+            target=self._work, args=(spec,), daemon=True, name="xflow-prefetch"
+        )
+        self._thread.start()
+
+    def adopt(self, spec: PassSpec, then: Optional[PassSpec]) -> Optional[int]:
+        """Take over the read-ahead as the pass `spec`: the batches it
+        had built by now, or None where it is not the stream a new
+        iterator over `spec` would give (then `stop` it)."""
+        key = (spec, shard_stats(spec.shards))
+        with self._cv:
+            if self._stopped or self._pass != self._live + 1 or self._key != key:
+                return None
+            self._live += 1
+            self._then = then
+            self._cv.notify_all()
+            return self._head
+
+    def stop(self) -> int:
+        """Signal the worker to close its pass and exit, and drop what
+        is queued. Returns the batches of an unadopted read-ahead that
+        go with it."""
+        with self._cv:
+            dropped = self._head if self._pass > self._live and not self._stopped else 0
+            self._stopped = True
+            self._buf.clear()
+            self._cv.notify_all()
+        if self._owned is not None:
+            self._owned.detach()
+        return dropped
+
+    def close(self) -> None:
+        """`stop`, and wait for the thread to be gone (the owner's
+        finalizer; a pass abandoned or ended for good)."""
+        self.stop()
+        t = self._thread
+        if t is not None and t is not threading.current_thread():
+            t.join(timeout=10.0)
+
+    def batches(self) -> Iterator:
+        """The live pass's items, each after its deferred calls."""
+        from xflow_tpu.telemetry import span
+
+        ended = False
+        try:
+            while True:
+                with self._cv:
+                    while not self._buf and not self._stopped:
+                        self._cv.wait()
+                    if self._stopped:
+                        raise RuntimeError("the batch producer was stopped under its consumer")
+                    item, calls = self._buf.popleft()
+                    depth = len(self._buf)
+                    blocked_at, self._blocked_at = self._blocked_at, None
+                    self._cv.notify_all()
+                for fn, args in calls:
+                    fn(*args)
+                if self._prof is not None:
+                    if blocked_at is not None:
+                        self._prof.add("producer_wait", time.perf_counter() - blocked_at)
+                    self._prof.observe_queue(depth, self._depth)
+                if item is _END:
+                    ended = True
+                    return
+                if isinstance(item, BaseException):
+                    raise item
+                yield item
+        finally:
+            with span("iter_end"):
+                if not ended or self._then is None:
+                    self.close()
+
+    # --------------------------------------------------------- the worker's
+    def _put(self, item) -> bool:
+        """Queue `item` with what building it deferred; False once
+        stopped. The head start's span is over when the pass is
+        adopted, or when nothing but adoption can make room: the queue
+        full of its own items."""
+        from xflow_tpu.telemetry import span
+
+        calls = self._fx.take()
+        with self._cv:
+            live = self._pass <= self._live
+            full = len(self._buf) >= self._depth
+            if not live and item is not _END and not isinstance(item, BaseException):
+                self._head += 1
+            if live or (full and not any(i is _END for i, _ in self._buf)):
+                self._ahead.close()
+            with span("producer_wait"):
+                if full and live:
+                    # the consumer's next take ends this wait and books
+                    # it, into the window it ends in
+                    self._blocked_at = time.perf_counter()
+                while len(self._buf) >= self._depth and not self._stopped:
+                    self._cv.wait()
+            if self._stopped:
+                return False
+            self._buf.append((item, calls))
+            depth = len(self._buf)
+            self._cv.notify_all()
+        if live and self._prof is not None:
+            self._prof.observe_queue(depth, self._depth)
+        return True
+
+    def _work(self, spec: Optional[PassSpec]) -> None:
+        from xflow_tpu.telemetry import span
+
+        it = None
+        try:
+            while True:
+                it = self._open(spec, self._fx.defer)
+                for item in it:
+                    if not self._put(item):
+                        return
+                it = None
+                # the pass is built: once it is the consumer's, mark its
+                # end and read ahead over it
+                with self._cv:
+                    while self._pass > self._live and not self._stopped:
+                        self._cv.wait()
+                    if self._stopped:
+                        return
+                    spec = self._then  # stands until the next adoption
+                if spec is not None:
+                    # the key BEFORE the mark: nobody can ask for the
+                    # read-ahead before its key stands, and a shard
+                    # that changes from here on changes the key
+                    key = (spec, shard_stats(spec.shards))
+                    with self._cv:
+                        self._key = key
+                        self._pass += 1
+                        self._head = 0
+                if not self._put(_END) or spec is None:
+                    return
+                self._ahead.enter_context(span("read_ahead"))
+        except BaseException as e:  # re-raised in the consumer
+            self._put(e)
+        finally:
+            self._ahead.close()
+            close = getattr(it, "close", None)
+            if close is not None:
+                close()
+
+
 def prefetch(
     iterator: Iterator[SparseBatch], depth: int = 2, profiler=None
 ) -> Iterator[SparseBatch]:
-    """Run the parse/batch pipeline in a background thread with a bounded queue.
-
-    Abandonment-safe: when the consumer drops the generator mid-epoch
-    (an exception in the fit loop, an early break), its `close()`/GC
-    signals the worker through `stop` and drains the queue so a worker
-    blocked on a full `q.put` wakes, notices the flag, closes the
-    underlying iterator (releasing native parser handles / quarantine
-    files promptly), and exits — previously it blocked on `q.put`
-    forever, leaking one thread (and pinning its batch buffers) per
-    abandoned epoch.
-
-    Time the WORKER spends blocked in `q.put` is the `producer_wait`
-    span (the consumer/device is the bottleneck), and the consumer's
-    teardown — drain, the worker's `join` — is `iter_end`, inside the
-    pass's terminating `next()`. `profiler` (an armed run's
-    telemetry.PipelineProfiler) accumulates the former (cumulative in
-    the `pipeline.producer_blocked_s` gauge where it publishes) and
-    takes both sides' `q.qsize()` samples. The CONSUMER-side starvation
-    signal (`data_wait`) is the fit loop's — not here — so the consumer
-    stages tile the loop with nothing counted twice."""
-    from xflow_tpu.telemetry import span
-
-    q: queue.Queue = queue.Queue(maxsize=depth)
-    _END = object()
-    stop = threading.Event()
-
-    def worker() -> None:
-        try:
-            for item in iterator:
-                with span("producer_wait", profiler):
-                    q.put(item)
-                if profiler is not None:
-                    profiler.observe_queue(q.qsize(), depth)
-                if stop.is_set():
-                    return
-            q.put(_END)
-        except BaseException as e:  # re-raised in the consumer
-            q.put(e)
-        finally:
-            if stop.is_set():
-                close = getattr(iterator, "close", None)
-                if close is not None:
-                    close()
-
-    t = threading.Thread(target=worker, daemon=True, name="xflow-prefetch")
-    t.start()
-    try:
-        while True:
-            item = q.get()
-            if profiler is not None:
-                profiler.observe_queue(q.qsize(), depth)
-            if item is _END:
-                break
-            if isinstance(item, BaseException):
-                raise item
-            yield item
-    finally:
-        with span("iter_end"):
-            stop.set()
-            # unblock a worker stuck in q.put: after the drain there is
-            # at least one free slot, so its pending put completes, it
-            # sees the flag, and exits (putting at most one more item,
-            # which fits)
-            while True:
-                try:
-                    q.get_nowait()
-                except queue.Empty:
-                    break
-            t.join(timeout=10.0)
+    """Run the parse/batch pipeline in a background thread with a
+    bounded queue: one pass of a `PassProducer`, which see. The thread
+    starts at the consumer's first `next()`; the pass's terminating
+    `next()` joins it (`iter_end`)."""
+    producer = PassProducer(lambda spec, defer: iterator, depth, profiler)
+    producer.start(None)
+    yield from producer.batches()
 
 
 # --------------------------------------------------------------- streaming

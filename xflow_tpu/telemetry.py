@@ -809,7 +809,8 @@ class StepTimer:
         # where the first step's interval starts
         self.first_fetch: Optional[float] = None
         # seconds inside the terminating next() calls (one an epoch):
-        # prefetch teardown and join, parser close
+        # the take of the end-of-pass mark; a pass with a worker of its
+        # own joins it there
         self.iter_end_s = 0.0
 
     def batches(self, iterable: Iterable) -> Iterator:
@@ -819,8 +820,8 @@ class StepTimer:
         terminating call, which yields no step and counts as
         `iter_end_s`). Abandonment (an early break / exception in the
         consuming loop) closes the wrapped iterator promptly, preserving
-        the prefetch worker's close-cascade contract (data/pipeline.py
-        prefetch)."""
+        the producer's close-cascade contract (data/pipeline.py
+        PassProducer)."""
         it = iter(iterable)
         end = object()
         try:
@@ -969,7 +970,7 @@ HOST_SPANS_FIT = (
     "fit", "fit_open", "data_wait", "transfer", "dispatch", "prev_ready",
     "iter_end", "fit_flush", "occupancy", "fit_close",
 )
-HOST_SPANS_PREFETCH = ("parse", "cache_read", "plan", "producer_wait")
+HOST_SPANS_PREFETCH = ("parse", "cache_read", "plan", "producer_wait", "read_ahead")
 HOST_SPANS_INIT = ("init_state", "place_state", "lower", "compile")
 HOST_SPANS = HOST_SPANS_FIT + HOST_SPANS_PREFETCH + HOST_SPANS_INIT
 _SPAN_NAMES = {s: "xflow:" + s for s in HOST_SPANS}
@@ -1279,9 +1280,12 @@ class HealthMonitor:
     occupancy/collision gauges, and the per-window values the trainer
     folds into its metrics-JSONL records.
 
-    Thread-safety: `observe_batch` runs on the prefetch/plan thread
-    (trainer._with_arrays) while `collect`/`window_record` run on the
-    fit loop — the bitmap and window state are lock-protected.
+    Thread-safety: `observe_batch` is one of a training batch's
+    deferred calls (trainer._BatchPrep): it runs where the batch is
+    consumed — the fit loop, inside the batch's `next()`, so a batch
+    read ahead and never trained marks nothing — or on the plan thread
+    for a caller that defers nothing; `collect`/`window_record` run on
+    the fit loop. The bitmap and window state are lock-protected.
     """
 
     KEYS = ("grad_norm", "update_norm", "param_norm")
@@ -1361,9 +1365,9 @@ class HealthMonitor:
 
     # --------------------------------------------------- occupancy side
     def observe_batch(self, slots, mask) -> None:
-        """Mark a training batch's masked slots as touched (called from
-        the plan/prefetch thread so the bitmap write overlaps device
-        compute). Drives the occupancy + collision-estimate gauges."""
+        """Mark a training batch's masked slots as touched (as the batch
+        is consumed; the previous step's device time still hides it).
+        Drives the occupancy + collision-estimate gauges."""
         if self._seen is None:
             return
         idx = np.asarray(slots)[np.asarray(mask) > 0]

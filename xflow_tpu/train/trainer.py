@@ -12,6 +12,7 @@ one jitted SPMD step; then an eval pass that dumps
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import math
 import os
 import sys
@@ -26,6 +27,10 @@ import numpy as np
 from xflow_tpu.config import Config
 from xflow_tpu.jsonl import JsonlAppender
 from xflow_tpu.data.pipeline import (
+    DeferredProfiler,
+    PassProducer,
+    PassSpec,
+    run_now,
     assign_shards,
     batch_iterator,
     count_batches,
@@ -79,6 +84,13 @@ class TrainResult:
     # state leaves re-laid into the engine's layout during this fit():
     # a state that came in from outside a step, once (`_place_state`)
     state_leaves_placed: int = 0
+    # the pass boundary's read-ahead (`Trainer._carried_pass`): passes
+    # this fit() took over from the running producer, the batches that
+    # producer had built for them by then, and the batches of read-aheads
+    # this fit() had to throw away (another stream than they assumed)
+    read_ahead_passes: int = 0
+    read_ahead_batches: int = 0
+    read_ahead_discarded: int = 0
 
     @property
     def examples_per_sec(self) -> float:
@@ -105,6 +117,69 @@ class MetricsLogger(JsonlAppender):
     mode."""
 
     log = JsonlAppender.append
+
+
+class _BatchPrep:
+    """Batch -> (batch, step-input arrays), and a `PassSpec` -> its
+    stream of them: what the prefetch thread runs. It holds the
+    trainer's host-side parts and never the `Trainer` or its state, so
+    a producer carried from pass to pass keeps neither alive."""
+
+    def __init__(self, cfg: Config, engine, health: HealthMonitor, profiler):
+        self._data = cfg.data
+        # MVM and FFM key their views/blocks on the field id: a field >=
+        # num_fields would be silently dropped by the one-hot, so reject
+        # it loudly
+        self._num_fields = (
+            cfg.model.num_fields if cfg.model.name in ("mvm", "ffm") else 0
+        )
+        self._batch_arrays = engine.batch_arrays
+        self._health = health
+        self._prof = profiler
+
+    def __call__(self, batch, track_health: bool = True, profiler=None, defer=run_now):
+        """Validation + sorted-plan building happen HERE so that, on
+        the prefetch thread, the host-side sort overlaps device compute
+        instead of serializing with dispatch. Training batches also
+        feed the health monitor's touched-slot bitmap (through `defer`:
+        where the batch is consumed; eval passes skip it). The array
+        build — sorted plan, dedup — is the "plan" span, which
+        `profiler` (armed runs) accumulates."""
+        if self._num_fields:
+            max_field = int(np.max(batch.fields)) if batch.fields.size else 0
+            if max_field >= self._num_fields:
+                raise ValueError(
+                    f"libffm field id {max_field} >= model.num_fields="
+                    f"{self._num_fields}; raise model.num_fields"
+                )
+        if track_health:
+            defer(self._health.observe_batch, batch.slots, batch.mask)
+        with span("plan", profiler):
+            arrays = self._batch_arrays(batch)
+        return batch, arrays
+
+    def open_pass(self, spec: PassSpec, defer=run_now):
+        """One pass over `spec`'s shards. A REAL generator (map objects
+        have no close): the producer's abandonment path close()s it,
+        which cascades into batch_iterator's finally — native parser
+        handles and the quarantine file release promptly, not at some
+        later GC."""
+        prof = (
+            DeferredProfiler(self._prof, defer)
+            if spec.profiled and self._prof is not None else None
+        )
+        skips = dict(spec.skips)
+        for idx, p in spec.shards:
+            if not os.path.exists(p):
+                continue  # ragged/elastic worlds: a missing shard idles
+            for b in batch_iterator(
+                p, self._data,
+                enforce_bad_rows=spec.enforce_bad_rows, quarantine=spec.quarantine,
+                skip=skips.get(idx, 0), profiler=prof, defer=defer,
+            ):
+                bb, arrays = self(b, spec.track_health, prof, defer)
+                arrays["_shard"] = idx
+                yield bb, arrays
 
 
 class Trainer:
@@ -197,6 +272,12 @@ class Trainer:
         # the previous fit()'s tail — last step ready, its parts, its
         # return instant — for the next fit()'s `boundary`
         self._prev_fit: Optional[dict] = None
+        # what the prefetch thread runs, and the training stream's
+        # producer: kept running over the end of a pass, so the next
+        # pass (of this fit() or the next) opens on a full queue
+        # (`_carried_pass`). It goes when the trainer goes.
+        self._prep = _BatchPrep(cfg, self._engine, self._health, self.pipeline_prof)
+        self._read_ahead: Optional[PassProducer] = None
         # liveness heartbeat (train.heartbeat_path): tiny {step} records
         # the launcher watchdog and metrics_report --health read to flag
         # dead ranks and stragglers; kind="heartbeat" keeps the stream
@@ -248,10 +329,6 @@ class Trainer:
         # validate the guard mode at CONSTRUCTION (identical config on
         # every rank → rank-symmetric), not on the first bad batch
         self._guarded = nonfinite_guard_on(cfg)
-        # MVM and FFM key their views/blocks on the field id: a field >=
-        # num_fields would be silently dropped by the one-hot, so reject
-        # it loudly
-        self._validate_fields = cfg.model.name in ("mvm", "ffm")
 
     def _build_state(self, shardings=None) -> None:
         """The run's first state, born in its shardings (`build_state`),
@@ -307,15 +384,6 @@ class Trainer:
         the row-major engines, which plan nothing."""
         return self._engine.planner
 
-    def _check_batch(self, batch) -> None:
-        if self._validate_fields:
-            max_field = int(np.max(batch.fields)) if batch.fields.size else 0
-            if max_field >= self.cfg.model.num_fields:
-                raise ValueError(
-                    f"libffm field id {max_field} >= model.num_fields="
-                    f"{self.cfg.model.num_fields}; raise model.num_fields"
-                )
-
     # -------------------------------------------------------- multi-process IO
     def _empty_batch(self):
         from xflow_tpu.data.schema import SparseBatch
@@ -365,25 +433,39 @@ class Trainer:
         counts = np.asarray(multihost_utils.process_allgather(np.int32(local)))
         return int(counts.max()), local
 
-    def _with_arrays(
-        self,
-        batch,
-        track_health: bool = True,
-        profiler=None,
-    ):
-        """(batch, step-input arrays) — validation + sorted-plan building
-        happen HERE so that, wrapped in `prefetch`, the host-side sort
-        overlaps device compute instead of serializing with dispatch.
-        Training batches also feed the health monitor's touched-slot
-        bitmap here (same overlap argument; eval passes skip it).
-        The array build — sorted plan, dedup — is the "plan" span, which
-        `profiler` (armed runs) accumulates."""
-        self._check_batch(batch)
-        if track_health:
-            self._health.observe_batch(batch.slots, batch.mask)
-        with span("plan", profiler):
-            arrays = self._engine.batch_arrays(batch)
-        return batch, arrays
+    def _with_arrays(self, batch, track_health: bool = True):
+        """(batch, step-input arrays), on the caller's thread
+        (`_BatchPrep`): a padding batch of a multi-process pass."""
+        return self._prep(batch, track_health)
+
+    def _carried_pass(self, spec: PassSpec, then: PassSpec, res: Optional[TrainResult]):
+        """The (batch, arrays) stream of the training pass `spec`, from
+        the producer that is kept running from pass to pass.
+
+        The producer the previous pass left behind has been reading
+        ahead on the assumption that nothing changes (`then`, as that
+        pass gave it). This pass takes it over if and only if that is
+        the stream a new iterator would give now (`PassProducer.adopt`:
+        equal arguments, every shard's `stat` as it was); otherwise it
+        signals it to stop — no join — and starts its own, as every pass
+        did before. Counted into `res`."""
+        ra = self._read_ahead
+        head = ra.adopt(spec, then) if ra is not None else None
+        if head is None:
+            dropped = ra.stop() if ra is not None else 0
+            ra = self._read_ahead = PassProducer(
+                self._prep.open_pass,
+                profiler=self.pipeline_prof if spec.profiled else None,
+                owner=self,  # no thread outlives its trainer
+            )
+            ra.start(spec, then)
+        if res is not None:
+            if head is None:
+                res.read_ahead_discarded += dropped
+            else:
+                res.read_ahead_passes += 1
+                res.read_ahead_batches += head
+        return ra.batches()
 
     def _coordinated_batches(
         self,
@@ -394,6 +476,8 @@ class Trainer:
         skip: int = 0,
         skips: Optional[dict] = None,
         profiled: bool = False,
+        then: Optional[dict] = None,
+        res: Optional[TrainResult] = None,
     ):
         """Yield exactly the globally-agreed number of (batch, arrays)
         pairs for this rank's shard stream, padding with fully-masked
@@ -419,46 +503,45 @@ class Trainer:
         transfer) so the fit loop can maintain the per-shard position
         the next checkpoint's data_state pins; padding pairs carry
         none. `profiled` threads the pipeline profiler through the
-        parser/prefetch/plan seams (fit's training stream only)."""
+        parser/prefetch/plan seams (fit's training stream only).
+        `then` (fit's training stream only) says how the pass that
+        follows this one differs if nothing changes — `{"quarantine":
+        ...}`; no resume offsets — and makes this a pass of the carried
+        producer (`_carried_pass`, counted into `res`); without it the
+        pass has a prefetch thread of its own, gone with the pass."""
         shards = [(self.rank, path)] if isinstance(path, str) else list(path)
         skips = dict(skips) if skips else {idx: skip for idx, _ in shards}
-        prof = self.pipeline_prof if profiled else None
-
-        prepare = lambda b: self._with_arrays(
-            b, track_health=track_health, profiler=prof
+        spec = PassSpec(
+            shards=tuple((idx, p) for idx, p in shards),
+            skips=tuple((idx, max(int(skips.get(idx, 0)), 0)) for idx, _ in shards),
+            enforce_bad_rows=enforce_bad_rows, quarantine=quarantine,
+            track_health=track_health, profiled=profiled,
         )
 
-        def feed():
-            # a REAL generator (map objects have no close): prefetch's
-            # abandonment path close()s it, which cascades into
-            # batch_iterator's finally — native parser handles and the
-            # quarantine file release promptly, not at some later GC
-            for idx, p in shards:
-                if not os.path.exists(p):
-                    continue  # ragged/elastic worlds: a missing shard idles
-                for b in batch_iterator(
-                    p, self.cfg.data,
-                    enforce_bad_rows=enforce_bad_rows, quarantine=quarantine,
-                    skip=max(int(skips.get(idx, 0)), 0),
-                    profiler=prof,
-                ):
-                    bb, arrays = prepare(b)
-                    arrays["_shard"] = idx
-                    yield bb, arrays
+        def stream():
+            if then is not None:
+                ahead = dataclasses.replace(
+                    spec, skips=tuple((idx, 0) for idx, _ in shards), **then
+                )
+                return self._carried_pass(spec, ahead, res)
+            return prefetch(
+                self._prep.open_pass(spec),
+                profiler=self.pipeline_prof if profiled else None,
+            )
 
         if jax.process_count() == 1:
             if not any(os.path.exists(p) for _, p in shards):
                 # legacy loudness: a single process with NO input at all
                 # is a user error, not an idle elastic rank
                 raise FileNotFoundError(shards[0][1] if shards else "<no shards>")
-            yield from prefetch(feed(), profiler=prof)
+            yield from stream()
             return
         global_steps, local = self._epoch_batch_count(shards, skips)
         # open the real iterator whenever any shard exists (even if
         # counted 0) so the drift check below can catch a counter that
         # under-reads
         have_any = any(os.path.exists(p) for _, p in shards)
-        it = iter(prefetch(feed(), profiler=prof)) if have_any else iter(())
+        it = iter(stream()) if have_any else iter(())
         produced = 0
         for _ in range(global_steps):
             pair = next(it, None)
@@ -537,6 +620,12 @@ class Trainer:
         with span("fit"):
             try:
                 return self._fit(train_path, entered)
+            except BaseException:
+                # whatever pass was open, or read ahead, goes now and not
+                # when the traceback lets go of the loop's frame
+                if self._read_ahead is not None:
+                    self._read_ahead.close()
+                raise
             finally:
                 # abnormal exits land here with the sinks still open
                 # (the normal path closed them inside its fit_close span)
@@ -896,6 +985,10 @@ class Trainer:
                     self._coordinated_batches(
                         epoch_shards, quarantine=epoch == 0, skips=skips,
                         profiled=True,
+                        # what comes next if nothing changes: the next
+                        # epoch, or a new fit()'s first over this path
+                        then={"quarantine": epoch + 1 == cfg.train.epochs},
+                        res=res,
                     )
                 ):
                     # which shard fed this step (None = a padding batch):
@@ -944,6 +1037,7 @@ class Trainer:
                             boundary = self._boundary(
                                 prev_fit, entered, steptimer, called.t1
                             )
+                            boundary["adopted"] = res.read_ahead_passes > 0
                     lap_mark = ready.t1
                     # the previous step's metrics are ready now — the
                     # health scalars (norms, loss for the EMA) read free
@@ -1239,6 +1333,9 @@ class Trainer:
                 "elapsed_s": round(res.seconds, 3),
                 "occupancy": res.occupancy,
                 "state_leaves_placed": res.state_leaves_placed,
+                "read_ahead_passes": res.read_ahead_passes,
+                "read_ahead_batches": res.read_ahead_batches,
+                "read_ahead_discarded": res.read_ahead_discarded,
             }
             if self.engine == "fullshard":
                 final_rec["fullshard_overflow_batches"] = res.fullshard_overflow_batches
