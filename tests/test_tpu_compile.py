@@ -157,6 +157,91 @@ def test_kernel_compiles_for_v5e(kernel, one_chip, no_persistent_cache):
     assert _pallas_calls(compiled) == 1
 
 
+@pytest.mark.parametrize(
+    "k,window",
+    [(11, 2048), (96, 2048), (128, 1024), (129, 1024), (157, 1024), (160, 1024), (176, 512), (256, 256)],
+)
+def test_fused_kernel_fits_vmem_at_the_window_its_rule_gives(k, window, one_chip, no_persistent_cache):
+    """`ops/sorted_table.state_window`: FM's 11-float row keeps 2048
+    slots a grid step, and so do packed rows up to 96 floats; 128, 129
+    and FFM at Criteo's 39 fields x k=4 (157 floats) get 1024, and the
+    rule's choice compiles at each step of its ladder. At 2048 the
+    157-float kernel was refused, "Scoped allocation with size 16.94M and
+    limit 16.00M" (128 floats: 21.10M; 176 at 1024: 16.61M) — the step
+    died in the compiler at its first batch."""
+    import jax
+
+    from xflow_tpu.config import FTRLConfig
+    from xflow_tpu.ops import sorted_table as st
+
+    assert st.state_window(k, PACK) == window
+    S, n_occ = 1 << 16, st.padded_len(8192)
+    table = jax.ShapeDtypeStruct((S // PACK, PACK * k), np.float32, sharding=one_chip)
+    args = [
+        jax.ShapeDtypeStruct((st._k8(k), n_occ), np.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((n_occ,), np.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((S // window + 1,), np.int32, sharding=one_chip),
+        table, table, table,
+    ]
+    fn = lambda d, s, w, a, b, c: st._scatter_ftrl_pallas(d, s, w, a, b, c, k, FTRLConfig(), False, PACK)
+    assert _pallas_calls(jax.jit(fn).lower(*args).compile()) == 1
+
+
+def _ffm_cell_step(one_chip):
+    """The single-device FFM step at `ffm-v4-f39-s21`'s sizes as the
+    engine builds it: 39 fields x k=4 at 2^21 slots, B=65536, one
+    feature a field, the aligned hybrid's flat plan with its placement
+    permutation, the state pinned in the kernels' layout."""
+    import jax
+
+    from xflow_tpu.analysis.ir import _abstract_state, _capture, _CapturingRecorder
+    from xflow_tpu.config import Config, override
+    from xflow_tpu.data.schema import SparseBatch
+    from xflow_tpu.models import get_model
+    from xflow_tpu.optim import get_optimizer
+    from xflow_tpu.train.engine import _sorted_arrays, state_formats
+    from xflow_tpu.train.step import make_train_step
+
+    nf = 39
+    cfg = override(Config(), **{
+        "model.name": "ffm", "model.num_fields": nf, "model.v_dim": 4,
+        "data.log2_slots": 21, "data.batch_size": BATCH, "data.max_nnz": nf,
+    })
+    rng = np.random.default_rng(0)
+    batch = SparseBatch(
+        slots=rng.integers(0, cfg.num_slots, (BATCH, nf)).astype(np.int32),
+        fields=np.tile(np.arange(nf, dtype=np.int32), (BATCH, 1)),
+        mask=np.ones((BATCH, nf), np.float32),
+        labels=np.zeros(BATCH, np.float32), row_mask=np.ones(BATCH, np.float32),
+    )
+    arrays = _sorted_arrays(cfg, lambda a, b: a)(batch)
+    model, opt = get_model("ffm"), get_optimizer("ftrl")
+    abstract = _abstract_state(model, opt, cfg)
+    formats = state_formats("sorted", abstract, jax.tree.map(lambda _: one_chip, abstract))
+    _, step = _capture(
+        lambda: make_train_step(
+            model, opt, cfg, recorder=_CapturingRecorder(), state_formats=formats
+        )
+    )
+    return step, _shapes(abstract, one_chip), _shapes(arrays, one_chip), arrays
+
+
+def test_ffm_cell_step_compiles_for_v5e(one_chip, no_persistent_cache, on_tpu):
+    """Field-aware FM at Criteo's shape on the normal path, every
+    option at its default: the fused step compiles for one v5e chip with
+    two Mosaic calls — the windowed gather and the fused scatter+FTRL at
+    a 1024-slot window; FFM's row side is XLA's (the placement and the
+    MXU pair contraction), it has no row-sum kernel — and 4.06 GB of
+    pinned state + 6.79 GB of temporaries inside the chip's 15.75 GB."""
+    step, state, batch, arrays = _ffm_cell_step(one_chip)
+    assert arrays["win_off"].shape == ((1 << 21) // 1024 + 1,) and "ffm_invperm" in arrays
+    compiled = step.lower(state, batch).compile()
+    assert _pallas_calls(compiled) == 2
+    mem = compiled.memory_analysis()
+    assert 4.0e9 < mem.argument_size_in_bytes < 4.2e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
 def _single_device_step(cfg, one_chip):
     """(jitted single-device train step, abstract state, abstract batch)
     as `xflow train --no-mesh` builds it: FM on its flat sorted plan, LR

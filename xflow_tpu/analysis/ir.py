@@ -205,12 +205,12 @@ def _sorted_flat_batch(cfg):
     import jax
     import jax.numpy as jnp
 
-    from xflow_tpu.ops.sorted_table import CHUNK, WINDOW
+    from xflow_tpu.ops.sorted_table import CHUNK, sorted_window
 
     sds = jax.ShapeDtypeStruct
     B, F = cfg.data.batch_size, cfg.data.max_nnz
     npad = (B * F // CHUNK + 2) * CHUNK
-    n_win = cfg.num_slots // WINDOW
+    n_win = cfg.num_slots // sorted_window(cfg)
     return {
         "sorted_slots": sds((npad,), jnp.int32),
         "sorted_row": sds((npad,), jnp.int32),
@@ -225,7 +225,7 @@ def _fullshard_batch(cfg, mesh):
     import jax
     import jax.numpy as jnp
 
-    from xflow_tpu.ops.sorted_table import WINDOW
+    from xflow_tpu.ops.sorted_table import sorted_window
     from xflow_tpu.parallel.mesh import (
         DATA_AXIS, TABLE_AXIS, batch_sharding,
     )
@@ -236,7 +236,7 @@ def _fullshard_batch(cfg, mesh):
     B = cfg.data.batch_size
     D, T = mesh.shape[DATA_AXIS], mesh.shape[TABLE_AXIS]
     cap = fullshard_capacity(cfg, mesh)
-    wpo = (cfg.num_slots // WINDOW) // (D * T)
+    wpo = (cfg.num_slots // sorted_window(cfg)) // (D * T)
     mk = lambda k, shape, dt: sds(shape, dt, sharding=sh[k])
     return {
         "fs_slots": mk("fs_slots", (D, T, D, cap), jnp.int32),

@@ -34,17 +34,19 @@ through ⟨v_{i,c}, v_{j,c}⟩, which IS the textbook term since f_j = c.
 and the diagonal counts same-field pairs twice plus the self terms;
 halving and subtracting the selves leaves exactly Σ_{i<j}.)
 
-Path choice (round 5, measured — docs/PERF.md): on ONE device
-`sorted_layout=auto` (and `on`) runs the ALIGNED HYBRID sorted engine
-(`make_ffm_aligned_op` below): windowed table gather + host placement
-permutation + layout-friendly MXU row side + fused scatter+FTRL —
-623k ex/s at B = 64k / 742k at the 128k practical batch (843k with
-`data.sorted_bf16`), 2^22 slots, vs 193k for the round-4 row-major
-einsum path at its 16k cap.
+Path choice: on ONE device `sorted_layout=auto` (and `on`) runs the
+ALIGNED HYBRID sorted engine (`make_ffm_aligned_op` below): windowed
+table gather + host placement permutation + layout-friendly MXU row
+side + fused scatter+FTRL. Measured on a v5e at Criteo's shape (39
+fields x k=4, rows of 157 floats, 2^21 slots; the benchmark's
+`ffm-v4-f39-s21.text-zipf`, my chip runs of PR 36, PERF.md sections 5
+and 6): 163k examples/s at B = 32768 (a 200 ms step) and 178k at
+B = 65536 (367 ms). Earlier rates in docs/PERF.md (round 5) are another
+rig's, at 18 fields, on a kernel window that did not compile here at
+this width.
 Batches with duplicate (row, field) occurrences fall back per batch
-to the row-major einsum path in `forward` (the general form, itself
-layout-rewritten this round: 282k at 16k where round 4's 4-D einsum
-formulation measured 191k and OOM'd at 64k). The per-(row, field)
+to the row-major einsum path in `forward` (the general form; counted
+in `final.ffm_rowmajor_batches`; not measured on this rig). The per-(row, field)
 SEGMENT engine (`make_ffm_row_op`) is the fullshard MESH engine's row
 side only, where the no-replication layout requires it — the round-4
 single-device forced-sorted segment path (and the XLA compiler crash
@@ -231,11 +233,13 @@ def _forward_sorted(tables, batch, cfg):
 # selector built in-graph (never a captured constant: a jit-embedded
 # array is baked into the program and re-sent with every compile).
 #
-# Measured at B = 64k, 2^22 slots (round-5 probes, docs/PERF.md):
-# round-4 row-major 4-D einsum path OOMs; the layout-fixed row-major
-# path runs 240k ex/s; this hybrid runs 512k exact / 565k with
-# data.sorted_bf16 — the step decomposition is gather 21.8 ms +
-# place 16 + row math 28 + backward 32 + fused scatter+FTRL 31.
+# Measured at B = 32768 x 39 fields, k = 4, 2^21 slots on a v5e (the
+# traced run of `ffm-v4-f39-s21.text-zipf`, PR 36; PERF.md section 5):
+# a 200.2 ms step — the pair contraction 107.4 (forward 50.0, its
+# transpose 54.1, one relayout 3.3: the selector product is 5.4e12
+# FLOPs a step where the pair sum needs 1.2e9), the placement 41.3 (two
+# permutation gathers of 16 and four relayouts of 3.2), the fused
+# scatter+FTRL 30.9, the windowed gather 17.1, the guard 3.6.
 # ---------------------------------------------------------------------------
 
 
@@ -290,8 +294,7 @@ def resolve_ffm_aligned(batch_fields, batch_mask) -> bool:
     general path (False). Host-side per batch, like MVM's product
     routing: the hybrid requires ≤1 masked occurrence per (row, field).
     Duplicate-field batches run the layout-fixed row-major einsum path
-    (the general form; measured 282k ex/s at 16k vs the sorted segment
-    engine's 123k — docs/PERF.md round 5)."""
+    (the general form)."""
     return not has_field_duplicates(batch_fields, batch_mask)
 
 
@@ -345,12 +348,13 @@ def make_ffm_aligned_op(nf: int, k: int, k8: int, rows: int):
     nfp = nf_padded(nf)
 
     def rowmath(A, T, Q, W):
-        # HIGHEST is the measured optimum here: a 3-pass bf16 selector
+        # HIGHEST was the measured optimum on an earlier rig (round 5,
+        # 18 fields; not re-measured on this one): a 3-pass bf16 selector
         # split (the gather kernels' _dot_f32 trick — T is 0/1 and each
         # output selects one A element, so it would be exact) benched
-        # SLOWER (195 vs 177 ms/step at B=128k) — the hi/mid/lo split's
-        # extra elementwise passes over [B, nfp, k8] cost more than the
-        # MXU passes they save on this skinny contraction
+        # SLOWER there (195 vs 177 ms/step at B=128k) — the hi/mid/lo
+        # split's extra elementwise passes over [B, nfp, k8] cost more
+        # than the MXU passes they saved
         X = jnp.einsum(
             "bce,cedf->bdf", A, T, precision=jax.lax.Precision.HIGHEST
         )
@@ -359,25 +363,35 @@ def make_ffm_aligned_op(nf: int, k: int, k8: int, rows: int):
         wx = (A * W[None]).sum((-1, -2))
         return wx + 0.5 * (full - qsum)
 
+    # scopes of their own for the two halves of the row side, apart from
+    # the table kernels' `gather` / `scatter_optimizer`: `ffm_place` (the
+    # permutation gather and its reverse) and `ffm_pair` (selectors, the
+    # pair contraction and its transpose). The step's compile record
+    # says which operations are whose (`op_scopes`; the device trace
+    # names an XLA fusion after what it fuses) — docs/OBSERVABILITY.md
     @jax.custom_vjp
     def place(occ_t, invperm, src, smask):
-        dead = (invperm != occ_t.shape[1] - 1).astype(occ_t.dtype)
-        return (occ_t.T[invperm] * dead[:, None]).reshape(rows, nfp, k8)
+        with jax.named_scope("ffm_place"):
+            dead = (invperm != occ_t.shape[1] - 1).astype(occ_t.dtype)
+            return (occ_t.T[invperm] * dead[:, None]).reshape(rows, nfp, k8)
 
     def _fwd(occ_t, invperm, src, smask):
         return place(occ_t, invperm, src, smask), (src, smask)
 
     def _bwd(res, d_A):
         src, smask = res
-        d_occ = (d_A.reshape(rows * nfp, k8)[src] * smask[:, None]).T
+        with jax.named_scope("ffm_place"):
+            d_occ = (d_A.reshape(rows * nfp, k8)[src] * smask[:, None]).T
         return d_occ, None, None, None
 
     place.defvjp(_fwd, _bwd)
 
     def op(occ_t, invperm, src, smask):
-        T, Q, W = _pair_selector(nf, k, nfp, k8, occ_t.dtype)
+        with jax.named_scope("ffm_pair"):
+            T, Q, W = _pair_selector(nf, k, nfp, k8, occ_t.dtype)
         A = place(occ_t, invperm, src, smask)
-        return rowmath(A, T, Q, W)
+        with jax.named_scope("ffm_pair"):
+            return rowmath(A, T, Q, W)
 
     return op
 

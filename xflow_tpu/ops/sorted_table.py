@@ -45,7 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-WINDOW = 2048  # table slots per grid step
+WINDOW = 2048  # table slots per grid step, for every row the rule below does not narrow it for
 CHUNK = 512  # sorted occurrences per inner iteration (DMA granularity)
 
 
@@ -54,6 +54,58 @@ def _k8(k: int) -> int:
 
 
 PACK = 8  # slots per packed table row (see pack_table)
+PIPE_NB = 6  # chunk-chain pipeline depth of the kernels (buffers; see _gather_pallas)
+
+# Mosaic's scoped VMEM on a v5e is 16 MiB, and the fused scatter+FTRL
+# kernel is the one that fills it. `state_window_bytes` is what that
+# kernel asks for, fitted to what the chip's compiler reports ("Scoped
+# allocation with size ..."; AOT for a described v5e, 60 compiles over
+# K = 11..385 and windows 256..8192, PR 36): the w, n, z blocks in and
+# out, double buffered; the chunk pipeline's scratch; and the kernel's
+# own values, which the compiler places — a window's transposed
+# accumulator has pack*K rows, and a row costs the larger of its chunk
+# side (the cotangent expanded and split in three bf16 terms, 4.7 KB)
+# and its window side (accumulator, gradient and FTRL temporaries,
+# 24 B a packed table row and 1.6 KB). Where the fit errs it errs high.
+VMEM_SCOPED_BYTES = 16 << 20
+
+
+def state_window_bytes(K: int, pack: int, window: int) -> int:
+    """Scoped VMEM the fused scatter+FTRL kernel asks for at `window`
+    slots a grid step (`_scatter_ftrl_pallas`)."""
+    rows = window // pack
+    lanes = -(-pack * K // 128) * 128
+    blocks = 12 * rows * lanes * 4
+    scratch = PIPE_NB * (_k8(K) + 8) * CHUNK * 4
+    values = pack * K * max(4800, 1600 + 24 * rows)
+    return blocks + scratch + values
+
+
+def state_window(K: int, pack: int = PACK) -> int:
+    """Table slots a grid step of the sorted kernels owns, from the row
+    width: WINDOW halved until the fused kernel fits scoped VMEM
+    (`state_window_bytes`). ONE rule for everything that must agree on
+    it — the planner's `win_off`, the gather, both scatters, the
+    engines' divisibility checks — so they read it here and nowhere
+    keep a width of their own. FM's 11 floats (2.4 of 16 MiB) and every
+    packed row up to 95 keep 2048; 96 to 160 floats get 1024 — FFM at 39
+    fields x k=4 is 157: 15.2 MiB, where 2048 slots were refused at
+    16.94; wider rows 512 and less. A smaller window also shortens the
+    one-hot contraction a chunk (its MXU work is window/pack x CHUNK x
+    3*pack*K), so wide rows lose nothing by it. Raises where even the
+    smallest window (8 packed rows) does not fit: the kernel's values
+    and the chunk scratch alone are then over."""
+    window = WINDOW
+    while window > 8 * pack and state_window_bytes(K, pack, window) > VMEM_SCOPED_BYTES:
+        window //= 2
+    need = state_window_bytes(K, pack, window)
+    if need > VMEM_SCOPED_BYTES:
+        raise ValueError(
+            f"sorted engine: a table row of {K} floats (pack {pack}) needs "
+            f"{need} B of VMEM at the smallest window of {window} slots, "
+            f"over the kernels' {VMEM_SCOPED_BYTES}"
+        )
+    return window
 
 
 def pack_table(t):
@@ -241,7 +293,7 @@ class SortedPlan(NamedTuple):
     sorted_slots: np.ndarray  # int32 [Np]
     sorted_row: np.ndarray  # int32 [Np]
     sorted_mask: np.ndarray  # float32 [Np]
-    win_off: np.ndarray  # int32 [S/WINDOW + 1]
+    win_off: np.ndarray  # int32 [S/window + 1], window = state_window(row width)
     sorted_fields: Optional[np.ndarray] = None  # int32 [Np] (MVM; pad 0)
 
 
@@ -319,8 +371,13 @@ def plan_sorted_batch(
     num_slots: int,
     fields: Optional[np.ndarray] = None,
     wire: bool = False,
+    window: int = WINDOW,
 ) -> SortedPlan:
     """Sort a [B, F] batch's occurrences by table slot (host side).
+
+    `window` is the table's `state_window` (the kernels take theirs from
+    the same rule and refuse a plan made at another); the default is
+    that of every packed row of up to 95 floats.
 
     Masked occurrences keep their (meaningless) slot — their mask rides
     along and zeroes both the forward contribution and the gradient.
@@ -337,7 +394,7 @@ def plan_sorted_batch(
     compaction happens downstream as before).
     """
     native = _native_planner()
-    if native and num_slots % WINDOW == 0:
+    if native and num_slots % window == 0:
         # no try/except: the numpy fallback exists for a MISSING toolchain
         # (handled once at load in _native_planner); a runtime failure in a
         # successfully-built planner is a bug and must raise, not silently
@@ -347,13 +404,13 @@ def plan_sorted_batch(
 
             ss, row, m, f, off = native_plan_sorted_wire(
                 np.ascontiguousarray(slots, np.int32),
-                mask, fields, num_slots, WINDOW,
+                mask, fields, num_slots, window,
                 padded_len(slots.size),
             )
             return SortedPlan(ss, row, m, off, f)
         ss, row, m, f, off = native(
             np.ascontiguousarray(slots, np.int32),
-            mask, fields, num_slots, WINDOW,
+            mask, fields, num_slots, window,
             padded_len(slots.size),
         )
         return SortedPlan(ss, row, m, off, f)
@@ -376,7 +433,7 @@ def plan_sorted_batch(
     # pads sort at (or past) the real occurrences of slot num_slots-1, so
     # the full padded array is sorted and the last window's range covers
     # every padded position — nothing is left unwritten by the kernels
-    win_off = np.searchsorted(ss, np.arange(0, num_slots + 1, WINDOW)).astype(np.int32)
+    win_off = np.searchsorted(ss, np.arange(0, num_slots + 1, window)).astype(np.int32)
     sorted_fields = None
     if fields is not None:
         flat_fields = np.ascontiguousarray(fields, np.int32).ravel()
@@ -409,6 +466,7 @@ def plan_sorted_stacked(
     fields: Optional[np.ndarray] = None,
     num_sub: int = 1,
     wire: bool = False,
+    window: int = WINDOW,
 ) -> SortedPlan:
     """Per-sub-batch sorted plans, stacked on a leading [NS] axis.
 
@@ -422,7 +480,9 @@ def plan_sorted_stacked(
     """
     B = slots.shape[0]
     if num_sub <= 1:
-        return plan_sorted_batch(slots, mask, num_slots, fields=fields, wire=wire)
+        return plan_sorted_batch(
+            slots, mask, num_slots, fields=fields, wire=wire, window=window
+        )
     if B % num_sub:
         raise ValueError(f"batch {B} not divisible by num_sub {num_sub}")
     bs = B // num_sub
@@ -434,9 +494,10 @@ def plan_sorted_stacked(
             num_slots,
             fields=None if fields is None else fields[i * bs : (i + 1) * bs],
             wire=wire,
+            window=window,
         )
 
-    if num_slots % WINDOW == 0:
+    if num_slots % window == 0:
         plans = map_host_parallel(one, num_sub)
     else:
         plans = [one(i) for i in range(num_sub)]
@@ -526,6 +587,23 @@ def resolve_sub_batches(cfg) -> int:
         per_row = cfg.model.num_fields * (cfg.model.num_fields * cfg.model.v_dim + 2) * 4
         return auto_sub_batches(B, per_row)
     return 1
+
+
+def sorted_row_width(cfg) -> int:
+    """Floats in one row of the table the sorted engines stream — the
+    one place the width lives: MVM [k], fused FM [1+k], FFM [1+nf·k]."""
+    if cfg.model.name == "mvm":
+        return cfg.model.v_dim
+    if cfg.model.name == "ffm":
+        return 1 + cfg.model.num_fields * cfg.model.v_dim
+    return 1 + cfg.model.v_dim
+
+
+def sorted_window(cfg) -> int:
+    """`state_window` of the configured model's table as `init_tables`
+    stores it (packed unless data.packed_tables=off)."""
+    packed = cfg.data.packed_tables != "off" and cfg.num_slots % PACK == 0
+    return state_window(sorted_row_width(cfg), PACK if packed else 1)
 
 
 # ------------------------------------------------------------------ XLA path
@@ -659,6 +737,7 @@ def _gather_span(slots_ref, out_ref, table_ref, slc, old, sem_s, sem_d, sem_o,
 
     NB = old.shape[0]  # pipeline depth = scratch buffer count
     K = table_ref.shape[1] // pack
+    window = table_ref.shape[0] * pack  # the block IS one window (state_window)
     astart = (start // CHUNK) * CHUNK  # aligned down: extras self-mask
     n_chunks = pl.cdiv(end - astart, CHUNK)
 
@@ -703,7 +782,7 @@ def _gather_span(slots_ref, out_ref, table_ref, slc, old, sem_s, sem_d, sem_o,
         # f32-exact and cannot see it)
         occ = _windowed_select(table_ref[:, :], rel, pack, bf16)  # [K, C]
         co.wait()
-        in_win = (rel >= 0) & (rel < WINDOW)  # [1, C]
+        in_win = (rel >= 0) & (rel < window)  # [1, C]
         # blend: positions whose slot is outside this window belong to a
         # neighboring window's (or buffer's) chunks — keep what is there.
         # No concat when K is already sublane-aligned: Mosaic rejects the
@@ -747,7 +826,7 @@ def _gather_kernel(off_ref, slots_ref, table_ref, out_ref, slc, old, sem_s, sem_
     t = pl.program_id(0)
     _gather_span(
         slots_ref, out_ref, table_ref, slc, old, sem_s, sem_d, sem_o,
-        (t % n_tw) * WINDOW, off_ref[t], off_ref[t + 1], bf16, pack,
+        (t % n_tw) * (table_ref.shape[0] * pack), off_ref[t], off_ref[t + 1], bf16, pack,
     )
 
 
@@ -767,19 +846,20 @@ def _gather_kernel_multi(off_ref, slots_ref, table_ref, out_ref, slc, old, sem_s
     def buf_step(i, carry):
         _gather_span(
             slots_ref, out_ref, table_ref, slc, old, sem_s, sem_d, sem_o,
-            j * WINDOW, i * cap + off_ref[i, j], i * cap + off_ref[i, j + 1],
-            bf16, pack,
+            j * (table_ref.shape[0] * pack), i * cap + off_ref[i, j],
+            i * cap + off_ref[i, j + 1], bf16, pack,
         )
         return carry
 
     jax.lax.fori_loop(0, nbuf, buf_step, 0)
 
 
-PIPE_NB = 6  # gather chunk-chain pipeline depth (buffers); the chain is
-# DMA-latency bound (_gather_span), so deeper prefetch hides more of the
-# per-chunk wait — 6 measured best vs 3 on v5e at bench shapes; VMEM cost
-# is NB × (K8+1) × CHUNK × 4 B: ~110 KB at K8=8, ~210 KB for the fused FM
-# row (K8=16), ~1 MB for FFM's K8=80 — all small next to the table block
+# PIPE_NB (6, defined beside WINDOW) is the chunk-chain pipeline depth in
+# buffers; the chain is DMA-latency bound (_gather_span), so deeper
+# prefetch hides more of the per-chunk wait — 6 measured best vs 3 on v5e
+# at bench shapes; VMEM cost is NB × (K8+1) × CHUNK × 4 B: ~110 KB at
+# K8=8, ~210 KB for the fused FM row (K8=16), 2 MB for FFM's K8=160 —
+# `state_window` counts it
 
 
 def _gather_pallas(table, sorted_slots, win_off, bf16=False, pack=1):
@@ -789,17 +869,19 @@ def _gather_pallas(table, sorted_slots, win_off, bf16=False, pack=1):
     Sp, Kp = table.shape
     K = Kp // pack
     K8 = _k8(K)
-    n_tw = Sp * pack // WINDOW
+    window = state_window(K, pack)
+    n_tw = Sp * pack // window
     # grid = logical windows = len(win_off)-1; a multiple of n_tw when the
     # occurrence stream is D concatenated buffers over the same table
     n_win = win_off.shape[0] - 1
+    assert n_win % n_tw == 0, (win_off.shape, Sp * pack, window)  # planned at another window
     n = sorted_slots.shape[0]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_win,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),  # slots [1, Np]
-            pl.BlockSpec((WINDOW // pack, Kp), lambda t, off: (t % n_tw, 0)),
+            pl.BlockSpec((window // pack, Kp), lambda t, off: (t % n_tw, 0)),
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),  # occ_t [K8, Np]
         scratch_shapes=[
@@ -825,7 +907,8 @@ def _gather_pallas_multi(table, sorted_slots, loc_off, cap, bf16=False, pack=1):
     Sp, Kp = table.shape
     K = Kp // pack
     K8 = _k8(K)
-    n_win = Sp * pack // WINDOW
+    window = state_window(K, pack)
+    n_win = Sp * pack // window
     nbuf, wpo1 = loc_off.shape
     n = sorted_slots.shape[0]
     assert wpo1 == n_win + 1, (loc_off.shape, n_win)
@@ -835,7 +918,7 @@ def _gather_pallas_multi(table, sorted_slots, loc_off, cap, bf16=False, pack=1):
         grid=(n_win,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),  # slots [1, Np]
-            pl.BlockSpec((WINDOW // pack, Kp), lambda t, off: (t, 0)),
+            pl.BlockSpec((window // pack, Kp), lambda t, off: (t, 0)),
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),  # occ_t [K8, Np]
         scratch_shapes=[
@@ -871,6 +954,7 @@ def _scatter_span(slots_ref, d_ref, slc, dch, sem_s, sem_d, base, start, end,
 
     astart = (start // CHUNK) * CHUNK
     n_chunks = pl.cdiv(end - astart, CHUNK)
+    window = acc_t.shape[1] * pack  # the accumulator IS one window (state_window)
 
     NB = dch.shape[0]  # pipeline depth = scratch buffer count
 
@@ -909,7 +993,7 @@ def _scatter_span(slots_ref, d_ref, slc, dch, sem_s, sem_d, base, start, end,
         rel = slc[sel][0:1, :] - base  # [1, C]; out-of-window: no lane
         if pack == 1:
             onehot = (
-                jax.lax.broadcasted_iota(jnp.int32, (WINDOW, CHUNK), 0) == rel
+                jax.lax.broadcasted_iota(jnp.int32, (window, CHUNK), 0) == rel
             ).astype(jnp.float32)  # [W, C]
             # [K8, C] x [W, C] contracting C -> [K8, W]
             # f32-accurate for the same reason as the gather; duplicate
@@ -918,7 +1002,7 @@ def _scatter_span(slots_ref, d_ref, slc, dch, sem_s, sem_d, base, start, end,
             return acc + _dot_f32(dch[sel], onehot, (((1,), (1,)), ((), ())), bf16)
         rel_p = rel // pack
         onehot_p = (
-            jax.lax.broadcasted_iota(jnp.int32, (WINDOW // pack, CHUNK), 0) == rel_p
+            jax.lax.broadcasted_iota(jnp.int32, (window // pack, CHUNK), 0) == rel_p
         ).astype(jnp.float32)  # [W/pack, C]
         sub = rel - rel_p * pack
         d_exp = jnp.concatenate(
@@ -936,11 +1020,12 @@ def _scatter_kernel(off_ref, slots_ref, d_ref, out_ref, slc, dch, sem_s, sem_d,
     t = pl.program_id(0)
     K8 = d_ref.shape[0]
     K = out_ref.shape[1] // pack
+    window = out_ref.shape[0] * pack
     rows = pack * K if pack > 1 else K8
-    acc_t = jnp.zeros((rows, WINDOW // pack), jnp.float32)
+    acc_t = jnp.zeros((rows, window // pack), jnp.float32)
     acc_t = _scatter_span(
         slots_ref, d_ref, slc, dch, sem_s, sem_d,
-        t * WINDOW, off_ref[t], off_ref[t + 1], acc_t, bf16, pack, K,
+        t * window, off_ref[t], off_ref[t + 1], acc_t, bf16, pack, K,
     )
     out_ref[:, :] = (acc_t if pack > 1 else acc_t[0:K, :]).T  # [W/pack, pack*K]
 
@@ -951,7 +1036,9 @@ def _scatter_pallas(d_occ_t, sorted_slots, win_off, num_slots, k: int, bf16=Fals
     from jax.experimental.pallas import tpu as pltpu
 
     K8, n = d_occ_t.shape
-    n_win = num_slots // WINDOW
+    window = state_window(k, pack)
+    n_win = num_slots // window
+    assert win_off.shape[0] == n_win + 1, (win_off.shape, num_slots, window)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_win,),
@@ -959,7 +1046,7 @@ def _scatter_pallas(d_occ_t, sorted_slots, win_off, num_slots, k: int, bf16=Fals
             pl.BlockSpec(memory_space=pl.ANY),  # slots [1, Np]
             pl.BlockSpec(memory_space=pl.ANY),  # d [K8, Np]
         ],
-        out_specs=pl.BlockSpec((WINDOW // pack, pack * k), lambda t, off: (t, 0)),
+        out_specs=pl.BlockSpec((window // pack, pack * k), lambda t, off: (t, 0)),
         scratch_shapes=[
             pltpu.VMEM((PIPE_NB, 1, CHUNK), jnp.int32),
             pltpu.VMEM((PIPE_NB, K8, CHUNK), jnp.float32),
@@ -990,17 +1077,18 @@ def _scatter_kernel_multi(off_ref, slots_ref, d_ref, out_ref, slc, dch, sem_s, s
     j = pl.program_id(0)
     K8 = d_ref.shape[0]
     K = out_ref.shape[1] // pack
+    window = out_ref.shape[0] * pack
 
     def buf_step(i, acc_t):
         # aligned-down reads stay >= i*cap (cap % CHUNK == 0)
         return _scatter_span(
             slots_ref, d_ref, slc, dch, sem_s, sem_d,
-            j * WINDOW, i * cap + off_ref[i, j], i * cap + off_ref[i, j + 1],
+            j * window, i * cap + off_ref[i, j], i * cap + off_ref[i, j + 1],
             acc_t, bf16, pack, K,
         )
 
     rows = pack * K if pack > 1 else K8
-    acc_t = jnp.zeros((rows, WINDOW // pack), jnp.float32)
+    acc_t = jnp.zeros((rows, window // pack), jnp.float32)
     acc_t = jax.lax.fori_loop(0, nbuf, buf_step, acc_t)
     out_ref[:, :] = (acc_t if pack > 1 else acc_t[0:K, :]).T
 
@@ -1012,7 +1100,8 @@ def _scatter_pallas_multi(d_occ_t, sorted_slots, loc_off, num_slots, k, cap,
 
     K8, n = d_occ_t.shape
     nbuf, wpo1 = loc_off.shape
-    n_win = num_slots // WINDOW
+    window = state_window(k, pack)
+    n_win = num_slots // window
     assert wpo1 == n_win + 1, (loc_off.shape, n_win)
     assert cap % CHUNK == 0 and nbuf * cap == n, (nbuf, cap, n)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -1022,7 +1111,7 @@ def _scatter_pallas_multi(d_occ_t, sorted_slots, loc_off, num_slots, k, cap,
             pl.BlockSpec(memory_space=pl.ANY),  # slots [1, Np]
             pl.BlockSpec(memory_space=pl.ANY),  # d [K8, Np]
         ],
-        out_specs=pl.BlockSpec((WINDOW // pack, pack * k), lambda t, off: (t, 0)),
+        out_specs=pl.BlockSpec((window // pack, pack * k), lambda t, off: (t, 0)),
         scratch_shapes=[
             pltpu.VMEM((PIPE_NB, 1, CHUNK), jnp.int32),
             pltpu.VMEM((PIPE_NB, K8, CHUNK), jnp.float32),
@@ -1056,11 +1145,12 @@ def _scatter_ftrl_kernel(off_ref, slots_ref, d_ref, w_ref, n_ref, z_ref,
     t = pl.program_id(0)
     K8 = d_ref.shape[0]
     K = w_out.shape[1] // pack
+    window = w_out.shape[0] * pack
     rows = pack * K if pack > 1 else K8
-    acc_t = jnp.zeros((rows, WINDOW // pack), jnp.float32)
+    acc_t = jnp.zeros((rows, window // pack), jnp.float32)
     acc_t = _scatter_span(
         slots_ref, d_ref, slc, dch, sem_s, sem_d,
-        t * WINDOW, off_ref[t], off_ref[t + 1], acc_t, bf16, pack, K,
+        t * window, off_ref[t], off_ref[t + 1], acc_t, bf16, pack, K,
     )
     g = (acc_t if pack > 1 else acc_t[0:K, :]).T  # [W/pack, pack*K]
     w_new, n_new, z_new = _update_one(
@@ -1078,8 +1168,10 @@ def _scatter_ftrl_pallas(d_occ_t, sorted_slots, win_off, w, n, z, k, hp,
 
     K8, n_occ = d_occ_t.shape
     num_slots = w.shape[0] * pack
-    n_win = num_slots // WINDOW
-    state_block = pl.BlockSpec((WINDOW // pack, pack * k), lambda t, off: (t, 0))
+    window = state_window(k, pack)
+    n_win = num_slots // window
+    assert win_off.shape[0] == n_win + 1, (win_off.shape, num_slots, window)
+    state_block = pl.BlockSpec((window // pack, pack * k), lambda t, off: (t, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n_win,),

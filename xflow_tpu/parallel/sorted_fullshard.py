@@ -70,11 +70,12 @@ from xflow_tpu.config import Config
 from xflow_tpu.metrics import binary_logloss_from_logits
 from xflow_tpu.ops.sorted_table import (
     CHUNK,
-    WINDOW,
     SortedPlan,
     map_host_parallel,
     plan_sorted_batch,
     row_sums_sorted,
+    sorted_row_width,
+    sorted_window,
     table_gather_sorted,
 )
 from xflow_tpu.parallel.mesh import DATA_AXIS, TABLE_AXIS
@@ -107,12 +108,6 @@ def validate_sorted_fullshard(cfg: Config, mesh: Mesh) -> None:
     specific reason."""
     d, t, p = _dims(cfg, mesh)
     S = cfg.num_slots
-    if S % (d * t * WINDOW) != 0:
-        raise ValueError(
-            f"fullshard layout needs num_slots (2^{cfg.data.log2_slots}) "
-            f"divisible by data*table*WINDOW = {d}*{t}*{WINDOW} (each device "
-            "owns whole windows)"
-        )
     if cfg.model.name == "fm":
         if not cfg.model.fm_fused:
             raise ValueError("fullshard FM needs model.fm_fused=true (one table)")
@@ -120,6 +115,13 @@ def validate_sorted_fullshard(cfg: Config, mesh: Mesh) -> None:
         raise ValueError(
             "fullshard layout supports fused FM, MVM, and FFM (LR keeps the "
             f"GSPMD row-major path); got model={cfg.model.name}"
+        )
+    window = sorted_window(cfg)  # of a supported model's row: checked above
+    if S % (d * t * window) != 0:
+        raise ValueError(
+            f"fullshard layout needs num_slots (2^{cfg.data.log2_slots}) "
+            f"divisible by data*table*WINDOW = {d}*{t}*{window} (each device "
+            "owns whole windows)"
         )
     if d % p != 0:
         raise ValueError(
@@ -257,12 +259,13 @@ def plan_fullshard_batch(
     cap = fullshard_capacity(cfg, mesh)
     s_local = cfg.num_slots // (d * t)
     with_fields = fields is not None
+    window = sorted_window(cfg)
 
     def one(i):
         sl = slice(i * rows, (i + 1) * rows)
         plan = plan_sorted_batch(
             slots[sl], mask[sl], cfg.num_slots,
-            fields=fields[sl] if with_fields else None,
+            fields=fields[sl] if with_fields else None, window=window,
         )
         return fullshard_buffers(
             plan, d, t, cap, s_local, cfg.data.fullshard_slack, with_fields,
@@ -434,14 +437,9 @@ def _mode_statics(cfg: Config, mesh: Mesh):
     builders — the ONE place the logical row width lives:
     MVM [k], FM [1+k], FFM [1+nf·k]."""
     D, _, _ = _dims(cfg, mesh)
-    mvm = cfg.model.name == "mvm"
-    ffm = cfg.model.name == "ffm"
-    nf = cfg.model.num_fields
-    K = cfg.model.v_dim if mvm else (
-        1 + nf * cfg.model.v_dim if ffm else 1 + cfg.model.v_dim
-    )
     return (
-        D, "v" if mvm else "wv", K, nf, cfg.data.sorted_bf16,
+        D, "v" if cfg.model.name == "mvm" else "wv", sorted_row_width(cfg),
+        cfg.model.num_fields, cfg.data.sorted_bf16,
         1.0 if cfg.model.mvm_plus_one else 0.0,
     )
 
@@ -536,7 +534,7 @@ def make_fullshard_train_step(
     # the compile record's `table_spans_per_step`: one span a local table
     # window in the gather and in its transpose, whatever the number of
     # source buffers (the merge, `merge_received`)
-    wpo = cfg.num_slots // (D * mesh.shape[TABLE_AXIS]) // WINDOW
+    wpo = cfg.num_slots // (D * mesh.shape[TABLE_AXIS]) // sorted_window(cfg)
 
     def local_logits(mode, tbl_local, fs_slots, fs_row, fs_mask, fs_off,
                      fs_fields, R):

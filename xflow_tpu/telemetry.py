@@ -55,6 +55,7 @@ is wall-clock, for cross-stream/cross-host log correlation only.
 from __future__ import annotations
 
 import os
+import re
 import threading
 import time
 import uuid
@@ -466,7 +467,17 @@ SCOPE_LABELS = (
     "optimizer",
     "scatter_optimizer",
     "train_step",
+    "ffm_place",
+    "ffm_pair",
 )
+# labels of scopes INSIDE the differentiated row side (models/ffm.py):
+# autodiff wraps their path component — `jvp(ffm_pair)`,
+# `transpose(jvp(ffm_pair))` — and an op keeps the label through it, so
+# a kernel-free row side can be told from the `loss` / `grad` around it.
+# (The device trace itself names an XLA fusion after what it fuses, not
+# after its scope: `op_scopes` is the only place the two meet.)
+INNER_SCOPE_LABELS = ("ffm_place", "ffm_pair")
+_INNER_SCOPE = re.compile(r"(?:\w+\()*(%s)\)*" % "|".join(INNER_SCOPE_LABELS))
 
 
 class CompileRecorder:
@@ -620,7 +631,8 @@ class CompileRecorder:
         final component is the primitive, the scope is the LAST path
         component matching a known label — so the backward's scatter
         attributes to `grad`, exactly as docs/OBSERVABILITY.md
-        describes the trace reading. The module name keys the map:
+        describes the trace reading; an `INNER_SCOPE_LABELS` label is
+        matched through autodiff's wrapping. The module name keys the map:
         op names are only unique WITHIN one compiled module, and
         tools/trace_attrib.py joins trace events per `hlo_module` so
         two programs' `fusion.1` never collide."""
@@ -628,8 +640,6 @@ class CompileRecorder:
             text = compiled.as_text()
         except Exception:
             return None, {}, 0
-        import re
-
         mod = re.match(r"\s*HloModule\s+([\w.\-]+)", text)
         module = mod.group(1) if mod else None
         scopes = set(self._scopes)
@@ -637,6 +647,9 @@ class CompileRecorder:
         for m in re.finditer(r'%?([\w.\-]+)\s*=\s*[^\n]*op_name="([^"]+)"', text):
             comps = m.group(2).split("/")
             for comp in reversed(comps[:-1]):
+                inner = _INNER_SCOPE.fullmatch(comp)
+                if inner and inner.group(1) in scopes:
+                    comp = inner.group(1)
                 if comp in scopes:
                     out[m.group(1)] = comp
                     break
@@ -970,7 +983,9 @@ HOST_SPANS_FIT = (
     "fit", "fit_open", "data_wait", "transfer", "dispatch", "prev_ready",
     "iter_end", "fit_flush", "occupancy", "fit_close",
 )
-HOST_SPANS_PREFETCH = ("parse", "cache_read", "plan", "producer_wait", "read_ahead")
+HOST_SPANS_PREFETCH = (
+    "parse", "cache_read", "plan", "producer_wait", "read_ahead", "ffm_place",
+)
 HOST_SPANS_INIT = ("init_state", "place_state", "lower", "compile")
 HOST_SPANS = HOST_SPANS_FIT + HOST_SPANS_PREFETCH + HOST_SPANS_INIT
 _SPAN_NAMES = {s: "xflow:" + s for s in HOST_SPANS}
@@ -983,6 +998,11 @@ _SPAN_NAMES = {s: "xflow:" + s for s in HOST_SPANS}
 HOST_CONSUMER_STAGES = (
     "data_wait", "transfer", "dispatch", "prev_ready", "loop_other",
 )
+# spans that run INSIDE another stage's span (FFM's placement
+# permutation, inside `plan`): the window keeps their sums and the step
+# records' `host` names them where they ran, but they are a part of
+# their stage, so no sum over the stages above counts them again
+HOST_NESTED_STAGES = ("ffm_place",)
 HOST_STAGES = PIPELINE_PRODUCER_STAGES + HOST_CONSUMER_STAGES
 
 # the host-side stages a bottleneck verdict can name, and the
@@ -1059,6 +1079,9 @@ def host_fields(win: dict) -> dict:
     """A profiler window as the step records' `host` payload: the
     window's sum of every stage, in ms, and its batches."""
     out = {host_field(s): round(win[s] * 1e3, 3) for s in HOST_STAGES}
+    out.update(
+        (host_field(s), round(win[s] * 1e3, 3)) for s in HOST_NESTED_STAGES if win[s] > 0.0
+    )
     out["batches"] = win["batches"]
     return out
 
@@ -1143,8 +1166,8 @@ class PipelineProfiler:
         self._reg = registry or default_registry()
         self.publish = bool(publish)
         self._lock = threading.Lock()
-        self._win = {s: 0.0 for s in HOST_STAGES}
-        self._total = {s: 0.0 for s in HOST_STAGES}
+        self._win = {s: 0.0 for s in HOST_STAGES + HOST_NESTED_STAGES}
+        self._total = {s: 0.0 for s in HOST_STAGES + HOST_NESTED_STAGES}
         self._win_batches = 0
         self._win_rows = 0
         self.batches = 0
@@ -1236,7 +1259,7 @@ class PipelineProfiler:
             win["rows"] = self._win_rows
             win["queue_depth"] = self._queue_depth
             win["queue_cap"] = self._queue_cap
-            self._win = {s: 0.0 for s in HOST_STAGES}
+            self._win = {s: 0.0 for s in HOST_STAGES + HOST_NESTED_STAGES}
             self._win_batches = 0
             self._win_rows = 0
             self._win_start = now

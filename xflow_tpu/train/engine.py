@@ -30,12 +30,12 @@ from xflow_tpu.config import Config
 from xflow_tpu.models.ffm import ffm_invperm, resolve_ffm_aligned
 from xflow_tpu.models.mvm import has_field_duplicates, resolve_mvm_product
 from xflow_tpu.ops.sorted_table import (
-    WINDOW,
     compact_plan_wire,
     dedup_slots,
     plan_sorted_stacked,
     planner_name,
     resolve_sub_batches,
+    sorted_window,
 )
 from xflow_tpu.parallel.mesh import batch_sharding, state_shardings
 from xflow_tpu.parallel.sorted_fullshard import (
@@ -49,6 +49,7 @@ from xflow_tpu.parallel.train_step import (
     make_sharded_eval_step,
     make_sharded_train_step,
 )
+from xflow_tpu.telemetry import span
 from xflow_tpu.train.state import init_state
 from xflow_tpu.train.step import batch_to_arrays, make_eval_step, make_train_step
 
@@ -120,11 +121,17 @@ class Engine:
     state_formats: Callable
     train_step: Callable  # (state, arrays) -> (state, metrics)
     eval_step: Callable  # (tables, arrays) -> pctr
-    batch_arrays: Callable  # SparseBatch -> step-input arrays (host)
+    # (SparseBatch, profiler=None) -> step-input arrays (host); runs
+    # inside the producer's `plan` span, and `profiler` is that span's
+    # (a builder with a stage of its own inside it books it there)
+    batch_arrays: Callable
     # (batch, arrays) -> arrays every rank runs the same program on
     agree: Callable
     # arrays -> this train batch left the engine's own step
     fell_back: Callable
+    # what `TrainResult` and the final record count such batches as
+    # (None: the engine has no fallback)
+    fallback_counter: Optional[str]
     shard_batch: Callable  # host arrays -> device arrays
 
     def place_state(self, state) -> tuple:
@@ -208,9 +215,12 @@ def _choose(cfg: Config, mesh) -> str:
                 f"model.name=ffm; got model={cfg.model.name} "
                 f"fm_fused={cfg.model.fm_fused}"
             )
-        if cfg.num_slots % WINDOW != 0:
+        # raises, with the numbers, for a row so wide that no window of
+        # its state fits the kernels' VMEM (ops/sorted_table.state_window)
+        window = sorted_window(cfg)
+        if cfg.num_slots % window != 0:
             raise ValueError(
-                f"sorted_layout=on needs num_slots divisible by {WINDOW}; "
+                f"sorted_layout=on needs num_slots divisible by {window}; "
                 f"got 2^{cfg.data.log2_slots}"
             )
         return "sorted"
@@ -220,8 +230,15 @@ def _choose(cfg: Config, mesh) -> str:
     # fall back per batch to the layout-fixed row-major einsum path
     # (_sorted_arrays); the per-(row, field) segment engine is the
     # fullshard MESH row side only.
-    if sl == "auto" and supported and cfg.num_slots % WINDOW == 0:
-        return "sorted"
+    if sl == "auto" and supported:
+        try:
+            window = sorted_window(cfg)
+        except ValueError as e:
+            # said here, at start-up, not by the compiler inside fit()
+            print(f"{e}; running the row-major engine", file=sys.stderr)
+            return "row_major"
+        if cfg.num_slots % window == 0:
+            return "sorted"
     return "row_major"
 
 
@@ -321,8 +338,9 @@ def _sorted_arrays(cfg: Config, maybe_dedup: Callable) -> Callable:
     # whole batch — always one flat plan
     num_sub = 1 if ffm else resolve_sub_batches(cfg)
     rows_bound = cfg.data.batch_size // max(num_sub, 1)
+    window = sorted_window(cfg)
 
-    def batch_arrays(batch) -> dict:
+    def batch_arrays(batch, profiler=None) -> dict:
         arrays = batch_to_arrays(batch)
         if ffm and not _ffm_aligned(cfg, batch):
             # duplicate (row, field) occurrence: the aligned hybrid
@@ -344,6 +362,7 @@ def _sorted_arrays(cfg: Config, maybe_dedup: Callable) -> Callable:
             # emits uint16/uint8 directly and the compaction below
             # passes the arrays through untouched
             wire=rows_bound <= (1 << 16) and fields_bound <= (1 << 8),
+            window=window,
         )
         arrays.update(
             sorted_slots=plan.sorted_slots,
@@ -354,10 +373,11 @@ def _sorted_arrays(cfg: Config, maybe_dedup: Callable) -> Callable:
         if want_fields:
             arrays["sorted_fields"] = plan.sorted_fields
         if ffm:
-            arrays["ffm_invperm"] = ffm_invperm(
-                plan.sorted_row, plan.sorted_fields, plan.sorted_mask,
-                int(arrays["labels"].shape[0]), cfg.model.num_fields,
-            )
+            with span("ffm_place", profiler):
+                arrays["ffm_invperm"] = ffm_invperm(
+                    plan.sorted_row, plan.sorted_fields, plan.sorted_mask,
+                    int(arrays["labels"].shape[0]), cfg.model.num_fields,
+                )
         return compact_plan_wire(
             arrays, rows_bound=rows_bound, fields_bound=fields_bound
         )
@@ -373,7 +393,7 @@ def _fullshard_arrays(cfg: Config, mesh, maybe_dedup: Callable) -> Callable:
     rows_bound = cfg.data.batch_size // (mesh.shape["data"] // jax.process_count())
     warned = []
 
-    def batch_arrays(batch) -> dict:
+    def batch_arrays(batch, profiler=None) -> dict:
         arrays = batch_to_arrays(batch)
         if mvm:
             want_fields, dup_flag = _mvm_wants_fields(cfg, "fullshard", batch)
@@ -508,13 +528,14 @@ def resolve_engine(cfg: Config, mesh, model, optimizer, recorder) -> Engine:
     maybe_dedup = _dedup(cfg)
     identity = lambda batch, arrays: arrays
     never = lambda arrays: False
-    row_major_arrays = lambda batch: maybe_dedup(batch_to_arrays(batch), batch)
+    row_major_arrays = lambda batch, profiler=None: maybe_dedup(batch_to_arrays(batch), batch)
     if mesh is None:
         # `build_state` without shardings leaves the state where jit puts
         # a result: the default device
         here = SingleDeviceSharding(jnp.zeros(()).devices().pop())
         formats = lambda s: state_formats(name, s, jax.tree.map(lambda _: here, s))
         abstract = jax.eval_shape(lambda: init_state(model, optimizer, cfg))
+        sorted_ffm = name == "sorted" and cfg.model.name == "ffm"
         return Engine(
             name=name,
             planner=planner_name() if name == "sorted" else None,
@@ -523,13 +544,18 @@ def resolve_engine(cfg: Config, mesh, model, optimizer, recorder) -> Engine:
             train_step=make_train_step(
                 model, optimizer, cfg, recorder=recorder,
                 state_formats=formats(abstract),
+                # the compile record says which window the kernels run at
+                record_fields={"state_window": sorted_window(cfg)} if name == "sorted" else {},
             ),
             eval_step=make_eval_step(model, cfg, recorder=recorder),
             batch_arrays=(
                 _sorted_arrays(cfg, maybe_dedup) if name == "sorted" else row_major_arrays
             ),
             agree=identity,
-            fell_back=never,
+            # FFM's aligned hybrid hands a batch with a duplicate (row,
+            # field) to the row-major step (`_sorted_arrays`)
+            fell_back=(lambda arrays: "sorted_slots" not in arrays) if sorted_ffm else never,
+            fallback_counter="ffm_rowmajor_batches" if sorted_ffm else None,
             # ONE async device_put for the whole dict: per-array
             # jnp.asarray is a synchronous round trip each (~9 arrays
             # per step)
@@ -556,6 +582,7 @@ def resolve_engine(cfg: Config, mesh, model, optimizer, recorder) -> Engine:
             batch_arrays=row_major_arrays,
             agree=identity,
             fell_back=never,
+            fallback_counter=None,
             shard_batch=shard_batch,
         )
     fullshard_step = make_fullshard_train_step(
@@ -593,5 +620,6 @@ def resolve_engine(cfg: Config, mesh, model, optimizer, recorder) -> Engine:
         batch_arrays=_fullshard_arrays(cfg, mesh, maybe_dedup),
         agree=_fullshard_agree if jax.process_count() > 1 else identity,
         fell_back=lambda arrays: "fs_slots" not in arrays,
+        fallback_counter="fullshard_overflow_batches",
         shard_batch=shard_batch,
     )

@@ -224,11 +224,13 @@ def _fused_scatter_eligible(cfg: Config, allow_fused: bool) -> bool:
             )
         return True
     # auto: FM (measured throughput-NEUTRAL; kept for the memory win)
-    # and FFM's aligned hybrid (the [S/8, 584]-wide dense gradient +
-    # optimizer sweep it removes is real throughput there — docs/PERF.md
-    # round 5). The MVM product path measured ~3% slower fused (41.3 vs
-    # 40.0 ms at the bench shape), so its memory win stays an explicit
-    # opt-in ("on").
+    # and FFM's aligned hybrid, where the fusion removes a [S/8, 1256]
+    # dense gradient (1.3 GB at 2^21 slots) and an optimizer sweep over
+    # 4 GB of state: the fused kernel takes 30.9 ms of the 200 ms step at
+    # 39 fields x k=4, B = 32768 (v5e; my chip run of PR 36, PERF.md
+    # section 5; the two-pass form compiles but was not timed). The MVM
+    # product path measured ~3% slower fused on an earlier rig (41.3 vs
+    # 40.0 ms), so its memory win stays an explicit opt-in ("on").
     return base_ok and (fm_ok or ffm_ok)
 
 
@@ -243,15 +245,14 @@ def _fused_sorted_step(state: TrainState, batch: dict, cfg: Config):
     occurrence cotangent before the kernel runs (`guard_nonfinite`), so
     the kernel's aliased w, n, z are the only copy: with
     train.health_metrics=off nothing reads the pre-step table after it."""
-    from xflow_tpu.ops.sorted_table import pack_of, scatter_ftrl_sorted, table_gather_sorted
+    from xflow_tpu.ops.sorted_table import (
+        pack_of, scatter_ftrl_sorted, sorted_row_width, table_gather_sorted,
+    )
 
     mvm = cfg.model.name == "mvm"
     ffm = cfg.model.name == "ffm"
     tname = "v" if mvm else "wv"
-    if ffm:
-        K = 1 + cfg.model.num_fields * cfg.model.v_dim
-    else:
-        K = cfg.model.v_dim if mvm else 1 + cfg.model.v_dim
+    K = sorted_row_width(cfg)
     table = state.tables[tname]
     pack = pack_of(table, K)
     with jax.named_scope("gather"):
@@ -320,7 +321,8 @@ def _fused_sorted_step(state: TrainState, batch: dict, cfg: Config):
 
 
 def make_train_step(model: Model, optimizer: Optimizer, cfg: Config, jit: bool = True,
-                    allow_fused: bool = True, recorder=None, state_formats=None) -> Callable:
+                    allow_fused: bool = True, recorder=None, state_formats=None,
+                    record_fields=None) -> Callable:
     """Returns train_step(state, batch_arrays) -> (state, metrics).
 
     `state_formats` (train/engine.py `state_formats`; None = the
@@ -336,7 +338,8 @@ def make_train_step(model: Model, optimizer: Optimizer, cfg: Config, jit: bool =
     `recorder` (telemetry.CompileRecorder) routes the jit through the
     compile-accounting seam: explicit timed .lower().compile() with
     cost/memory analysis into a kind="compile" record, program name
-    "train_step"."""
+    "train_step"; `record_fields` are the builder's own fields of that
+    record (the sorted engine's `state_window`)."""
     fuse = _fused_scatter_eligible(cfg, allow_fused)
 
     def train_step(state: TrainState, batch: dict):
@@ -388,7 +391,7 @@ def make_train_step(model: Model, optimizer: Optimizer, cfg: Config, jit: bool =
         if pinned:
             train_step = past_cache(train_step)
         if recorder is not None:
-            return recorder.wrap("train_step", train_step)
+            return recorder.wrap("train_step", train_step, **(record_fields or {}))
     return train_step
 
 

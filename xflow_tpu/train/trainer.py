@@ -81,6 +81,9 @@ class TrainResult:
     # train batches the fullshard engine handed to the GSPMD row-major
     # step (too skewed for data.fullshard_slack)
     fullshard_overflow_batches: int = 0
+    # train batches FFM's sorted engine handed to the row-major step (a
+    # row with two occurrences of one field: no placement exists)
+    ffm_rowmajor_batches: int = 0
     # state leaves re-laid into the engine's layout during this fit():
     # a state that came in from outside a step, once (`_place_state`)
     state_leaves_placed: int = 0
@@ -155,7 +158,7 @@ class _BatchPrep:
         if track_health:
             defer(self._health.observe_batch, batch.slots, batch.mask)
         with span("plan", profiler):
-            arrays = self._batch_arrays(batch)
+            arrays = self._batch_arrays(batch, profiler)
         return batch, arrays
 
     def open_pass(self, spec: PassSpec, defer=run_now):
@@ -370,6 +373,18 @@ class Trainer:
             "bytes": nbytes,
             "dur_ms": round(placed.seconds * 1e3, 3),
         })
+
+    def _count_fallback(self, res: TrainResult, arrays: dict) -> None:
+        """Count a train batch that left the engine's own step, under
+        the name its engine has for that (`Engine.fallback_counter`)."""
+        if self._engine.fell_back(arrays):
+            name = self._engine.fallback_counter
+            setattr(res, name, getattr(res, name) + 1)
+
+    def _fallback_fields(self, res: TrainResult) -> dict:
+        """The final record's fallback count, on the engines that have one."""
+        name = self._engine.fallback_counter
+        return {name: getattr(res, name)} if name else {}
 
     @property
     def engine(self) -> str:
@@ -998,7 +1013,7 @@ class Trainer:
                     if step_delay_s:  # drill injector (testing/faults.py)
                         time.sleep(step_delay_s)
                     arrays = self._engine.agree(batch, arrays)
-                    res.fullshard_overflow_batches += self._engine.fell_back(arrays)
+                    self._count_fallback(res, arrays)
                     with span("transfer", prof) as moved:
                         arrays = self._engine.shard_batch(arrays)
                     with span("dispatch", prof) as called:
@@ -1337,8 +1352,7 @@ class Trainer:
                 "read_ahead_batches": res.read_ahead_batches,
                 "read_ahead_discarded": res.read_ahead_discarded,
             }
-            if self.engine == "fullshard":
-                final_rec["fullshard_overflow_batches"] = res.fullshard_overflow_batches
+            final_rec.update(self._fallback_fields(res))
             # tail window (steps since the last log tick) + run-total counters
             final_rec.update(steptimer.window_record())
             final_rec.update(hbm_window_fields(registry))
@@ -1505,7 +1519,7 @@ class Trainer:
                 ):
                     arrays.pop("_shard", None)
                     arrays = self._engine.agree(batch, arrays)
-                    res.fullshard_overflow_batches += self._engine.fell_back(arrays)
+                    self._count_fallback(res, arrays)
                     arrays = self._engine.shard_batch(arrays)
                     self.state, m = self.train_step(self.state, arrays)
                     steptimer.dispatched(m, batch.num_rows)
@@ -1655,8 +1669,7 @@ class Trainer:
             "occupancy": res.occupancy,
             "state_leaves_placed": res.state_leaves_placed,
         }
-        if self.engine == "fullshard":
-            final_rec["fullshard_overflow_batches"] = res.fullshard_overflow_batches
+        final_rec.update(self._fallback_fields(res))
         final_rec.update(steptimer.window_record())
         final_rec.update(hbm_window_fields(registry))
         final_rec.update(health.window_record())
