@@ -206,7 +206,7 @@ def hybrid_arrays(b, nf=NF):
 @pytest.mark.parametrize("fused", ["auto", "off"])
 def test_aligned_hybrid_step_matches_row_major(packed, fused):
     """Full train-step equality: the round-5 aligned hybrid (windowed
-    gather + placement permutation + MXU selector row side, fused
+    gather + placement permutation + block-transposition row side, fused
     scatter+FTRL under `auto`) vs the row-major autodiff oracle path,
     across storage layouts and with/without the fused optimizer."""
     over = {"data.packed_tables": packed, "optim.fused_scatter": fused,
@@ -237,8 +237,8 @@ def test_aligned_hybrid_step_matches_row_major(packed, fused):
 
 def test_aligned_hybrid_untouched_slots_bitwise_initial():
     """FTRL lazy-init parity through the hybrid: slots no batch touches
-    must keep their initial weights BITWISE (the selector-contraction
-    VJP is exact at structural zeros — make_ffm_aligned_op docstring)."""
+    must keep their initial weights BITWISE (the pair term's hand-written
+    VJP is exact at structural zeros — make_ffm_pair docstring)."""
     from xflow_tpu.ops.sorted_table import pack_of, unpack_table
 
     cfg = ffm_cfg(**{"data.sorted_layout": "on", "data.batch_size": 32,
@@ -286,3 +286,111 @@ def test_trainer_routes_ffm_sorted_and_falls_back_on_dup(tmp_path):
     t_on = Trainer(override(cfg, **{"data.sorted_layout": "on"}))
     with pytest.raises(ValueError, match="aligned"):
         t_on._engine.batch_arrays(sbd)
+
+
+def _placed_rows(rng, B, nf, k):
+    """A [B, nfp, k8] as the placement hands it over: w in column 0, nf
+    k-blocks, zero in every pad; a fifth of the (row, field) pairs
+    absent, row 0 with one occupant only and the last row empty (a pad
+    row of a short batch)."""
+    from xflow_tpu.models.ffm import nf_padded
+    from xflow_tpu.ops.sorted_table import _k8
+
+    nfp, K = nf_padded(nf), 1 + nf * k
+    present = rng.random((B, nf)) < 0.8
+    present[0] = False
+    present[0, nf // 2] = True
+    present[-1] = False
+    A = np.zeros((B, nfp, _k8(K)), np.float32)
+    A[:, :nf, :K] = rng.normal(0, 0.5, (B, nf, K)).astype(np.float32) * present[..., None]
+    return A, present
+
+
+def _pair_loop(A64, nf, k):
+    """wx + sum over field pairs c < d of <v_c against d, v_d against c>
+    — the textbook sum over the pairs a double loop lists (taken in one
+    indexed read: 741 pairs of slices at 39 fields take XLA half a
+    minute to compile); an absent field's rows are 0."""
+    pairs = np.array([(c, d) for c in range(nf) for d in range(c + 1, nf)], np.int32).reshape(-1, 2)
+    V = A64[:, :nf, 1: 1 + nf * k].reshape(A64.shape[0], nf, nf, k)
+    c, d = pairs[:, 0], pairs[:, 1]
+    return A64[:, :nf, 0].sum(axis=1) + (V[:, c, d] * V[:, d, c]).sum(axis=(1, 2))
+
+
+@pytest.mark.parametrize("nf,k", [(39, 4), (8, 4), (5, 3), (1, 2)])
+def test_aligned_pair_is_a_block_transposition(nf, k):
+    """The aligned op's pair term at each width: the crossing X is
+    bitwise the copied elements of A and zero in every pad; logits and
+    the hand-written VJP match a float64 double loop over pairs and
+    jax.grad of it; a single-occupant field's own block gets a gradient
+    of bitwise 0 (FTRL's lazy-init guard); d_A is 0 in every pad apart
+    from the w channel."""
+    from xflow_tpu.models.ffm import cross_fields, make_ffm_pair
+
+    rng = np.random.default_rng(nf * 10 + k)
+    B = 6
+    A, present = _placed_rows(rng, B, nf, k)
+    K = 1 + nf * k
+    X = np.asarray(cross_fields(jnp.asarray(A), nf, k))
+    want = np.zeros_like(A)
+    for c in range(nf):
+        for d in range(nf):
+            want[:, d, 1 + c * k: 1 + (c + 1) * k] = A[:, c, 1 + d * k: 1 + (d + 1) * k]
+    assert X.shape == A.shape and X.tobytes() == want.tobytes()
+
+    pair = make_ffm_pair(nf, k)
+    dl = rng.normal(0, 1, B).astype(np.float32)
+    logits, vjp = jax.vjp(pair, jnp.asarray(A))
+    (d_A,) = vjp(jnp.asarray(dl))
+    d_A = np.asarray(d_A)
+    with jax.enable_x64(True):
+        A64, dl64 = jnp.asarray(A, jnp.float64), jnp.asarray(dl, jnp.float64)
+        ref = np.asarray(_pair_loop(A64, nf, k))
+        ref_grad = np.asarray(jax.grad(lambda a: (_pair_loop(a, nf, k) * dl64).sum())(A64))
+    np.testing.assert_allclose(np.asarray(logits), ref, rtol=1e-5, atol=1e-5)
+    # the oracle differentiates w of absent fields too (their A is 0,
+    # not their w channel): compare where the op defines the gradient
+    real = np.zeros_like(A, bool)
+    real[:, :nf, 1:K] = True
+    np.testing.assert_allclose(d_A[real], ref_grad[real], rtol=1e-5, atol=1e-6)
+    assert (d_A[:, :nf, 0] == dl[:, None]).all()
+    c = nf // 2  # row 0's only occupant: nothing to pair with, so its
+    # own block (X - A·Q, a copy minus itself) and every other are 0
+    assert present[0].sum() == 1
+    assert (d_A[0, c, 1:] == 0).all() and np.asarray(logits)[0] == A[0, c, 0]
+    assert (d_A[:, nf:] == 0).all() and (d_A[:, :, K:] == 0).all()
+
+
+def test_aligned_pair_costs_no_selector_product():
+    """At Criteo's width the compiled value-and-gradient of the aligned
+    op is data movement and 3 elementwise passes: XLA counts under 1e8
+    FLOPs at 256 rows where a [6400 x 6400] selector product and its
+    transpose count 4.2e10 — the quadratic form cannot come back
+    unseen, and no dot is left at all."""
+    from xflow_tpu.models.ffm import ffm_invperm, make_ffm_aligned_op, nf_padded
+    from xflow_tpu.ops.sorted_table import _k8, padded_len
+
+    nf, k, rows = 39, 4, 256
+    nfp, k8 = nf_padded(nf), _k8(1 + nf * k)
+    Np = padded_len(rows * nf)
+    rng = np.random.default_rng(0)
+    order = rng.permutation(rows * nf)
+    sorted_row = np.zeros(Np, np.int32)
+    sorted_fields = np.zeros(Np, np.int32)
+    smask = np.zeros(Np, np.float32)
+    sorted_row[: rows * nf], sorted_fields[: rows * nf] = order // nf, order % nf
+    smask[: rows * nf] = 1
+    inv = ffm_invperm(sorted_row, sorted_fields, smask, rows, nf)
+    op = make_ffm_aligned_op(nf, k, k8, rows)
+    fn = jax.jit(jax.value_and_grad(
+        lambda occ_t, inv, src, sm: op(occ_t, inv, src, sm).sum()
+    ))
+    compiled = fn.lower(
+        jnp.zeros((k8, Np), jnp.float32), jnp.asarray(inv),
+        jnp.asarray(sorted_row * nfp + sorted_fields), jnp.asarray(smask),
+    ).compile()
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+    assert cost["flops"] < 1e8, cost["flops"]
+    text = compiled.as_text()
+    assert " dot(" not in text and " convolution(" not in text

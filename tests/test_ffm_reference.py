@@ -41,8 +41,8 @@ def test_sorted_trainer_follows_the_plain_reference(tmp_path, bench_path, nf):
     at 5 fields and at Criteo's 39 (rows of 157 floats: the state window
     is 1024 there). Float32 against float32 on one backend: the losses
     differ by the order of a 256-term sum, the norms by the order of the
-    pair sum (the program contracts against a selector, the reference
-    multiplies pair by pair)."""
+    pair sum (the program sums A times its block transposition, the
+    reference multiplies pair by pair)."""
     from lib import compare, drive, weights
     from lib.traffic import load_traffic, make_run_data, slots_of_ids
     from reference import core as refcore

@@ -231,12 +231,14 @@ def test_ffm_cell_step_compiles_for_v5e(one_chip, no_persistent_cache, on_tpu):
     option at its default: the fused step compiles for one v5e chip with
     two Mosaic calls — the windowed gather and the fused scatter+FTRL at
     a 1024-slot window; FFM's row side is XLA's (the placement and the
-    MXU pair contraction), it has no row-sum kernel — and 4.06 GB of
-    pinned state + 6.79 GB of temporaries inside the chip's 15.75 GB."""
+    pair term over a block transposition: no dot is left in the step),
+    it has no row-sum kernel — and 4.06 GB of pinned state + 6.7 GB of
+    temporaries inside the chip's 15.75 GB."""
     step, state, batch, arrays = _ffm_cell_step(one_chip)
     assert arrays["win_off"].shape == ((1 << 21) // 1024 + 1,) and "ffm_invperm" in arrays
     compiled = step.lower(state, batch).compile()
     assert _pallas_calls(compiled) == 2
+    assert " dot(" not in compiled.as_text()
     mem = compiled.memory_analysis()
     assert 4.0e9 < mem.argument_size_in_bytes < 4.2e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9

@@ -36,14 +36,11 @@ halving and subtracting the selves leaves exactly Σ_{i<j}.)
 
 Path choice: on ONE device `sorted_layout=auto` (and `on`) runs the
 ALIGNED HYBRID sorted engine (`make_ffm_aligned_op` below): windowed
-table gather + host placement permutation + layout-friendly MXU row
-side + fused scatter+FTRL. Measured on a v5e at Criteo's shape (39
-fields x k=4, rows of 157 floats, 2^21 slots; the benchmark's
-`ffm-v4-f39-s21.text-zipf`, my chip runs of PR 36, PERF.md sections 5
-and 6): 163k examples/s at B = 32768 (a 200 ms step) and 178k at
-B = 65536 (367 ms). Earlier rates in docs/PERF.md (round 5) are another
-rig's, at 18 fields, on a kernel window that did not compile here at
-this width.
+table gather + host placement permutation + the pair term over a
+block transposition + fused scatter+FTRL. Measured on a v5e at
+Criteo's shape (39 fields x k=4, rows of 157 floats, 2^21 slots; the
+benchmark's `ffm-v4-f39-s21.text-zipf`, my chip runs of PR 37, PERF.md
+sections 5 and 6): 298k examples/s at B = 32768, a 110 ms step.
 Batches with duplicate (row, field) occurrences fall back per batch
 to the row-major einsum path in `forward` (the general form; counted
 in `final.ffm_rowmajor_batches`; not measured on this rig). The per-(row, field)
@@ -229,17 +226,23 @@ def _forward_sorted(tables, batch, cfg):
 # one host-planned inverse permutation places it as A [B, nfp, K8]
 # (nfp = nf rounded up to the 8-sublane multiple, so [B·nfp, K8] →
 # [B, nfp, K8] is a free view — no lane-boundary reshape anywhere),
-# and the pairwise term is ONE MXU contraction against a static 0/1
-# selector built in-graph (never a captured constant: a jit-embedded
-# array is baked into the program and re-sent with every compile).
+# and the pairwise term's field crossing X[b, d, c-block] = A[b, c,
+# d-block] is the block transposition it is (`cross_fields`): data
+# movement at HBM cost, linear in nf·K8 at every width, with a
+# hand-written VJP that reuses the forward's X. No dot is left on the
+# row side.
 #
 # Measured at B = 32768 x 39 fields, k = 4, 2^21 slots on a v5e (the
-# traced run of `ffm-v4-f39-s21.text-zipf`, PR 36; PERF.md section 5):
-# a 200.2 ms step — the pair contraction 107.4 (forward 50.0, its
-# transpose 54.1, one relayout 3.3: the selector product is 5.4e12
-# FLOPs a step where the pair sum needs 1.2e9), the placement 41.3 (two
-# permutation gathers of 16 and four relayouts of 3.2), the fused
-# scatter+FTRL 30.9, the windowed gather 17.1, the guard 3.6.
+# traced run of `ffm-v4-f39-s21.text-zipf`, PR 37; PERF.md section 5;
+# operations joined to scopes through the compile record's `op_scopes`):
+# a 109.8 ms step — the placement 43.9 (two permutation gathers of 16.0
+# and 15.6, three relayouts of 3.1-3.3 — the third lays A out with the
+# batch in lanes, XLA's own choice for the crossing — and the absent
+# pairs' mask 2.6), the fused scatter+FTRL 30.9, the windowed gather
+# 17.1, the pair term 14.0 (the slice of the v blocks 2.5, the crossing
+# 2.4, the forward sum 2.2, the backward 3.5, d_A back to rows 3.4: each
+# a pass over 0.84-1.34 GB at 650-680 GB/s), the guard 3.5. XLA counts
+# 4.0e9 FLOPs a step.
 # ---------------------------------------------------------------------------
 
 
@@ -298,30 +301,74 @@ def resolve_ffm_aligned(batch_fields, batch_mask) -> bool:
     return not has_field_duplicates(batch_fields, batch_mask)
 
 
-def _pair_selector(nf: int, k: int, nfp: int, k8: int, dtype):
-    """Static 0/1 selector tensors for the aligned row side, built
-    IN-GRAPH from iota/compares (a captured constant would put 14.7 MB
-    into the program text of every compile and every cache entry):
+def _pair_masks(nf: int, k: int, nfp: int, k8: int, dtype):
+    """Static 0/1 masks of the aligned row side, built IN-GRAPH from
+    iota/compares (never a captured constant):
 
-      T [nfp, k8, nfp, k8]: T[c1, 1+c2·k+kk, c2, 1+c1·k+kk] = 1
-      Q [nfp, k8]:          own-block select (column block c of row c)
-      W [nfp, k8]:          the w channel (column 0, real fields only)
+      Q [nfp, k8]: own-block select (column block c of row c)
+      W [nfp, k8]: the w channel (column 0, real fields only)
     """
-    c = jnp.arange(nfp)[:, None, None, None]  # c1
-    e = jnp.arange(k8)[None, :, None, None]
-    d = jnp.arange(nfp)[None, None, :, None]  # c2
-    f = jnp.arange(k8)[None, None, None, :]
-    ke = e - 1 - d * k  # kk from e given c2=d
-    kf = f - 1 - c * k  # kk from f given c1=c
-    T = (
-        (ke == kf) & (ke >= 0) & (ke < k) & (c < nf) & (d < nf)
-    ).astype(dtype)
     cq = jnp.arange(nfp)[:, None]
     eq = jnp.arange(k8)[None, :]
     kq = eq - 1 - cq * k
     Q = ((kq >= 0) & (kq < k) & (cq < nf)).astype(dtype)
     W = ((eq == 0) & (cq < nf)).astype(dtype)
-    return T, Q, W
+    return Q, W
+
+
+def cross_fields(A, nf: int, k: int):
+    """The pair term's field crossing, A [B, nfp, k8] -> X [B, nfp, k8]:
+
+        X[b, d, 1 + c·k + kk] = A[b, c, 1 + d·k + kk]     (c, d < nf)
+
+    zero in every pad (column 0, columns >= 1 + nf·k, rows >= nf). A
+    block transposition: every element of X is a COPY of one element of
+    A, so X is exact and the op costs a pass over A, linear in nf·k8.
+    (XLA's layout assignment puts the batch in lanes for it by itself:
+    the crossing is then a major <-> sublane exchange of [k, B] blocks.)"""
+    B, nfp, k8 = A.shape
+    V = A[:, :nf, 1 : 1 + nf * k].reshape(B, nf, nf, k)
+    Xv = V.transpose(0, 2, 1, 3).reshape(B, nf, nf * k)
+    return jnp.pad(Xv, ((0, 0), (0, nfp - nf), (1, k8 - 1 - nf * k)))
+
+
+def make_ffm_pair(nf: int, k: int):
+    """Build the aligned row side's pair term, pair(A [B, nfp, k8]) ->
+    logits [B], with a HAND-WRITTEN VJP:
+
+        logits = wx + ½(Σ A·X − Σ A²·Q),   X = cross_fields(A)
+        d_A    = dl·(X − A·Q + W)
+
+    from the forward's own X — the crossing is an involution, so the
+    backward needs no second one. Exactness at FTRL's zeros (the
+    lazy-init parity class both sibling ops document): for a
+    single-occupant field, X at the self position is a copy of A, so the
+    subtraction is EXACTLY zero; absent fields have A = 0 ⇒ X = 0; X, Q
+    and W are zero in every pad, so d_A's pads are. Tested against a
+    float64 double loop over pairs, and through the step against the
+    row-major oracle path."""
+
+    @jax.custom_vjp
+    def pair(A):
+        return _fwd(A)[0]
+
+    def _fwd(A):
+        with jax.named_scope("ffm_pair"):
+            Q, W = _pair_masks(nf, k, A.shape[1], A.shape[2], A.dtype)
+            X = cross_fields(A, nf, k)
+            full = (A * X).sum((-1, -2))
+            qsum = (A * A * Q[None]).sum((-1, -2))
+            wx = (A * W[None]).sum((-1, -2))
+            return wx + 0.5 * (full - qsum), (A, X)
+
+    def _bwd(res, dl):
+        A, X = res
+        with jax.named_scope("ffm_pair"):
+            Q, W = _pair_masks(nf, k, A.shape[1], A.shape[2], A.dtype)
+            return (dl[:, None, None] * (X - A * Q[None] + W[None]),)
+
+    pair.defvjp(_fwd, _bwd)
+    return pair
 
 
 def make_ffm_aligned_op(nf: int, k: int, k8: int, rows: int):
@@ -331,44 +378,23 @@ def make_ffm_aligned_op(nf: int, k: int, k8: int, rows: int):
             -> logits [rows]
 
     occ_t is the slot-sorted windowed gather output; `invperm` places
-    it (ffm_invperm); `src` = sorted_row·nfp + sorted_field is the
-    reverse map. The placement carries a HAND-WRITTEN VJP: the
-    transpose of a (partial) permutation gather is the reverse gather —
-    d_occ[:, p] = d_A[src[p]]·smask[p] — never an XLA scatter (which
-    would pay ~35 ns/row random-write latency for what is a
-    permutation).
-
-    Exactness at FTRL's zeros (the lazy-init parity class both sibling
-    ops document): d_A = dl·(X − A·Q + W) with X = T(A); for a
-    single-occupant field, X at the self position is bitwise A (the
-    selector row is one-hot, and the f32-exact 3-pass contraction
-    reconstructs the operand exactly), so the subtraction is EXACTLY
-    zero; absent fields have A = 0 ⇒ X = 0. Equality-tested against
-    the row-major oracle path."""
+    it (ffm_invperm) as A [rows, nfp, K8]; `src` = sorted_row·nfp +
+    sorted_field is the reverse map; the pair term over A is
+    `make_ffm_pair`'s. The placement carries a HAND-WRITTEN VJP too:
+    the transpose of a (partial) permutation gather is the reverse
+    gather — d_occ[:, p] = d_A[src[p]]·smask[p] — never an XLA scatter
+    (which would pay ~35 ns/row random-write latency for what is a
+    permutation)."""
     nfp = nf_padded(nf)
-
-    def rowmath(A, T, Q, W):
-        # HIGHEST was the measured optimum on an earlier rig (round 5,
-        # 18 fields; not re-measured on this one): a 3-pass bf16 selector
-        # split (the gather kernels' _dot_f32 trick — T is 0/1 and each
-        # output selects one A element, so it would be exact) benched
-        # SLOWER there (195 vs 177 ms/step at B=128k) — the hi/mid/lo
-        # split's extra elementwise passes over [B, nfp, k8] cost more
-        # than the MXU passes they saved
-        X = jnp.einsum(
-            "bce,cedf->bdf", A, T, precision=jax.lax.Precision.HIGHEST
-        )
-        full = (A * X).sum((-1, -2))
-        qsum = (A * A * Q[None]).sum((-1, -2))
-        wx = (A * W[None]).sum((-1, -2))
-        return wx + 0.5 * (full - qsum)
+    pair = make_ffm_pair(nf, k)
 
     # scopes of their own for the two halves of the row side, apart from
     # the table kernels' `gather` / `scatter_optimizer`: `ffm_place` (the
-    # permutation gather and its reverse) and `ffm_pair` (selectors, the
-    # pair contraction and its transpose). The step's compile record
-    # says which operations are whose (`op_scopes`; the device trace
-    # names an XLA fusion after what it fuses) — docs/OBSERVABILITY.md
+    # permutation gather and its reverse) and `ffm_pair` (the crossing,
+    # the pair sum and its hand-written backward). The step's compile
+    # record says which operations are whose (`op_scopes`; the device
+    # trace names an XLA fusion after what it fuses) —
+    # docs/OBSERVABILITY.md
     @jax.custom_vjp
     def place(occ_t, invperm, src, smask):
         with jax.named_scope("ffm_place"):
@@ -387,11 +413,7 @@ def make_ffm_aligned_op(nf: int, k: int, k8: int, rows: int):
     place.defvjp(_fwd, _bwd)
 
     def op(occ_t, invperm, src, smask):
-        with jax.named_scope("ffm_pair"):
-            T, Q, W = _pair_selector(nf, k, nfp, k8, occ_t.dtype)
-        A = place(occ_t, invperm, src, smask)
-        with jax.named_scope("ffm_pair"):
-            return rowmath(A, T, Q, W)
+        return pair(place(occ_t, invperm, src, smask))
 
     return op
 

@@ -226,8 +226,8 @@ def _fused_scatter_eligible(cfg: Config, allow_fused: bool) -> bool:
     # auto: FM (measured throughput-NEUTRAL; kept for the memory win)
     # and FFM's aligned hybrid, where the fusion removes a [S/8, 1256]
     # dense gradient (1.3 GB at 2^21 slots) and an optimizer sweep over
-    # 4 GB of state: the fused kernel takes 30.9 ms of the 200 ms step at
-    # 39 fields x k=4, B = 32768 (v5e; my chip run of PR 36, PERF.md
+    # 4 GB of state: the fused kernel takes 30.9 ms of the 110 ms step at
+    # 39 fields x k=4, B = 32768 (v5e; my chip run of PR 37, PERF.md
     # section 5; the two-pass form compiles but was not timed). The MVM
     # product path measured ~3% slower fused on an earlier rig (41.3 vs
     # 40.0 ms), so its memory win stays an explicit opt-in ("on").
