@@ -107,7 +107,7 @@ def test_chip_smoke_alone_fails_with_the_same_last_line(tmp_path):
     assert last == {"ok": False, "device": {"platform": None, "kind": None, "count": 0}}
 
 
-def test_compile_cache_placed_from_outside_sets_nothing_in_code(monkeypatch):
+def test_compile_cache_placed_from_outside_sets_no_directory_in_code(monkeypatch):
     import jax
 
     from xflow_tpu.compile_cache import enable_compile_cache
@@ -116,7 +116,10 @@ def test_compile_cache_placed_from_outside_sets_nothing_in_code(monkeypatch):
     monkeypatch.setattr(jax.config, "update", lambda *a: updates.append(a))
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/outside")
     assert enable_compile_cache() == "/somewhere/outside"
-    assert updates == []
+    # no directory of its own; the metadata goes into the key wherever the
+    # cache lies (a program is never read back with another commit's scopes)
+    assert updates == [("jax_compilation_cache_include_metadata_in_key", True),
+                       ("jax_traceback_in_locations_limit", 1)]
 
 
 def test_compile_cache_default_is_one_fixed_path_in_the_checkout(tmp_path):

@@ -111,7 +111,7 @@ def _aligned_rows(n):
 
 
 def test_ffm_fit_records_the_placement_the_window_and_the_fallbacks(tmp_path, monkeypatch):
-    from xflow_tpu.telemetry import SCOPE_LABELS
+    from xflow_tpu.telemetry import PHASE_LABELS
 
     """An armed FFM run on the sorted engine: every step record's `host`
     carries `ffm_place_ms` inside its `plan_ms`, the step's compile
@@ -137,9 +137,9 @@ def test_ffm_fit_records_the_placement_the_window_and_the_fallbacks(tmp_path, mo
     compiles = [r for r in recs if r.get("kind") == "compile" and r.get("program") == "train_step"]
     assert compiles and all(r["state_window"] == 2048 for r in compiles)
     # the aligned step's record says which operations are the row side's
-    # two scopes (the device trace does not: benchmark/metrics/ffm_pair_roofline.py)
+    # two labels (the device trace does not: benchmark/metrics/ffm_pair_roofline.py)
     labels = set(compiles[0]["op_scopes"].values())
-    assert {"ffm_place", "ffm_pair"} <= labels and labels <= set(SCOPE_LABELS)
+    assert {"ffm_place", "ffm_pair"} <= labels and labels <= set(dict(PHASE_LABELS)) | {""}
 
 
 def test_ffm_place_span_opens_inside_plan_on_the_producer(tmp_path, monkeypatch):

@@ -1,7 +1,7 @@
 """Performance-attribution layer (round 9): CompileRecorder unit
 coverage on fake lowered/compiled seams and real jax, the CPU-backend
 memory_stats guard, the window records' `host` fields, tools/trace_attrib.py
-on the checked-in minimal trace fixture, tools/perf_ledger.py
+on device planes written by hand, tools/perf_ledger.py
 consolidation + regression-gate exit codes, the metrics_report
 compile-schema / exactly-once-recompile gates, and the
 tools/smoke_perf.sh CI gate end to end.
@@ -28,7 +28,6 @@ from xflow_tpu.telemetry import (
 )
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-TRACE_FIXTURE = os.path.join(REPO_ROOT, "tests", "data", "minimal.trace.json.gz")
 
 
 def tool(name: str) -> str:
@@ -49,9 +48,14 @@ def run_tool(args, **kw):
 
 HLO_TEXT = """\
 HloModule jit_step
-fusion.1 = f32[8]{0} fusion(x), kind=kLoop, metadata={op_name="jit(step)/jit(main)/grad/gather" source_file="x.py"}
-add.2 = f32[] add(a, b), metadata={op_name="jit(step)/jit(main)/optimizer/add"}
-noise.3 = f32[] add(a, b), metadata={op_name="jit(step)/jit(main)/mul"}
+
+ENTRY %main (a: f32[8], b: f32[]) -> f32[] {
+  %a = f32[8]{0} parameter(0)
+  %b = f32[] parameter(1)
+  %fusion.1 = f32[8]{0} fusion(%a), kind=kLoop, calls=%fused, metadata={op_name="jit(step)/jit(main)/transpose(jvp(rows))/gather/scatter-add" source_file="x.py"}
+  %add.2 = f32[] add(%b, %b), metadata={op_name="jit(step)/jit(main)/update/add"}
+  ROOT %noise.3 = f32[] add(%add.2, %b), metadata={op_name="jit(step)/jit(main)/mul"}
+}
 """
 
 
@@ -130,10 +134,12 @@ def test_compile_recorder_records_and_caches():
     assert r["compile_time_s"] >= 0 and r["compiles"] == 1
     assert r["flops"] == 10.0 and r["bytes_accessed"] == 100.0
     assert r["argument_bytes"] == 11 and r["temp_bytes"] == 33
-    # op_scopes: the LAST scope component wins, the primitive (final
-    # component) never matches, unscoped ops stay out
-    assert r["op_scopes"] == {"fusion.1": "grad", "add.2": "optimizer"}
+    # op_scopes: the LAST scope component wins (a `gather` under
+    # `transpose(` is the scatter), the primitive (final component) never
+    # matches, an operation whose path names no phase is there with ""
+    assert r["op_scopes"] == {"fusion.1": "scatter", "add.2": "update", "noise.3": ""}
     assert r["hlo_module"] == "jit_step"  # the trace-join key
+    assert r["analysis_s"] >= 0 and "op_scopes_dropped" not in r
     assert rec.recompiles == 0
 
 
@@ -309,137 +315,139 @@ def test_trainer_compile_metrics_off(tmp_path):
 
 
 # ------------------------------------------------------------ trace_attrib
+# tools/trace_attrib.py is ONE join: a device plane's operations ->
+# instruction name -> the module whose interval on the same plane holds
+# the event -> that module's `op_scopes` -> phase. The planes here are
+# written by hand in the form benchmark/tests/data holds a real one in.
 
 
-def _compile_jsonl(tmp_path) -> str:
+def _planes(tmp_path, ops, modules, name="t.planes.json", extra_planes=()) -> str:
+    planes = [{"name": "/device:TPU:0", "lines": [
+        {"name": "Steps", "events": [["0", 0.0, 1e6]]},
+        {"name": "XLA Modules", "events": modules},
+        {"name": "XLA Ops", "events": ops},
+    ]}, *extra_planes]
+    path = tmp_path / name
+    path.write_text(json.dumps({"planes": planes}))
+    return str(path)
+
+
+def _compile_jsonl(tmp_path, records) -> str:
     run_dir = tmp_path / "run"
     run_dir.mkdir(exist_ok=True)
-    rec = {
-        "ts": 1.0, "rank": 0, "run_id": "fix", "kind": "compile",
-        "program": "train_step", "sig": "abc", "compile_time_s": 0.1,
-        "flops": 1.0, "bytes_accessed": 2.0,
-        "op_scopes": {
-            "gather_fusion.1": "gather",
-            "multiply_subtract_fusion": "optimizer",
-            "while": "grad",
-        },
-    }
-    path = run_dir / "metrics_rank0.jsonl"
-    path.write_text(json.dumps(rec) + "\n")
+    base = {"ts": 1.0, "rank": 0, "run_id": "fix", "kind": "compile", "sig": "abc",
+            "compile_time_s": 0.1, "flops": 1.0, "bytes_accessed": 2.0}
+    (run_dir / "metrics_rank0.jsonl").write_text(
+        "".join(json.dumps({**base, **r}) + "\n" for r in records))
     return str(run_dir)
 
 
-def test_trace_attrib_fixture_with_map(tmp_path):
-    run_dir = _compile_jsonl(tmp_path)
+STEP_OPS = [  # two executions of one step program, ns
+    ["gather.5", 0.0, 100e3], ["fusion.1", 100e3, 300e3], ["scatter_optimizer.1", 400e3, 50e3],
+    ["mystery.9", 450e3, 25e3],
+    ["gather.5", 500e3, 100e3], ["fusion.1", 600e3, 300e3], ["scatter_optimizer.1", 900e3, 50e3],
+    ["mystery.9", 950e3, 25e3],
+]
+STEP_MODULES = [["jit_train_step(4001266711181281872)", 0.0, 480e3],
+                ["jit_train_step(4001266711181281872)", 500e3, 480e3]]
+STEP_RECORD = {"program": "train_step", "hlo_module": "jit_train_step", "op_scopes": {
+    "gather.5": "gather", "fusion.1": "ffm_pair", "scatter_optimizer.1": "scatter_optimizer",
+    "add.3": ""}}
+
+
+def test_trace_attrib_joins_the_trace_with_the_map(tmp_path):
+    trace = _planes(tmp_path, STEP_OPS, STEP_MODULES)
     out = tmp_path / "attrib.json"
-    r = run_tool([tool("trace_attrib.py"), TRACE_FIXTURE,
-                  "--run-dir", run_dir, "--json", str(out)])
+    r = run_tool([tool("trace_attrib.py"), trace,
+                  "--run-dir", _compile_jsonl(tmp_path, [STEP_RECORD]), "--json", str(out)])
     assert r.returncode == 0, r.stderr
     got = json.loads(out.read_text())
-    scopes = got["scopes"]
-    # map join: gather 100us, optimizer 50us (+10us from the TPU-style
-    # path event), grad 300us, unknown op -> other; the host python
-    # event (1000us) is excluded entirely
-    assert scopes["gather"]["ms"] == pytest.approx(0.1)
-    assert scopes["grad"]["ms"] == pytest.approx(0.3)
-    assert scopes["optimizer"]["ms"] == pytest.approx(0.06)
-    assert scopes["other"]["ms"] == pytest.approx(0.025)
-    assert got["total_ms"] == pytest.approx(0.485)
-    assert "grad" in r.stdout and "%" in r.stdout  # the table rendered
+    assert got["steps"] == 2 and got["devices"] == 1
+    phases = got["phases"]
+    # ms a step: a label inside a phase has its own row and counts as the phase
+    assert phases["gather"]["ms"] == pytest.approx(0.1)
+    assert phases["rows"]["ms"] == pytest.approx(0.3) and phases["ffm_pair"]["ms"] == pytest.approx(0.3)
+    assert phases["update"]["ms"] == pytest.approx(0.05)
+    assert phases["scatter_optimizer"]["events"] == 2
+    assert got["busy_ms"] == pytest.approx(0.475)
+    assert "rows" in r.stdout and "ms/step" in r.stdout  # the table rendered
 
 
-def test_trace_attrib_fixture_keyword_fallback(tmp_path):
-    # no --run-dir: the keyword fallback attributes gather_fusion to
-    # "gather"; the rest buckets other (honest: it cannot tell phases)
-    r = run_tool([tool("trace_attrib.py"), TRACE_FIXTURE,
-                  "--json", str(tmp_path / "a.json")])
+def test_trace_attrib_unscoped_share_where_the_map_lacks_an_operation(tmp_path):
+    # an operation the record does not hold is `unscoped`, by name and
+    # with its share — never guessed at from its name
+    trace = _planes(tmp_path, STEP_OPS, STEP_MODULES)
+    out = tmp_path / "a.json"
+    r = run_tool([tool("trace_attrib.py"), trace,
+                  "--run-dir", _compile_jsonl(tmp_path, [STEP_RECORD]), "--json", str(out)])
     assert r.returncode == 0, r.stderr
-    got = json.loads((tmp_path / "a.json").read_text())
-    assert got["scopes"]["gather"]["ms"] == pytest.approx(0.1)
-    # the TPU-style path event still attributes via its long_name
-    assert got["scopes"]["optimizer"]["ms"] == pytest.approx(0.01)
+    unscoped = json.loads(out.read_text())["phases"]["unscoped"]
+    assert unscoped["ms"] == pytest.approx(0.025) and unscoped["events"] == 2
+    assert unscoped["pct"] == pytest.approx(100 * 25 / 475)
+    # with no record at all every operation is: the tool says so and
+    # does not fall back to the names (a `gather.5` is not "gather")
+    r = run_tool([tool("trace_attrib.py"), trace, "--steps", "2", "--json", str(out)])
+    assert r.returncode == 0 and "every operation is unscoped" in r.stderr
+    phases = json.loads(out.read_text())["phases"]
+    assert list(phases) == ["unscoped"] and phases["unscoped"]["pct"] == pytest.approx(100.0)
 
 
 def test_trace_attrib_module_keyed_join(tmp_path):
-    # two programs reuse the HLO op name "fusion.1" (op names are only
-    # module-unique): the event's hlo_module picks ITS program's map,
-    # never the other's — and an op missing from its own module's map
-    # buckets "other" instead of borrowing a colliding entry
-    run_dir = tmp_path / "run"
-    run_dir.mkdir()
-    recs = [
-        {"kind": "compile", "program": "train_step", "sig": "a",
-         "compile_time_s": 0.1, "flops": 1.0, "bytes_accessed": 1.0,
-         "hlo_module": "jit_train_step", "op_scopes": {"fusion.1": "grad"}},
-        {"kind": "compile", "program": "predict", "sig": "b",
-         "compile_time_s": 0.1, "flops": 1.0, "bytes_accessed": 1.0,
-         "hlo_module": "jit_predict", "op_scopes": {"fusion.1": "gather"}},
-    ]
-    (run_dir / "m.jsonl").write_text(
-        "".join(json.dumps(r) + "\n" for r in recs))
-    trace = tmp_path / "t.trace.json"
-    trace.write_text(json.dumps({"traceEvents": [
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 0, "dur": 100.0,
-         "name": "fusion.1",
-         "args": {"hlo_op": "fusion.1", "hlo_module": "jit_train_step"}},
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 200, "dur": 40.0,
-         "name": "fusion.1",
-         "args": {"hlo_op": "fusion.1", "hlo_module": "jit_predict"}},
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 300, "dur": 7.0,
-         "name": "unmapped.9",
-         "args": {"hlo_op": "unmapped.9", "hlo_module": "jit_predict"}},
-    ]}))
+    # two programs reuse the names `fusion.1` and `copy.1` (instruction
+    # names are unique within a module only): the module whose interval
+    # holds the event picks ITS program's map, never the other's — and an
+    # operation missing from its own module's map is unscoped instead of
+    # borrowing a colliding entry
+    run_dir = _compile_jsonl(tmp_path, [
+        {"program": "train_step.fullshard.fm", "hlo_module": "jit_grad_part",
+         "op_scopes": {"fusion.1": "rows", "copy.1": "scatter", "only_grad.2": "gather"}},
+        {"program": "update_step.fullshard.fm", "hlo_module": "jit_update_part",
+         "op_scopes": {"fusion.1": "rows"}},  # superseded by the next record of the module
+        {"program": "update_step.fullshard.fm", "hlo_module": "jit_update_part",
+         "op_scopes": {"fusion.1": "update", "copy.1": "update"}},
+    ])
+    trace = _planes(tmp_path, [
+        ["fusion.1", 0.0, 100e3], ["copy.1", 100e3, 60e3],
+        ["fusion.1", 200e3, 40e3], ["copy.1", 240e3, 30e3], ["only_grad.2", 270e3, 7e3],
+    ], [["jit_grad_part(1)", 0.0, 190e3], ["jit_update_part(2)", 200e3, 90e3]])
     out = tmp_path / "a.json"
-    r = run_tool([tool("trace_attrib.py"), str(trace),
-                  "--run-dir", str(run_dir), "--json", str(out)])
+    r = run_tool([tool("trace_attrib.py"), trace, "--run-dir", run_dir, "--json", str(out)])
     assert r.returncode == 0, r.stderr
-    scopes = json.loads(out.read_text())["scopes"]
-    assert scopes["grad"]["ms"] == pytest.approx(0.1)
-    assert scopes["gather"]["ms"] == pytest.approx(0.04)
-    assert scopes["other"]["ms"] == pytest.approx(0.007)
+    phases = json.loads(out.read_text())["phases"]
+    assert phases["rows"]["ms"] == pytest.approx(0.1)
+    assert phases["scatter"]["ms"] == pytest.approx(0.06)
+    assert phases["update"]["ms"] == pytest.approx(0.07)
+    assert phases["unscoped"]["ms"] == pytest.approx(0.007)
+    assert "gather" not in phases
 
 
-def test_trace_attrib_excludes_device_summary_rows(tmp_path):
-    # TPU xprof device pids carry an "XLA Modules" row whose one span
-    # covers the same wall time as every op on the "XLA Ops" row —
-    # counting both would double total_us and halve every percentage
-    run_dir = tmp_path / "run"
-    run_dir.mkdir()
-    (run_dir / "m.jsonl").write_text(json.dumps(
-        {"kind": "compile", "program": "train_step", "sig": "a",
-         "compile_time_s": 0.1, "flops": 1.0, "bytes_accessed": 1.0,
-         "op_scopes": {"fusion.1": "grad"}}) + "\n")
-    trace = tmp_path / "t.trace.json"
-    trace.write_text(json.dumps({"traceEvents": [
-        {"ph": "M", "name": "process_name", "pid": 7,
-         "args": {"name": "/device:TPU:0"}},
-        {"ph": "M", "name": "thread_name", "pid": 7, "tid": 1,
-         "args": {"name": "XLA Modules"}},
-        {"ph": "M", "name": "thread_name", "pid": 7, "tid": 2,
-         "args": {"name": "XLA Ops"}},
-        {"ph": "X", "pid": 7, "tid": 1, "ts": 0, "dur": 140.0,
-         "name": "jit_train_step(1)"},
-        {"ph": "X", "pid": 7, "tid": 2, "ts": 0, "dur": 100.0,
-         "name": "fusion.1"},
-    ]}))
+def test_trace_attrib_leaves_out_summary_rows_and_host_events(tmp_path):
+    # a device plane's `XLA Modules` / `Steps` lines span the same wall
+    # time as every operation on `XLA Ops`, a control-flow wrapper covers
+    # its children, and the host's plane is not the device's: counting any
+    # of them would double the total and halve every share
+    host = {"name": "/host:CPU", "lines": [{"name": "python3", "events": [
+        ["fusion.1", 0.0, 1e6], ["xflow:fit", 0.0, 1e6]]}]}
+    trace = _planes(tmp_path, [["while.3", 0.0, 140e3], ["fusion.1", 0.0, 100e3]],
+                    [["jit_train_step(7)", 0.0, 140e3]], extra_planes=[host])
     out = tmp_path / "a.json"
-    r = run_tool([tool("trace_attrib.py"), str(trace),
-                  "--run-dir", str(run_dir), "--json", str(out)])
+    r = run_tool([tool("trace_attrib.py"), trace, "--json", str(out), "--run-dir", _compile_jsonl(
+        tmp_path, [{"program": "train_step", "hlo_module": "jit_train_step",
+                    "op_scopes": {"fusion.1": "rows", "while.3": "rows"}}])])
     assert r.returncode == 0, r.stderr
     got = json.loads(out.read_text())
-    assert got["total_ms"] == pytest.approx(0.1)  # the module span is out
-    assert got["scopes"]["grad"]["pct"] == pytest.approx(100.0)
+    assert got["busy_ms"] == pytest.approx(0.1)  # the module span, the wrapper and the host are out
+    assert got["phases"]["rows"]["pct"] == pytest.approx(100.0) and got["phases"]["rows"]["events"] == 1
 
 
 def test_trace_attrib_empty_trace_exits_1(tmp_path):
-    empty = tmp_path / "empty.trace.json"
-    empty.write_text(json.dumps({"traceEvents": [
-        {"ph": "M", "name": "process_name", "pid": 1,
-         "args": {"name": "/host:CPU"}},
-    ]}))
+    # a CPU capture: host planes only
+    empty = tmp_path / "empty.planes.json"
+    empty.write_text(json.dumps({"planes": [
+        {"name": "/host:CPU", "lines": [{"name": "python3", "events": [["fusion.1", 0.0, 5.0]]}]}]}))
     r = run_tool([tool("trace_attrib.py"), str(empty)])
     assert r.returncode == 1
-    assert "no device-op events" in r.stderr
+    assert "no device operation" in r.stderr
 
 
 def test_trace_attrib_missing_trace_exits_2(tmp_path):

@@ -90,6 +90,26 @@ def _pallas_calls(compiled) -> int:
     return compiled.as_text().count('custom_call_target="tpu_custom_call"')
 
 
+def _phase_map(compiled, unnamed_ok: int = 0) -> dict:
+    """{operation -> label} as the step's compile record would hold it
+    (telemetry.op_phases), off the chip's own compiler's text: what the
+    CPU suite's tests/test_phase_map.py reads off XLA's CPU backend."""
+    from xflow_tpu.telemetry import op_phases
+
+    phases = op_phases(compiled.as_text())
+    unnamed = [op for op, label in phases.items() if not label]
+    assert len(unnamed) < max(0.05 * len(phases), unnamed_ok + 1), unnamed
+    return phases
+
+
+def _kernels(phases: dict, text: str) -> list:
+    """[(Mosaic call's name, numbered suffix folded; its label)], sorted."""
+    import re
+
+    names = re.findall(r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    return sorted((re.sub(r"\.\d+$", "", n), phases[n]) for n in names)
+
+
 def _kernel_cases():
     """name -> (fn, arg shapes as (shape, dtype) tuples): the six kernels
     of the sorted engine at the FM main-path widths."""
@@ -239,6 +259,14 @@ def test_ffm_cell_step_compiles_for_v5e(one_chip, no_persistent_cache, on_tpu):
     compiled = step.lower(state, batch).compile()
     assert _pallas_calls(compiled) == 2
     assert " dot(" not in compiled.as_text()
+    # the step's phases by the program's own word: the two kernels keep the
+    # names their roofline readers match, the row side's two labels sit
+    # between them, and no table scatter stands alone in the fused step
+    phases = _phase_map(compiled)
+    assert _kernels(phases, compiled.as_text()) == [
+        ("gather", "gather"), ("scatter_optimizer", "scatter_optimizer")]
+    assert {"ffm_place", "ffm_pair", "rows", "update"} <= set(phases.values())
+    assert not {"scatter", "exchange", "health"} & set(phases.values())
     mem = compiled.memory_analysis()
     assert 4.0e9 < mem.argument_size_in_bytes < 4.2e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
@@ -294,6 +322,13 @@ def test_fm_train_step_compiles_for_v5e(one_chip, no_persistent_cache, on_tpu):
     assert _pallas_calls(compiled) == 3
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9
+    # the gather is the only kernel named `gather`, the fused kernel the
+    # only `scatter_optimizer` (the benchmark's readers match those names);
+    # the row sums are `rows`, named after the `jvp()` autodiff writes
+    phases = _phase_map(compiled)
+    assert _kernels(phases, compiled.as_text()) == [
+        ("gather", "gather"), ("jvp__", "rows"), ("scatter_optimizer", "scatter_optimizer")]
+    assert set(phases.values()) <= {"gather", "rows", "update", "scatter_optimizer", ""}
 
 
 @pytest.mark.parametrize("model_name", ["fm", "lr"])
@@ -467,6 +502,29 @@ def test_fm_fullshard_step_compiles_for_four_chips(log2_slots, topo, no_persiste
     assert _pallas_calls(update) == 0 and "all-to-all" not in update.as_text()
     assert _pallas_calls(compiled) == 3
     assert "all-to-all" in text
+    # three kernels, three phases: the windowed gather, its transpose (the
+    # two-pass scatter keeps the gather's name in the trace, and reads
+    # `scatter` through autodiff's `transpose(`) and the row sums; the
+    # merge's sort and every collective are `exchange`; the gradient's
+    # relayout on each side of the cut is booked to that side's phase
+    import re
+
+    phases = _phase_map(compiled)
+    assert _kernels(phases, text) == [("gather", "gather"), ("gather", "scatter"), ("rows", "rows")]
+    from xflow_tpu.telemetry import _operations
+
+    by_opcode: dict = {}
+    for name, (opcode, _, _) in _operations(text).items():
+        by_opcode.setdefault(opcode, set()).add(phases.get(name))
+    assert by_opcode["sort"] == by_opcode["all-to-all"] == by_opcode["all-gather"] == {"exchange"}
+    leaf = f"f32[{(1 << log2_slots) // 4 // PACK},{PACK * K}]"
+    relaid = lambda c: re.findall(r"%([\w.\-]+) = " + re.escape(leaf) + r"\S* copy\(", c.as_text())
+    assert [phases[n] for n in relaid(compiled)] == ["scatter"]
+    # (the loss and the row count pass through the update program: two
+    # scalar copies from a parameter to the result, in no phase)
+    updating = _phase_map(update, unnamed_ok=2)
+    assert [updating[n] for n in relaid(update)] == ["update"]
+    assert set(updating.values()) <= {"update", "exchange", ""}
     # the four received buffers are merged into one slot-sorted stream on
     # the device (one sort), and the kernels walk ONE span a window: no
     # Mosaic call takes the buffers' [D, wpo+1] offset table

@@ -619,7 +619,7 @@ def test_contract_matrix_covers_known_invariants():
     for rel, eng in data["engines"].items():
         if rel == "xflow_tpu/parallel/train_step.py":
             continue  # inherits the shared step's scopes by delegation
-        assert {"gather", "loss", "grad", "optimizer"} <= set(eng["scopes"]), rel
+        assert {"gather", "rows", "update", "health"} <= set(eng["scopes"]), rel
 
 
 def test_cli_check_contracts_green_then_drift_exits_4(tmp_path):
@@ -644,7 +644,7 @@ def test_cli_check_contracts_green_then_drift_exits_4(tmp_path):
 
 
 def test_xf704_scope_drift_across_builders(tmp_path):
-    """Renaming one builder's 'optimizer' scope (present in every other
+    """Renaming one builder's 'update' scope (present in every other
     builder) fires XF704 on that builder only."""
     root = tmp_path / "tree"
     for rel in ("xflow_tpu/train/step.py", "xflow_tpu/parallel/mesh.py",
@@ -657,12 +657,12 @@ def test_xf704_scope_drift_across_builders(tmp_path):
     assert [f for f in run_passes(project) if f.rule == "XF704"] == []
     sf = root / "xflow_tpu/parallel/sorted_fullshard.py"
     sf.write_text(sf.read_text().replace(
-        'named_scope("optimizer")', 'named_scope("optimzer")'))
+        'named_scope("update")', 'named_scope("updat")'))
     findings = [f for f in run_passes(Project.load(str(root)))
                 if f.rule == "XF704"]
     assert len(findings) == 1
     assert findings[0].path == "xflow_tpu/parallel/sorted_fullshard.py"
-    assert "'optimizer'" in findings[0].message
+    assert "'update'" in findings[0].message
 
 
 def test_xf704_silent_on_partial_scan_without_shared_step():

@@ -237,8 +237,8 @@ cp xflow_tpu/parallel/train_step.py \
    "$DRIFT/xflow_tpu/parallel/"
 python tools/xflowlint.py --root "$DRIFT" --no-baseline >/dev/null 2>&1 \
     || { echo "smoke_lint: faithful builder copies must lint clean"; exit 1; }
-# rename one builder's "optimizer" scope: every OTHER builder covers it
-sed -i 's/named_scope("optimizer")/named_scope("optimzer")/' \
+# rename one builder's "update" scope: every OTHER builder covers it
+sed -i 's/named_scope("update")/named_scope("updat")/' \
     "$DRIFT/xflow_tpu/parallel/sorted_fullshard.py"
 line=$(grep -n 'jax.named_scope' "$DRIFT/xflow_tpu/parallel/sorted_fullshard.py" \
     | head -1 | cut -d: -f1)

@@ -9,8 +9,9 @@
 #   2. the host timeline in the window records (`host`: one field a
 #      stage; `boundary` on the first) and the compile record's
 #      lower/compile split;
-#   3. tools/trace_attrib.py producing a per-scope device-time table
-#      from the run's TraceWindow trace;
+#   3. tools/trace_attrib.py joining the run's compile records with a
+#      device plane (the CPU's TraceWindow capture has none: the tool
+#      says so; the plane is written from the records' own operations);
 #   4. the round's BENCH_r09.json datapoint rendered through
 #      tools/perf_ledger.py (markdown + JSON);
 #   5. the ledger's regression mode exiting 3 on a controlled
@@ -85,17 +86,37 @@ print(f"smoke_perf: {len(comp)} compile record(s), "
       f"host timeline in {len(wins)} window(s)")
 EOF
 
-# ---- 3. trace attribution from the run's own trace window -----------------
-python tools/trace_attrib.py "$WORK/prof" --run-dir "$WORK/run" \
+# ---- 3. trace attribution: the run's compile records joined by module ------
+# A CPU capture has host planes only (the operations run on host
+# threads): the tool reads the run's .xplane.pb and says so, exit 1.
+rc=0
+python tools/trace_attrib.py "$WORK/prof" --run-dir "$WORK/run" 2>"$WORK/attrib.err" || rc=$?
+[ "$rc" -eq 1 ] || {
+    echo "smoke_perf: trace_attrib on a CPU capture expected exit 1, got $rc"
+    cat "$WORK/attrib.err"; exit 1; }
+grep -q "no device operation" "$WORK/attrib.err"
+# The join itself, on a device plane written from the step record's own
+# operations (one event each, under a module event of the record's name)
+python - "$WORK/run/metrics_rank0.jsonl" "$WORK/planes.json" <<'EOF'
+import json, sys
+recs = [json.loads(l) for l in open(sys.argv[1]) if l.strip()]
+step = [r for r in recs if r.get("kind") == "compile" and r["program"] == "train_step"][-1]
+ops = [[op, 10.0 * i, 10.0] for i, op in enumerate(step["op_scopes"])]
+planes = [{"name": "/device:TPU:0", "lines": [
+    {"name": "XLA Modules", "events": [[step["hlo_module"] + "(1)", 0.0, 10.0 * len(ops)]]},
+    {"name": "XLA Ops", "events": ops}]}]
+json.dump({"planes": planes}, open(sys.argv[2], "w"))
+EOF
+python tools/trace_attrib.py "$WORK/planes.json" --run-dir "$WORK/run" \
     --json "$WORK/attrib.json"
 python - "$WORK/attrib.json" <<'EOF'
 import json, sys
 d = json.load(open(sys.argv[1]))
-assert d["total_ms"] > 0, "trace attributed zero device time"
-named = [s for s in d["scopes"] if s != "other"]
-assert named, f"no named scope attributed any time: {d}"
-print(f"smoke_perf: trace attributed ({d['total_ms']} ms device time, "
-      f"named scopes: {named})")
+assert d["busy_ms"] > 0, "trace attributed zero device time"
+named = [s for s in d["phases"] if s != "unscoped"]
+assert {"gather", "rows", "scatter", "update"} <= set(named), f"phases missing: {d}"
+assert d["phases"].get("unscoped", {"pct": 0.0})["pct"] < 10.0, f"too much unscoped: {d}"
+print(f"smoke_perf: trace attributed ({d['busy_ms']} ms device time, phases: {named})")
 EOF
 
 # ---- 4. the round's bench datapoint through the ledger path ---------------

@@ -1,266 +1,218 @@
 #!/usr/bin/env python3
-"""Attribute an xprof trace's device-op time to the step's named
-scopes (docs/OBSERVABILITY.md "Trace attribution").
+"""A device trace's time by the step phase the program says it is in
+(docs/OBSERVABILITY.md "Trace attribution").
 
-The TraceWindow (`train.trace_start_step`/`train.trace_num_steps`)
-captures a steady-state trace nobody could read as op soup: hundreds of
-fused HLO ops per step. The step builders already label the program
-with `jax.named_scope`s (gather / loss / grad / optimizer /
-scatter_optimizer / train_step), and the CompileRecorder stamps every
-compile record with the {optimized-HLO op -> scope} map scraped from
-the compiled module's metadata — this tool joins the two:
+The step builders wrap their phases in `jax.named_scope`s of one
+vocabulary (`telemetry.PHASE_LABELS`: exchange / gather / rows / scatter
+/ update / health, with `ffm_place`, `ffm_pair`, `scatter_optimizer`
+inside them), and the CompileRecorder writes, into each program's
+`kind="compile"` record, `op_scopes`: {operation of the compiled module
+-> label, "" where the program names none}. A device trace names an
+event by its HLO instruction and nothing else (no scope, no path, no
+`hlo_op` statistic on this chip), so the record is the only place the
+two meet, and this tool is that ONE join:
 
     python tools/trace_attrib.py /runs/exp1/prof --run-dir /runs/exp1
-    python tools/trace_attrib.py trace.json.gz --run-dir /runs/exp1 --json -
 
-and prints the per-scope device-time table ("the gather is 34% of the
-step") that is the before/after evidence any kernel PR needs.
+    an event on a device plane's `XLA Ops` line
+      -> the instruction's name (the event's text up to " = ")
+      -> the module whose interval on the SAME plane's `XLA Modules`
+         line holds the event's start: instruction names are unique
+         within a module only, and a step of two programs (the fullshard
+         engine's gradient and update) has two `copy.1`
+      -> that module's `op_scopes` (the compile record under --run-dir
+         with the same `hlo_module`, the newest winning)
+      -> label -> phase.
 
-How the join works, per trace event (Chrome-trace `ph == "X"`):
+What has no phase is `unscoped`: an operation the map gives "", one the
+record does not hold, one of a module with no record. An instant counts
+once, to the operation that started first, so the rows sum to the time
+an operation ran on the device. Times are a step's and a chip's: the
+mean over the device planes, over the executions of the most-run
+recorded module (or --steps).
 
-1. the event's `args.hlo_op` (CPU backend) or name is looked up in the
-   op->scope map from the `kind="compile"` records under --run-dir —
-   keyed per `hlo_module` when both the record and the event carry the
-   module name (HLO op names are only unique within one module, so a
-   run that compiled train_step AND predict never cross-attributes),
-   with a flat merged map (newest mapping wins) for events/records
-   that lack it;
-2. failing that, any path-shaped arg value (`tf_op` / `long_name` /
-   `name`, the TPU backends' op metadata) is split on "/" and the last
-   component matching a known scope label attributes the event;
-3. with NO map available at all (no --run-dir), a last-resort keyword
-   match on the op name itself runs (a `bitcast_gather_fusion` counts
-   as "gather") — honest enough for a quick look, but it cannot tell a
-   backward gather under `grad` from the forward's, so the compile-
-   record join is the real path. Unmatched device ops bucket "other";
-   host-side python events are excluded entirely.
+The trace is the profiler's `.xplane.pb` (what `train.profile_dir` /
+`train.trace_start_step` and the benchmark's `--trace 1` both leave on
+disk), read with `jax.profiler.ProfileData`; a `.json` / `.json.gz` file
+holds the same planes as plain data, {"planes": [{"name", "lines":
+[{"name", "events": [[name, start_ns, duration_ns], ...]}]}]} (the
+tests' form, and `benchmark/lib/trace.py`'s).
 
-Exit codes: 0 = table printed; 1 = no device-op events in the trace;
-2 = no trace found / unreadable input.
+`attribute` is a twin of `benchmark/lib/phases.py`'s: the benchmark
+imports nothing of the program, so the yardstick keeps its own copy of
+these thirty lines and this one serves any `xflow train` capture.
+
+Exit codes: 0 = table printed; 1 = no device operation in the trace (a
+CPU capture has no device plane); 2 = no trace found / unreadable input.
 """
 
 from __future__ import annotations
 
 import argparse
+import bisect
 import glob
 import gzip
 import json
 import os
+import re
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from xflow_tpu.jsonl import read_jsonl  # noqa: E402
-from xflow_tpu.telemetry import SCOPE_LABELS  # noqa: E402
+from xflow_tpu.telemetry import PHASE_LABELS  # noqa: E402
+
+PHASE_OF = dict(PHASE_LABELS)
+# how the profiler names things on this chip (benchmark/trace_names.json
+# holds the same, read from a trace of a v5e by hand)
+DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
+OP_LINE, MODULE_LINE = "XLA Ops", "XLA Modules"
+SKIP_OPS = re.compile(r"^(while|conditional|call)([.\d]*)$")  # they cover their children
 
 
-def find_trace(path: str) -> str:
-    """`path` itself when it is a trace file, else the newest
-    *.trace.json(.gz) under it (TraceWindow writes
-    <profile_dir>/plugins/profile/<ts>/<host>.trace.json.gz)."""
-    if os.path.isfile(path):
-        return path
-    hits = glob.glob(os.path.join(path, "**", "*.trace.json.gz"), recursive=True)
-    hits += glob.glob(os.path.join(path, "**", "*.trace.json"), recursive=True)
+def load_planes(path: str) -> list:
+    """The trace's planes as plain data: `path` a plain-data JSON file,
+    or a directory whose newest `.xplane.pb` is read."""
+    if os.path.isfile(path) and not path.endswith(".pb"):
+        opener = gzip.open if path.endswith(".gz") else open
+        with opener(path, "rt") as f:
+            return json.load(f)["planes"]
+    hits = [path] if os.path.isfile(path) else glob.glob(
+        os.path.join(path, "**", "*.xplane.pb"), recursive=True)
     if not hits:
-        raise FileNotFoundError(f"no *.trace.json(.gz) under {path!r}")
-    return max(hits, key=os.path.getmtime)
+        raise FileNotFoundError(f"no .xplane.pb under {path!r}")
+    from jax.profiler import ProfileData
+
+    data = ProfileData.from_file(max(hits, key=os.path.getmtime))
+    return [{"name": plane.name, "lines": [
+        {"name": line.name,
+         "events": [[e.name.split(" = ", 1)[0].lstrip("%"), float(e.start_ns), float(e.duration_ns)]
+                    for e in line.events]}
+        for line in plane.lines]} for plane in data.planes]
 
 
-def load_trace(path: str) -> list:
-    """The trace's event list, from gzip or plain chrome-trace JSON."""
-    opener = gzip.open if path.endswith(".gz") else open
-    with opener(path, "rt") as f:
-        data = json.load(f)
-    if isinstance(data, dict):
-        return data.get("traceEvents", [])
-    return data if isinstance(data, list) else []
-
-
-def load_op_scopes(run_dir: str) -> tuple[dict, dict]:
-    """({hlo_module: {op -> scope}}, flat merged {op -> scope}) over
-    every kind="compile" record in the run dir's JSONL files (newest
-    mapping wins — a recompile's map supersedes). The per-module maps
-    drive the join when the trace event names its module; the flat map
-    is the fallback for records or events without one."""
-    by_module: dict = {}
-    flat: dict = {}
+def load_op_scopes(run_dir: str) -> dict:
+    """{hlo_module: op_scopes} over every kind="compile" record in the
+    run dir's JSONL files, the newest record of a module winning."""
+    maps: dict = {}
     for path in sorted(glob.glob(os.path.join(run_dir, "*.jsonl"))):
         for rec in read_jsonl(path, warn=False):
-            if rec.get("kind") == "compile" and isinstance(
-                rec.get("op_scopes"), dict
-            ):
-                flat.update(rec["op_scopes"])
-                if rec.get("hlo_module"):
-                    by_module.setdefault(rec["hlo_module"], {}).update(
-                        rec["op_scopes"]
-                    )
-    return by_module, flat
+            if rec.get("kind") == "compile" and rec.get("hlo_module") and isinstance(
+                    rec.get("op_scopes"), dict):
+                maps[rec["hlo_module"]] = rec["op_scopes"]
+    return maps
 
 
-def scope_of(
-    name: str, args: dict, by_module: dict, op_scopes: dict, scopes: tuple,
-    keyword_ok: bool
-) -> str:
-    """One event's scope bucket (see module docstring for the order)."""
-    op = args.get("hlo_op") if isinstance(args, dict) else None
-    mod_map = (
-        by_module.get(args.get("hlo_module")) if isinstance(args, dict) else None
-    )
-    if mod_map is not None:
-        # the event's own module is known: its map is authoritative —
-        # never fall through to another program's identically-named op
-        for key in (op, name):
-            if key and key in mod_map:
-                return mod_map[key]
-    else:
-        for key in (op, name):
-            if key and key in op_scopes:
-                return op_scopes[key]
-    # path-shaped metadata (TPU op events): last scope component wins,
-    # excluding the final component (the primitive name)
-    candidates = [name] if "/" in name else []
-    if isinstance(args, dict):
-        for k in ("tf_op", "long_name", "name"):
-            v = args.get(k)
-            if isinstance(v, str) and "/" in v:
-                candidates.append(v)
-    for path in candidates:
-        comps = path.split("/")
-        for comp in reversed(comps[:-1]):
-            if comp in scopes:
-                return comp
-    if keyword_ok:
-        for scope in scopes:
-            base = scope.split("_")[0]  # scatter_optimizer -> scatter
-            if base and base in (op or name or ""):
-                return scope
-    return "other"
-
-
-def attribute(
-    events: list, by_module: dict, op_scopes: dict, scopes: tuple
-) -> tuple[dict, dict, float]:
-    """({scope: total_us}, {scope: event count}, total_us) over the
-    trace's device-op events. Device-op = a complete event carrying an
-    `hlo_op` arg (CPU backend) or living on a `/device:` process row
-    (TPU/GPU backends) — minus the "XLA Modules"/"Steps" summary rows,
-    whose spans aggregate the op rows over the same wall time."""
-    device_pids = set()
-    summary_tids = set()  # (pid, tid) rows whose spans AGGREGATE ops
-    for e in events:
-        if e.get("ph") != "M":
+def attribute(planes: list, maps: dict) -> dict:
+    """{"labels": {label or "unscoped": ns}, "events": {label: count},
+    "busy": ns, "steps": executions of the most-run recorded module,
+    "devices": n}, each time the mean over the device planes."""
+    labels: dict = {}
+    events: dict = {}
+    runs: dict = {}
+    devices = 0
+    for plane in planes:
+        if not DEVICE_PLANE.match(plane["name"]):
             continue
-        args = e.get("args") or {}
-        if e.get("name") == "process_name":
-            pname = str(args.get("name", ""))
-            if "/device:" in pname or pname.startswith("TPU"):
-                device_pids.add(e.get("pid"))
-        elif e.get("name") == "thread_name":
-            # TPU xprof device rows: "XLA Ops" holds the per-op events;
-            # "XLA Modules"/"Steps" rows span WHOLE program executions
-            # over the same wall time — counting both double-counts
-            # every op and halves every per-scope percentage
-            tname = str(args.get("name", "")).lower()
-            if "module" in tname or tname.startswith("step"):
-                summary_tids.add((e.get("pid"), e.get("tid")))
-    keyword_ok = not op_scopes
-    totals: dict = {}
-    counts: dict = {}
-    total_us = 0.0
-    for e in events:
-        if e.get("ph") != "X":
+        lines = {ln["name"]: ln["events"] for ln in plane["lines"]}
+        ops = sorted((e for e in lines.get(OP_LINE, ()) if e[2] > 0 and not SKIP_OPS.search(e[0])),
+                     key=lambda e: e[1])
+        if not ops:
             continue
-        args = e.get("args") or {}
-        is_device = (isinstance(args, dict) and "hlo_op" in args) or (
-            e.get("pid") in device_pids
-        )
-        if not is_device:
-            continue
-        if "hlo_op" not in args and (e.get("pid"), e.get("tid")) in summary_tids:
-            continue  # an op event is never excluded, a summary span is
-        dur = e.get("dur")
-        if not isinstance(dur, (int, float)) or dur <= 0:
-            continue
-        scope = scope_of(str(e.get("name", "")), args, by_module, op_scopes,
-                         scopes, keyword_ok)
-        totals[scope] = totals.get(scope, 0.0) + float(dur)
-        counts[scope] = counts.get(scope, 0) + 1
-        total_us += float(dur)
-    return totals, counts, total_us
+        devices += 1
+        mods = sorted(([re.sub(r"\(\d+\)$", "", m[0]), m[1], m[2]]
+                       for m in lines.get(MODULE_LINE, ()) if m[2] > 0), key=lambda m: m[1])
+        if devices == 1:
+            for m in mods:
+                if m[0] in maps:
+                    runs[m[0]] = runs.get(m[0], 0) + 1
+        starts = [m[1] for m in mods]
+        covered = ops[0][1]
+        for name, start, dur in ops:
+            end = start + dur
+            counted = max(0.0, end - max(start, covered))
+            covered = max(covered, end)
+            i = bisect.bisect_right(starts, start) - 1
+            module = mods[i][0] if i >= 0 and start < mods[i][1] + mods[i][2] else None
+            label = maps.get(module, {}).get(name.removesuffix("[pallas]"))
+            key = label if label in PHASE_OF else "unscoped"
+            labels[key] = labels.get(key, 0.0) + counted
+            events[key] = events.get(key, 0) + 1
+    labels = {k: v / devices for k, v in labels.items()} if devices else {}
+    return {"labels": labels, "events": events, "busy": sum(labels.values()),
+            "steps": max(runs.values(), default=0), "devices": devices}
 
 
-def render(totals: dict, counts: dict, total_us: float) -> str:
-    rows = sorted(totals.items(), key=lambda kv: -kv[1])
-    lines = ["scope                 device_ms       %   events",
-             "-----                 ---------       -   ------"]
-    for scope, us in rows:
-        lines.append(
-            f"{scope:<20}  {us / 1e3:>9.3f}  {100.0 * us / total_us:>6.1f}"
-            f"   {counts[scope]:>6}"
-        )
-    lines.append(
-        f"{'total':<20}  {total_us / 1e3:>9.3f}   100.0   {sum(counts.values()):>6}"
-    )
+def rows_of(got: dict, steps: int) -> list:
+    """[(row name, ms a step, share %, events)]: a row a phase, under it
+    a row for each label inside it, `unscoped` last."""
+    by_phase: dict = {}
+    for label in got["labels"]:
+        if label != "unscoped":
+            by_phase.setdefault(PHASE_OF[label], []).append(label)
+    out = []
+    cell = lambda name, labels: (
+        name, sum(got["labels"][x] for x in labels) / steps / 1e6,
+        100.0 * sum(got["labels"][x] for x in labels) / got["busy"],
+        sum(got["events"][x] for x in labels))
+    for phase in dict.fromkeys(PHASE_OF.values()):
+        if phase not in by_phase:
+            continue
+        out.append(cell(phase, by_phase[phase]))
+        out.extend(cell("  " + x, [x]) for x in by_phase[phase] if x != phase)
+    if "unscoped" in got["labels"]:
+        out.append(cell("unscoped", ["unscoped"]))
+    return out
+
+
+def render(rows: list, got: dict, steps: int) -> str:
+    lines = ["phase                 ms/step       %   events",
+             "-----                 -------       -   ------"]
+    lines += [f"{name:<20}  {ms:>8.3f}  {pct:>6.2f}   {n:>6}" for name, ms, pct, n in rows]
+    lines.append(f"{'busy':<20}  {got['busy'] / steps / 1e6:>8.3f}  100.00   {sum(got['events'].values()):>6}")
     return "\n".join(lines)
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
-        description="bucket an xprof trace's device-op time by the step's "
-        "named scopes"
-    )
-    ap.add_argument("trace", help="profile dir (train.profile_dir) or a "
-                                  "*.trace.json(.gz) file")
+        description="a device trace's time by the step phase the program says it is in")
+    ap.add_argument("trace", help="profile dir (train.profile_dir), an .xplane.pb, or the planes "
+                                  "as plain-data .json(.gz)")
     ap.add_argument("--run-dir", default="",
-                    help="run dir holding metrics JSONL with kind=\"compile\" "
-                         "records — their op_scopes maps drive the join")
-    ap.add_argument("--scopes", default=",".join(SCOPE_LABELS),
-                    help="comma-separated scope labels (default: the step "
-                         "builders' named scopes)")
+                    help="run dir holding metrics JSONL with kind=\"compile\" records: their "
+                         "op_scopes, keyed by hlo_module, are the join")
+    ap.add_argument("--steps", type=int, default=0,
+                    help="steps the trace holds (default: the executions of the most-run "
+                         "recorded module on the first device)")
     ap.add_argument("--json", default="", metavar="OUT",
-                    help="also write {scope: {ms, pct, events}} JSON "
+                    help="also write {steps, devices, busy_ms, phases: {row: {ms, pct, events}}} "
                          "('-' = stdout)")
     args = ap.parse_args(argv)
-
-    scopes = tuple(s for s in args.scopes.split(",") if s)
     try:
-        trace_path = find_trace(args.trace)
-        events = load_trace(trace_path)
-    except (OSError, json.JSONDecodeError, FileNotFoundError) as e:
+        planes = load_planes(args.trace)
+    except (OSError, ValueError, KeyError) as e:
         print(f"trace_attrib: {e}", file=sys.stderr)
         return 2
-    by_module, op_scopes = (
-        load_op_scopes(args.run_dir) if args.run_dir else ({}, {})
-    )
-    if args.run_dir and not op_scopes:
-        print(
-            f"trace_attrib: warning: no kind=\"compile\" op_scopes under "
-            f"{args.run_dir!r}; falling back to path/keyword matching",
-            file=sys.stderr,
-        )
-    totals, counts, total_us = attribute(events, by_module, op_scopes, scopes)
-    if total_us <= 0:
-        print(
-            f"trace_attrib: no device-op events in {trace_path!r} "
-            "(trace captured before any step dispatched?)",
-            file=sys.stderr,
-        )
+    maps = load_op_scopes(args.run_dir) if args.run_dir else {}
+    if not maps:
+        print("trace_attrib: warning: no kind=\"compile\" record with op_scopes"
+              + (f" under {args.run_dir!r}" if args.run_dir else " (no --run-dir)")
+              + ": every operation is unscoped", file=sys.stderr)
+    got = attribute(planes, maps)
+    if got["busy"] <= 0:
+        print(f"trace_attrib: no device operation in {args.trace!r} (a CPU capture has no "
+              "device plane; a window before the first step has no operation)", file=sys.stderr)
         return 1
-    print(f"# trace: {trace_path}")
-    if op_scopes:
-        print(f"# op->scope map: {len(op_scopes)} ops from {args.run_dir!r}")
-    print(render(totals, counts, total_us))
+    steps = args.steps or got["steps"] or 1
+    rows = rows_of(got, steps)
+    print(f"# trace: {args.trace}  devices: {got['devices']}  steps: {steps}")
+    print(f"# phase maps: {sorted(maps)} from {args.run_dir!r}")
+    print(render(rows, got, steps))
     if args.json:
-        payload = {
-            scope: {
-                "ms": round(us / 1e3, 3),
-                "pct": round(100.0 * us / total_us, 2),
-                "events": counts[scope],
-            }
-            for scope, us in sorted(totals.items(), key=lambda kv: -kv[1])
-        }
-        out = json.dumps({"total_ms": round(total_us / 1e3, 3), "scopes": payload})
+        out = json.dumps({
+            "steps": steps, "devices": got["devices"], "busy_ms": got["busy"] / steps / 1e6,
+            "phases": {name.strip(): {"ms": ms, "pct": pct, "events": n} for name, ms, pct, n in rows},
+        })
         if args.json == "-":
             print(out)
         else:

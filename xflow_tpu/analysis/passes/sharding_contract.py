@@ -65,12 +65,15 @@ RULES = ("XF701", "XF702", "XF703", "XF704")
 # the step builders' modules, each once, in train/engine.py's order
 ENGINE_MODULES = tuple(dict.fromkeys(astutil.engine_modules().values()))
 SHARED_STEP_MODULE = "xflow_tpu/train/step.py"
-# XF704(a)'s vocabulary: the stages every engine's trace attributes to
-# (docs/OBSERVABILITY.md). A scope beyond it is one builder's own — the
-# single-device step's fused "scatter_optimizer" kernel has no
-# counterpart on a mesh engine, whose two-pass form reads as "grad" +
-# "optimizer"
-STAGE_SCOPES = frozenset({"gather", "loss", "grad", "optimizer"})
+# XF704(a)'s vocabulary: the phases every engine's step has
+# (telemetry.PHASE_LABELS, docs/OBSERVABILITY.md "Step phases"). A label
+# beyond it is not every builder's — "exchange" needs a mesh, "scatter"
+# is never written (it is a "gather" under autodiff's `transpose(`), and
+# the single-device step's fused "scatter_optimizer" kernel has no
+# counterpart on a mesh engine, whose two-pass form reads "scatter" +
+# "update". ("gather" is opened where the table lookup is called —
+# ops/sorted_table.py, models/ — and so is no builder's to lack.)
+STAGE_SCOPES = frozenset({"rows", "update", "health"})
 MESH_MODULE = "xflow_tpu/parallel/mesh.py"
 ARTIFACT_REL = "tools/engine_contracts.json"
 

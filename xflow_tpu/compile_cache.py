@@ -5,8 +5,9 @@ calls `enable_compile_cache()` once, before its first compile. The
 directory is part of every cache key, so it must be the same path in
 every process of every run: `JAX_COMPILATION_CACHE_DIR` where the
 operator (or the machine) sets it — JAX reads that variable itself, and
-this module then sets nothing — and otherwise `.jax_cache/` beside the
-package, a path that depends only on where the code is.
+this module then sets no directory — and otherwise `.jax_cache/` beside
+the package, a path that depends only on where the code is. Wherever it
+lies, the operations' metadata is part of the key.
 """
 
 from __future__ import annotations
@@ -21,12 +22,30 @@ CACHE_DIR = os.path.join(
 
 
 def enable_compile_cache() -> str:
-    """Turn the persistent compile cache on; returns its directory."""
+    """Turn the persistent compile cache on; returns its directory.
+
+    The operations' metadata goes into the cache's key. JAX leaves it
+    out by default, and a program that differs from a cached one by its
+    `jax.named_scope`s alone is then READ from the cache with the other
+    commit's `op_name` paths in its text: its compile record's
+    `op_scopes` (telemetry.op_phases) would carry labels this checkout
+    does not write (tests/test_phase_map.py shows both halves). The
+    price: source lines are metadata too, so an edit that moves a step
+    builder's lines compiles once more — what a checkout's first run
+    pays anyway. An operation's location is held to its innermost frame
+    (JAX's default writes ten frames of the call stack into it; the
+    switch that drops the stack whole, `jax_include_full_tracebacks_in_
+    locations`, drops the scopes from `op_name` with it): with the stack
+    in the key, the same program reached from another entry point — the
+    trainer's eval and the serve runner share `predict`; the benchmark
+    and a script around it — would never share an entry."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+    jax.config.update("jax_traceback_in_locations_limit", 1)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     return CACHE_DIR
 

@@ -446,8 +446,9 @@ class TrainConfig:
     # telemetry.CompileRecorder — explicit .lower().compile() with the
     # compile timed and XLA's cost/memory analysis captured into
     # kind="compile" records in the metrics JSONL, plus the
-    # {HLO op -> named_scope} map tools/trace_attrib.py joins traces
-    # against, and the recompile counter metrics_report --check gates
+    # {operation -> step phase} map (`op_scopes`; the one vocabulary,
+    # telemetry.PHASE_LABELS) that tools/trace_attrib.py joins with a
+    # device trace, and the recompile counter metrics_report --check gates
     # on ("each program compiles exactly once per run"). The compile
     # itself costs the same either way (jit would have built the same
     # executable lazily); off restores the implicit-jit path.

@@ -388,12 +388,12 @@ def make_ffm_aligned_op(nf: int, k: int, k8: int, rows: int):
     nfp = nf_padded(nf)
     pair = make_ffm_pair(nf, k)
 
-    # scopes of their own for the two halves of the row side, apart from
-    # the table kernels' `gather` / `scatter_optimizer`: `ffm_place` (the
-    # permutation gather and its reverse) and `ffm_pair` (the crossing,
-    # the pair sum and its hand-written backward). The step's compile
-    # record says which operations are whose (`op_scopes`; the device
-    # trace names an XLA fusion after what it fuses) —
+    # labels of their own for the two halves of the row side, INSIDE
+    # the step's `rows` phase and counted as it (telemetry.PHASE_LABELS):
+    # `ffm_place` (the permutation gather and its reverse) and `ffm_pair`
+    # (the crossing, the pair sum and its hand-written backward). The
+    # step's compile record says which operations are whose (`op_scopes`;
+    # the device trace names an XLA fusion after what it fuses) —
     # docs/OBSERVABILITY.md
     @jax.custom_vjp
     def place(occ_t, invperm, src, smask):
@@ -438,10 +438,11 @@ def _forward_sorted_aligned(wv, batch, cfg):
 
     nf, k = _dims(cfg)
     K = 1 + nf * k
-    occ_t = table_gather_sorted(
-        wv, batch["sorted_slots"], batch["win_off"], cfg.data.sorted_bf16,
-        pack_of(wv, K),
-    )
+    with jax.named_scope("gather"):
+        occ_t = table_gather_sorted(
+            wv, batch["sorted_slots"], batch["win_off"], cfg.data.sorted_bf16,
+            pack_of(wv, K),
+        )
     return ffm_aligned_logits(occ_t, batch, cfg)
 
 

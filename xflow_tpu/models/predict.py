@@ -36,7 +36,10 @@ def predict_fn(tables, batch: dict, model: Model, cfg: Config):
     """Pure (tables, batch arrays) -> pctr [B] (reference-clamped σ)."""
     from xflow_tpu.metrics import reference_pctr
 
-    return reference_pctr(model.forward(tables, batch, cfg))
+    # the forward's phases as in a train step: `gather` is opened by the
+    # table lookups, the rest is `rows` (telemetry.PHASE_LABELS)
+    with jax.named_scope("rows"):
+        return reference_pctr(model.forward(tables, batch, cfg))
 
 
 def make_predict_fn(model: Model, cfg: Config, jit: bool = True,

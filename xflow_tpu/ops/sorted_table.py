@@ -214,10 +214,13 @@ def dedup_slots(slots: np.ndarray, cap: int):
 def batch_rows(table, batch: dict, K: int):
     """Per-occurrence LOGICAL table rows for a row-major batch: the
     deduped two-level gather when the host attached (unique_slots,
-    inverse), else the direct gather. Layout-blind (`table_rows`)."""
-    if "unique_slots" in batch:
-        return table_rows(table, batch["unique_slots"], K)[batch["inverse"]]
-    return table_rows(table, batch["slots"], K)
+    inverse), else the direct gather. Layout-blind (`table_rows`). The
+    row-major steps' `gather` phase (telemetry.PHASE_LABELS); autodiff's
+    transpose of it is their `scatter`."""
+    with jax.named_scope("gather"):
+        if "unique_slots" in batch:
+            return table_rows(table, batch["unique_slots"], K)[batch["inverse"]]
+        return table_rows(table, batch["slots"], K)
 
 
 # packed-row gather intermediate cap (bytes). The packed gather
@@ -529,12 +532,17 @@ def sorted_gather_map(table, batch: dict, row_keys: tuple, batch_rows: int,
     pack = pack_of(table, K)
     ss, wo = batch["sorted_slots"], batch["win_off"]
     arrs = tuple(batch[k] for k in row_keys)
+    # the `gather` phase (telemetry.PHASE_LABELS) is opened where the
+    # op is CALLED: its VJP, the scatter, inherits the caller's path
+    # under autodiff's `transpose(`, which reads `scatter`
     if ss.ndim == 1:
-        occ_t = table_gather_sorted(table, ss, wo, bf16, pack)
+        with jax.named_scope("gather"):
+            occ_t = table_gather_sorted(table, ss, wo, bf16, pack)
         return row_fn(occ_t, *arrs, batch_rows)
     ns, np_sub = ss.shape
     rows = batch_rows // ns
-    occ_all = table_gather_sorted_multi(table, ss.reshape(-1), wo, bf16, pack)
+    with jax.named_scope("gather"):
+        occ_all = table_gather_sorted_multi(table, ss.reshape(-1), wo, bf16, pack)
     occ_ns = occ_all.reshape(occ_all.shape[0], ns, np_sub).transpose(1, 0, 2)
     logits = jax.lax.map(
         lambda a: row_fn(a[0], *a[1:], rows), (occ_ns, *arrs)

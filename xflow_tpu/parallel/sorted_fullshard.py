@@ -338,33 +338,43 @@ def _local_logits(mode, tbl_local, fs_slots, fs_row, fs_mask, fs_off, fs_fields,
         return jax.lax.all_to_all(x, DATA_AXIS, 0, 0, tiled=True)
 
     with_fields = mode in ("ffm", "mvm_segment")
-    r_slots = a2a(fs_slots)  # [D_src, cap]
-    r_off = a2a(fs_off)  # [D_src, wpo+1]
-    # rows arrive shard-local [0, R); globalize by source index so one
-    # segment space covers all D source shards' rows. The compacted wire
-    # dtypes (compact_plan_wire) ride through the all_to_all — less ICI
-    # traffic — and are folded here into the merge's one payload word,
-    # [seg | mask bit]: seg = global row, times nf plus the field where
-    # the mode has fields (validate_sorted_fullshard bounds it to 31 bits)
-    seg = wire_rows(a2a(fs_row)) + jnp.arange(D, dtype=jnp.int32)[:, None] * R
-    if with_fields:
-        seg = seg * nf + wire_rows(a2a(fs_fields))
-    word = seg * 2 + a2a(fs_mask).astype(jnp.int32)
-    slots_flat, win_off, word = merge_received(r_slots, r_off, word)
+    # the step's phases (telemetry.PHASE_LABELS): `exchange` is what this
+    # chip does to receive its work — the all_to_all of the occurrences
+    # and the merge's sort here, the row aggregates' return below;
+    # `gather` is the windowed gather alone (its transpose, the two-pass
+    # scatter, reads `scatter` through the `transpose(...)` autodiff
+    # writes into the path); what is left of this body is the callers'
+    # `rows`
+    with jax.named_scope("exchange"):
+        r_slots = a2a(fs_slots)  # [D_src, cap]
+        r_off = a2a(fs_off)  # [D_src, wpo+1]
+        # rows arrive shard-local [0, R); globalize by source index so one
+        # segment space covers all D source shards' rows. The compacted wire
+        # dtypes (compact_plan_wire) ride through the all_to_all — less ICI
+        # traffic — and are folded here into the merge's one payload word,
+        # [seg | mask bit]: seg = global row, times nf plus the field where
+        # the mode has fields (validate_sorted_fullshard bounds it to 31 bits)
+        seg = wire_rows(a2a(fs_row)) + jnp.arange(D, dtype=jnp.int32)[:, None] * R
+        if with_fields:
+            seg = seg * nf + wire_rows(a2a(fs_fields))
+        word = seg * 2 + a2a(fs_mask).astype(jnp.int32)
+        slots_flat, win_off, word = merge_received(r_slots, r_off, word)
     seg = word >> 1
     mask_flat = jax.lax.stop_gradient((word & 1).astype(jnp.float32))
     grow, fields_flat = (seg // nf, seg % nf) if with_fields else (seg, None)
 
-    occ_t = table_gather_sorted(
-        tbl_local, slots_flat, win_off, bf16, pack_of(tbl_local, K)
-    )
+    with jax.named_scope("gather"):
+        occ_t = table_gather_sorted(
+            tbl_local, slots_flat, win_off, bf16, pack_of(tbl_local, K)
+        )
     occm_t = occ_t[:K] * mask_flat[None, :]
 
     def owner_reduce(partials):
-        mine = jax.lax.psum_scatter(
-            partials, DATA_AXIS, scatter_dimension=0, tiled=True
-        )  # [1, R(*nf), ch]
-        return jax.lax.psum(mine, TABLE_AXIS)[0]
+        with jax.named_scope("exchange"):
+            mine = jax.lax.psum_scatter(
+                partials, DATA_AXIS, scatter_dimension=0, tiled=True
+            )  # [1, R(*nf), ch]
+            return jax.lax.psum(mine, TABLE_AXIS)[0]
 
     if mode == "ffm":
         from xflow_tpu.models.ffm import make_ffm_row_op
@@ -479,11 +489,12 @@ def make_fullshard_eval_step(cfg: Config, mesh: Mesh, recorder=None) -> Callable
         )
         def sharded_pctr(tbl, fss, fsr, fsm, fso, fsf, labels):
             sq = lambda x: x[0, 0]
-            logits = _local_logits(
-                mode, tbl, sq(fss), sq(fsr), sq(fsm), sq(fso), sq(fsf),
-                labels.shape[1], cfg, D, K, nf, bf16, plus,
-            )
-            return reference_pctr(logits)[None, :]
+            with jax.named_scope("rows"):
+                logits = _local_logits(
+                    mode, tbl, sq(fss), sq(fsr), sq(fsm), sq(fso), sq(fsf),
+                    labels.shape[1], cfg, D, K, nf, bf16, plus,
+                )
+                return reference_pctr(logits)[None, :]
 
         def eval_step(tables, batch: dict):
             fsf = batch["fs_fields"] if with_fields else batch["fs_slots"]
@@ -547,14 +558,15 @@ def make_fullshard_train_step(
                    labels, row_mask):
         """Device (d, t) body: the shared forward (`_local_logits`) plus
         the loss reduction."""
-        # "gather" holds the forward: shard-local windowed gather, the
-        # occurrence all_to_all, and the row-aggregate return collectives
-        with jax.named_scope("gather"):
+        # `rows` holds the row side and the loss; inside it `_local_logits`
+        # opens `exchange` (all_to_all, merge, the aggregates' return) and
+        # `gather` (the windowed gather; transposed, the scatter), and the
+        # innermost label wins
+        with jax.named_scope("rows"):
             logits = local_logits(
                 mode, tbl_local, fs_slots, fs_row, fs_mask, fs_off, fs_fields,
                 labels.shape[0],
             )
-        with jax.named_scope("loss"):
             per_row = binary_logloss_from_logits(logits, labels)
             loss_sum = jax.lax.psum((per_row * row_mask).sum(), DATA_AXIS)
             rows_n = jax.lax.psum(row_mask.sum(), DATA_AXIS)
@@ -598,31 +610,33 @@ def make_fullshard_train_step(
             )
 
         def grad_part(table, batch: dict):
-            # "grad" covers forward+backward: the scatter (gather's
-            # transpose, staying on the owning device) lands here
-            with jax.named_scope("grad"):
-                return jax.value_and_grad(loss_for_grad, has_aux=True)(table, batch)
+            # forward and backward carry `local_loss`'s scopes; the
+            # scatter (the gather's transpose, staying on the owning
+            # device) is the `gather` scope under autodiff's `transpose(`
+            return jax.value_and_grad(loss_for_grad, has_aux=True)(table, batch)
 
         def update_part(state: TrainState, grads, loss, rows):
             metrics = {"loss": loss, "rows": rows}
-            # non-finite guard: update_ok computed from the replicated
-            # loss + the sharded gradient (the isfinite reduction GSPMDs
-            # to shard-local alls + one psum) — every rank/device sees
-            # the same flag, so the zeroed gradient stays rank-symmetric
-            safe_grads, metrics = guard_nonfinite(cfg, {tname: grads}, metrics)
-            with jax.named_scope("optimizer"):
+            with jax.named_scope("update"):
+                # non-finite guard: update_ok computed from the replicated
+                # loss + the sharded gradient (the isfinite reduction GSPMDs
+                # to shard-local alls + one psum) — every rank/device sees
+                # the same flag, so the zeroed gradient stays rank-symmetric
+                safe_grads, metrics = guard_nonfinite(cfg, {tname: grads}, metrics)
                 new_tables, new_opt = optimizer.apply(
                     {tname: state.tables[tname]}, state.opt_state, safe_grads, cfg
                 )
+                new_state = TrainState(new_tables, new_opt, state.step + 1)
             # health norms ride the same replicated-scalar contract as
             # the guard flag (shared helper, train/step.py): sharded
             # reductions + one psum, identical values on every rank
-            metrics.update(
-                health_norms(
-                    cfg, state.tables, new_tables, grads={tname: grads}
+            with jax.named_scope("health"):
+                metrics.update(
+                    health_norms(
+                        cfg, state.tables, new_tables, grads={tname: grads}
+                    )
                 )
-            )
-            return TrainState(new_tables, new_opt, state.step + 1), metrics
+            return new_state, metrics
 
         return grad_part, update_part, fullshard_batch_sharding(mesh, with_fields=with_fields)
 

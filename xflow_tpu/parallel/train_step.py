@@ -50,11 +50,12 @@ def make_sharded_train_step(
     bsh = batch_sharding(mesh)
 
     def sharded(state: TrainState, batch: dict):
-        # inner gather/grad/optimizer scopes come from make_train_step;
-        # this outer scope brackets the whole GSPMD step (incl. the
-        # compiler-inserted collectives) in an xprof trace
-        with jax.named_scope("train_step"):
-            return step(state, batch)
+        # the step's phases (gather / rows / scatter / update / health)
+        # are make_train_step's scopes; the collectives the compiler puts
+        # in come with the path of what they serve, and the compile
+        # record's phase map books every collective to `exchange`
+        # (telemetry.op_phases)
+        return step(state, batch)
 
     # the non-finite guard's update_ok flag rides in the metrics dict
     # (train/step.py metrics_keys), replicated like loss/rows

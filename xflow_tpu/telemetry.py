@@ -40,8 +40,10 @@ aggregate. This module is the first-class instrumentation layer:
   `lower_s` / `xla_compile_s`), and extracts XLA's cost analysis
   (FLOPs, bytes accessed) and memory analysis
   (argument/output/temp/generated bytes) into one stamped
-  `kind="compile"` JSONL record per program — plus the {HLO op ->
-  named_scope} map tools/trace_attrib.py joins against an xprof trace.
+  `kind="compile"` JSONL record per program — plus `op_scopes`, the
+  {operation -> label of the ONE vocabulary of step phases,
+  `PHASE_LABELS`} map that tools/trace_attrib.py and the benchmark's
+  readers join with a device trace, by program.
 - `device_memory_stats` / `hbm_window_fields`: runtime HBM gauges
   polled from the device allocator into the log-window records,
   guarded for backends that don't report (CPU).
@@ -457,27 +459,205 @@ def hbm_window_fields(registry: Optional[Registry] = None, device=None) -> dict:
 
 # ------------------------------------------------------- CompileRecorder
 
-# the named_scope labels the step builders emit (docs/OBSERVABILITY.md
-# "Capturing and reading an xprof trace window") — the buckets
-# tools/trace_attrib.py attributes device-op time to
-SCOPE_LABELS = (
-    "gather",
-    "loss",
-    "grad",
-    "optimizer",
-    "scatter_optimizer",
-    "train_step",
-    "ffm_place",
-    "ffm_pair",
+# The ONE vocabulary of step phases (docs/OBSERVABILITY.md "Step
+# phases"): every `jax.named_scope` a step builder opens is a label of
+# this tuple, (label, the phase it counts as), and means the same in
+# every engine. Six phases; three labels sit INSIDE a phase and count as
+# it, because a reader keys on them (`ffm_place` / `ffm_pair`:
+# benchmark/metrics/ffm_pair_roofline.py; `scatter_optimizer`: the
+# fused kernel's name in the trace).
+#   exchange  what a chip does to receive its work: the occurrences'
+#             all_to_all, the merge's sort, the row aggregates' return —
+#             and every collective operation, XLA's own included
+#   gather    table rows -> occurrence space, forward only
+#   rows      everything between the gathered occurrences and their
+#             cotangent: row sums, row math, the loss, their backward
+#   scatter   the gather's transpose where it is an operation of its
+#             own (never scoped: JAX writes `transpose(...)` into the
+#             path of a `gather`, and `phase_of_path` reads that)
+#   update    the gradient -> the new state: the guard's read and
+#             select, the optimizer's sweep or the fused scatter+FTRL
+#   health    `health_norms` (train.health_metrics; default off)
+# A device trace names an operation by its HLO instruction, never by
+# its scope: a compile record's `op_scopes` is the only place the two
+# meet, and tools/trace_attrib.py and benchmark/lib/phases.py join on it.
+PHASE_LABELS = (
+    ("exchange", "exchange"),
+    ("gather", "gather"),
+    ("rows", "rows"),
+    ("ffm_place", "rows"),
+    ("ffm_pair", "rows"),
+    ("scatter", "scatter"),
+    ("update", "update"),
+    ("scatter_optimizer", "update"),
+    ("health", "health"),
 )
-# labels of scopes INSIDE the differentiated row side (models/ffm.py):
-# autodiff wraps their path component — `jvp(ffm_pair)`,
-# `transpose(jvp(ffm_pair))` — and an op keeps the label through it, so
-# a kernel-free row side can be told from the `loss` / `grad` around it.
-# (The device trace itself names an XLA fusion after what it fuses, not
-# after its scope: `op_scopes` is the only place the two meet.)
-INNER_SCOPE_LABELS = ("ffm_place", "ffm_pair")
-_INNER_SCOPE = re.compile(r"(?:\w+\()*(%s)\)*" % "|".join(INNER_SCOPE_LABELS))
+_PHASE_OF = dict(PHASE_LABELS)
+# a path component is a label as the program wrote it or as autodiff
+# wrapped it: `gather`, `jvp(gather)`, `transpose(jvp(gather))`
+_COMPONENT = re.compile(r"(?:[\w.]+\()*(\w+)\)*")
+# operations that are no work of their own on a device's operations line
+_NO_OP = frozenset(
+    ("parameter", "tuple", "get-tuple-element", "bitcast", "constant",
+     "after-all", "partition-id", "replica-id")
+)
+_COLLECTIVE = re.compile(
+    r"(all-to-all|all-reduce|all-gather|reduce-scatter|collective-permute)"
+    r"(-start|-done)?$"
+)
+# what a trace shows by a name a reader would look for: kept first when
+# a record's map has to be cut to OP_SCOPES_CAP
+_NAMED_ON_A_TRACE = re.compile(r"(fusion|custom-call|copy|sort)(-start|-done)?$")
+
+
+def phase_of_path(op_name: str) -> str:
+    """The label an `op_name` path gives its operation, "" where it
+    names none. The path is `jit(step)/jit(main)/<scopes>/<primitive>`:
+    the last component is the primitive and never a label; the label is
+    the LAST scope component that is in the vocabulary, read through
+    autodiff's wrapping. One rule tells a phase's forward from its
+    transpose: where a `transpose(` stands in the path at or before the
+    label, `gather` reads `scatter` (the gather's transpose is the
+    scatter-add); every other label is its own transpose. JAX writes the
+    wrapper on the label itself under plain autodiff
+    (`transpose(jvp(rows))/gather`, `transpose(jvp(gather))`) and on the
+    component before it inside a `shard_map`
+    (`transpose(jvp())/shard_map/rows/gather`): all read the same."""
+    comps = op_name.split("/")[:-1]
+    for i in range(len(comps) - 1, -1, -1):
+        m = _COMPONENT.fullmatch(comps[i])
+        if m and m.group(1) in _PHASE_OF:
+            label = m.group(1)
+            if label == "gather" and any("transpose(" in c for c in comps[: i + 1]):
+                return "scatter"
+            return label
+    return ""
+
+
+def _operations(text: str) -> dict:
+    """{instruction -> (opcode, operand names, op_name or None)} of a
+    compiled module's text, for every computation that runs as
+    operations of its own: the entry computation and what it reaches
+    through `while` / `conditional` / `call`. Fused computations and
+    reducers are left out: their instructions never show on a device's
+    operations line."""
+    comps: dict = {}  # computation -> [(name, opcode, operands, op_name, callees)]
+    entry, current = None, None
+    for line in text.splitlines():
+        if current is None:
+            head = re.match(r"(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$", line)
+            if head and " = " not in line.split("(", 1)[0]:
+                current = comps.setdefault(head.group(2), [])
+                if head.group(1):
+                    entry = head.group(2)
+            continue
+        if line.startswith("}"):
+            current = None
+            continue
+        inst = re.match(r"\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$", line)
+        if not inst:
+            continue
+        rest = inst.group(2)
+        op = re.search(r"(?:^|[\s)])([a-z][a-z0-9\-]*)\(", rest)
+        if not op:
+            continue
+        depth, end = 0, len(rest)
+        for j in range(op.end() - 1, len(rest)):
+            depth += (rest[j] == "(") - (rest[j] == ")")
+            if depth == 0:
+                end = j
+                break
+        path = re.search(r'op_name="([^"]*)"', rest)
+        callees = re.findall(
+            r"(?:body|condition|to_apply|true_computation|false_computation)=%?([\w.\-]+)",
+            rest[end:],
+        ) if op.group(1) in ("while", "conditional", "call") else []
+        branches = re.search(r"branch_computations=\{([^}]*)\}", rest[end:])
+        if branches:
+            callees += re.findall(r"%?([\w.\-]+)", branches.group(1))
+        current.append((
+            inst.group(1), op.group(1),
+            re.findall(r"%([\w.\-]+)", rest[op.end():end]),
+            path.group(1) if path else None, callees,
+        ))
+    out: dict = {}
+    todo, seen = [entry], set()
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for name, opcode, operands, path, callees in comps[comp]:
+            out[name] = (opcode, operands, path)
+            todo.extend(callees)
+    return out
+
+
+def op_phases(text: str) -> dict:
+    """{operation -> label or ""} for every operation of a compiled
+    module that can show on a device's operations line (fusions, custom
+    calls, copies, sorts, collectives, slices, …; not parameters, tuples,
+    bitcasts, constants).
+
+    The label is the path's (`phase_of_path`), "" where the path names
+    none — the program gave that operation no phase, and a reader counts
+    it as unscoped. A collective operation is `exchange` whatever scope
+    it stands in (the compiler's own, in a GSPMD step, come with the
+    path of what they serve). An operation WITHOUT a path of its own — a
+    layout `copy` XLA put in, the halves of an async pair, the name of a
+    parameter where a path would be — takes the label its consumers
+    agree on, else the one its producers agree on, else "". A fusion
+    that holds operations of two phases is booked to its root's: the
+    path on the fusion instruction is its root's."""
+    ops = _operations(text)
+    label: dict = {}
+    pathless = []
+    for name, (opcode, _, path) in ops.items():
+        if opcode in _NO_OP:
+            continue
+        if _COLLECTIVE.match(opcode):
+            label[name] = "exchange"
+        elif path and "/" in path:
+            label[name] = phase_of_path(path)
+        else:
+            pathless.append(name)
+    consumers: dict = {}
+    for name, (_, operands, _) in ops.items():
+        for src in operands:
+            consumers.setdefault(src, []).append(name)
+    # through views (a bitcast, a tuple element) to the operations behind
+    def behind(names, step):
+        found, todo, seen = set(), list(names), set()
+        while todo:
+            n = todo.pop()
+            if n in seen or n not in ops:
+                continue
+            seen.add(n)
+            if ops[n][0] in ("bitcast", "get-tuple-element", "tuple"):
+                todo.extend(step(n))
+            else:
+                found.add(n)
+        return found
+
+    uses = lambda n: consumers.get(n, [])
+    feeds = lambda n: ops[n][1]
+    todo = pathless
+    while todo:
+        left = []
+        for name in todo:
+            for side in (behind(uses(name), uses), behind(feeds(name), feeds)):
+                known = {label[n] for n in side if label.get(n)}
+                if len(known) == 1:
+                    label[name] = known.pop()
+                    break
+            else:
+                left.append(name)
+        if len(left) == len(todo):
+            break
+        todo = left
+    for name in todo:
+        label[name] = ""
+    return {name: label[name] for name in ops if name in label}  # in the text's order
 
 
 class CompileRecorder:
@@ -492,10 +672,11 @@ class CompileRecorder:
     - `cost_analysis()`: model FLOPs and bytes accessed per execution,
     - `memory_analysis()`: argument/output/temp/generated code bytes
       (the compile-time HBM footprint),
-    - `op_scopes`: {optimized-HLO op name -> named_scope label} scraped
-      from the compiled module's metadata, which tools/trace_attrib.py
-      joins against an xprof trace's per-op events to bucket device
-      time by step phase
+    - `op_scopes`: {optimized-HLO operation -> label of `PHASE_LABELS`,
+      or "" where the program names none} for every operation a device
+      trace can show (`op_phases`), which tools/trace_attrib.py and the
+      benchmark's readers join with a trace's events, by program, to
+      give device time by step phase
 
     into ONE `kind="compile"` JSONL record through `sink` (anything
     with .append(dict) — the trainer's MetricsLogger, so records land
@@ -511,16 +692,17 @@ class CompileRecorder:
     the error of the run, not a note on stderr followed by a second,
     unrecorded compile under plain jit. Only the cost/memory/op-scope
     ANALYSIS of a program that did compile stays soft (a backend
-    without it leaves those fields out).
+    without it leaves those fields out); it is timed (`analysis_s`, the
+    `xflow:analyze` span).
     """
 
-    OP_SCOPES_CAP = 2048  # entries per record: bounds the JSONL line
+    # entries per record: bounds the JSONL line. A record that hit it
+    # says how many it left out (`op_scopes_dropped`)
+    OP_SCOPES_CAP = 2048
 
-    def __init__(self, sink=None, registry: Optional[Registry] = None,
-                 scopes: Iterable = SCOPE_LABELS):
+    def __init__(self, sink=None, registry: Optional[Registry] = None):
         self._sink = sink
         self._reg = registry or default_registry()
-        self._scopes = tuple(scopes)
         self._lock = threading.Lock()
         self._compiled: dict = {}  # (name, sig) -> compile count
         self._latest: dict = {}  # name -> latest record
@@ -622,40 +804,35 @@ class CompileRecorder:
         return out
 
     def _op_scopes(self, compiled) -> tuple:
-        """(HLO module name or None, {optimized-HLO op name ->
-        named_scope label}, Pallas kernel count) from the compiled
-        module's text. The kernel count is the number of Mosaic custom
-        calls the program really contains — the proof that a step runs
-        the Pallas kernels and not their XLA stand-ins. The
-        op_name path looks like `jit(step)/jit(main)/grad/gather`: the
-        final component is the primitive, the scope is the LAST path
-        component matching a known label — so the backward's scatter
-        attributes to `grad`, exactly as docs/OBSERVABILITY.md
-        describes the trace reading; an `INNER_SCOPE_LABELS` label is
-        matched through autodiff's wrapping. The module name keys the map:
-        op names are only unique WITHIN one compiled module, and
-        tools/trace_attrib.py joins trace events per `hlo_module` so
-        two programs' `fusion.1` never collide."""
+        """(HLO module name or None, {operation -> label or ""}, entries
+        left out at the cap, Pallas kernel count) from the compiled
+        module's text. The map is `op_phases`'s, cut to `OP_SCOPES_CAP`
+        entries with the ones a trace shows by a reader's name (fusions,
+        custom calls, copies, sorts, collectives) kept first. The kernel
+        count is the number of Mosaic custom calls the program really
+        contains — the proof that a step runs the Pallas kernels and not
+        their XLA stand-ins. The module name keys the map: instruction
+        names are unique WITHIN one compiled module only, and a trace's
+        events are joined per module (the device plane's `XLA Modules`
+        line) so two programs' `fusion.1` never collide."""
         try:
             text = compiled.as_text()
         except Exception:
-            return None, {}, 0
+            return None, {}, 0, 0
         mod = re.match(r"\s*HloModule\s+([\w.\-]+)", text)
-        module = mod.group(1) if mod else None
-        scopes = set(self._scopes)
-        out: dict = {}
-        for m in re.finditer(r'%?([\w.\-]+)\s*=\s*[^\n]*op_name="([^"]+)"', text):
-            comps = m.group(2).split("/")
-            for comp in reversed(comps[:-1]):
-                inner = _INNER_SCOPE.fullmatch(comp)
-                if inner and inner.group(1) in scopes:
-                    comp = inner.group(1)
-                if comp in scopes:
-                    out[m.group(1)] = comp
-                    break
-            if len(out) >= self.OP_SCOPES_CAP:
-                break
-        return module, out, text.count('custom_call_target="tpu_custom_call"')
+        phases = op_phases(text)
+        dropped = max(0, len(phases) - self.OP_SCOPES_CAP)
+        if dropped:
+            ops = _operations(text)
+            named = lambda n: not (
+                _NAMED_ON_A_TRACE.match(ops[n][0]) or _COLLECTIVE.match(ops[n][0])
+            )
+            keep = sorted(phases, key=named)[: self.OP_SCOPES_CAP]  # stable: text order
+            phases = {n: phases[n] for n in keep}
+        return (
+            mod.group(1) if mod else None, phases, dropped,
+            text.count('custom_call_target="tpu_custom_call"'),
+        )
 
     # ---------------------------------------------------------- recording
     def record(self, name: str, jitted, *args, static_fields=None, **kwargs):
@@ -700,7 +877,12 @@ class CompileRecorder:
         }
         if getattr(jitted, "persistent_cache", True) is False:
             rec["persistent_cache"] = False  # compiled past it, by the builder's wrapper
-        rec.update(self._cost_fields(compiled))
+        # what this record costs beyond the compile: XLA's cost and
+        # memory analysis, the module's text and the phase map
+        with span("analyze") as analyzing:
+            rec.update(self._cost_fields(compiled))
+            module, scopes, dropped, rec["pallas_calls"] = self._op_scopes(compiled)
+        rec["analysis_s"] = round(analyzing.seconds, 6)
         with self._lock:
             seen = self._compiled.get((name, sig), 0)
             self._compiled[(name, sig)] = seen + 1
@@ -712,12 +894,13 @@ class CompileRecorder:
         else:
             self._reg.counter("compile.programs").inc()
         self._reg.timer("compile.time").observe(dt)
-        module, scopes, rec["pallas_calls"] = self._op_scopes(compiled)
         rec.update(static_fields or {})
         if module:
             rec["hlo_module"] = module
         if scopes:
             rec["op_scopes"] = scopes
+        if dropped:
+            rec["op_scopes_dropped"] = dropped
         with self._lock:
             self.records.append(rec)
             self._latest[name] = rec
@@ -986,7 +1169,7 @@ HOST_SPANS_FIT = (
 HOST_SPANS_PREFETCH = (
     "parse", "cache_read", "plan", "producer_wait", "read_ahead", "ffm_place",
 )
-HOST_SPANS_INIT = ("init_state", "place_state", "lower", "compile")
+HOST_SPANS_INIT = ("init_state", "place_state", "lower", "compile", "analyze")
 HOST_SPANS = HOST_SPANS_FIT + HOST_SPANS_PREFETCH + HOST_SPANS_INIT
 _SPAN_NAMES = {s: "xflow:" + s for s in HOST_SPANS}
 

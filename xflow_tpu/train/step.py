@@ -55,12 +55,15 @@ def masked_mean_logloss(logits, labels, row_mask):
 
 
 def loss_fn(tables, batch, model: Model, cfg: Config):
-    # named scopes label the xprof trace (docs/OBSERVABILITY.md): the
-    # forward holds the table gather; autodiff transposes it into the
-    # scatter, which lands under the enclosing "grad" scope
-    with jax.named_scope("gather"):
+    # the step's phases (telemetry.PHASE_LABELS, docs/OBSERVABILITY.md
+    # "Step phases"): `rows` holds the model's forward and the loss
+    # reduction, and with them their backward; the table lookups inside
+    # the forward open `gather` scopes of their own (ops/sorted_table.py
+    # `batch_rows`, `sorted_gather_map`) and the innermost label wins.
+    # Autodiff transposes a `gather` into the scatter-add and writes
+    # `transpose(...)` into its path: that is the `scatter` phase
+    with jax.named_scope("rows"):
         logits = model.forward(tables, batch, cfg)
-    with jax.named_scope("loss"):
         return masked_mean_logloss(logits, batch["labels"], batch["row_mask"])
 
 
@@ -287,36 +290,42 @@ def _fused_sorted_step(state: TrainState, batch: dict, cfg: Config):
             )
         return masked_mean_logloss(logits, batch["labels"], batch["row_mask"])
 
-    with jax.named_scope("loss"):
+    # `rows`: the gathered occurrences -> the occurrence cotangent (row
+    # sums, row math, loss, their backward); no `scatter` phase here:
+    # the gather's transpose is the fused kernel's first half
+    with jax.named_scope("rows"):
         loss, vjp = jax.vjp(row_loss, occ_t)
-    with jax.named_scope("grad"):
         (d_occ,) = vjp(jnp.ones_like(loss))
-    metrics = {"loss": loss, "rows": batch["row_mask"].sum()}
-    # the guard decides on the cotangent the kernel is about to receive
-    # ([K8, Np], batch-sized): zeroed on a bad step, the window write
-    # leaves every slot as it leaves an untouched one
-    g_occ, metrics = guard_nonfinite(cfg, d_occ, metrics)
+        metrics = {"loss": loss, "rows": batch["row_mask"].sum()}
     st = state.opt_state[tname]
-    # the fused kernel IS scatter + optimizer in one window write
-    with jax.named_scope("scatter_optimizer"):
-        w_new, n_new, z_new = scatter_ftrl_sorted(
-            g_occ, batch["sorted_slots"], batch["win_off"], table, st["n"], st["z"],
-            K, cfg.optim.ftrl, cfg.data.sorted_bf16, pack,
+    with jax.named_scope("update"):
+        # the guard decides on the cotangent the kernel is about to
+        # receive ([K8, Np], batch-sized): zeroed on a bad step, the
+        # window write leaves every slot as it leaves an untouched one
+        g_occ, metrics = guard_nonfinite(cfg, d_occ, metrics)
+        # the fused kernel IS scatter + optimizer in one window write; it
+        # keeps a label of its own inside `update`, the name the trace
+        # and the kernel's roofline readers know it by
+        with jax.named_scope("scatter_optimizer"):
+            w_new, n_new, z_new = scatter_ftrl_sorted(
+                g_occ, batch["sorted_slots"], batch["win_off"], table, st["n"], st["z"],
+                K, cfg.optim.ftrl, cfg.data.sorted_bf16, pack,
+            )
+        new_state = TrainState(
+            {tname: w_new}, {tname: {"n": n_new, "z": z_new}}, state.step + 1
         )
-    new_state = TrainState(
-        {tname: w_new}, {tname: {"n": n_new, "z": z_new}}, state.step + 1
-    )
     # the table gradient never materializes on this path (that is the
     # point of the fusion) — the occurrence-space cotangent's norm, taken
     # before the guard zeroes it, stands in for the grad norm (equal when
     # the batch's occurrences hit distinct slots; a divergence signal
     # either way). update/param norms keep the pre-step table live.
-    metrics.update(
-        health_norms(
-            cfg, state.tables, new_state.tables,
-            grad_sq={tname: (d_occ.astype(jnp.float32) ** 2).sum()},
+    with jax.named_scope("health"):
+        metrics.update(
+            health_norms(
+                cfg, state.tables, new_state.tables,
+                grad_sq={tname: (d_occ.astype(jnp.float32) ** 2).sum()},
+            )
         )
-    )
     return new_state, metrics
 
 
@@ -368,18 +377,22 @@ def make_train_step(model: Model, optimizer: Optimizer, cfg: Config, jit: bool =
                 "cannot run; use auto to allow the two-pass form on "
                 "such batches"
             )
-        # "grad" wraps forward+backward: the backward's table scatter
-        # (the gather's transpose) shows up here in an xprof trace
-        with jax.named_scope("grad"):
-            loss, grads = jax.value_and_grad(loss_fn)(state.tables, batch, model, cfg)
-        metrics = {"loss": loss, "rows": batch["row_mask"].sum()}
-        safe_grads, metrics = guard_nonfinite(cfg, grads, metrics)
-        with jax.named_scope("optimizer"):
+        # forward and backward carry `loss_fn`'s scopes: `gather`,
+        # `rows`, and the gather's transpose (`scatter`)
+        loss, grads = jax.value_and_grad(loss_fn)(state.tables, batch, model, cfg)
+        with jax.named_scope("rows"):
+            metrics = {"loss": loss, "rows": batch["row_mask"].sum()}
+        # `update`: the gradient -> the new state, guard included (XLA
+        # fuses its select into the optimizer's sweep)
+        with jax.named_scope("update"):
+            safe_grads, metrics = guard_nonfinite(cfg, grads, metrics)
             new_tables, new_opt = optimizer.apply(
                 state.tables, state.opt_state, safe_grads, cfg
             )
-        metrics.update(health_norms(cfg, state.tables, new_tables, grads=grads))
-        return TrainState(new_tables, new_opt, state.step + 1), metrics
+            new_state = TrainState(new_tables, new_opt, state.step + 1)
+        with jax.named_scope("health"):
+            metrics.update(health_norms(cfg, state.tables, new_tables, grads=grads))
+        return new_state, metrics
 
     if jit:
         # donate the state: tables and optimizer state update in place in HBM
