@@ -171,6 +171,33 @@ def test_fullshard_higher_slack_absorbs_skew():
     assert total == float(b["mask"].sum())
 
 
+def test_fullshard_chunk_counts_are_the_merged_streams():
+    """The counters a fullshard plan books are those of the stream each
+    chip walks after `merge_received`: the sum of its sources' offsets,
+    pads included, one chain a chip."""
+    from xflow_tpu.ops.sorted_table import chunk_chain_counts
+    from xflow_tpu.parallel.sorted_fullshard import fullshard_chunk_counts, merge_received
+
+    cfg = cfg_for("fm", 4, 2)
+    mesh = make_mesh(cfg)
+    arrays = plan_fullshard_batch(*(rand_batch(np.random.default_rng(5))[k] for k in ("slots", "mask")), cfg, mesh)
+    fs_off = arrays["fs_off"]  # [sources, T, D, wpo + 1]
+    cap = arrays["fs_slots"].shape[-1]
+    got = fullshard_chunk_counts(fs_off)
+    chips = []
+    for t in range(fs_off.shape[1]):
+        for d in range(fs_off.shape[2]):
+            _, off, _ = merge_received(
+                jnp.asarray(arrays["fs_slots"][:, t, d]), jnp.asarray(fs_off[:, t, d]),
+                jnp.zeros((fs_off.shape[0], cap), jnp.int32),
+            )
+            chips.append(chunk_chain_counts(np.asarray(off)))
+    # every chip's stream is its four sources' buffers end to end
+    assert all(c["chunk_loads"] == fs_off.shape[0] * cap // 512 for c in chips)
+    assert got == {k: round(sum(c[k] for c in chips) / len(chips)) for k in chips[0]}
+    assert got["chunk_visits"] >= got["chunk_loads"]
+
+
 def test_fullshard_validation_messages():
     mesh = make_mesh(cfg_for("fm", 4, 2))
     with pytest.raises(ValueError, match="divisible by data\\*table\\*WINDOW"):

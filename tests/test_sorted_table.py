@@ -100,40 +100,152 @@ def test_scatter_vjp_matches_xla_scatter():
     np.testing.assert_allclose(np.asarray(d_table), want, rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("seed", [3, 4])
-def test_pallas_interpret_matches_xla(seed):
-    # the TPU kernels, run in interpreter mode, must equal the XLA path
+def _plan_stream(seed, n_win, window):
+    # a batch through the planner, as the step gets it (seeds 3 and 4:
+    # the two windows and ~1.3 chunks the test has always had)
+    rng = np.random.default_rng(seed)
+    S = n_win * window
+    slots = rng.integers(0, S, (24, 11)).astype(np.int32)
+    mask = (rng.random((24, 11)) < 0.7).astype(np.float32)
+    plan = plan_sorted_batch(slots, mask, S, window=window)
+    return plan.sorted_slots, plan.win_off
+
+
+def _counted_stream(counts, tail=0, head=0):
+    """A slot-sorted stream with counts[t] occurrences in window t, its
+    offsets, and pads that repeat the last slot (so windows past it are
+    empty; the planner's pads, slot S-1, fill the last window instead).
+    `tail` positions at the end are owned by no window and `head`
+    positions at the start: a stream that begins or ends inside a
+    chunk."""
+    def make(seed, n_win, window):
+        assert len(counts) == n_win
+        rng = np.random.default_rng(seed)
+        real = np.concatenate([
+            np.sort(rng.integers(t * window, (t + 1) * window, c))
+            for t, c in enumerate(counts)
+        ]).astype(np.int32)
+        n_pos = (real.size // CHUNK + 2) * CHUNK
+        ss = np.concatenate([real, np.full(n_pos - real.size, real[-1], np.int32)])
+        off = np.searchsorted(ss, np.arange(0, (n_win + 1) * window, window))
+        off = np.clip(off, head, n_pos - tail).astype(np.int32)
+        return ss, off
+
+    return make
+
+
+def _wrapped_stream(seed, n_win, window):
+    # D = 2 buffers over ONE table, concatenated: the grid has 2 * n_tw
+    # steps and step t owns table window t % n_tw; the offsets stay
+    # monotone over the whole grid
+    a, off_a = _counted_stream([100] * n_win)(seed, n_win, window)
+    b, off_b = _counted_stream([0, 0] + [150] * (n_win - 2))(seed + 1, n_win, window)
+    return np.concatenate([a, b]), np.concatenate([off_a[:-1], off_a[-1] + off_b])
+
+
+# name -> (windows of the table, stream maker, what the stream is).
+# The carried chunk chain (ops/sorted_table.py `_gather_span`) must
+# survive each: the scratch, the SMEM carry and the DMA semaphores live
+# from one grid step to the next
+_CHAIN_STREAMS = {
+    "seed3": (2, lambda s, n, w: _plan_stream(3, n, w)),
+    "seed4": (2, lambda s, n, w: _plan_stream(4, n, w)),
+    # the benchmark cells' regime: a window is a quarter of a chunk
+    "quarter_chunk": (16, _counted_stream([128] * 16)),
+    # empty windows at the start, in the middle and at the end
+    "empty_windows": (
+        16, _counted_stream([0, 0, 100, 130, 0, 0, 0, 90, 700, 0, 128, 128, 5, 0, 0, 0])
+    ),
+    "span_of_chunks": (16, _counted_stream([30, 1500, 0, 40] + [100] * 12)),
+    # 1024 occurrences: the last real one ends a chunk, two pad chunks follow
+    "chunk_edge": (16, _counted_stream([64] * 16)),
+    # the line starts and ends inside a chunk (no planner makes this):
+    # the close writes a partial chunk
+    "inside_chunk": (16, _counted_stream([128] * 16, tail=CHUNK + 200, head=70)),
+    "grid_wraps": (16, _wrapped_stream),
+}
+
+
+# the scatters take one buffer over the table with every position owned
+_GATHER_ONLY = ("inside_chunk", "grid_wraps")
+
+
+@pytest.mark.parametrize("pack", [1, 8])
+@pytest.mark.parametrize(
+    "kernel,stream",
+    [
+        (kernel, stream)
+        for kernel in ("gather", "scatter", "scatter_ftrl")
+        for stream in _CHAIN_STREAMS
+        if kernel == "gather" or stream not in _GATHER_ONLY
+    ],
+)
+def test_pallas_interpret_matches_xla(kernel, stream, pack):
+    """The single-stream TPU kernels, run in interpreter mode, equal the
+    XLA path on every shape of stream their carried chunk chain meets.
+    The fused scatter+FTRL is held to `_scatter_xla` followed by
+    `optim/ftrl._update_one`, the composition it replaces."""
     pltpu = pytest.importorskip("jax.experimental.pallas.tpu")
+
+    from xflow_tpu.config import FTRLConfig
+    from xflow_tpu.ops.sorted_table import (
+        _scatter_ftrl_pallas,
+        pack_table,
+        state_window,
+    )
+    from xflow_tpu.optim.ftrl import _update_one
 
     if not hasattr(pltpu, "force_tpu_interpret_mode"):
         pytest.skip("pallas TPU interpret mode unavailable in this jax build")
-    rng = np.random.default_rng(seed)
-    slots, mask, table = _random_case(rng, B=24, F=11)
-    plan = plan_sorted_batch(slots, mask, S)
-    n = slots.size
-    np_len = plan.sorted_slots.shape[0]
-    jt = jnp.asarray(table)
-    jss = jnp.asarray(plan.sorted_slots)
-    joff = jnp.asarray(plan.win_off)
-    with pltpu.force_tpu_interpret_mode():
-        occ_p = _gather_pallas(jt, jss, joff)
-    occ_x = _gather_xla(jt, jss, joff)
+    n_tw, make = _CHAIN_STREAMS[stream]
+    window = state_window(K, pack)
+    size = n_tw * window
+    ss, off = make(11, n_tw, window)
+    owned = slice(int(off[0]), int(off[-1]))
+    rng = np.random.default_rng(5)
+    table = rng.normal(size=(size, K)).astype(np.float32)
+    jt = jnp.asarray(pack_table(table) if pack > 1 else table)
+    jss, joff = jnp.asarray(ss), jnp.asarray(off)
     # rtol 5e-5, not exact: the kernels' 3-term bf16 decomposition
     # (_dot_f32) reconstructs f32 bit-exactly on the real MXU
     # (verified on-device against the XLA gather), but the INTERPRETER's
     # bf16 rounding emulation can drop the low term's last ulp on rare
     # elements (~2^-16 relative). This test gates the structural parity
-    # (windows, blend, offsets), not MXU arithmetic.
-    np.testing.assert_allclose(
-        np.asarray(occ_p[:K, :n]), np.asarray(occ_x[:K, :n]), rtol=5e-5
-    )
-
-    d_t = jnp.asarray(rng.normal(size=(K8, np_len)).astype(np.float32))
+    # (windows, chain, offsets), not MXU arithmetic.
+    if kernel == "gather":
+        with pltpu.force_tpu_interpret_mode():
+            occ_p = np.asarray(_gather_pallas(jt, jss, joff, False, pack))
+        occ_x = np.asarray(_gather_xla(jt, jss, joff, pack))
+        np.testing.assert_allclose(occ_p[:K, owned], occ_x[:K, owned], rtol=5e-5)
+        np.testing.assert_array_equal(occ_p[K:, owned], 0.0)
+        # a column of a visited chunk that no window owns holds its
+        # slot's row where that slot's window visited the chunk, else
+        # zeros — never what the buffer held before (chunks past the
+        # stream's end are not written at all)
+        first, last = owned.start // CHUNK * CHUNK, -(-owned.stop // CHUNK) * CHUNK
+        for cols in (slice(first, owned.start), slice(owned.stop, last)):
+            got, row = occ_p[:K, cols], occ_x[:K, cols]
+            assert np.all((got == 0.0).all(axis=0) | np.isclose(got, row, rtol=5e-5).all(axis=0))
+        return
+    d_t = jnp.asarray(rng.normal(size=(K8, ss.size)).astype(np.float32))
+    g_x = _scatter_xla(d_t, jss, joff, size, K, pack)
+    if kernel == "scatter":
+        with pltpu.force_tpu_interpret_mode():
+            g_p = _scatter_pallas(d_t, jss, joff, size, K, False, pack)
+        # same interpreter-emulation tolerance as the gather above
+        np.testing.assert_allclose(np.asarray(g_p), np.asarray(g_x), rtol=5e-5, atol=2e-5)
+        return
+    hp = FTRLConfig()
+    n0 = jnp.abs(jnp.asarray(rng.normal(size=jt.shape).astype(np.float32)))
+    z0 = jnp.asarray(rng.normal(size=jt.shape).astype(np.float32))
     with pltpu.force_tpu_interpret_mode():
-        dt_p = _scatter_pallas(d_t, jss, joff, S, K)
-    dt_x = _scatter_xla(d_t, jss, joff, S, K)
-    # same interpreter-emulation tolerance as the gather above
-    np.testing.assert_allclose(np.asarray(dt_p), np.asarray(dt_x), rtol=5e-5, atol=2e-5)
+        got = _scatter_ftrl_pallas(d_t, jss, joff, jt, n0, z0, K, hp, False, pack)
+    want = _update_one(jt, n0, z0, g_x, hp.alpha, hp.beta, hp.lambda1, hp.lambda2)
+    for leaf, g, w in zip("wnz", got, want):
+        # the gradient's emulation noise, through FTRL's square and divide
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-5, err_msg=leaf
+        )
 
 
 def test_rowsum_pallas_interpret_matches_xla():
@@ -251,6 +363,79 @@ def test_trainer_sorted_layout_matches_off(tmp_path, model_name, table):
     auc_on, _ = t_on.evaluate()
     auc_off, _ = t_off.evaluate()
     assert auc_on == pytest.approx(auc_off, abs=1e-6)
+
+
+def test_chunk_chain_counts():
+    """`chunk_visits` / `chunk_loads` from the offsets alone: a window
+    visits every chunk its span overlaps, an empty window none; a flat
+    plan's chunks are loaded once each, a stacked plan's spans load what
+    they visit."""
+    from xflow_tpu.ops.sorted_table import chunk_chain_counts, plan_sorted_stacked
+
+    C = CHUNK
+    # four windows a chunk, then an empty one, then a span of three chunks
+    off = np.array([0, 128, 256, 384, 512, 512, 512 + 2 * C + 1, 4 * C])
+    assert chunk_chain_counts(off) == {"chunk_visits": 4 + 0 + 3 + 1, "chunk_loads": 4}
+    assert chunk_chain_counts(np.zeros(5, np.int32)) == {"chunk_visits": 0, "chunk_loads": 0}
+    # a line that starts and ends inside a chunk
+    assert chunk_chain_counts(np.array([70, 600, 700])) == {"chunk_visits": 3, "chunk_loads": 2}
+    # the benchmark's regime through the planner: every window a piece of a chunk
+    rng = np.random.default_rng(0)
+    S = 64 * WINDOW
+    slots = rng.integers(0, S, (512, 16)).astype(np.int32)
+    mask = np.ones(slots.shape, np.float32)
+    plan = plan_sorted_batch(slots, mask, S)
+    got = chunk_chain_counts(plan.win_off)
+    assert got["chunk_loads"] == plan.sorted_slots.size // C  # the two pad chunks too
+    assert 64 <= got["chunk_visits"] <= 64 + got["chunk_loads"]
+    stacked = plan_sorted_stacked(slots, mask, S, num_sub=4)
+    both = chunk_chain_counts(stacked.win_off)
+    assert both["chunk_loads"] == both["chunk_visits"] == sum(
+        chunk_chain_counts(o)["chunk_visits"] for o in stacked.win_off
+    )
+
+
+def test_fit_books_the_kernels_chunk_counts(tmp_path):
+    """An armed sorted-engine run: every step record's `host` holds its
+    batch's `chunk_visits` / `chunk_loads`, and the final record this
+    fit()'s sums (the same on `TrainResult`); a row-major run has
+    neither."""
+    import json
+
+    from xflow_tpu.data.synth import generate_shards
+    from xflow_tpu.ops.sorted_table import chunk_chain_counts
+    from xflow_tpu.train.trainer import Trainer
+
+    generate_shards(str(tmp_path / "train"), 1, 256, 8, 50, seed=1)
+    base = {
+        "model.name": "fm", "data.log2_slots": 14, "data.batch_size": 64,
+        "data.train_path": str(tmp_path / "train"), "data.max_nnz": 8,
+        "model.num_fields": 8, "train.epochs": 1, "train.pred_dump": False,
+        "train.log_every": 1,
+    }
+
+    def run(layout):
+        path = tmp_path / f"m_{layout}.jsonl"
+        trainer = Trainer(override(Config(), **{
+            **base, "data.sorted_layout": layout, "train.metrics_path": str(path),
+        }))
+        res = trainer.fit()
+        recs = [json.loads(line) for line in open(path)]
+        return trainer, res, recs
+
+    trainer, res, recs = run("on")
+    assert trainer.engine == "sorted" and res.steps == 4
+    hosts = [r["host"] for r in recs if "host" in r and "kind" not in r]
+    # a batch of 64 x 8 occurrences: one chunk of data and the plan's two
+    # of pads, booked with the batch (a window can hold two, or none)
+    assert all(h.get("chunk_loads", 0) == 3 * h["batches"] for h in hosts)
+    assert all(h["chunk_visits"] >= h["chunk_loads"] for h in hosts if h["batches"])
+    final = [r for r in recs if r.get("final")][-1]
+    assert final["chunk_loads"] == res.chunk_loads == 4 * 3
+    assert final["chunk_visits"] == res.chunk_visits == sum(h.get("chunk_visits", 0) for h in hosts)
+    trainer, res, recs = run("off")
+    assert trainer.engine == "row_major" and res.chunk_visits == 0
+    assert not any("chunk_visits" in r.get("host", {}) or "chunk_visits" in r for r in recs)
 
 
 def test_mvm_sorted_forward_and_step_match_rowmajor():

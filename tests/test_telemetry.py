@@ -808,6 +808,8 @@ def test_metrics_report_check_admits_and_gates_the_host_timeline(tmp_path):
 
     assert set(metrics_report.HOST_KEYS) == (
         {host_field(s) for s in HOST_STAGES} | {"batches"})
+    assert metrics_report.HOST_OPTIONAL_KEYS == (
+        {host_field(s) for s in telemetry.HOST_NESTED_STAGES} | set(telemetry.HOST_COUNTERS))
     stamp = {"ts": 1.0, "rank": 0, "run_id": "r", "gen": 0}
     window = {"steps_per_s": 1.0, "rows_per_s": 64.0, "step_time_p50_ms": 9.0,
               "step_time_p99_ms": 9.0, "data_wait_ms": 1.0, "dispatch_ms": 1.0,
@@ -826,6 +828,8 @@ def test_metrics_report_check_admits_and_gates_the_host_timeline(tmp_path):
     good = check(
         {"step": 1, **window, "host": host, "boundary": opened},
         {"step": 2, **window, "host": host, "boundary": {**opened, **tail}},
+        # a sorted engine's plan: the kernels' chunk counts ride beside the stages
+        {"step": 3, **window, "host": {**host, "chunk_visits": 20444, "chunk_loads": 4098}},
         {**comp, "lower_s": 0.2, "xla_compile_s": 0.1},
     )
     assert good.returncode == 0, good.stderr

@@ -82,6 +82,10 @@ HOST_KEYS = (
     "plan_ms", "producer_wait_ms", "data_wait_ms", "transfer_ms",
     "dispatch_call_ms", "prev_ready_ms", "loop_other_ms", "batches",
 )
+# what a `host` holds besides, only where it ran or was counted: the
+# stages nested in another (telemetry.HOST_NESTED_STAGES) and the
+# planner's counts (telemetry.HOST_COUNTERS)
+HOST_OPTIONAL_KEYS = {"ffm_place_ms", "chunk_visits", "chunk_loads"}
 BOUNDARY_OPEN_KEYS = ("fit_open_ms", "first_batch_ms", "first_dispatch_ms")
 BOUNDARY_TAIL_KEYS = ("fit_tail_ms", "occupancy_ms", "close_ms", "between_fits_ms")
 BOUNDARY_FLAGS = {"adopted"}  # a bool beside the milliseconds; older writers have none
@@ -733,7 +737,8 @@ def check_streams(streams: dict, files: list[str]) -> list[str]:
                     continue
                 got = rec[group]
                 if not isinstance(got, dict) or not any(
-                    set(got) - BOUNDARY_FLAGS == set(keys) for keys in want
+                    set(got) - BOUNDARY_FLAGS - HOST_OPTIONAL_KEYS == set(keys)
+                    for keys in want
                 ):
                     problems.append(
                         f"{tag}: record {i} has a {group} that is not one "

@@ -27,8 +27,13 @@ here the sort becomes the *device layout*):
 
 Chunks are CHUNK-aligned (Mosaic requires aligned DMA offsets), so a
 window's chunk range may include occurrences of neighboring windows;
-the in-window test masks them in compute (scatter) or blends them back
-from the existing output (gather) — no explicit tail masking needed.
+the in-window test masks them in compute (scatter) or leaves their
+columns of the chunk's staging buffer to the window that owns them
+(gather) — no explicit tail masking needed. A window of a large table
+holds a fraction of a chunk, so the single-stream kernels carry their
+chunk chain from grid step to grid step: a chunk is loaded once a call
+and the next ones are in flight while this one is computed on
+(`_gather_span`).
 
 Two implementations with identical semantics:
 - Pallas TPU kernels (grid over windows; MXU does the heavy lifting);
@@ -451,6 +456,27 @@ def plan_sorted_batch(
     )
 
 
+def chunk_chain_counts(win_off) -> dict:
+    """What one call of the sorted kernels does with a plan, from its
+    offsets alone: `chunk_visits`, the (window, chunk) pairs a kernel
+    computes on — every one a CHUNK-wide one-hot pass however few of the
+    chunk's occurrences the window owns — and `chunk_loads`, the chunks
+    it asks HBM for. A flat plan is one line and the single-stream
+    kernels carry their chunk chain along it (`_gather_span`): a chunk
+    is loaded once. Stacked plans ([NS, windows + 1]) go through the
+    multi-buffer kernels, where every span is a chain of its own and
+    loads what it visits. The step records' `host.chunk_visits` /
+    `host.chunk_loads` (docs/OBSERVABILITY.md)."""
+    off = np.asarray(win_off, np.int64)
+    start, end = off[..., :-1], off[..., 1:]
+    visits = int(np.where(end > start, (end - 1) // CHUNK - start // CHUNK + 1, 0).sum())
+    if off.ndim > 1:
+        return {"chunk_visits": visits, "chunk_loads": visits}
+    lo, hi = int(off[0]), int(off[-1])
+    loads = (hi - 1) // CHUNK - lo // CHUNK + 1 if hi > lo else 0
+    return {"chunk_visits": visits, "chunk_loads": loads}
+
+
 def map_host_parallel(fn, n: int) -> list:
     """Run fn(0..n-1) on the shared planning pool when the C planner is
     built (it releases the GIL during the sort, so plans parallelize
@@ -724,64 +750,103 @@ def _windowed_select(table_block, rel, pack: int, bf16: bool):
     return occ
 
 
-def _gather_span(slots_ref, out_ref, table_ref, slc, old, sem_s, sem_d, sem_o,
-                 base, start, end, bf16, pack):
-    """NB-deep pipelined windowed gather of ONE occurrence span [start,
-    end) against the table block at `base` (NB = the scratch buffer
-    count, `PIPE_NB`): the chunk chain is DMA-LATENCY bound, not
-    bandwidth bound (~460 MB of traffic measured ~18 ms serialized =
-    ~4 us/chunk of waits), so inputs for chunk c+NB-1 prefetch during
-    compute of c and the output copy of c drains while later chunks
-    run. Buffer sel = c % NB; `old[sel]` is both the blend source and
-    the out staging, so its input copy for c+NB-1 waits the out copy of
-    c-1 (same buffer). The epilogue drains the min(n, NB) out copies
-    still in flight (one per buffer); spans run sequentially (grid
-    steps / the multi kernel's buffer loop), so the next span (whose
-    aligned chunk range can overlap this one's) never races these
-    writes. Shared by the single-stream and multi-buffer gather
-    kernels — a fix here fixes both."""
+def _open_chain(cur, first, last, when, start_in, nb):
+    """Open a chunk chain over chunks first..last (where `when`): no
+    chunk entered yet, the first nb-1 asked for."""
+    from jax.experimental import pallas as pl
+
+    @pl.when(when)
+    def _():
+        cur[0] = first - 1
+        for i in range(nb - 1):
+            @pl.when(first + i <= last)
+            def _(i=i):
+                start_in(first + i)
+
+
+def _gather_span(slots_ref, out_ref, table_ref, slc, stage, cur, sem_s, sem_o, sem_d,
+                 base, start, end, bf16, pack, stream=None):
+    """Windowed gather of ONE occurrence span [start, end) against the
+    table block at `base`, as one link of a CHUNK CHAIN that outlives
+    the span. A chunk's ring buffer is chosen by its global index
+    (`position // CHUNK % NB`, NB = the scratch buffer count, `PIPE_NB`)
+    and `cur` (SMEM) holds the chunk the chain is on, so a span that
+    begins on the chunk the span before it ended on — a window of the
+    benchmark's cells holds a quarter of a chunk — neither loads nor
+    waits: it computes on the buffer. Entering a chunk waits for its
+    slots (asked for NB-1 chunks ago) and asks for chunk c+NB-1's,
+    whatever span that one belongs to: a chunk asked for at the top of
+    a grid step stands behind the step's own block transfers (1.3 us a
+    window on FM's fused kernel, 6 us on FFM's; PERF.md §5), and a chain
+    that restarted at every span had nothing to overlap that wait with.
+
+    `stage[sel]` collects the columns of every span that overlaps the
+    chunk (columns whose slot is outside this window are left as they
+    are) and is written out ONCE, when a span reaches the chunk's end or
+    the chain closes. `stream` = (stream start, stream end, first grid
+    step?, last grid step?) is the single-stream kernels': their spans
+    are one monotone line over the grid's steps, so the chain is the
+    whole call's — opened in the first grid step, closed (a partial last
+    chunk flushed, the out copies drained) in the last — and a chunk's
+    staging starts from zeros. Without it (the multi-buffer kernels,
+    which walk nbuf streams inside a grid step) the span is a chain of
+    its own and starts each chunk from what the output holds, since a
+    neighbouring span's columns of the same chunk go through HBM; the
+    out copy of c-1 is then drained before chunk c+NB-1's read lands in
+    the same buffer. Shared by both kernels — a fix here fixes both."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    NB = old.shape[0]  # pipeline depth = scratch buffer count
+    own = stream is None
+    lo, hi, opens, closes = (start, end, True, True) if own else stream
+    first, last, live = lo // CHUNK, (hi - 1) // CHUNK, hi > lo
+    NB = stage.shape[0]  # pipeline depth = scratch buffer count
     K = table_ref.shape[1] // pack
     window = table_ref.shape[0] * pack  # the block IS one window (state_window)
-    astart = (start // CHUNK) * CHUNK  # aligned down: extras self-mask
-    n_chunks = pl.cdiv(end - astart, CHUNK)
+    c0 = start // CHUNK  # aligned down: a neighbour's columns self-mask
+    n_chunks = jnp.where(end > start, (end - 1) // CHUNK - c0 + 1, 0)
 
     def in_copies(c):
         sel = c % NB
-        o = astart + c * CHUNK
-        return (
-            pltpu.make_async_copy(
-                slots_ref.at[:, pl.ds(o, CHUNK)], slc.at[sel], sem_s.at[sel]
-            ),
-            pltpu.make_async_copy(
-                out_ref.at[:, pl.ds(o, CHUNK)], old.at[sel], sem_d.at[sel]
-            ),
+        cols = pl.ds(c * CHUNK, CHUNK)
+        slots = pltpu.make_async_copy(slots_ref.at[:, cols], slc.at[sel], sem_s.at[sel])
+        if not own:
+            return (slots,)
+        return slots, pltpu.make_async_copy(
+            out_ref.at[:, cols], stage.at[sel], sem_d.at[sel]
         )
 
     def out_copy(c):
         sel = c % NB
-        o = astart + c * CHUNK
         return pltpu.make_async_copy(
-            old.at[sel], out_ref.at[:, pl.ds(o, CHUNK)], sem_o.at[sel]
+            stage.at[sel], out_ref.at[:, pl.ds(c * CHUNK, CHUNK)], sem_o.at[sel]
         )
 
     def start_in(c):
-        cs, co = in_copies(c)
-        cs.start()
-        co.start()
+        for cp in in_copies(c):
+            cp.start()
 
-    for i in range(NB - 1):
-        @pl.when(n_chunks > i)
-        def _(i=i):
-            start_in(i)
+    _open_chain(cur, first, last, opens & live, start_in, NB)
 
-    def chunk_step(c, carry):
+    def chunk_step(i, carry):
+        c = c0 + i
         sel = c % NB
-        cs, co = in_copies(c)
-        cs.wait()
+        entering = c > cur[0]
+
+        @pl.when(entering)
+        def _():
+            cur[0] = c
+            for cp in in_copies(c):
+                cp.wait()
+            if not own:
+                # stage[sel] was chunk c-NB's staging: its out copy has
+                # had NB-1 chunks of time
+                @pl.when(c - NB >= first)
+                def _():
+                    out_copy(c - NB).wait()
+
+                stage[sel] = jnp.zeros(stage.shape[1:], jnp.float32)
+
         rel = slc[sel][0:1, :] - base  # [1, C]
         # f32-accurate selection via the stacked 3-term bf16 contraction
         # (_dot_f32): the MXU's default bf16 pass would round every
@@ -789,71 +854,93 @@ def _gather_span(slots_ref, out_ref, table_ref, slc, old, sem_s, sem_d, sem_o,
         # parity check vs the XLA gather, ~2^-8 rel error — CPU tests are
         # f32-exact and cannot see it)
         occ = _windowed_select(table_ref[:, :], rel, pack, bf16)  # [K, C]
-        co.wait()
         in_win = (rel >= 0) & (rel < window)  # [1, C]
-        # blend: positions whose slot is outside this window belong to a
-        # neighboring window's (or buffer's) chunks — keep what is there.
         # No concat when K is already sublane-aligned: Mosaic rejects the
         # zero-row pad array (K=96/128/... would fail to compile)
-        if old.shape[1] > K:
-            pad = jnp.zeros((old.shape[1] - K, CHUNK), jnp.float32)
+        if stage.shape[1] > K:
+            pad = jnp.zeros((stage.shape[1] - K, CHUNK), jnp.float32)
             occ = jnp.concatenate([occ, pad], axis=0)
-        old[sel] = jnp.where(in_win, occ, old[sel])
-        out_copy(c).start()
+        stage[sel] = jnp.where(in_win, occ, stage[sel])
 
-        @pl.when(c + NB - 1 < n_chunks)
+        @pl.when(end >= (c + 1) * CHUNK)
         def _():
-            # old[(c+NB-1)%NB] was the out staging of chunk c-1: drain
-            # that copy before overwriting the buffer
-            @pl.when(c >= 1)
-            def _():
-                out_copy(c - 1).wait()
+            out_copy(c).start()  # no later span of the chain owns a column
 
-            start_in(c + NB - 1)
+        @pl.when(entering & (c + NB - 1 <= last))
+        def _():
+            if own:
+                @pl.when(c - 1 >= first)
+                def _():
+                    out_copy(c - 1).wait()
+
+            start_in(c + NB - 1)  # chunk c-1's buffers: the chain has left it
 
         return carry
 
     jax.lax.fori_loop(0, n_chunks, chunk_step, 0)
 
-    # drain every out copy not waited in-loop: iteration c waits out(c-1)
-    # only while prefetching (c+NB-1 < n), so the last min(n, NB) outs
-    # (one per buffer) are still in flight here — an unwaited DMA would
-    # leave its semaphore signaled and corrupt the next span
-    for i in range(NB, 0, -1):
-        @pl.when(n_chunks > i - 1)
-        def _(i=i):
-            out_copy(n_chunks - i).wait()
+    @pl.when(closes & live)
+    def _():
+        @pl.when((last + 1) * CHUNK > hi)
+        def _():
+            out_copy(last).start()  # the chain ends inside its last chunk
+
+        # entering c (on its own chain: asking for c+NB-1) drained the out
+        # copies up to last-NB: one per buffer is still in flight, and an
+        # unwaited DMA would leave its semaphore signaled for the next
+        # chain
+        for i in range(NB):
+            @pl.when(last - i >= first)
+            def _(i=i):
+                out_copy(last - i).wait()
 
 
-def _gather_kernel(off_ref, slots_ref, table_ref, out_ref, slc, old, sem_s, sem_d,
+def _stream_of(off_ref):
+    """The single-stream kernels' `stream` for the span functions: the
+    whole call's occurrence line and where on the grid this step is."""
+    from jax.experimental import pallas as pl
+
+    t, n_win = pl.program_id(0), pl.num_programs(0)
+    return off_ref[0], off_ref[n_win], t == 0, t == n_win - 1
+
+
+def _gather_kernel(off_ref, slots_ref, table_ref, out_ref, slc, stage, cur, sem_s,
                    sem_o, *, bf16, n_tw, pack):
     """Single-stream windowed gather: grid step t owns logical window
-    t % n_tw (identity when the stream covers the table once)."""
+    t % n_tw (identity when the stream covers the table once). The
+    offsets are monotone over the whole grid (also where the stream is
+    D buffers over one table and the grid wraps), so the steps' spans
+    are one line and share one chunk chain (`_gather_span`): the grid
+    runs in order (`dimension_semantics`), the scratch lives across its
+    steps, and each chunk is loaded and written once a call."""
     from jax.experimental import pallas as pl
 
     t = pl.program_id(0)
     _gather_span(
-        slots_ref, out_ref, table_ref, slc, old, sem_s, sem_d, sem_o,
+        slots_ref, out_ref, table_ref, slc, stage, cur, sem_s, sem_o, None,
         (t % n_tw) * (table_ref.shape[0] * pack), off_ref[t], off_ref[t + 1], bf16, pack,
+        stream=_stream_of(off_ref),
     )
 
 
-def _gather_kernel_multi(off_ref, slots_ref, table_ref, out_ref, slc, old, sem_s,
-                         sem_d, sem_o, *, bf16, nbuf, cap, pack):
+def _gather_kernel_multi(off_ref, slots_ref, table_ref, out_ref, slc, stage, cur, sem_s,
+                         sem_o, sem_d, *, bf16, nbuf, cap, pack):
     """Windowed gather over `nbuf` concatenated per-source buffers,
     WINDOW-MAJOR: grid step j owns table window j and walks every
     buffer's matching span, so each table block is DMA'd into VMEM
     exactly ONCE per call instead of once per buffer — the source-major
     order read the whole table nbuf times (nbuf = NS sub-batches on one
     device; measured 2×+ on the MVM segment path at NS=4). `off_ref` is [nbuf, wpo+1]
-    buffer-local window offsets, the `_scatter_kernel_multi` contract."""
+    buffer-local window offsets, the `_scatter_kernel_multi` contract.
+    The spans of a grid step lie in nbuf streams, not on one line: each
+    is a chunk chain of its own (`_gather_span` without `stream`)."""
     from jax.experimental import pallas as pl
 
     j = pl.program_id(0)
 
     def buf_step(i, carry):
         _gather_span(
-            slots_ref, out_ref, table_ref, slc, old, sem_s, sem_d, sem_o,
+            slots_ref, out_ref, table_ref, slc, stage, cur, sem_s, sem_o, sem_d,
             j * (table_ref.shape[0] * pack), i * cap + off_ref[i, j],
             i * cap + off_ref[i, j + 1], bf16, pack,
         )
@@ -863,9 +950,9 @@ def _gather_kernel_multi(off_ref, slots_ref, table_ref, out_ref, slc, old, sem_s
 
 
 # PIPE_NB (6, defined beside WINDOW) is the chunk-chain pipeline depth in
-# buffers; the chain is DMA-latency bound (_gather_span), so deeper
-# prefetch hides more of the per-chunk wait — 6 measured best vs 3 on v5e
-# at bench shapes; VMEM cost is NB × (K8+1) × CHUNK × 4 B: ~110 KB at
+# buffers: deeper prefetch hides more of a chunk's wait (_gather_span) —
+# 6 measured best vs 3 on v5e at bench shapes (the earlier rig, with a
+# chain that restarted at every span); VMEM cost is NB × (K8+1) × CHUNK × 4 B: ~110 KB at
 # K8=8, ~210 KB for the fused FM row (K8=16), 2 MB for FFM's K8=160 —
 # `state_window` counts it
 
@@ -893,9 +980,9 @@ def _gather_pallas(table, sorted_slots, win_off, bf16=False, pack=1):
         ],
         out_specs=pl.BlockSpec(memory_space=pl.ANY),  # occ_t [K8, Np]
         scratch_shapes=[
-            pltpu.VMEM((PIPE_NB, 1, CHUNK), jnp.int32),  # slc, pipelined
-            pltpu.VMEM((PIPE_NB, K8, CHUNK), jnp.float32),  # old/staging
-            pltpu.SemaphoreType.DMA((PIPE_NB,)),
+            pltpu.VMEM((PIPE_NB, 1, CHUNK), jnp.int32),  # slc, the chain's ring
+            pltpu.VMEM((PIPE_NB, K8, CHUNK), jnp.float32),  # out staging
+            pltpu.SMEM((1,), jnp.int32),  # the chunk the chain is on
             pltpu.SemaphoreType.DMA((PIPE_NB,)),
             pltpu.SemaphoreType.DMA((PIPE_NB,)),
         ],
@@ -904,7 +991,9 @@ def _gather_pallas(table, sorted_slots, win_off, bf16=False, pack=1):
         partial(_gather_kernel, bf16=bf16, n_tw=n_tw, pack=pack),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((K8, n), jnp.float32),
-        compiler_params=pltpu.CompilerParams(has_side_effects=True),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), has_side_effects=True
+        ),
     )(win_off, sorted_slots.reshape(1, n), table)
 
 
@@ -932,6 +1021,7 @@ def _gather_pallas_multi(table, sorted_slots, loc_off, cap, bf16=False, pack=1):
         scratch_shapes=[
             pltpu.VMEM((PIPE_NB, 1, CHUNK), jnp.int32),  # slc, pipelined
             pltpu.VMEM((PIPE_NB, K8, CHUNK), jnp.float32),  # old/staging
+            pltpu.SMEM((1,), jnp.int32),  # the chunk a span's chain is on
             pltpu.SemaphoreType.DMA((PIPE_NB,)),
             pltpu.SemaphoreType.DMA((PIPE_NB,)),
             pltpu.SemaphoreType.DMA((PIPE_NB,)),
@@ -945,8 +1035,8 @@ def _gather_pallas_multi(table, sorted_slots, loc_off, cap, bf16=False, pack=1):
     )(loc_off, sorted_slots.reshape(1, n), table)
 
 
-def _scatter_span(slots_ref, d_ref, slc, dch, sem_s, sem_d, base, start, end,
-                  acc_t, bf16, pack=1, k=None):
+def _scatter_span(slots_ref, d_ref, slc, dch, cur, sem_s, sem_d, base, start, end,
+                  acc_t, bf16, pack=1, k=None, stream=None):
     """Accumulate one occurrence span's contribution to the window at
     `base` into acc_t ([K8, W] logical, [pack*K, W/pack] packed) — the
     precision-critical DMA + one-hot + `_dot_f32` sequence shared by
@@ -954,49 +1044,54 @@ def _scatter_span(slots_ref, d_ref, slc, dch, sem_s, sem_d, base, start, end,
     fixes both). Packed expands the [K, C] cotangent chunk to
     [pack*K, C] with `pack` static 0/1-masked block copies (exact) and
     contracts against the PACKED one-hot — pack× fewer MXU MACs per
-    chunk. NB-deep pipelined (NB = scratch buffer count, `PIPE_NB`):
-    chunk c+NB-1's inputs prefetch during compute of c (the chain is
-    DMA-latency bound, like the gather's)."""
+    chunk. The span is one link of the chunk chain `_gather_span`
+    describes: a chunk's slots and cotangent sit in ring buffer
+    `position // CHUNK % NB`, `cur` holds the chunk the chain is on, a
+    span that begins on it computes without a load or a wait, and
+    entering chunk c asks for chunk c+NB-1 whatever span that belongs
+    to. With `stream` (the single-stream kernels) the chain is the whole
+    call's, carried over the grid's steps: a chunk is loaded once a
+    call. Without it (the multi-buffer kernel) each span is its own. A
+    window adds its chunks in the order it always did, so the sums keep
+    their bits."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    astart = (start // CHUNK) * CHUNK
-    n_chunks = pl.cdiv(end - astart, CHUNK)
+    lo, hi, opens = (start, end, True) if stream is None else stream[:3]
+    first, last, live = lo // CHUNK, (hi - 1) // CHUNK, hi > lo
+    c0 = start // CHUNK  # aligned down: a neighbour's columns get no one-hot lane
+    n_chunks = jnp.where(end > start, (end - 1) // CHUNK - c0 + 1, 0)
     window = acc_t.shape[1] * pack  # the accumulator IS one window (state_window)
 
     NB = dch.shape[0]  # pipeline depth = scratch buffer count
 
     def in_copies(c):
         sel = c % NB
-        o = astart + c * CHUNK
+        cols = pl.ds(c * CHUNK, CHUNK)
         return (
-            pltpu.make_async_copy(
-                slots_ref.at[:, pl.ds(o, CHUNK)], slc.at[sel], sem_s.at[sel]
-            ),
-            pltpu.make_async_copy(
-                d_ref.at[:, pl.ds(o, CHUNK)], dch.at[sel], sem_d.at[sel]
-            ),
+            pltpu.make_async_copy(slots_ref.at[:, cols], slc.at[sel], sem_s.at[sel]),
+            pltpu.make_async_copy(d_ref.at[:, cols], dch.at[sel], sem_d.at[sel]),
         )
 
     def start_in(c):
-        cs, cd = in_copies(c)
-        cs.start()
-        cd.start()
+        for cp in in_copies(c):
+            cp.start()
 
-    for i in range(NB - 1):
-        @pl.when(n_chunks > i)
-        def _(i=i):
-            start_in(i)
+    _open_chain(cur, first, last, opens & live, start_in, NB)
 
-    def chunk_step(c, acc):
+    def chunk_step(i, acc):
+        c = c0 + i
         sel = c % NB
-        cs, cd = in_copies(c)
-        cs.wait()
-        cd.wait()
 
-        @pl.when(c + NB - 1 < n_chunks)
+        @pl.when(c > cur[0])
         def _():
-            start_in(c + NB - 1)
+            cur[0] = c
+            for cp in in_copies(c):
+                cp.wait()
+
+            @pl.when(c + NB - 1 <= last)
+            def _():
+                start_in(c + NB - 1)  # chunk c-1's buffers: the chain has left it
 
         rel = slc[sel][0:1, :] - base  # [1, C]; out-of-window: no lane
         if pack == 1:
@@ -1021,7 +1116,7 @@ def _scatter_span(slots_ref, d_ref, slc, dch, sem_s, sem_d, base, start, end,
     return jax.lax.fori_loop(0, n_chunks, chunk_step, acc_t)
 
 
-def _scatter_kernel(off_ref, slots_ref, d_ref, out_ref, slc, dch, sem_s, sem_d,
+def _scatter_kernel(off_ref, slots_ref, d_ref, out_ref, slc, dch, cur, sem_s, sem_d,
                     *, bf16, pack):
     from jax.experimental import pallas as pl
 
@@ -1032,8 +1127,9 @@ def _scatter_kernel(off_ref, slots_ref, d_ref, out_ref, slc, dch, sem_s, sem_d,
     rows = pack * K if pack > 1 else K8
     acc_t = jnp.zeros((rows, window // pack), jnp.float32)
     acc_t = _scatter_span(
-        slots_ref, d_ref, slc, dch, sem_s, sem_d,
+        slots_ref, d_ref, slc, dch, cur, sem_s, sem_d,
         t * window, off_ref[t], off_ref[t + 1], acc_t, bf16, pack, K,
+        stream=_stream_of(off_ref),
     )
     out_ref[:, :] = (acc_t if pack > 1 else acc_t[0:K, :]).T  # [W/pack, pack*K]
 
@@ -1058,6 +1154,7 @@ def _scatter_pallas(d_occ_t, sorted_slots, win_off, num_slots, k: int, bf16=Fals
         scratch_shapes=[
             pltpu.VMEM((PIPE_NB, 1, CHUNK), jnp.int32),
             pltpu.VMEM((PIPE_NB, K8, CHUNK), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),  # the chunk the chain is on
             pltpu.SemaphoreType.DMA((PIPE_NB,)),
             pltpu.SemaphoreType.DMA((PIPE_NB,)),
         ],
@@ -1066,11 +1163,12 @@ def _scatter_pallas(d_occ_t, sorted_slots, win_off, num_slots, k: int, bf16=Fals
         partial(_scatter_kernel, bf16=bf16, pack=pack),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_slots // pack, pack * k), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(win_off, sorted_slots.reshape(1, n), d_occ_t)
 
 
-def _scatter_kernel_multi(off_ref, slots_ref, d_ref, out_ref, slc, dch, sem_s, sem_d,
-                          *, bf16, nbuf, cap, pack):
+def _scatter_kernel_multi(off_ref, slots_ref, d_ref, out_ref, slc, dch, cur, sem_s,
+                          sem_d, *, bf16, nbuf, cap, pack):
     """Windowed scatter over `nbuf` concatenated per-source buffers.
 
     The cotangent stream is nbuf buffers of `cap` positions each (stacked
@@ -1090,7 +1188,7 @@ def _scatter_kernel_multi(off_ref, slots_ref, d_ref, out_ref, slc, dch, sem_s, s
     def buf_step(i, acc_t):
         # aligned-down reads stay >= i*cap (cap % CHUNK == 0)
         return _scatter_span(
-            slots_ref, d_ref, slc, dch, sem_s, sem_d,
+            slots_ref, d_ref, slc, dch, cur, sem_s, sem_d,
             j * window, i * cap + off_ref[i, j], i * cap + off_ref[i, j + 1],
             acc_t, bf16, pack, K,
         )
@@ -1123,6 +1221,7 @@ def _scatter_pallas_multi(d_occ_t, sorted_slots, loc_off, num_slots, k, cap,
         scratch_shapes=[
             pltpu.VMEM((PIPE_NB, 1, CHUNK), jnp.int32),
             pltpu.VMEM((PIPE_NB, K8, CHUNK), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),  # the chunk the chain is on
             pltpu.SemaphoreType.DMA((PIPE_NB,)),
             pltpu.SemaphoreType.DMA((PIPE_NB,)),
         ],
@@ -1135,7 +1234,7 @@ def _scatter_pallas_multi(d_occ_t, sorted_slots, loc_off, num_slots, k, cap,
 
 
 def _scatter_ftrl_kernel(off_ref, slots_ref, d_ref, w_ref, n_ref, z_ref,
-                         w_out, n_out, z_out, slc, dch, sem_s, sem_d,
+                         w_out, n_out, z_out, slc, dch, cur, sem_s, sem_d,
                          *, bf16, pack, alpha, beta, lambda1, lambda2):
     """Fused windowed scatter-add + FTRL-proximal window update: grid
     step t accumulates window t's complete gradient block (every chunk
@@ -1157,8 +1256,9 @@ def _scatter_ftrl_kernel(off_ref, slots_ref, d_ref, w_ref, n_ref, z_ref,
     rows = pack * K if pack > 1 else K8
     acc_t = jnp.zeros((rows, window // pack), jnp.float32)
     acc_t = _scatter_span(
-        slots_ref, d_ref, slc, dch, sem_s, sem_d,
+        slots_ref, d_ref, slc, dch, cur, sem_s, sem_d,
         t * window, off_ref[t], off_ref[t + 1], acc_t, bf16, pack, K,
+        stream=_stream_of(off_ref),
     )
     g = (acc_t if pack > 1 else acc_t[0:K, :]).T  # [W/pack, pack*K]
     w_new, n_new, z_new = _update_one(
@@ -1192,6 +1292,7 @@ def _scatter_ftrl_pallas(d_occ_t, sorted_slots, win_off, w, n, z, k, hp,
         scratch_shapes=[
             pltpu.VMEM((PIPE_NB, 1, CHUNK), jnp.int32),
             pltpu.VMEM((PIPE_NB, K8, CHUNK), jnp.float32),
+            pltpu.SMEM((1,), jnp.int32),  # the chunk the chain is on
             pltpu.SemaphoreType.DMA((PIPE_NB,)),
             pltpu.SemaphoreType.DMA((PIPE_NB,)),
         ],
@@ -1209,6 +1310,7 @@ def _scatter_ftrl_pallas(d_occ_t, sorted_slots, win_off, w, n, z, k, hp,
         # 1=slots, 2=d, 3=w, 4=n, 5=z -> outputs 0..2 (verified: a
         # {2: 0} mapping is rejected with d's shape in the error)
         input_output_aliases={3: 0, 4: 1, 5: 2},
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
     )(win_off, sorted_slots.reshape(1, n_occ), d_occ_t, w, n, z)
 
 
@@ -1431,8 +1533,10 @@ def table_gather_sorted_multi(table, sorted_slots, loc_off, bf16=False, pack=1):
     order read it nbuf times; measured 2×+ on the MVM segment path at
     NS=4). What grows with nbuf is the SPANS: one per (window, buffer),
     each at least one CHUNK-wide one-hot pass however few occurrences
-    it holds (PERF.md §5: the same ~1 us a chunk visit as the
-    single-stream kernels, nbuf times the visits on sparse windows).
+    it holds, and each a chunk chain of its own (PERF.md §5: a visit's
+    compute as in the single-stream kernels plus the cold load their
+    carried chain no longer pays, nbuf times the visits on sparse
+    windows).
     The VJP accumulates every buffer's span into one [W, K] block write
     per window (`_scatter_kernel_multi`).
 

@@ -71,6 +71,7 @@ from xflow_tpu.metrics import binary_logloss_from_logits
 from xflow_tpu.ops.sorted_table import (
     CHUNK,
     SortedPlan,
+    chunk_chain_counts,
     map_host_parallel,
     plan_sorted_batch,
     row_sums_sorted,
@@ -274,6 +275,19 @@ def plan_fullshard_batch(
 
     bufs = map_host_parallel(one, d_local)
     return {k: np.stack([b[k] for b in bufs]) for k in bufs[0]}
+
+
+def fullshard_chunk_counts(fs_off) -> dict:
+    """`chunk_chain_counts` of the streams the chips walk, a chip: a
+    chip's merged stream has the sum of its sources' window offsets
+    (`merge_received`), so `fs_off` [sources, T, D, wpo+1] summed over
+    the sources is each destination's `win_off`; the mean over the
+    destinations, rounded. One process that plans for every source (a
+    single host's mesh) counts whole streams; of several, each the part
+    its own sources send."""
+    merged = np.asarray(fs_off, np.int64).sum(axis=0)
+    chips = [chunk_chain_counts(off) for off in merged.reshape(-1, merged.shape[-1])]
+    return {k: round(sum(c[k] for c in chips) / len(chips)) for k in chips[0]}
 
 
 def fullshard_batch_sharding(mesh: Mesh, with_fields: bool = False) -> dict:

@@ -1187,6 +1187,12 @@ HOST_CONSUMER_STAGES = (
 # their stage, so no sum over the stages above counts them again
 HOST_NESTED_STAGES = ("ffm_place",)
 HOST_STAGES = PIPELINE_PRODUCER_STAGES + HOST_CONSUMER_STAGES
+# what the planner counts for the window's batches beside its seconds
+# (`ops/sorted_table.chunk_chain_counts`, booked with the batch's plan
+# span): the sorted kernels' (window, chunk) visits and the chunks they
+# load. The step records' `host` names them where a plan had them, as
+# they are; a fit()'s sums are its final record's
+HOST_COUNTERS = ("chunk_visits", "chunk_loads")
 
 # the host-side stages a bottleneck verdict can name, and the
 # benchmark's producer_busy_pct sums (producer work; producer_wait is
@@ -1265,6 +1271,7 @@ def host_fields(win: dict) -> dict:
     out.update(
         (host_field(s), round(win[s] * 1e3, 3)) for s in HOST_NESTED_STAGES if win[s] > 0.0
     )
+    out.update((c, win[c]) for c in HOST_COUNTERS if win[c])
     out["batches"] = win["batches"]
     return out
 
@@ -1349,8 +1356,8 @@ class PipelineProfiler:
         self._reg = registry or default_registry()
         self.publish = bool(publish)
         self._lock = threading.Lock()
-        self._win = {s: 0.0 for s in HOST_STAGES + HOST_NESTED_STAGES}
-        self._total = {s: 0.0 for s in HOST_STAGES + HOST_NESTED_STAGES}
+        self._win = self._empty_window()
+        self._total = self._empty_window()
         self._win_batches = 0
         self._win_rows = 0
         self.batches = 0
@@ -1372,6 +1379,12 @@ class PipelineProfiler:
         if self.publish:
             self._reg.gauge("pipeline.queue_depth").set(0)
             self._reg.gauge("pipeline.producer_blocked_s").set(0.0)
+
+    @staticmethod
+    def _empty_window() -> dict:
+        win = {s: 0.0 for s in HOST_STAGES + HOST_NESTED_STAGES}
+        win.update({c: 0 for c in HOST_COUNTERS})
+        return win
 
     # ------------------------------------------------------- accumulation
     def add(self, stage: str, seconds: float) -> None:
@@ -1442,7 +1455,7 @@ class PipelineProfiler:
             win["rows"] = self._win_rows
             win["queue_depth"] = self._queue_depth
             win["queue_cap"] = self._queue_cap
-            self._win = {s: 0.0 for s in HOST_STAGES + HOST_NESTED_STAGES}
+            self._win = self._empty_window()
             self._win_batches = 0
             self._win_rows = 0
             self._win_start = now

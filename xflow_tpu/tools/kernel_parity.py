@@ -47,12 +47,18 @@ def check_kernel_parity(
     k: int = 11,
     batch: int = 4096,
     seed: int = 0,
+    window_stride: int = 1,
 ) -> dict:
     """Returns {"ok": bool, "checks": {name: max_rel_err}, "backend": str}.
 
     Gather checks require rel err == 0.0 (bit-exact); scatter/rowsum
     allow 1e-4 over a 1e-2 floor (f32 reduction reorder on unit-scale
-    terms); bf16 mode allows 2^-7.
+    terms); bf16 mode allows 2^-7. The default shape puts 16 chunks in
+    a window; `CASES` adds the benchmark cells' regime, a span shorter
+    than a chunk (the kernels' chunk chain then runs across grid steps,
+    ops/sorted_table.py `_gather_span`), and with `window_stride` > 1
+    only every stride-th window holds occurrences: the chain crosses
+    runs of empty windows, the first and (but for the pads) the last.
     """
     from xflow_tpu.ops.sorted_table import (
         _gather_xla,
@@ -69,6 +75,9 @@ def check_kernel_parity(
     S = 1 << log2_slots
     nnz = n_occ // batch
     slots = rng.integers(0, S, (batch, nnz)).astype(np.int32)
+    if window_stride > 1:
+        live = np.arange(1 % window_stride, S // WINDOW, window_stride)
+        slots = (rng.choice(live, slots.shape) * WINDOW + slots % WINDOW).astype(np.int32)
     mask = (rng.random((batch, nnz)) < 0.9).astype(np.float32)
     table = rng.standard_normal((S, k)).astype(np.float32)
     # exercise the full f32 mantissa: values whose hi/mid/lo bf16 terms
@@ -322,11 +331,24 @@ def check_kernel_parity(
     return {"ok": ok, "checks": checks, "backend": jax.default_backend()}
 
 
+# what `main` runs beside the default shape, as <suffix of the check's
+# name>: <arguments>. 2^22 slots x 2^17 occurrences is 64 occurrences a
+# window, eight windows a chunk
+CASES = {
+    "@s22": {"log2_slots": 22},
+    "@s22_holes": {"log2_slots": 22, "window_stride": 4},
+}
+
+
 def main() -> int:
     import json
     import sys
 
     res = check_kernel_parity()
+    for suffix, kwargs in CASES.items():
+        more = check_kernel_parity(**kwargs)
+        res["ok"] = res["ok"] and more["ok"]
+        res["checks"].update({name + suffix: v for name, v in more["checks"].items()})
     if res["backend"] != "tpu":
         # every check would trivially compare the XLA path against
         # itself — "ok" here would be a false all-clear

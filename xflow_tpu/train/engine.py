@@ -30,6 +30,7 @@ from xflow_tpu.config import Config
 from xflow_tpu.models.ffm import ffm_invperm, resolve_ffm_aligned
 from xflow_tpu.models.mvm import has_field_duplicates, resolve_mvm_product
 from xflow_tpu.ops.sorted_table import (
+    chunk_chain_counts,
     compact_plan_wire,
     dedup_slots,
     plan_sorted_stacked,
@@ -40,6 +41,7 @@ from xflow_tpu.ops.sorted_table import (
 from xflow_tpu.parallel.mesh import batch_sharding, state_shardings
 from xflow_tpu.parallel.sorted_fullshard import (
     FullshardOverflowError,
+    fullshard_chunk_counts,
     make_fullshard_eval_step,
     make_fullshard_train_step,
     plan_fullshard_batch,
@@ -370,6 +372,8 @@ def _sorted_arrays(cfg: Config, maybe_dedup: Callable) -> Callable:
             sorted_mask=plan.sorted_mask,
             win_off=plan.win_off,
         )
+        if profiler is not None:
+            profiler.add_many(chunk_chain_counts(plan.win_off))
         if want_fields:
             arrays["sorted_fields"] = plan.sorted_fields
         if ffm:
@@ -433,6 +437,8 @@ def _fullshard_arrays(cfg: Config, mesh, maybe_dedup: Callable) -> Callable:
             if jax.process_count() > 1:
                 arrays["_fs_overflow"] = True
             return arrays
+        if profiler is not None:
+            profiler.add_many(fullshard_chunk_counts(out["fs_off"]))
         out = compact_plan_wire(
             out,
             rows_bound=rows_bound,

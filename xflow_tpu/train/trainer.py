@@ -39,6 +39,7 @@ from xflow_tpu.data.pipeline import (
 from xflow_tpu.metrics import auc_logloss
 from xflow_tpu.models import get_model
 from xflow_tpu.telemetry import (
+    HOST_COUNTERS,
     HangWatchdog,
     HealthMonitor,
     PipelineProfiler,
@@ -94,6 +95,11 @@ class TrainResult:
     read_ahead_passes: int = 0
     read_ahead_batches: int = 0
     read_ahead_discarded: int = 0
+    # what the sorted kernels did with this fit()'s plans, summed from
+    # the step records' `host` (`telemetry.HOST_COUNTERS`; a run with the
+    # host timeline armed): (window, chunk) pairs computed on, chunks loaded
+    chunk_visits: int = 0
+    chunk_loads: int = 0
 
     @property
     def examples_per_sec(self) -> float:
@@ -745,6 +751,14 @@ class Trainer:
         pending_ok = None  # (metrics, step index) awaiting the flag check
         pending_rec = None  # a log-cadence step's payload, written one behind
 
+        def take_window() -> dict:
+            """The profiler's window since the last take, its counters
+            added to this fit()'s sums."""
+            win = prof.take_window() if prof is not None else {}
+            for c in HOST_COUNTERS:
+                setattr(res, c, getattr(res, c) + win.get(c, 0))
+            return win
+
         def log_window(rec: dict, at_step: int, win: Optional[dict] = None) -> None:
             """Write a record that carries step timings, with the host
             timeline's share of it: the profiler's window (taken now,
@@ -755,7 +769,7 @@ class Trainer:
             (docs/OBSERVABILITY.md "Input-pipeline attribution")."""
             nonlocal boundary
             if win is None:
-                win = prof.take_window() if prof is not None else {}
+                win = take_window()
             if win:
                 rec["host"] = host_fields(win)
             if boundary is not None and "step_time_p50_ms" in rec:
@@ -1310,7 +1324,7 @@ class Trainer:
         # what the window holds past the last log tick, for the final
         # record: taken BEFORE the occupancy sweep below — post-loop host
         # work is not pipeline wall
-        tail_win = prof.take_window() if prof is not None else {}
+        tail_win = take_window()
         res.seconds = time.perf_counter() - start
         # final sync boundary: publish the tail block's delta and fold
         # in whatever peers have landed, so the state this fit returns
@@ -1353,6 +1367,7 @@ class Trainer:
                 "read_ahead_discarded": res.read_ahead_discarded,
             }
             final_rec.update(self._fallback_fields(res))
+            final_rec.update((c, getattr(res, c)) for c in HOST_COUNTERS if getattr(res, c))
             # tail window (steps since the last log tick) + run-total counters
             final_rec.update(steptimer.window_record())
             final_rec.update(hbm_window_fields(registry))
