@@ -7,8 +7,12 @@ For each seed: the program's first steps against the reference (the
 lower reading), the control (the reference in the precision below the
 configuration's, put in the program's place), each fault planted in the
 reference, and with --program-control the program's own lower-precision
-path (`control_set` of the configuration file). The benchmark's own
-runs never call this; PERF.md section 2 holds what it read on the chip.
+path (`control_set` of the configuration file). A serve cell: one server
+over the first seed's table, and for each seed a short window of that
+seed's traffic (--seconds), every answer against the reference, then the
+control and the three serving faults in the program's place. The
+benchmark's own runs never call this; PERF.md section 2 holds what it
+read on the chip.
 """
 
 from __future__ import annotations
@@ -35,6 +39,7 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default="")
     ap.add_argument("--program-control", action="store_true")
     ap.add_argument("--rehearsal", action="store_true")
+    ap.add_argument("--seconds", type=float, default=6.0, help="a serve cell's window for each seed")
     args = ap.parse_args(argv)
     import run as harness
 
@@ -60,6 +65,12 @@ def main(argv=None) -> int:
         if sink:
             sink.write(line + "\n")
             sink.flush()
+
+    if cfg.get("path", "train") == "serve":
+        serve_pass(args, cell, cfg, traffic, width, workdir, emit)
+        if sink:
+            sink.close()
+        return 0
 
     def program_pass(cfg_run, tag):
         trainer = None
@@ -103,6 +114,56 @@ def main(argv=None) -> int:
     if sink:
         sink.close()
     return 0
+
+
+def serve_pass(args, cell, cfg, traffic, width, workdir, emit) -> None:
+    """One server over the first seed's table; each seed's traffic through
+    a short window; readings of the program, the control and the faults."""
+    import shutil
+
+    from lib import loadgen, serve_run, serve_stats, weights
+    from lib.traffic import slots_of_ids
+    from reference import predict as refpredict
+
+    shutil.rmtree(workdir, ignore_errors=True)
+    os.makedirs(workdir)
+    sock, sock_dir = serve_run.socket_path(workdir)
+    table_seed = args.first_seed
+    served, _ = serve_run.bring_up(cfg, table_seed, width, workdir, sock,
+                                lambda m: print("control.py: " + m, file=sys.stderr))
+    try:
+        for k in range(args.seeds):
+            seed = args.first_seed + 7919 * k
+            t = time.perf_counter()
+            gen = serve_run.Generator(HERE, seed, cfg, traffic, sock, workdir)
+            try:
+                gen.read()
+                log = gen.run("window", args.seconds)
+            finally:
+                gen.close()
+            window = serve_stats.window_stats(log)
+            pool = loadgen.make_pool(seed, cfg, traffic)
+            entries, row_of_answer, offsets = serve_stats.answered_rows(log, pool)
+            ids = serve_stats.entry_ids(pool, entries)
+
+            def answers(**kw):
+                return refpredict.serve_pctr(cfg, table_seed, ids, slots_of_ids, weights.rows_numpy,
+                                             offsets=offsets, **kw)[row_of_answer]
+
+            ref = answers()
+            base = {"workload": cell["name"], "seed": seed, "table_seed": table_seed, "rows": len(ref)}
+            emit({**base, "what": "program", "numbers": serve_stats.pctr_gaps(log["pctr"], ref),
+                  "window": {k2: window[k2] for k2 in ("requests", "failed", "shed", "generations_extra")}})
+            emit({**base, "what": "control:" + LOWER[cfg["dtype"]],
+                  "numbers": serve_stats.pctr_gaps(answers(dtype=LOWER[cfg["dtype"]]), ref)})
+            for fault in refpredict.FAULTS:
+                emit({**base, "what": "fault:" + fault, "numbers": serve_stats.pctr_gaps(answers(fault=fault), ref)})
+            print(f"control.py: seed {seed} in {time.perf_counter() - t:.1f}s", file=sys.stderr)
+    finally:
+        served.close()
+        if sock_dir:
+            shutil.rmtree(sock_dir, ignore_errors=True)
+        shutil.rmtree(workdir, ignore_errors=True)
 
 
 if __name__ == "__main__":

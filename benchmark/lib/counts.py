@@ -79,3 +79,14 @@ def kernel_roofline_pct(run: dict, pattern: str, needs_fn) -> float | None:
     per_chip = 1.0 / run["chips"]
     needs = needs_fn(shape["distinct_slots"] * per_chip, shape["occurrences"] * per_chip, run["width"])
     return 100.0 * least_seconds(needs, run["peak"])[0] / seconds
+
+
+def predict_needs(distinct_slots: float, occurrences: float, width: int) -> dict:
+    """What one served batch needs: each distinct row read once, one row
+    and 12 bytes (slot, field, mask) read an occurrence; the forward of
+    the row math (3 * width an occurrence). The pCTRs written are a few
+    bytes a row and are left out: a lower bound."""
+    return {
+        "bytes": distinct_slots * width * 4 + occurrences * (width * 4 + OCCURRENCE_BYTES),
+        "flops": occurrences * 3 * width,
+    }

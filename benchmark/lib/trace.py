@@ -161,6 +161,24 @@ def idle_gaps(per_device: list, spans: list, n: int = 10) -> list:
     return [[k, v / 1e9] for k, v in sorted(tot.items(), key=lambda kv: -kv[1])[:n]]
 
 
+def module_runs(trace: dict, names: dict) -> dict:
+    """{module name: [executions, seconds]} on the first device plane's
+    line of whole program executions (`module_line`), the run id in
+    brackets dropped (`jit_step(123)` -> `jit_step`)."""
+    out: dict = {}
+    for plane in trace["planes"]:
+        if not re.match(names["device_plane"], plane["name"]):
+            continue
+        for line in plane["lines"]:
+            if line["name"] == names.get("module_line", "XLA Modules"):
+                for name, _, dur in line["events"]:
+                    c = out.setdefault(re.sub(r"\(\d+\)$", "", name), [0, 0.0])
+                    c[0] += 1
+                    c[1] += dur / 1e9
+        break
+    return out
+
+
 def summarize(trace: dict, names: dict, chips: int) -> dict:
     """Everything the harness keeps of a trace."""
     per_device = device_ops(trace, names, chips)

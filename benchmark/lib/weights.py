@@ -65,3 +65,20 @@ def packed_table_fn(seed: int, num_slots: int, width: int, pack: int, scale: flo
         return jnp.where(q % jnp.uint32(width) == 0, jnp.float32(0.0), vals)
 
     return make
+
+
+def chunk_table_fn(seed: int, num_slots: int, width: int, scale: float):
+    """(first_slot) -> float32 [num_slots, width], the logical rows of
+    slots first_slot .. first_slot + num_slots: one jitted program
+    for every chunk of a table that is made on the way to the host."""
+    import jax.numpy as jnp
+
+    s1, s2 = seed_words(seed)
+
+    def make(first_slot):
+        r = first_slot.astype(jnp.uint32) + jnp.arange(num_slots, dtype=jnp.uint32)[:, None]
+        q = jnp.arange(width, dtype=jnp.uint32)[None, :]
+        vals = _draw(jnp, r * jnp.uint32(width) + q, s1, s2, scale)
+        return jnp.where(q == 0, jnp.float32(0.0), vals)
+
+    return make

@@ -1,6 +1,7 @@
 """Field-aware FM's row side against its roofline: the device time a
-step of the operations in the program's `ffm_pair` scope (the selectors,
-the pair contraction, its transpose) and `ffm_place` scope (the
+step of the operations in the program's `ffm_pair` scope (the field
+crossing as a block transposition, the multiply-and-sum, the
+hand-written backward; no dot since PR 37) and `ffm_place` scope (the
 placement of the gathered occurrences by row and field, and its reverse
 for the cotangent), against the least time the algorithm needs — the
 larger of its operations at the chip's peak FLOP/s (one multiply-add for

@@ -3,16 +3,15 @@ fullshard engine: every chip gathers, from its own shard of the table,
 the rows that all the chips' batch shards ask of it, and scatters their
 gradients back into a shard-sized buffer).
 
-What the trace calls `gather[pallas]` there is three kernels, not one:
-the step's whole forward sits in the `gather` scope and a transpose
-keeps its scope's name, so the windowed gather, the row sums and the
-gather's transpose (the two-pass scatter) all carry it (PERF.md
+What the trace calls `gather[pallas]` there is two kernels, not one: a
+transpose keeps its scope's name, so the windowed gather and the
+gather's transpose (the two-pass scatter) both carry it; the row sums,
+which carried it too until PR 38, are `rows[pallas]` now (PERF.md
 section 5 has each one's time). The time is theirs together, a step and
 chip; the needed bytes are a chip's share of the gather's (distinct rows
 read, one row written an occurrence) and of the scatter's (one row read
 an occurrence, distinct rows written): the same count in both
-directions. The row sums re-read what the gather wrote and are left
-out, so the count stays a lower bound and the share cannot pass 100%.
+directions, a lower bound, so the share cannot pass 100%.
 Nothing to read on one chip: `gather_roofline` reads the single-device
 kernel, which has the name to itself."""
 

@@ -5,9 +5,13 @@
 
 makes the data from the seed, builds the trainer as `xflow train` does,
 takes the first steps, warms up, drives the window, compares with the
-plain reference, and prints one JSON object as its last line. The cell,
-its configuration, its traffic and its per-layer metrics are found by
-name from BENCHMARK.json and the files beside this one (README.md).
+plain reference, and prints one JSON object as its last line. A
+configuration whose file says `"path": "serve"` is served instead
+(`lib/serve_run.py`): the seed's table loaded by `xflow serve`'s body,
+requests from a child process over its unix socket, every answer
+compared. The cell, its configuration, its traffic and its per-layer
+metrics are found by name from BENCHMARK.json and the files beside this
+one (README.md).
 """
 
 from __future__ import annotations
@@ -51,6 +55,7 @@ def load_cell(workload: str, rehearsal: bool) -> tuple[dict, dict, dict, dict]:
     if rehearsal:
         with open(os.path.join(HERE, "rehearsal.json")) as f:
             tiny = json.load(f)
+        tiny = tiny.get("paths", {}).get(cfg.get("path", "train"), tiny)
         cfg.update(tiny["config"])
         traffic.update(tiny["traffic"])
     return bench, cell, cfg, traffic
@@ -118,6 +123,9 @@ def main(argv=None) -> int:
     if peak is None and not args.rehearsal:
         log(f"run.py: device kind {device['kind']!r} is not in peaks.json")
         return 3
+
+    if cfg.get("path", "train") == "serve":
+        return main_serve(args, bench, cell, cfg, traffic, jax, devices, device, peak, t_ready)
 
     from lib import compare, counts, drive, trace as tracelib, weights
     from lib.traffic import make_run_data, slots_of_ids
@@ -224,21 +232,75 @@ def main(argv=None) -> int:
     device["memory_peak_bytes"] = peak_bytes
     shutil.rmtree(workdir, ignore_errors=True)
 
-    result = {
-        "correct": bool(correct), "attempted": window["steps"],
-        "failed": window["bad_steps"], "metrics": out_metrics, "device": device,
-    }
+    extra = {"workload": cell["name"], "seed": args.seed, "rehearsal": args.rehearsal,
+             "client_s": t_ready - _T0, "window_s": window["window_s"], "passes": window["passes"],
+             "pass_end_s": [round(x, 4) for x in window["pass_s"]], "program": info}
+    return print_result(correct, window["steps"], window["bad_steps"], out_metrics, device, breakdown,
+                        extra, compared)
+
+
+def print_result(correct, attempted, failed, out_metrics, device, breakdown, extra, compared) -> int:
+    """The result's line, last on stdout; the numbers compared, each
+    beside its limit, last on stderr and last in the line."""
+    result = {"correct": bool(correct), "attempted": attempted, "failed": failed,
+              "metrics": out_metrics, "device": device}
     if breakdown:
         result["breakdown"] = breakdown
-    result.update({"workload": cell["name"], "seed": args.seed, "rehearsal": args.rehearsal,
-                   "client_s": t_ready - _T0, "window_s": window["window_s"], "passes": window["passes"],
-                   "pass_end_s": [round(x, 4) for x in window["pass_s"]], "program": info,
-                   "compared": compared})
+    result.update(extra)
+    result["compared"] = compared
     for name, c in compared.items():
         log(f"compared {name} = {c['value']:.6g}  limit {c['limit']:.6g}")
     log(f"correct = {correct}")
     print(json.dumps(result), flush=True)
     return 0
+
+
+def main_serve(args, bench, cell, cfg, traffic, jax, devices, device, peak, t_ready) -> int:
+    """A serve cell's run (`lib/serve_run.py`) and its result's line."""
+    from lib import serve_run, trace as tracelib
+
+    got = serve_run.run(args, cell, cfg, traffic, jax, devices, t_ready, HERE, ROOT, log)
+    out_metrics: dict = {}
+    breakdown = None
+    if not args.trace:
+        if not args.rehearsal:
+            for m in cell_metrics(bench, cell, "end_to_end"):
+                value = got["setup_s"] if m["name"] == "setup_s" else got["end_to_end"][m["name"]]
+                out_metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    else:
+        summary = None
+        if got["profile_dir"]:
+            loaded = tracelib.load(got["profile_dir"])
+            if args.keep:
+                tracelib.keep(loaded, args.keep, cell["name"])
+            names = tracelib.load_names(HERE)
+            summary = tracelib.summarize(loaded, names, 1)
+            summary["module_runs"] = tracelib.module_runs(loaded, names)
+        run = dict(got["run"], trace=summary, peak=peak,
+                   memory_peak_bytes=None if args.rehearsal else got["peak_bytes"], info=got["info"])
+        for m in cell_metrics(bench, cell, "per_layer"):
+            value = load_metric(m["name"]).read(run)
+            if value is not None and not args.rehearsal:  # a CPU run prints no time or share
+                out_metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+        if summary and summary.get("devices"):
+            device["busy_s"], device["window_s"] = summary["busy_s"], summary["window_s"]
+            breakdown = {"device_ops": summary["device_ops"], "idle_gaps": summary["idle_gaps"]}
+    device["memory_peak_bytes"] = got["peak_bytes"]
+    shutil.rmtree(got["workdir"], ignore_errors=True)
+    w = got["window"]  # the tail and the median are printed in every run, beside the count of requests
+    extra = {"workload": cell["name"], "seed": args.seed, "rehearsal": args.rehearsal,
+             "client_s": t_ready - _T0, "window_s": w["window_s"],
+             "window": {k: w[k] for k in ("requests", "answered", "shed", "rows_in_window", "requests_per_s",
+                                          "rows_per_s", "p50_ms", "p95_ms", "p99_ms", "max_ms",
+                                          "closed_s", "generator_late_p99_ms",
+                                          "offered")},
+             "program": got["info"]}
+    if args.rehearsal:  # a CPU run prints no time
+        extra["window"] = {k: w[k] for k in ("requests", "answered", "shed", "rows_in_window", "offered")}
+    elif got["spans"]:
+        extra["spans"] = got["spans"]
+    return print_result(got["correct"], got["attempted"], got["failed"], out_metrics, device, breakdown,
+                        extra, got["compared"])
 
 
 if __name__ == "__main__":

@@ -15,8 +15,14 @@ from lib import compare, drive, weights
 from lib.traffic import make_run_data, slots_of_ids
 from reference import core as refcore
 
+def _trains(cell: dict) -> bool:
+    """Serve cells have faults and tests of their own (test_serve_cell.py)."""
+    with open(os.path.join(harness.HERE, "configs", cell["config"] + ".json")) as f:
+        return json.load(f).get("path", "train") == "train"
+
+
 with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as _f:
-    _WORKLOADS = json.load(_f)["workloads"]
+    _WORKLOADS = [w for w in json.load(_f)["workloads"] if _trains(w)]
 CELLS = [w["name"] for w in _WORKLOADS]
 FOUR_CHIP = [w["name"] for w in _WORKLOADS if w["chips"] == 4]
 
