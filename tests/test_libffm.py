@@ -39,6 +39,27 @@ def test_feature_id_hashed_as_string():
     assert a != b
 
 
+@pytest.mark.parametrize("tok", [
+    "3", " 3\n", "-2.5e1", ".5", "1_0", "0x1A", "0x1p3", "inf", "-Infinity",
+    "nan(x)", "junk", "2e", "1e5000",
+    "١", "1١", "١2", "１", " 3", "ınf", "0x1p١", "1e١",
+])
+def test_strtod_reads_what_c_strtod_reads(tok):
+    # the native parser reads labels and field ids with strtod; the
+    # Python path must read the same number, non-ASCII text included
+    import ctypes
+    import math
+
+    from xflow_tpu.data.libffm import _strtod
+
+    libc = ctypes.CDLL(None)
+    libc.strtod.restype = ctypes.c_double
+    libc.strtod.argtypes = [ctypes.c_char_p, ctypes.c_void_p]
+    want = libc.strtod(tok.encode("utf-8"), None)
+    got = _strtod(tok)
+    assert (math.isnan(got) and math.isnan(want)) or got == want, tok
+
+
 def test_shard_path_convention():
     assert shard_path("/x/train", 0) == "/x/train-00000"
     assert shard_path("/x/train", 42) == "/x/train-00042"

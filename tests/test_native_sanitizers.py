@@ -1,7 +1,8 @@
 """Sanitizer runs for the native data plane (SURVEY.md §5 "race
-detection / sanitizers": the reference has none; our plan gives the one
-concurrent C++ component — the MT parser pool,
-native/parser.cc (mutex/condvar/atomics) — TSan and ASan+UBSan runs).
+detection / sanitizers": the reference has none; our plan gives the
+concurrent C++ components — the MT parser pool, native/parser.cc
+(mutex/condvar/atomics), and the serving entry point xf_parse_rows
+that request threads call at once — TSan and ASan+UBSan runs).
 
 Each case rebuilds parser.cc with `-fsanitize=...` (the flag joins the
 build-cache key, data/native.py _build_lib) and exercises the
@@ -59,6 +60,25 @@ DRIVER = textwrap.dedent("""
     assert native_count_rows(path, 4096) == sum(
         int(x.row_mask.sum()) for x in a
     )
+    # the serving entry point: one buffer, rows by offsets, four
+    # threads in the library at once (ctypes drops the GIL)
+    import threading
+    from xflow_tpu.data.native import native_parse_rows
+    rows = open(path, "rb").read().splitlines()
+    buf = b"".join(rows)
+    off = np.concatenate([[0], np.cumsum([len(r) for r in rows])])
+    want = native_parse_rows(buf, off, seq)
+    assert want[0] == len(rows), want[0]
+    got = [None] * 4
+    def parse(k):
+        got[k] = native_parse_rows(buf, off, seq)
+    threads = [threading.Thread(target=parse, args=(k,)) for k in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    for g in got:
+        assert g[0] == want[0] and all((x == y).all() for x, y in zip(g[1:], want[1:]))
     print("SANITIZED_PARITY_OK", len(a))
 """)
 

@@ -24,6 +24,7 @@ from xflow_tpu.serve.coalescer import (
     MicroBatcher,
     PendingRequest,
     RejectedRequest,
+    RowBlock,
     assemble_batch,
 )
 from xflow_tpu.serve.runner import BadRequest, ServeRunner, parse_rows
@@ -43,13 +44,13 @@ class FakeClock:
 def _rows(n, nnz=3):
     fields = [np.arange(nnz, dtype=np.int32) for _ in range(n)]
     slots = [np.full(nnz, 7, dtype=np.int32) for _ in range(n)]
-    return fields, slots
+    return RowBlock.from_rows(fields, slots, nnz)
 
 
 def test_coalescer_size_flush_before_window():
     clock = FakeClock()
     mb = MicroBatcher(max_rows=4, window_s=100.0, clock=clock)
-    futs = [mb.submit(*_rows(2)) for _ in range(2)]
+    futs = [mb.submit(_rows(2)) for _ in range(2)]
     # 4 rows queued = max_rows: take returns NOW despite the huge window
     group = mb.take(timeout=0.0)
     assert group is not None and sum(r.num_rows for r in group) == 4
@@ -59,7 +60,7 @@ def test_coalescer_size_flush_before_window():
 def test_coalescer_deadline_flush():
     clock = FakeClock()
     mb = MicroBatcher(max_rows=100, window_s=5.0, clock=clock)
-    mb.submit(*_rows(1))
+    mb.submit(_rows(1))
     assert mb.take(timeout=0.0) is None  # window not expired, no flush
     clock.t = 5.1
     group = mb.take(timeout=0.0)
@@ -69,8 +70,8 @@ def test_coalescer_deadline_flush():
 def test_coalescer_whole_request_boundary():
     clock = FakeClock()
     mb = MicroBatcher(max_rows=4, window_s=0.0, clock=clock)
-    mb.submit(*_rows(3))
-    mb.submit(*_rows(3))
+    mb.submit(_rows(3))
+    mb.submit(_rows(3))
     g1 = mb.take(timeout=0.0)
     # 3 + 3 > 4: the second request must NOT split across batches
     assert [r.num_rows for r in g1] == [3]
@@ -81,32 +82,33 @@ def test_coalescer_whole_request_boundary():
 def test_coalescer_rejects_oversized_and_backlog():
     mb = MicroBatcher(max_rows=4, window_s=0.0, max_queue_rows=6)
     with pytest.raises(RejectedRequest, match="max_batch"):
-        mb.submit(*_rows(5))
+        mb.submit(_rows(5))
     with pytest.raises(RejectedRequest, match="no rows"):
-        mb.submit([], [])
-    mb.submit(*_rows(4))
-    mb.submit(*_rows(2))
+        mb.submit(_rows(0))
+    mb.submit(_rows(4))
+    mb.submit(_rows(2))
     with pytest.raises(RejectedRequest, match="queue full"):
-        mb.submit(*_rows(1))
+        mb.submit(_rows(1))
 
 
 def test_coalescer_close_drains_then_none():
     clock = FakeClock()
     mb = MicroBatcher(max_rows=8, window_s=100.0, clock=clock)
-    mb.submit(*_rows(2))
+    mb.submit(_rows(2))
     mb.close()
     with pytest.raises(RejectedRequest):
-        mb.submit(*_rows(1))
+        mb.submit(_rows(1))
     assert len(mb.take(timeout=0.0)) == 1  # backlog drains on close
     assert mb.take(timeout=0.0) is None  # then the worker's exit signal
 
 
 def test_assemble_batch_padding_and_truncation():
-    r1 = PendingRequest(
-        fields=[np.asarray([1, 2], np.int32)], slots=[np.asarray([10, 20], np.int32)]
-    )
+    r1 = PendingRequest(RowBlock.from_rows(
+        [np.asarray([1, 2], np.int32)], [np.asarray([10, 20], np.int32)], 4
+    ))
     long = np.arange(9, dtype=np.int32)
-    r2 = PendingRequest(fields=[long], slots=[long + 100])
+    r2 = PendingRequest(RowBlock.from_rows([long], [long + 100], 4))
+    assert r2.block.nnz.tolist() == [9]  # counted before the 4-prefix cut
     arrays, spans = assemble_batch([r1, r2], batch_size=4, max_nnz=4)
     assert arrays["slots"].shape == (4, 4)
     np.testing.assert_array_equal(arrays["slots"][0], [10, 20, 0, 0])
@@ -486,6 +488,44 @@ def test_serve_metrics_window_schema(tmp_path):
     # the file passes the report tool's schema gate
     mr = _metrics_report()
     assert mr.main([str(path), "--check"]) == 0
+
+
+def test_python_parser_fallback_answers_bit_for_bit_and_is_counted(trained, tmp_path):
+    """`data.use_native_parser=false` serves the same pCTRs bit for bit
+    through the Python parser; the serve stream's windows and the
+    registry say which parser read the answered rows, and the stream
+    passes metrics_report --check."""
+    from xflow_tpu.serve.metrics import ServeMetrics
+    from xflow_tpu.serve.server import ServeApp
+    from xflow_tpu.telemetry import Registry
+
+    body = json.dumps({"rows": trained["rows"][:30]}).encode()  # max_batch 32
+    answers, windows = {}, {}
+    for native in (True, False):
+        path = tmp_path / f"serve_{native}.jsonl"
+        cfg = _serve_cfg(trained["work"] / "ck", **{
+            "data.use_native_parser": native, "serve.metrics_path": str(path)})
+        runner = ServeRunner(cfg)
+        runner.load()
+        reg = Registry()
+        app = ServeApp(cfg, runner, ServeMetrics(str(path), every_s=60.0, batch_size=32,
+                                                 registry=reg))
+        app.start()
+        try:
+            got = [app.handle_predict(body) for _ in range(3)]
+        finally:
+            app.close()
+        assert [s for s, _ in got] == [200] * 3
+        answers[native] = [p["pctr"] for _, p in got]
+        counts = {k: reg.counter(f"serve.parse_{k}_rows").value for k in ("native", "python")}
+        assert counts == ({"native": 90, "python": 0} if native else {"native": 0, "python": 90})
+        windows[native] = [r for r in (json.loads(x) for x in open(path)) if "batches" in r]
+        assert _metrics_report().main([str(path), "--check"]) == 0
+    assert answers[True] == answers[False]
+    for native, recs in windows.items():
+        assert sum(r["rows"] for r in recs) == 90
+        assert all(r["parse_native_rows"] == (r["rows"] if native else 0) for r in recs)
+        assert all(r["parse_python_rows"] == (0 if native else r["rows"]) for r in recs)
 
 
 def _metrics_report():

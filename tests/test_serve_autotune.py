@@ -26,7 +26,7 @@ from xflow_tpu.serve.autotune import (
     parse_ladder,
     pick_rung,
 )
-from xflow_tpu.serve.coalescer import MicroBatcher, assemble_batch
+from xflow_tpu.serve.coalescer import MicroBatcher, RowBlock, assemble_batch
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -206,16 +206,16 @@ def test_pick_rung_smallest_fit():
 def _rows(n, nnz=3):
     fields = [np.arange(nnz, dtype=np.int32) for _ in range(n)]
     slots = [np.full(nnz, 7, dtype=np.int32) for _ in range(n)]
-    return fields, slots
+    return RowBlock.from_rows(fields, slots, nnz)
 
 
 def test_release_rung_flushes_below_max_rows():
     clock = FakeClock()
     mb = MicroBatcher(max_rows=64, window_s=100.0, clock=clock)
     mb.set_release_rows(8)
-    mb.submit(*_rows(4))
+    mb.submit(_rows(4))
     assert mb.take(timeout=0.0) is None  # 4 < release rung 8
-    mb.submit(*_rows(4))
+    mb.submit(_rows(4))
     group = mb.take(timeout=0.0)  # 8 rows = the rung: size flush NOW
     assert group is not None and sum(r.num_rows for r in group) == 8
 
@@ -226,7 +226,7 @@ def test_release_rung_never_wedges_an_oversize_head():
     mb.set_release_rows(8)
     # a 32-row request is legal (max_rows contract unchanged) and must
     # pop whole even though it exceeds the release rung
-    mb.submit(*_rows(32))
+    mb.submit(_rows(32))
     group = mb.take(timeout=0.0)
     assert group is not None and [r.num_rows for r in group] == [32]
 
@@ -234,7 +234,7 @@ def test_release_rung_never_wedges_an_oversize_head():
 def test_set_window_takes_effect_on_queued_requests():
     clock = FakeClock()
     mb = MicroBatcher(max_rows=64, window_s=100.0, clock=clock)
-    mb.submit(*_rows(1))
+    mb.submit(_rows(1))
     assert mb.take(timeout=0.0) is None
     mb.set_window_s(1.0)  # the controller shrinks the deadline
     clock.t = 1.5

@@ -149,9 +149,12 @@ def _mb(clock, **kw):
 def _rows(n, nnz=2):
     import numpy as np
 
-    return (
+    from xflow_tpu.serve.coalescer import RowBlock
+
+    return RowBlock.from_rows(
         [np.arange(nnz, dtype=np.int32) for _ in range(n)],
         [np.full(nnz, 3, dtype=np.int32) for _ in range(n)],
+        nnz,
     )
 
 
@@ -159,18 +162,18 @@ def test_brownout_enters_on_sustained_backlog_and_sheds_low_priority():
     clock = FakeClock()
     mb, events = _mb(clock)
     for _ in range(3):  # 9 rows queued >= high_rows=8
-        mb.submit(*_rows(3))
+        mb.submit(_rows(3))
     assert not mb.brownout  # over the line but not SUSTAINED yet
     clock.t = 1.1
-    mb.submit(*_rows(1))  # the submit that observes the sustain window
+    mb.submit(_rows(1))  # the submit that observes the sustain window
     assert mb.brownout
     assert events == [(True, 10)]
     # low priority sheds with a retryable 503-class rejection...
     with pytest.raises(RejectedRequest, match="brownout") as ei:
-        mb.submit(*_rows(1), priority=-1)
+        mb.submit(_rows(1), priority=-1)
     assert ei.value.shed and not ei.value.client_error
     # ...normal priority still queues (the hard cliff is far away)
-    mb.submit(*_rows(1), priority=0)
+    mb.submit(_rows(1), priority=0)
     assert mb.queued_rows == 11
 
 
@@ -178,9 +181,9 @@ def test_brownout_shrinks_the_coalescing_window():
     clock = FakeClock()
     mb, _ = _mb(clock)
     for _ in range(4):
-        mb.submit(*_rows(3))
+        mb.submit(_rows(3))
     clock.t = 1.1
-    mb.submit(*_rows(3))  # sustained over high_rows: brownout enters
+    mb.submit(_rows(3))  # sustained over high_rows: brownout enters
     assert mb.brownout
     # drain down to exactly the t=1.1 request (3 rows > low_rows=2, so
     # the exit timer never starts while we measure)
@@ -200,9 +203,9 @@ def test_brownout_exits_after_sustained_drain_with_hysteresis():
     clock = FakeClock()
     mb, events = _mb(clock)
     for _ in range(4):
-        mb.submit(*_rows(3))
+        mb.submit(_rows(3))
     clock.t = 1.1
-    mb.submit(*_rows(1))  # 13 rows; brownout on
+    mb.submit(_rows(1))  # 13 rows; brownout on
     assert mb.brownout
     while mb.take(timeout=0.0) is not None:
         pass

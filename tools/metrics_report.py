@@ -122,6 +122,8 @@ SERVE_KEYS = (
     "window_s",
     "bad_requests",
     "shed_requests",
+    "parse_native_rows",
+    "parse_python_rows",
     "generation",
     "step",
     "data_freshness_s",
@@ -132,7 +134,10 @@ SERVE_KEYS = (
 # data_freshness_s is doubly optional: it only exists while the served
 # generation carries a publication sidecar (train.publish_every), so a
 # window without it means "not measurable", never a violation
-OPTIONAL_SERVE_KEYS = ("shed_requests", "data_freshness_s")
+OPTIONAL_SERVE_KEYS = (
+    "shed_requests", "parse_native_rows", "parse_python_rows",
+    "data_freshness_s",
+)
 # the key set every kind="autotune" decision record carries (serve
 # /autotune.py controller applied by server.ServeApp._autotune —
 # docs/OBSERVABILITY.md "SLO autotuning"); --check enforces

@@ -32,9 +32,15 @@ import numpy as np
 from xflow_tpu.hashing import fnv1a64, slot_of
 from xflow_tpu.jsonl import JsonlAppender
 
-_NUM_PREFIX = re.compile(r"^[+-]?(?:\d+\.?\d*|\.\d+)(?:[eE][+-]?\d+)?")
-_HEX_PREFIX = re.compile(r"^[+-]?0[xX][0-9a-fA-F]+(?:\.[0-9a-fA-F]*)?(?:[pP][+-]?\d+)?")
-_INFNAN_PREFIX = re.compile(r"^[+-]?(?:infinity|inf|nan(?:\([a-zA-Z0-9_]*\))?)", re.IGNORECASE)
+# re.ASCII: strtod reads ASCII digits and letters only ('١' is no 1,
+# 'ı' no i)
+_NUM_PREFIX = re.compile(r"^[+-]?(?:\d+\.?\d*|\.\d+)(?:[eE][+-]?\d+)?", re.ASCII)
+_HEX_PREFIX = re.compile(
+    r"^[+-]?0[xX][0-9a-fA-F]+(?:\.[0-9a-fA-F]*)?(?:[pP][+-]?\d+)?", re.ASCII
+)
+_INFNAN_PREFIX = re.compile(
+    r"^[+-]?(?:infinity|inf|nan(?:\([a-zA-Z0-9_]*\))?)", re.IGNORECASE | re.ASCII
+)
 # ASCII whitespace only: C code (and strtod) never treats unicode
 # whitespace specially, so the Python path must not either
 _ASCII_WS = " \t\r\n\v\f"
@@ -49,10 +55,11 @@ def _strtod(tok: str) -> float:
     same batches (round-1 divergence: `int(float(...))` raised on junk
     fgids while the native path yielded 0 and continued). Covers the
     strtod corners Python's float() handles differently: hex floats
-    (C99, float() rejects) and underscore digit groups (float() accepts,
-    strtod stops at the underscore)."""
+    (C99, float() rejects), underscore digit groups (float() accepts,
+    strtod stops at the underscore) and non-ASCII digits and spaces
+    (float() reads '١' as 1, strtod stops there)."""
     tok = tok.strip(_ASCII_WS)
-    if "_" not in tok:
+    if "_" not in tok and tok.isascii():
         try:
             return float(tok)  # fast path; also covers inf/nan like strtod
         except ValueError:

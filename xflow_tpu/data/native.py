@@ -82,6 +82,19 @@ def get_lib() -> ctypes.CDLL:
         lib.xf_parser_truncated.argtypes = [ctypes.c_void_p]
         lib.xf_parser_close.restype = None
         lib.xf_parser_close.argtypes = [ctypes.c_void_p]
+        lib.xf_parse_rows.restype = ctypes.c_long
+        lib.xf_parse_rows.argtypes = [
+            ctypes.c_char_p,
+            ctypes.POINTER(ctypes.c_int64),
+            ctypes.c_long,
+            ctypes.c_long,
+            ctypes.c_int,
+            ctypes.c_uint64,
+            ctypes.POINTER(ctypes.c_int32),
+            ctypes.POINTER(ctypes.c_int32),
+            ctypes.POINTER(ctypes.c_float),
+            ctypes.POINTER(ctypes.c_int32),
+        ]
         lib.xf_count_rows.restype = ctypes.c_long
         lib.xf_count_rows.argtypes = [ctypes.c_char_p, ctypes.c_long]
         lib.xf_mt_open.restype = ctypes.c_void_p
@@ -238,6 +251,40 @@ def native_count_rows(path: str, block_bytes: int) -> int:
     if n < 0:
         raise OSError(f"xf_count_rows failed for {path}")
     return n
+
+
+def native_parse_rows(buf: bytes, offsets: np.ndarray, cfg: DataConfig):
+    """Parse rows laid end to end in `buf` (row i is
+    buf[offsets[i]:offsets[i + 1]]) into padded blocks (xf_parse_rows).
+    Returns (rc, slots, fields, mask, nnz): slots/fields int32 and mask
+    float32 [n, max_nnz], nnz int32 [n] (parseable tokens a row before
+    truncation); rc is n when every row parsed, else the row it stopped
+    at (see parser.cc). ctypes releases the GIL for the call, so request
+    threads parse in parallel."""
+    lib = get_lib()
+    offsets = np.ascontiguousarray(offsets, np.int64)
+    n, k = offsets.size - 1, cfg.max_nnz
+    if n < 0 or (n and (offsets[0] < 0 or offsets[-1] > len(buf)
+                        or np.any(np.diff(offsets) < 0))):
+        raise ValueError(f"offsets do not lie within the {len(buf)}-byte buffer")
+    slots = np.zeros((n, k), np.int32)
+    fields = np.zeros((n, k), np.int32)
+    mask = np.zeros((n, k), np.float32)
+    nnz = np.zeros((n,), np.int32)
+    i32p = ctypes.POINTER(ctypes.c_int32)
+    rc = lib.xf_parse_rows(
+        buf,
+        offsets.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+        n,
+        k,
+        cfg.log2_slots,
+        cfg.hash_salt,
+        slots.ctypes.data_as(i32p),
+        fields.ctypes.data_as(i32p),
+        mask.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+        nnz.ctypes.data_as(i32p),
+    )
+    return int(rc), slots, fields, mask, nnz
 
 
 def native_hash(token: bytes, salt: int = 0) -> int:

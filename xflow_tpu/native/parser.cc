@@ -25,6 +25,9 @@
 //       -> rows filled (0 = EOF, -1 = error)
 //   xf_parser_truncated(handle) -> truncated-feature count so far
 //   xf_parser_close(handle)
+//   xf_parse_rows(buf, offsets, n_rows, max_nnz, log2_slots, salt,
+//                 slots*, fields*, mask*, nnz*)
+//       -> n_rows, or the first row that could not be parsed (serving)
 
 #include <atomic>
 #include <condition_variable>
@@ -248,6 +251,70 @@ void xf_parser_close(void* handle) {
   Parser* p = static_cast<Parser*>(handle);
   if (p->fp != nullptr) fclose(p->fp);
   delete p;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// A serving request's rows (serve/runner.py parse_request), from memory.
+//
+// Row i is buf[offsets[i], offsets[i+1]): rows are delimited by offsets,
+// never by '\n', since a row may hold one. Each row goes through the same
+// parse_row as a training line, under the rules serving adds in front of
+// data/libffm.parse_line: a row without a tab is features only and is
+// read as "0\t" + row, and the row is stripped of surrounding ASCII
+// whitespace INCLUDING '\n' (Python's strip; parse_row's own strip leaves
+// '\n'). No state outside the call: request threads parse at once.
+// ---------------------------------------------------------------------------
+
+namespace {
+
+inline bool is_line_ws(char c) {
+  return c == ' ' || c == '\t' || c == '\r' || c == '\n' || c == '\v' ||
+         c == '\f';
+}
+
+}  // namespace
+
+extern "C" {
+
+// Buffers as xf_parser_next_batch's, one row per request row (zeroed by
+// the caller): slots, fields int32 and mask float [n_rows, max_nnz]; nnz
+// int32 [n_rows] gets the row's parseable tokens BEFORE truncation at
+// max_nnz (the row keeps the first max_nnz). Returns n_rows when every
+// row parsed, else the first row that holds no parseable token (parsing
+// stops there).
+long xf_parse_rows(const char* buf, const int64_t* offsets, long n_rows,
+                   long max_nnz, int log2_slots, uint64_t salt,
+                   int32_t* slots, int32_t* fields, float* mask,
+                   int32_t* nnz) {
+  std::string line;
+  for (long i = 0; i < n_rows; ++i) {
+    const char* row = buf + offsets[i];
+    size_t len = static_cast<size_t>(offsets[i + 1] - offsets[i]);
+    line.clear();
+    if (memchr(row, '\t', len) == nullptr) line.append("0\t", 2);
+    line.append(row, len);
+    const char* s = line.data();
+    size_t n = line.size();
+    while (n > 0 && is_line_ws(s[n - 1])) --n;
+    while (n > 0 && is_line_ws(s[0])) {
+      ++s;
+      --n;
+    }
+    float label = 0.0f;
+    long truncated = 0;
+    float* mrow = mask + i * max_nnz;
+    if (!parse_row(s, n, max_nnz, log2_slots, salt, slots + i * max_nnz,
+                   fields + i * max_nnz, mrow, &label, &truncated)) {
+      return i;
+    }
+    long kept = 0;
+    while (kept < max_nnz && mrow[kept] != 0.0f) ++kept;
+    nnz[i] = static_cast<int32_t>(kept + truncated);
+    if (nnz[i] == 0) return i;
+  }
+  return n_rows;
 }
 
 }  // extern "C"

@@ -82,11 +82,54 @@ class BrownoutPolicy:
 
 
 @dataclass
-class PendingRequest:
-    """One queued request: ragged rows awaiting a device batch."""
+class RowBlock:
+    """A request's rows, parsed and padded to the batch's row width —
+    what the coalescer queues and `assemble_batch` copies whole.
 
-    fields: list  # per-row int32 arrays
-    slots: list  # per-row int32 arrays
+    `slots`, `fields` int32 and `mask` float32 are [n, max_nnz]: a row
+    keeps the prefix of its first max_nnz features (the training
+    parser's truncation contract, data/schema.make_batch) and its mask
+    is 1 there. `nnz` int32 [n] counts a row's parseable features before
+    that cut. `native` says which parser read the rows
+    (serve/runner.py parse_request)."""
+
+    slots: np.ndarray
+    fields: np.ndarray
+    mask: np.ndarray
+    nnz: np.ndarray
+    native: bool = False
+
+    @property
+    def num_rows(self) -> int:
+        return int(self.slots.shape[0])
+
+    @classmethod
+    def from_rows(cls, fields_rows: list, slots_rows: list, max_nnz: int) -> "RowBlock":
+        """Pad ragged per-row (fields, slots) arrays into a block."""
+        n = len(slots_rows)
+        slots = np.zeros((n, max_nnz), np.int32)
+        fields = np.zeros((n, max_nnz), np.int32)
+        mask = np.zeros((n, max_nnz), np.float32)
+        nnz = np.zeros((n,), np.int32)
+        for i, (rf, rs) in enumerate(zip(fields_rows, slots_rows)):
+            k = min(len(rs), max_nnz)
+            slots[i, :k] = rs[:k]
+            fields[i, :k] = rf[:k]
+            mask[i, :k] = 1.0
+            nnz[i] = len(rs)
+        return cls(slots, fields, mask, nnz)
+
+    def part(self, lo: int, hi: int) -> "RowBlock":
+        """Rows [lo, hi) as a block of views."""
+        return RowBlock(self.slots[lo:hi], self.fields[lo:hi],
+                        self.mask[lo:hi], self.nnz[lo:hi], self.native)
+
+
+@dataclass
+class PendingRequest:
+    """One queued request: its parsed rows awaiting a device batch."""
+
+    block: RowBlock
     future: Future = field(default_factory=Future)
     t_submit: float = 0.0
     priority: int = 0  # < 0 = sheddable under brownout (request header)
@@ -99,7 +142,7 @@ class PendingRequest:
 
     @property
     def num_rows(self) -> int:
-        return len(self.slots)
+        return self.block.num_rows
 
 
 class MicroBatcher:
@@ -218,7 +261,7 @@ class MicroBatcher:
             self._release_rows = max(1, min(int(rows), self.max_rows))
             self._cv.notify_all()
 
-    def submit(self, fields_rows: list, slots_rows: list,
+    def submit(self, block: RowBlock,
                priority: int = 0, trace: str = "", span: str = "") -> Future:
         """Queue one request's rows; returns the Future its caller
         blocks on. Raises RejectedRequest (never queues half a request)
@@ -226,7 +269,7 @@ class MicroBatcher:
         batcher is closed, or brownout is shedding its priority class
         (priority < 0 while the backlog runs hot). `trace`/`span` carry
         the request's tracing identity to the device worker."""
-        n = len(slots_rows)
+        n = block.num_rows
         if n == 0:
             raise RejectedRequest("request has no rows", client_error=True)
         if n > self.max_rows:
@@ -237,8 +280,8 @@ class MicroBatcher:
             )
         now = self._clock()
         req = PendingRequest(
-            fields=list(fields_rows), slots=list(slots_rows),
-            t_submit=now, priority=int(priority), trace=trace, span=span,
+            block=block, t_submit=now, priority=int(priority),
+            trace=trace, span=span,
         )
         flipped = None
         try:
@@ -351,16 +394,20 @@ class MicroBatcher:
 def assemble_batch(
     group: list, batch_size: int, max_nnz: int
 ) -> tuple[dict, list]:
-    """Pack a group's ragged rows into ONE padded row-major batch.
+    """Pack a group's requests into ONE padded row-major batch: each
+    request's block lands with one slice assignment an array.
 
     Returns (arrays, spans): `arrays` is the {slots, fields, mask,
     row_mask} dict the predict step consumes — fixed [batch_size,
     max_nnz] shape so the jitted program compiles ONCE — and `spans`
     is [(request, start, stop)] mapping each request back to its row
-    slice of the pctr output. Rows longer than max_nnz truncate to a
-    deterministic prefix (the training parser's contract,
-    data/schema.make_batch); padding rows are fully masked.
+    slice of the pctr output. Blocks come `max_nnz` wide from the
+    parser (rows already cut to their prefix); padding rows are fully
+    masked.
     """
+    total = sum(req.num_rows for req in group)
+    if total > batch_size:
+        raise ValueError(f"group rows {total} > batch_size {batch_size} (bug)")
     slots = np.zeros((batch_size, max_nnz), dtype=np.int32)
     fields = np.zeros((batch_size, max_nnz), dtype=np.int32)
     mask = np.zeros((batch_size, max_nnz), dtype=np.float32)
@@ -368,17 +415,14 @@ def assemble_batch(
     spans = []
     i = 0
     for req in group:
-        start = i
-        for rf, rs in zip(req.fields, req.slots):
-            k = min(len(rs), max_nnz)
-            slots[i, :k] = rs[:k]
-            fields[i, :k] = rf[:k]
-            mask[i, :k] = 1.0
-            row_mask[i] = 1.0
-            i += 1
-        spans.append((req, start, i))
-    if i > batch_size:
-        raise ValueError(f"group rows {i} > batch_size {batch_size} (bug)")
+        b = req.block
+        n = b.num_rows
+        slots[i : i + n] = b.slots
+        fields[i : i + n] = b.fields
+        mask[i : i + n] = b.mask
+        row_mask[i : i + n] = 1.0
+        spans.append((req, i, i + n))
+        i += n
     return (
         {"slots": slots, "fields": fields, "mask": mask, "row_mask": row_mask},
         spans,

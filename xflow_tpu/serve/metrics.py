@@ -10,7 +10,9 @@ Window records (one per `every_s`, only when traffic flowed) carry the
 serving SLO view: QPS, rows/s, batch-fill ratio (how well the
 coalescer amortizes the device), and the latency decomposition —
 queue-wait (coalescing delay), device (predict step), total
-(submit -> response ready) p50/p99. `generation`/`step` carry the
+(submit -> response ready) p50/p99 — and which parser read the
+window's answered rows (`parse_native_rows`, `parse_python_rows`: they
+sum to `rows`). `generation`/`step` carry the
 newest model generation this sink has recorded at flush time — a
 monotone high-water mark shared with the event path, so a window
 flushed right after a reload event can never stamp the pre-swap
@@ -51,6 +53,8 @@ SERVE_WINDOW_KEYS = (
     "window_s",
     "bad_requests",
     "shed_requests",
+    "parse_native_rows",
+    "parse_python_rows",
     "generation",
     "step",
 )
@@ -108,6 +112,7 @@ class ServeMetrics:
         self._capacity = 0  # sum of per-batch padded shapes (ladder rungs)
         self._bad = 0
         self._shed = 0
+        self._native_rows = 0
         self._queue_waits: list = []
         self._device: list = []
         self._totals: list = []
@@ -121,14 +126,18 @@ class ServeMetrics:
         device_s: float,
         totals_s: list,
         batch_size: Optional[int] = None,
+        native_rows: int = 0,
     ) -> None:
         """`batch_size` is the PADDED shape this batch shipped at — the
         ladder rung (serve/autotune.py). None (the pre-ladder callers)
         falls back to the constructor's fixed batch size, so batch_fill
-        keeps meaning rows/padded-capacity either way."""
+        keeps meaning rows/padded-capacity either way. `native_rows` of
+        the `n_rows` were read by the native parser, the rest by the
+        Python one (serve/runner.py parse_request)."""
         with self._lock:
             self._requests += n_requests
             self._rows += n_rows
+            self._native_rows += native_rows
             self._batches += 1
             self._capacity += int(batch_size) if batch_size else self._batch_size
             self._queue_waits.extend(queue_waits_s)
@@ -137,6 +146,8 @@ class ServeMetrics:
         self._reg.counter("serve.requests").inc(n_requests)
         self._reg.counter("serve.rows").inc(n_rows)
         self._reg.counter("serve.batches").inc()
+        self._reg.counter("serve.parse_native_rows").inc(native_rows)
+        self._reg.counter("serve.parse_python_rows").inc(n_rows - native_rows)
 
     def observe_bad_request(self) -> None:
         with self._lock:
@@ -214,6 +225,8 @@ class ServeMetrics:
                 "window_s": round(elapsed, 3),
                 "bad_requests": self._bad,
                 "shed_requests": self._shed,
+                "parse_native_rows": self._native_rows,
+                "parse_python_rows": self._rows - self._native_rows,
             }
             # stamp the high-water (generation, step): the caller's pair
             # is a snapshot that may predate a reload event already in

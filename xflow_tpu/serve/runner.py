@@ -31,6 +31,7 @@ serving cannot drift). Three properties carry the design:
 
 from __future__ import annotations
 
+import subprocess
 import sys
 import threading
 import time
@@ -40,6 +41,7 @@ from typing import Optional
 import numpy as np
 
 from xflow_tpu.config import Config
+from xflow_tpu.serve.coalescer import RowBlock
 
 
 class BadRequest(ValueError):
@@ -49,31 +51,110 @@ class BadRequest(ValueError):
     record, never crash the process."""
 
 
-def parse_rows(rows: list, dcfg) -> tuple[list, list]:
+_NATIVE_READY: Optional[bool] = None  # the library's load, tried once a process
+
+
+def native_parser_ready(dcfg) -> bool:
+    """Whether requests are parsed by the native parser: the rule
+    training's pipeline has — `data.use_native_parser` and the library
+    builds and loads (else one warning, and the Python parser). The
+    first call builds or loads it; `ServeRunner.load()` makes that
+    call, so the cost lands in set-up and never in a request."""
+    global _NATIVE_READY
+    if not dcfg.use_native_parser:
+        return False
+    if _NATIVE_READY is None:
+        try:
+            from xflow_tpu.data.native import get_lib
+
+            get_lib()
+            _NATIVE_READY = True
+        except (ImportError, OSError, RuntimeError, subprocess.SubprocessError) as e:
+            from xflow_tpu.data.pipeline import _warn_python_parser
+
+            _warn_python_parser(e)
+            _NATIVE_READY = False
+    return _NATIVE_READY
+
+
+def parse_request(rows: list, dcfg) -> RowBlock:
     """Parse request rows (libffm feature lists, optional leading label
-    ignored) into per-row (fields, slots) int32 arrays using the SAME
-    hash path training used (data/libffm.parse_line), so a served
-    feature lands in the same table slot it trained into.
+    ignored) into one padded RowBlock through the SAME hash path
+    training uses, so a served feature lands in the table slot it
+    trained into: the native parser's `parse_row` (GIL released for
+    the call) when `native_parser_ready`, else data/libffm.parse_line.
+    Both give the same block, row for row.
 
     Raises BadRequest on a non-string row or a row with zero parseable
     features — the quarantine philosophy: a row whose features ALL
     failed to parse must not silently predict the bias."""
+    if native_parser_ready(dcfg):
+        block = _parse_native(rows, dcfg)
+        if block is not None:
+            return block
+    return _parse_python(rows, dcfg)
+
+
+def _bad_row(i: int, row) -> BadRequest:
+    return BadRequest(f"row {i}: no parseable field:feature tokens in {row!r}")
+
+
+def _not_a_string(i: int, row) -> BadRequest:
+    return BadRequest(f"row {i}: expected a string, got {type(row).__name__}")
+
+
+def _parse_native(rows: list, dcfg) -> Optional[RowBlock]:
+    """The native read of `rows` (parser.cc xf_parse_rows), or None
+    where a row holds a lone surrogate: it has no UTF-8 bytes to hash,
+    and the Python parser answers it with a 400."""
+    from xflow_tpu.data.native import native_parse_rows
+
+    n = next((i for i, r in enumerate(rows) if not isinstance(r, str)), len(rows))
+    try:
+        encoded = [r.encode("utf-8") for r in rows[:n]]
+    except UnicodeEncodeError:
+        return None
+    offsets = np.zeros((n + 1,), np.int64)
+    np.cumsum(np.fromiter(map(len, encoded), np.int64, n), out=offsets[1:])
+    rc, slots, fields, mask, nnz = native_parse_rows(b"".join(encoded), offsets, dcfg)
+    if rc < n:
+        raise _bad_row(rc, rows[rc])
+    if n < len(rows):
+        raise _not_a_string(n, rows[n])
+    return RowBlock(slots, fields, mask, nnz, native=True)
+
+
+def _parse_python(rows: list, dcfg) -> RowBlock:
     from xflow_tpu.data.libffm import parse_line
 
     fields_rows, slots_rows = [], []
     for i, row in enumerate(rows):
         if not isinstance(row, str):
-            raise BadRequest(f"row {i}: expected a string, got {type(row).__name__}")
+            raise _not_a_string(i, row)
         # no tab = features-only (the serving shape); a tab means the
         # client sent a full libffm line and the label is ignored
         line = row if "\t" in row else "0\t" + row
-        parsed = parse_line(line, dcfg.log2_slots, dcfg.hash_salt)
+        try:
+            parsed = parse_line(line, dcfg.log2_slots, dcfg.hash_salt)
+        except UnicodeEncodeError as e:
+            raise BadRequest(f"row {i}: a feature id is not UTF-8 text ({e.reason})") from None
         if parsed is None or parsed[2].size == 0:
-            raise BadRequest(f"row {i}: no parseable field:feature tokens in {row!r}")
+            raise _bad_row(i, row)
         _, f, s = parsed
         fields_rows.append(f)
         slots_rows.append(s)
-    return fields_rows, slots_rows
+    return RowBlock.from_rows(fields_rows, slots_rows, dcfg.max_nnz)
+
+
+def parse_rows(rows: list, dcfg) -> tuple[list, list]:
+    """`parse_request` as per-row (fields, slots) int32 arrays, each
+    row its first data.max_nnz features."""
+    b = parse_request(rows, dcfg)
+    kept = np.minimum(b.nnz, b.slots.shape[1])
+    return (
+        [b.fields[i, :k] for i, k in enumerate(kept)],
+        [b.slots[i, :k] for i, k in enumerate(kept)],
+    )
 
 
 @dataclass
@@ -243,6 +324,7 @@ class ServeRunner:
         failure never kills serving."""
         from xflow_tpu.train import checkpoint as ckpt
 
+        native_parser_ready(self.cfg.data)  # the request parser, built in set-up
         with self._reload_lock:
             is_reload = self._gen is not None
             t0_wall, t0 = time.time(), time.perf_counter()
@@ -386,14 +468,12 @@ class ServeRunner:
         batch shape stays fixed. Returns (pctr [len(rows)], generation)."""
         from xflow_tpu.serve.coalescer import PendingRequest, assemble_batch
 
-        fields_rows, slots_rows = parse_rows(rows, self.cfg.data)
+        block = parse_request(rows, self.cfg.data)
         B = self.cfg.serve.max_batch
         out = np.empty((len(rows),), np.float32)
         gen = None
         for lo in range(0, len(rows), B):
-            req = PendingRequest(
-                fields=fields_rows[lo : lo + B], slots=slots_rows[lo : lo + B]
-            )
+            req = PendingRequest(block=block.part(lo, lo + B))
             arrays, _ = assemble_batch([req], B, self.cfg.data.max_nnz)
             p, gen = self.predict(arrays)
             out[lo : lo + req.num_rows] = p[: req.num_rows]

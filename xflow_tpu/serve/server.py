@@ -2,8 +2,9 @@
 
 Request path (docs/SERVING.md):
 
-    HTTP handler thread: parse JSON -> parse rows (same hash path as
-      training) -> MicroBatcher.submit -> block on the request Future
+    HTTP handler thread: parse JSON -> parse rows into one padded block
+      (the training parser's hash path; native, off the GIL, where it
+      builds) -> MicroBatcher.submit -> block on the request Future
     device worker thread: MicroBatcher.take (coalescing window) ->
       assemble ONE padded batch -> ServeRunner.predict -> scatter pctr
       slices + generation provenance back to every request's Future
@@ -43,7 +44,7 @@ from xflow_tpu.serve.coalescer import (
     assemble_batch,
 )
 from xflow_tpu.serve.metrics import ServeMetrics
-from xflow_tpu.serve.runner import BadRequest, CheckpointWatcher, ServeRunner, parse_rows
+from xflow_tpu.serve.runner import BadRequest, CheckpointWatcher, ServeRunner, parse_request
 from xflow_tpu.tracing import (
     FORCE_HEADER,
     PARENT_HEADER,
@@ -194,11 +195,13 @@ class ServeApp:
                 self._first_serve_span(gen)
             self._trace_batch(group, spans, t_batch, t_done, gen, rung)
             queue_waits, totals = [], []
-            n_rows = 0
+            n_rows = native_rows = 0
             for req, lo, hi in spans:
                 queue_waits.append(t_batch - req.t_submit)
                 totals.append(t_done - req.t_submit)
                 n_rows += hi - lo
+                if req.block.native:
+                    native_rows += hi - lo
                 req.future.set_result(
                     {
                         "pctr": [float(x) for x in p[lo:hi]],
@@ -210,7 +213,7 @@ class ServeApp:
                 )
             self.metrics.observe_batch(
                 len(group), n_rows, queue_waits, device_s, totals,
-                batch_size=rung,
+                batch_size=rung, native_rows=native_rows,
             )
             self._autotune(self.metrics.maybe_flush(
                 gen.gen, gen.step, freshness_s=_freshness(gen),
@@ -392,7 +395,7 @@ class ServeApp:
             return 400, {"error": 'expected {"rows": [<libffm feature row>, ...]}'}
         t_parse = time.perf_counter()
         try:
-            fields_rows, slots_rows = parse_rows(rows, self.cfg.data)
+            block = parse_request(rows, self.cfg.data)
         except BadRequest as e:
             self.metrics.observe_bad_request()
             return 400, {"error": str(e)}
@@ -406,7 +409,7 @@ class ServeApp:
             })
         try:
             fut = self.batcher.submit(
-                fields_rows, slots_rows, priority=priority,
+                block, priority=priority,
                 trace=trace_id if tr is not None else "",
                 span=root["span"] if tr is not None else "",
             )
