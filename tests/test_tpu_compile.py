@@ -637,3 +637,47 @@ def test_occupancy_sweep_compiles_in_seconds(one_chip, no_persistent_cache):
     t0 = time.perf_counter()
     jax.jit(lambda n: jnp.mean(_slot_any(n > 0, K))).lower(n).compile()
     assert time.perf_counter() - t0 < 60
+
+
+def test_served_table_is_pinned_where_its_gather_reads_it(topo, one_chip, no_persistent_cache):
+    """The serve cell's predict program (2^27 slots, one `[1024, 32]`
+    rung) with the table pinned as the one-chip runner pins it
+    (serve/runner.py `pinned_table_formats`): the packed table comes in
+    row-major in (8, 128) tiles, its 88 columns padded to 128 lanes, and
+    the gather reads it there — no table-sized copy, no temporaries
+    beside it. In the client's default layout the same program re-lays
+    the whole table in every call: 8.59 GB of temporaries. The pCTRs
+    leave in the default layout either way, so the pinned program may
+    be read back from the persistent cache."""
+    import jax
+    import jax.numpy as jnp
+
+    from xflow_tpu.config import override
+    from xflow_tpu.models import get_model
+    from xflow_tpu.models.predict import make_predict_fn
+    from xflow_tpu.optim import get_optimizer
+    from xflow_tpu.serve.runner import pinned_table_formats
+    from xflow_tpu.train.engine import KERNEL_LAYOUT
+    from xflow_tpu.train.state import init_state
+
+    cfg = override(_fm_cfg(27), **{"model.num_fields": 32, "serve.max_batch": 1024})
+    model = get_model("fm")
+    abstract = jax.eval_shape(lambda: init_state(model, get_optimizer(cfg.optim.name), cfg))
+    tables = _shapes(abstract.tables, one_chip)
+    B, N = cfg.serve.max_batch, cfg.data.max_nnz
+    batch = {k: jax.ShapeDtypeStruct((B, N), t, sharding=one_chip)
+             for k, t in (("slots", jnp.int32), ("fields", jnp.int32), ("mask", jnp.float32))}
+    batch["row_mask"] = jax.ShapeDtypeStruct((B,), jnp.float32, sharding=one_chip)
+    formats = pinned_table_formats(abstract.tables, topo.devices[0])
+    pinned = make_predict_fn(model, cfg, table_formats=formats).lower(tables, batch).compile()
+    plain = make_predict_fn(model, cfg).lower(tables, batch).compile()
+
+    padded = (1 << 27) // PACK * 128 * 4
+    mem = pinned.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1e9 < 8e9 < plain.memory_analysis().temp_size_in_bytes
+    assert padded <= mem.argument_size_in_bytes < padded + (1 << 20)  # + the batch
+    (table_in, _), _ = pinned.input_formats
+    assert table_in["wv"].layout == KERNEL_LAYOUT
+    assert pinned.output_formats == plain.output_formats  # the default: cache-safe
+    leaf = f"f32[{(1 << 27) // PACK},{PACK * K}]"
+    assert not [ln for ln in pinned.as_text().splitlines() if " copy(" in ln and leaf in ln]

@@ -43,18 +43,34 @@ def predict_fn(tables, batch: dict, model: Model, cfg: Config):
 
 
 def make_predict_fn(model: Model, cfg: Config, jit: bool = True,
-                    recorder=None, name: str = "predict") -> Callable:
+                    recorder=None, name: str = "predict",
+                    table_formats=None) -> Callable:
     """Returns pctr_step(tables, batch_arrays) -> pctr [B].
 
     The single factory behind `make_eval_step` AND the serve runner —
     offline eval and online serving cannot drift because they compile
     the same function. `recorder` (telemetry.CompileRecorder) routes
-    the jit through the compile-accounting seam under `name`."""
+    the jit through the compile-accounting seam under `name`.
+
+    `table_formats` (serve/runner.py `pinned_table_formats`; None = the
+    devices' defaults) compiles the program for tables placed in those
+    formats, so a call reads the table where it lies and re-lays none of
+    it. The pCTRs leave in the default layout, so the program stays in
+    the persistent cache (`compile_cache.no_persistent_cache`); its
+    compile records say so, with the layout of each pinned table."""
 
     def step(tables, batch: dict):
         return predict_fn(tables, batch, model, cfg)
 
     if not jit:
         return step
-    jitted = jax.jit(step)
-    return recorder.wrap(name, jitted) if recorder is not None else jitted
+    fields, pinned = {}, {}
+    if table_formats is not None:
+        from jax.experimental.layout import Format
+
+        pinned = {"in_shardings": (table_formats, None)}
+        fields = {"persistent_cache": True, "table_layouts": {
+            k: str(f.layout) for k, f in table_formats.items() if isinstance(f, Format)
+        }}
+    jitted = jax.jit(step, **pinned)
+    return recorder.wrap(name, jitted, **fields) if recorder is not None else jitted

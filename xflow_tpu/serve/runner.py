@@ -157,6 +157,29 @@ def parse_rows(rows: list, dcfg) -> tuple[list, list]:
     )
 
 
+def pinned_table_formats(tables, device) -> Optional[dict]:
+    """The formats a one-chip runner on `device` places a generation's
+    tables in at load, and compiles its predict rungs for: training's rule
+    (train/engine.py `kernel_layout`). On a TPU a rank-2 table — packed
+    [S/pack, pack·K] — is pinned row-major in (8, 128) tiles, the layout
+    its row gather reads; in the client's default, transposed and
+    unpadded, every predict call would first re-lay the whole table.
+    Every other leaf (LR's 1-D table) keeps the device's default. None
+    where nothing is pinned: off the TPU, or no rank-2 table."""
+    from jax.experimental.layout import Format
+    from jax.sharding import SingleDeviceSharding
+
+    from xflow_tpu.train import engine
+
+    sh = SingleDeviceSharding(device)
+    layout = engine.kernel_layout(device)
+    formats = {
+        k: Format(layout, sh) if layout is not None and v.ndim == 2 else sh
+        for k, v in tables.items()
+    }
+    return formats if any(isinstance(f, Format) for f in formats.values()) else None
+
+
 @dataclass
 class Generation:
     """One loaded model generation: the serving tables + provenance.
@@ -239,7 +262,10 @@ class ServeRunner:
             # its single [max_batch] program (docs/SERVING.md)
             self.rungs = (int(cfg.serve.max_batch),)
             self._predict_steps = {self.rungs[0]: self._predict_step}
+            self._table_formats = None
         else:
+            import jax
+
             from xflow_tpu.models.predict import make_predict_fn
             from xflow_tpu.serve.autotune import parse_ladder
 
@@ -250,19 +276,23 @@ class ServeRunner:
             # the single "predict.serve" program — byte-identical
             # compile records to the pre-ladder build.
             self.rungs = parse_ladder(cfg.serve)
+            # decided once: `load()` places every generation in these
+            # formats, and each rung is compiled for them
+            self._table_formats = pinned_table_formats(
+                self._template().tables, jax.devices()[0]
+            )
             if len(self.rungs) == 1:
                 names = {self.rungs[0]: "predict.serve"}
             else:
                 names = {r: f"predict.serve.b{r}" for r in self.rungs}
             self._predict_steps = {
                 r: make_predict_fn(
-                    self.model, cfg, recorder=recorder, name=names[r]
+                    self.model, cfg, recorder=recorder, name=names[r],
+                    table_formats=self._table_formats,
                 )
                 for r in self.rungs
             }
             self._predict_step = self._predict_steps[self.rungs[-1]]
-            import jax
-
             self._put = jax.device_put
 
     # ------------------------------------------------------------- loading
@@ -346,6 +376,10 @@ class ServeRunner:
                     f"serving step {self._gen.step} — keeping the current "
                     "generation"
                 )
+            tables = state.tables
+            del state  # orbax restores n and z too: gone before the relayout
+            # placed before the swap: no request sees it unpinned
+            tables, pinned, pinned_bytes = self._pin(tables)
             self._gen_counter += 1
             # publication sidecar (train.publish_every): best-effort —
             # read_publication returns None for unpublished steps and
@@ -357,7 +391,7 @@ class ServeRunner:
                 fmt=self.cfg.train.checkpoint_format,
             )
             gen = Generation(
-                tables=state.tables, step=int(step), gen=self._gen_counter,
+                tables=tables, step=int(step), gen=self._gen_counter,
                 publication=pub if isinstance(pub, dict) else None,
             )
             # the swap: one reference assignment — in-flight requests
@@ -368,9 +402,7 @@ class ServeRunner:
                 # the window a reload can lengthen request queues in
                 import jax
 
-                nbytes = int(sum(
-                    x.nbytes for x in jax.tree.leaves(state.tables)
-                ))
+                nbytes = int(sum(x.nbytes for x in jax.tree.leaves(tables)))
                 trace = pub.get("trace") if isinstance(pub, dict) else None
                 if isinstance(trace, str) and trace:
                     # a PUBLISHED step's swap CONTINUES the ingest trace
@@ -388,6 +420,8 @@ class ServeRunner:
                         step=gen.step,
                         generation=gen.gen,
                         bytes=nbytes,
+                        pinned_leaves=pinned,
+                        pinned_bytes=pinned_bytes,
                     )
                     gen.reload_span = rec["span"]
                 else:
@@ -401,8 +435,38 @@ class ServeRunner:
                         step=gen.step,
                         generation=gen.gen,
                         bytes=nbytes,
+                        pinned_leaves=pinned,
+                        pinned_bytes=pinned_bytes,
                     )
             return gen
+
+    def _pin(self, tables) -> tuple:
+        """A restored generation's tables -> (the tables in the formats
+        the predict rungs are compiled for, leaves pinned, their bytes as
+        the device holds them, padding included). Leaf by leaf, as
+        `Engine.place_state` does: each relayout is waited for and its
+        source deleted, so no more than one leaf exists twice at any
+        moment. Two generations, one serving and one being placed, must
+        both fit the device (docs/SERVING.md "Resident layout")."""
+        if self._table_formats is None:
+            return tables, 0, 0
+        import jax
+        from jax.experimental.layout import Format
+
+        from xflow_tpu.compile_cache import no_persistent_cache
+
+        out, pinned, nbytes = dict(tables), 0, 0
+        with no_persistent_cache():  # the relayout hands back a pinned leaf
+            for k, fmt in self._table_formats.items():
+                if not isinstance(fmt, Format):
+                    continue
+                old = out[k]
+                out[k] = jax.block_until_ready(jax.device_put(old, fmt))
+                if out[k] is not old:
+                    old.delete()
+                pinned += 1
+                nbytes += out[k].on_device_size_in_bytes()
+        return out, pinned, nbytes
 
     def maybe_reload(self) -> Optional[Generation]:
         """Reload iff a COMMITTED step newer than the serving one
