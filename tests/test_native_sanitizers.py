@@ -2,7 +2,8 @@
 detection / sanitizers": the reference has none; our plan gives the
 concurrent C++ components — the MT parser pool, native/parser.cc
 (mutex/condvar/atomics), and the serving entry point xf_parse_rows
-that request threads call at once — TSan and ASan+UBSan runs).
+that request threads call at once — TSan and ASan+UBSan runs), and the
+serving batch's assembler xf_assemble_rows.
 
 Each case rebuilds parser.cc with `-fsanitize=...` (the flag joins the
 build-cache key, data/native.py _build_lib) and exercises the
@@ -83,19 +84,73 @@ DRIVER = textwrap.dedent("""
 """)
 
 
-@pytest.mark.parametrize("sanitize", ["thread", "address,undefined"])
-def test_mt_parser_under_sanitizer(tmp_path, sanitize):
+# the serving batch's assembler (xf_assemble_rows, called with the GIL
+# held): a group that fills its rung exactly, one one-row request, and
+# blocks sliced out of a larger one, each against numpy's assemble_batch;
+# then four threads assembling from the same blocks at once
+ASSEMBLE_DRIVER = textwrap.dedent("""
+    import sys, threading
+    import numpy as np
+    from xflow_tpu.config import DataConfig
+    from xflow_tpu.serve.coalescer import PendingRequest, assemble_batch, assemble_packed
+    from xflow_tpu.serve.runner import parse_request
+    k = 10
+    rows = open(sys.argv[1]).read().splitlines()[:300]
+    big = parse_request(rows, DataConfig(log2_slots=16, max_nnz=k))
+    assert big.native and big.num_rows == 300
+    cases = {
+        "fills_the_rung": ([(0, 30), (30, 64)], 64),
+        "one_row": ([(5, 6)], 8),
+        "sliced": ([(10, 17), (100, 140), (299, 300)], 64),
+    }
+    for name, (parts, rung) in cases.items():
+        group = [PendingRequest(big.part(lo, hi)) for lo, hi in parts]
+        packed, _ = assemble_packed(group, rung, k)
+        want, _ = assemble_batch(group, rung, k)
+        for key in ("slots", "fields", "mask", "row_mask"):
+            got = packed[key]
+            assert got.dtype == want[key].dtype, (name, key)
+            assert (got.view(np.uint32) == want[key].view(np.uint32)).all(), (name, key)
+    group = [PendingRequest(big.part(lo, hi)) for lo, hi in cases["sliced"][0]]
+    want = assemble_packed(group, 64, k)[0].buf
+    got = [None] * 4
+    def assemble(i):
+        got[i] = assemble_packed(group, 64, k)[0].buf
+    threads = [threading.Thread(target=assemble, args=(i,)) for i in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert all((g == want).all() for g in got)
+    print("SANITIZED_ASSEMBLE_OK", len(cases))
+""")
+
+
+@pytest.fixture(scope="module")
+def sanitized(tmp_path_factory):
+    """sanitize flag -> (env that loads that build, or a reason to skip).
+    One build a flag for the module's tests."""
+    made: dict = {}
+
+    def get(sanitize: str) -> dict:
+        if sanitize not in made:
+            made[sanitize] = _sanitized_env(tmp_path_factory.mktemp("build"), sanitize)
+        env = made[sanitize]
+        if isinstance(env, str):
+            pytest.skip(env)
+        return env
+
+    return get
+
+
+def _sanitized_env(build_dir, sanitize: str):
     runtime = _runtime_for(sanitize)
     if runtime is None:
-        pytest.skip(f"no sanitizer runtime for -fsanitize={sanitize}")
-    generate_shards(str(tmp_path / "train"), 1, 700, num_fields=7,
-                    ids_per_field=40, seed=3)
-    driver = tmp_path / "driver.py"
-    driver.write_text(DRIVER)
+        return f"no sanitizer runtime for -fsanitize={sanitize}"
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO_ROOT + os.pathsep + env.get("PYTHONPATH", "")
     env["XFLOW_NATIVE_SANITIZE"] = sanitize
-    env["XFLOW_NATIVE_CACHE"] = str(tmp_path / "build")
+    env["XFLOW_NATIVE_CACHE"] = str(build_dir)
     # pre-build the sanitized .so WITHOUT the preload: the driver would
     # otherwise spawn g++ with the sanitizer runtime LD_PRELOADed into
     # it, which deadlocks outright on some kernels (observed on 4.4:
@@ -112,14 +167,32 @@ def test_mt_parser_under_sanitizer(tmp_path, sanitize):
     # the parser's handles are close()d explicitly, which IS exercised
     env["ASAN_OPTIONS"] = "detect_leaks=0:halt_on_error=1:exitcode=66"
     env["TSAN_OPTIONS"] = "halt_on_error=1:exitcode=66"
+    return env
+
+
+def _run_driver(tmp_path, env, source: str, ok: str) -> None:
+    generate_shards(str(tmp_path / "train"), 1, 700, num_fields=7,
+                    ids_per_field=40, seed=3)
+    driver = tmp_path / "driver.py"
+    driver.write_text(source)
     r = subprocess.run(
         [sys.executable, str(driver), str(tmp_path / "train-00000")],
         capture_output=True, text=True, env=env, timeout=600,
     )
     if r.returncode != 0 and "cannot be preloaded" in (r.stderr or ""):
-        pytest.skip(f"sanitizer runtime not preloadable: {runtime}")
+        pytest.skip(f"sanitizer runtime not preloadable: {env['LD_PRELOAD']}")
     assert r.returncode == 0, (
-        f"-fsanitize={sanitize} run failed "
+        f"-fsanitize={env['XFLOW_NATIVE_SANITIZE']} run failed "
         f"(rc={r.returncode})\nstdout:\n{r.stdout}\nstderr:\n{r.stderr}"
     )
-    assert "SANITIZED_PARITY_OK" in r.stdout, r.stdout
+    assert ok in r.stdout, r.stdout
+
+
+@pytest.mark.parametrize("sanitize", ["thread", "address,undefined"])
+def test_mt_parser_under_sanitizer(tmp_path, sanitized, sanitize):
+    _run_driver(tmp_path, sanitized(sanitize), DRIVER, "SANITIZED_PARITY_OK")
+
+
+@pytest.mark.parametrize("sanitize", ["thread", "address,undefined"])
+def test_assemble_rows_under_sanitizer(tmp_path, sanitized, sanitize):
+    _run_driver(tmp_path, sanitized(sanitize), ASSEMBLE_DRIVER, "SANITIZED_ASSEMBLE_OK")

@@ -26,6 +26,7 @@ import pytest
 
 from xflow_tpu.config import Config, override
 from xflow_tpu.jsonl import JsonlAppender, read_jsonl
+from xflow_tpu.serve.coalescer import assemble_batch
 from xflow_tpu.tracing import (
     FORCE_HEADER,
     PARENT_HEADER,
@@ -223,6 +224,9 @@ class FakeRunner:
     generation = FakeGen()
     compile_recorder = None
     span_sink = None
+
+    def assemble(self, group, rung):
+        return assemble_batch(group, rung, 8)
 
     def predict(self, arrays):
         n = arrays["row_mask"].shape[0]

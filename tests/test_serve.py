@@ -470,6 +470,28 @@ def test_concurrent_http_requests_coalesce(trained, http_app):
     assert batches < requests, (batches, requests)
 
 
+
+def test_server_queues_a_burst_of_new_connections(tmp_path):
+    """A burst of new connections waits in the listen queue instead of
+    being refused or reset: socketserver's backlog of 5 took six."""
+    import socket
+
+    from xflow_tpu.serve.server import make_unix_server
+
+    path = str(tmp_path / "s.sock")
+    srv = make_unix_server(None, path)  # not serving: nothing accepts
+    clients = []
+    try:
+        for _ in range(32):
+            c = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+            clients.append(c)
+            c.setblocking(False)
+            c.connect(path)  # BlockingIOError once the queue is full
+    finally:
+        for c in clients:
+            c.close()
+        srv.server_close()
+
 # ------------------------------------------------------- serve telemetry
 def test_serve_metrics_window_schema(tmp_path):
     from xflow_tpu.serve.metrics import SERVE_WINDOW_KEYS, ServeMetrics
@@ -526,6 +548,61 @@ def test_python_parser_fallback_answers_bit_for_bit_and_is_counted(trained, tmp_
         assert sum(r["rows"] for r in recs) == 90
         assert all(r["parse_native_rows"] == (r["rows"] if native else 0) for r in recs)
         assert all(r["parse_python_rows"] == (0 if native else r["rows"]) for r in recs)
+
+
+@pytest.mark.parametrize("path", ["solo", "mesh", "no_library"])
+def test_windows_count_the_natively_assembled_batches(trained, tmp_path, monkeypatch, path):
+    """A solo runner packs every batch with `xf_assemble_rows`, and the
+    serve stream's windows say so: `assemble_native_batches` equals
+    `batches`. The mesh runner, and a runner whose library does not
+    build, assemble numpy's dict: 0. The stream passes --check."""
+    import jax
+
+    from xflow_tpu.data import native
+    from xflow_tpu.serve import runner as runner_mod
+    from xflow_tpu.serve.metrics import ServeMetrics
+    from xflow_tpu.serve.server import ServeApp
+    from xflow_tpu.telemetry import Registry
+
+    path_file = tmp_path / "serve.jsonl"
+    cfg = _serve_cfg(trained["work"] / "ck", **{"serve.metrics_path": str(path_file)})
+    mesh = None
+    if path == "mesh":
+        if len(jax.devices()) < 2:
+            pytest.skip("single-device jax build")
+        from xflow_tpu.parallel.mesh import make_mesh
+
+        mesh = make_mesh(cfg)
+    if path == "no_library":
+        native.get_lib()  # the parser's handle, loaded before the build breaks
+
+        def no_compiler():
+            raise subprocess.CalledProcessError(1, ["g++"])
+
+        monkeypatch.setattr(native, "_build_lib", no_compiler)
+        monkeypatch.setattr(native, "_GIL_LIB", None)
+        monkeypatch.setattr(runner_mod, "_ASSEMBLY_READY", None)
+    runner = ServeRunner(cfg, mesh=mesh)
+    assert runner.packs_batches is (path == "solo")
+    runner.load()
+    reg = Registry()
+    app = ServeApp(cfg, runner, ServeMetrics(str(path_file), every_s=60.0, batch_size=32,
+                                             registry=reg))
+    app.start()
+    try:
+        got = [app.handle_predict(json.dumps({"rows": trained["rows"][i:i + 20]}).encode())
+               for i in range(0, 60, 20)]
+    finally:
+        app.close()
+    assert [s for s, _ in got] == [200] * 3
+    np.testing.assert_allclose([x for _, p in got for x in p["pctr"]],
+                               np.asarray(trained["preds"][:60], np.float32), atol=1e-5)
+    recs = [r for r in (json.loads(x) for x in open(path_file)) if "batches" in r]
+    assert sum(r["batches"] for r in recs) == 3
+    for r in recs:
+        assert r["assemble_native_batches"] == (r["batches"] if path == "solo" else 0)
+    assert reg.counter("serve.assemble_native_batches").value == (3 if path == "solo" else 0)
+    assert _metrics_report().main([str(path_file), "--check"]) == 0
 
 
 def _metrics_report():

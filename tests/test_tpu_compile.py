@@ -681,3 +681,41 @@ def test_served_table_is_pinned_where_its_gather_reads_it(topo, one_chip, no_per
     assert pinned.output_formats == plain.output_formats  # the default: cache-safe
     leaf = f"f32[{(1 << 27) // PACK},{PACK * K}]"
     assert not [ln for ln in pinned.as_text().splitlines() if " copy(" in ln and leaf in ln]
+
+
+def test_packed_serve_program_reads_the_pinned_table(topo, one_chip, no_persistent_cache):
+    """The program a one-chip runner compiles for the serve cell's
+    `[1024, 32]` rung (serve/runner.py `make_packed_predict_fn`): one
+    int32 vector of 3 x 1024 x 32 + 1 in, unpacked by static slices and
+    a bitcast, then the same forward over the pinned table — no
+    table-sized copy, no temporaries beside it, the argument the table
+    and the vector alone, and the program's name on a trace `jit_step`."""
+    import jax
+    import jax.numpy as jnp
+
+    from xflow_tpu.config import override
+    from xflow_tpu.models import get_model
+    from xflow_tpu.optim import get_optimizer
+    from xflow_tpu.serve.runner import make_packed_predict_fn, pinned_table_formats
+    from xflow_tpu.train.engine import KERNEL_LAYOUT
+    from xflow_tpu.train.state import init_state
+
+    cfg = override(_fm_cfg(27), **{"model.num_fields": 32, "serve.max_batch": 1024})
+    model = get_model("fm")
+    abstract = jax.eval_shape(lambda: init_state(model, get_optimizer(cfg.optim.name), cfg))
+    tables = _shapes(abstract.tables, one_chip)
+    B, N = cfg.serve.max_batch, cfg.data.max_nnz
+    packed = jax.ShapeDtypeStruct((3 * B * N + 1,), jnp.int32, sharding=one_chip)
+    formats = pinned_table_formats(abstract.tables, topo.devices[0])
+    lowered = make_packed_predict_fn(model, cfg, table_formats=formats).lower(tables, packed)
+    assert lowered.as_text().splitlines()[0].startswith("module @jit_step")
+    compiled = lowered.compile()
+
+    padded = (1 << 27) // PACK * 128 * 4
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 0.1e9
+    assert padded + packed.size * 4 <= mem.argument_size_in_bytes < padded + (1 << 20)
+    (table_in, _), _ = compiled.input_formats
+    assert table_in["wv"].layout == KERNEL_LAYOUT
+    leaf = f"f32[{(1 << 27) // PACK},{PACK * K}]"
+    assert not [ln for ln in compiled.as_text().splitlines() if " copy(" in ln and leaf in ln]

@@ -124,6 +124,7 @@ SERVE_KEYS = (
     "shed_requests",
     "parse_native_rows",
     "parse_python_rows",
+    "assemble_native_batches",
     "generation",
     "step",
     "data_freshness_s",
@@ -136,7 +137,7 @@ SERVE_KEYS = (
 # window without it means "not measurable", never a violation
 OPTIONAL_SERVE_KEYS = (
     "shed_requests", "parse_native_rows", "parse_python_rows",
-    "data_freshness_s",
+    "assemble_native_batches", "data_freshness_s",
 )
 # the key set every kind="autotune" decision record carries (serve
 # /autotune.py controller applied by server.ServeApp._autotune —

@@ -23,6 +23,7 @@ from xflow_tpu.data.schema import SparseBatch
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native", "parser.cc")
 _LIB = None
+_GIL_LIB = None
 
 
 def _build_lib() -> str:
@@ -285,6 +286,54 @@ def native_parse_rows(buf: bytes, offsets: np.ndarray, cfg: DataConfig):
         nnz.ctypes.data_as(i32p),
     )
     return int(rc), slots, fields, mask, nnz
+
+
+def get_gil_lib() -> ctypes.PyDLL:
+    """The same library through a handle whose calls KEEP the GIL, for
+    `native_assemble_rows` alone.
+
+    A CDLL call releases the GIL and takes it back on return. A serving
+    worker that hands it off while request threads wait for it needs
+    ~0.5 ms to win it back under the benchmark's 16 callers, each time;
+    the copies of a whole packed batch (~0.4 MB) take tens of µs with
+    the GIL held. So the call that only copies keeps it, and the worker
+    pays no hand-off for assembly."""
+    global _GIL_LIB
+    if _GIL_LIB is None:
+        lib = ctypes.PyDLL(_build_lib())
+        lib.xf_assemble_rows.restype = ctypes.c_long
+        lib.xf_assemble_rows.argtypes = [
+            ctypes.c_void_p,
+            ctypes.c_long,
+            ctypes.c_long,
+            ctypes.c_long,
+            ctypes.c_void_p,
+        ]
+        _GIL_LIB = lib
+    return _GIL_LIB
+
+
+def native_assemble_rows(dst: np.ndarray, rung: int, max_nnz: int, blocks: list) -> int:
+    """Fill `dst`, int32 [3 * rung * max_nnz + 1], with one packed
+    serving batch (xf_assemble_rows): the slots, fields and mask planes
+    of `blocks` — (slots, fields, mask) C-contiguous [n, max_nnz], int32
+    / int32 / float32, laid end to end — the padding rows zeroed, and
+    the count of real rows last. Returns that count."""
+    if dst.dtype != np.int32 or dst.size != 3 * rung * max_nnz + 1 or not dst.flags.c_contiguous:
+        raise ValueError(f"dst must be C-contiguous int32 [{3 * rung * max_nnz + 1}]")
+    desc = []
+    for arrays in blocks:
+        for a, dtype in zip(arrays, (np.int32, np.int32, np.float32)):
+            if (a.dtype != dtype or a.shape != arrays[0].shape or a.shape[1:] != (max_nnz,)
+                    or not a.flags.c_contiguous):
+                raise ValueError(f"a block plane must be C-contiguous {np.dtype(dtype)} [n, {max_nnz}]")
+            desc.append(a.ctypes.data)
+        desc.append(arrays[0].shape[0])
+    rec = np.array(desc, np.int64)
+    rc = get_gil_lib().xf_assemble_rows(dst.ctypes.data, rung, max_nnz, len(blocks), rec.ctypes.data)
+    if rc < 0:
+        raise ValueError(f"{sum(b[0].shape[0] for b in blocks)} rows do not fit a rung of {rung}")
+    return int(rc)
 
 
 def native_hash(token: bytes, salt: int = 0) -> int:

@@ -64,6 +64,14 @@ def make_predict_fn(model: Model, cfg: Config, jit: bool = True,
 
     if not jit:
         return step
+    return jit_predict(step, recorder=recorder, name=name, table_formats=table_formats)
+
+
+def jit_predict(step: Callable, recorder=None, name: str = "predict",
+                table_formats=None) -> Callable:
+    """`step(tables, batch)` jitted as `make_predict_fn` jits the forward:
+    compiled for tables in `table_formats`, through `recorder` under
+    `name`. The serve runner jits its packed-batch wrapper here."""
     fields, pinned = {}, {}
     if table_formats is not None:
         from jax.experimental.layout import Format

@@ -28,6 +28,8 @@
 //   xf_parse_rows(buf, offsets, n_rows, max_nnz, log2_slots, salt,
 //                 slots*, fields*, mask*, nnz*)
 //       -> n_rows, or the first row that could not be parsed (serving)
+//   xf_assemble_rows(dst*, rung, max_nnz, n_blocks, blocks*)
+//       -> rows assembled into one packed serving batch, or -1
 
 #include <atomic>
 #include <condition_variable>
@@ -315,6 +317,55 @@ long xf_parse_rows(const char* buf, const int64_t* offsets, long n_rows,
     if (nnz[i] == 0) return i;
   }
   return n_rows;
+}
+
+}  // extern "C"
+
+// ---------------------------------------------------------------------------
+// A serving batch (serve/coalescer.py assemble_packed), from the padded
+// blocks of the requests that ride it.
+//
+// dst is ONE int32 vector of 3 * rung * max_nnz + 1, planar: the slots
+// plane [rung, max_nnz], the fields plane, the mask plane (the float32
+// mask's bits, copied as they are) and, last, the count of real rows. A
+// block's rows follow its predecessor's, one memcpy a plane; the rows
+// after the last block are zeroed. data/native.py binds this entry point
+// through a handle that keeps the GIL: it only copies, and must not block.
+// ---------------------------------------------------------------------------
+
+extern "C" {
+
+// blocks holds n_blocks records of four int64: the addresses of a block's
+// C-contiguous slots, fields (int32) and mask (float32) [rows, max_nnz],
+// then its rows. Returns the rows assembled, or -1 (dst untouched) when a
+// count is negative or the rows exceed rung.
+long xf_assemble_rows(int32_t* dst, long rung, long max_nnz, long n_blocks,
+                      const int64_t* blocks) {
+  long total = 0;
+  for (long b = 0; b < n_blocks; ++b) {
+    const long n = static_cast<long>(blocks[4 * b + 3]);
+    if (n < 0 || n > rung - total) return -1;
+    total += n;
+  }
+  const long plane = rung * max_nnz;
+  int32_t* planes[3] = {dst, dst + plane, dst + 2 * plane};
+  long at = 0;  // the next free element of each plane
+  for (long b = 0; b < n_blocks; ++b) {
+    const int64_t* rec = blocks + 4 * b;
+    const long len = static_cast<long>(rec[3]) * max_nnz;
+    for (int p = 0; p < 3; ++p) {
+      if (len > 0) {
+        memcpy(planes[p] + at, reinterpret_cast<const void*>(rec[p]),
+               static_cast<size_t>(len) * sizeof(int32_t));
+      }
+    }
+    at += len;
+  }
+  for (int p = 0; p < 3; ++p) {
+    memset(planes[p] + at, 0, static_cast<size_t>(plane - at) * sizeof(int32_t));
+  }
+  dst[3 * plane] = static_cast<int32_t>(total);
+  return total;
 }
 
 }  // extern "C"

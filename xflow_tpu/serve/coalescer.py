@@ -25,6 +25,7 @@ from __future__ import annotations
 import threading
 import time
 from collections import deque
+from collections.abc import Mapping
 from concurrent.futures import Future
 from dataclasses import dataclass, field
 from typing import TYPE_CHECKING, Callable, Optional
@@ -427,3 +428,82 @@ def assemble_batch(
         {"slots": slots, "fields": fields, "mask": mask, "row_mask": row_mask},
         spans,
     )
+
+
+class PackedBatch(Mapping):
+    """One device batch as ONE int32 vector `buf` of 3·rung·max_nnz + 1,
+    planar: the slots plane [rung, max_nnz], the fields plane, the mask
+    plane (the float32 mask's bits) and, last, the count of real rows,
+    which fill the first rows; the padding rows are zero. What the solo
+    runner's predict program takes (serve/runner.py unpack_batch): one
+    argument, one transfer. It reads as the dict `assemble_batch` makes
+    (views of `buf`; `row_mask` from the count), for callers that want
+    the arrays."""
+
+    def __init__(self, buf: np.ndarray, rung: int, max_nnz: int):
+        self.buf, self.rung, self.max_nnz = buf, int(rung), int(max_nnz)
+
+    @property
+    def num_rows(self) -> int:
+        return int(self.buf[-1])
+
+    def __getitem__(self, key: str) -> np.ndarray:
+        plane = self.rung * self.max_nnz
+        shape = (self.rung, self.max_nnz)
+        if key == "slots":
+            return self.buf[:plane].reshape(shape)
+        if key == "fields":
+            return self.buf[plane:2 * plane].reshape(shape)
+        if key == "mask":
+            return self.buf[2 * plane:3 * plane].view(np.float32).reshape(shape)
+        if key == "row_mask":
+            return (np.arange(self.rung) < self.num_rows).astype(np.float32)
+        raise KeyError(key)
+
+    def __iter__(self):
+        return iter(("slots", "fields", "mask", "row_mask"))
+
+    def __len__(self) -> int:
+        return 4
+
+
+def assemble_packed(
+    group: list, batch_size: int, max_nnz: int
+) -> tuple[PackedBatch, list]:
+    """`assemble_batch`'s batch as one `PackedBatch`, filled by ONE
+    native call that keeps the GIL (data/native.py
+    native_assemble_rows): no buffer zeroed by numpy, no copy per
+    request and plane, each of which would hand the GIL to the request
+    threads and wait to win it back. Returns (batch, spans) as
+    `assemble_batch` does."""
+    from xflow_tpu.data.native import native_assemble_rows
+
+    buf = np.empty((3 * batch_size * max_nnz + 1,), np.int32)  # np.zeros would drop the GIL
+    blocks, spans = [], []
+    i = 0
+    for req in group:
+        b = req.block
+        blocks.append((np.ascontiguousarray(b.slots, np.int32),
+                       np.ascontiguousarray(b.fields, np.int32),
+                       np.ascontiguousarray(b.mask, np.float32)))
+        spans.append((req, i, i + b.num_rows))
+        i += b.num_rows
+    if i > batch_size:
+        raise ValueError(f"group rows {i} > batch_size {batch_size} (bug)")
+    native_assemble_rows(buf, batch_size, max_nnz, blocks)
+    return PackedBatch(buf, batch_size, max_nnz), spans
+
+
+def pack_arrays(arrays: dict) -> PackedBatch:
+    """A batch dict as a `PackedBatch` (numpy; direct `predict` callers):
+    its real rows are the `row_mask` rows, which lead."""
+    slots = np.asarray(arrays["slots"], np.int32)
+    rung, max_nnz = slots.shape
+    n = int(np.count_nonzero(arrays["row_mask"]))
+    buf = np.concatenate([
+        slots.ravel(),
+        np.asarray(arrays["fields"], np.int32).ravel(),
+        np.asarray(arrays["mask"], np.float32).view(np.int32).ravel(),
+        np.asarray([n], np.int32),
+    ])
+    return PackedBatch(buf, rung, max_nnz)

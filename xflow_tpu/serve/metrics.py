@@ -12,8 +12,9 @@ coalescer amortizes the device), and the latency decomposition —
 queue-wait (coalescing delay), device (predict step), total
 (submit -> response ready) p50/p99 — and which parser read the
 window's answered rows (`parse_native_rows`, `parse_python_rows`: they
-sum to `rows`). `generation`/`step` carry the
-newest model generation this sink has recorded at flush time — a
+sum to `rows`), and how many of its batches the native assembler packed
+(`assemble_native_batches`, at most `batches`). `generation`/`step`
+carry the newest model generation this sink has recorded at flush time — a
 monotone high-water mark shared with the event path, so a window
 flushed right after a reload event can never stamp the pre-swap
 pair (the stream stays monotone in file order even though the
@@ -55,6 +56,7 @@ SERVE_WINDOW_KEYS = (
     "shed_requests",
     "parse_native_rows",
     "parse_python_rows",
+    "assemble_native_batches",
     "generation",
     "step",
 )
@@ -113,6 +115,7 @@ class ServeMetrics:
         self._bad = 0
         self._shed = 0
         self._native_rows = 0
+        self._native_batches = 0
         self._queue_waits: list = []
         self._device: list = []
         self._totals: list = []
@@ -127,17 +130,21 @@ class ServeMetrics:
         totals_s: list,
         batch_size: Optional[int] = None,
         native_rows: int = 0,
+        native_assembly: bool = False,
     ) -> None:
         """`batch_size` is the PADDED shape this batch shipped at — the
         ladder rung (serve/autotune.py). None (the pre-ladder callers)
         falls back to the constructor's fixed batch size, so batch_fill
         keeps meaning rows/padded-capacity either way. `native_rows` of
         the `n_rows` were read by the native parser, the rest by the
-        Python one (serve/runner.py parse_request)."""
+        Python one (serve/runner.py parse_request). `native_assembly`:
+        the batch was packed by `xf_assemble_rows`
+        (serve/coalescer.py assemble_packed)."""
         with self._lock:
             self._requests += n_requests
             self._rows += n_rows
             self._native_rows += native_rows
+            self._native_batches += int(native_assembly)
             self._batches += 1
             self._capacity += int(batch_size) if batch_size else self._batch_size
             self._queue_waits.extend(queue_waits_s)
@@ -148,6 +155,7 @@ class ServeMetrics:
         self._reg.counter("serve.batches").inc()
         self._reg.counter("serve.parse_native_rows").inc(native_rows)
         self._reg.counter("serve.parse_python_rows").inc(n_rows - native_rows)
+        self._reg.counter("serve.assemble_native_batches").inc(int(native_assembly))
 
     def observe_bad_request(self) -> None:
         with self._lock:
@@ -227,6 +235,7 @@ class ServeMetrics:
                 "shed_requests": self._shed,
                 "parse_native_rows": self._native_rows,
                 "parse_python_rows": self._rows - self._native_rows,
+                "assemble_native_batches": self._native_batches,
             }
             # stamp the high-water (generation, step): the caller's pair
             # is a snapshot that may predate a reload event already in

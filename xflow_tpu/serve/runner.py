@@ -41,7 +41,13 @@ from typing import Optional
 import numpy as np
 
 from xflow_tpu.config import Config
-from xflow_tpu.serve.coalescer import RowBlock
+from xflow_tpu.serve.coalescer import (
+    PackedBatch,
+    RowBlock,
+    assemble_batch,
+    assemble_packed,
+    pack_arrays,
+)
 
 
 class BadRequest(ValueError):
@@ -75,6 +81,65 @@ def native_parser_ready(dcfg) -> bool:
             _warn_python_parser(e)
             _NATIVE_READY = False
     return _NATIVE_READY
+
+
+_ASSEMBLY_READY: Optional[bool] = None  # the GIL-keeping handle's load, tried once
+
+
+def native_assembly_ready() -> bool:
+    """Whether a solo runner packs its batches with the native
+    `xf_assemble_rows` (serve/coalescer.py assemble_packed): the library
+    builds and loads (else one warning, and `assemble_batch`'s dict).
+    Not a config key: the library is the only condition."""
+    global _ASSEMBLY_READY
+    if _ASSEMBLY_READY is None:
+        try:
+            from xflow_tpu.data.native import get_gil_lib
+
+            get_gil_lib()
+            _ASSEMBLY_READY = True
+        except (ImportError, OSError, RuntimeError, subprocess.SubprocessError) as e:
+            from xflow_tpu.telemetry import warn_once
+
+            warn_once(
+                "native_assembly",
+                f"native batch assembly unavailable ({type(e).__name__}: {e}); "
+                "serve batches are assembled in numpy",
+            )
+            _ASSEMBLY_READY = False
+    return _ASSEMBLY_READY
+
+
+def unpack_batch(packed, max_nnz: int) -> dict:
+    """In the program: a `PackedBatch` vector -> the {slots, fields,
+    mask, row_mask} batch `models/predict.py predict_fn` reads. Static
+    slices and reshapes; the mask's bits are the float32 mask's, exact."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    plane = (packed.shape[0] - 1) // 3
+    shape = (plane // max_nnz, max_nnz)
+    return {
+        "slots": packed[:plane].reshape(shape),
+        "fields": packed[plane:2 * plane].reshape(shape),
+        "mask": lax.bitcast_convert_type(packed[2 * plane:3 * plane], jnp.float32).reshape(shape),
+        "row_mask": (jnp.arange(shape[0]) < packed[3 * plane]).astype(jnp.float32),
+    }
+
+
+def make_packed_predict_fn(model, cfg: Config, recorder=None, name: str = "predict",
+                           table_formats=None):
+    """`make_predict_fn`'s program over a `PackedBatch` vector: unpack,
+    then the unchanged forward. Its function keeps the name `step`, so
+    the program keeps its name on a device trace (`jit_step`)."""
+    from xflow_tpu.models.predict import jit_predict, predict_fn
+
+    max_nnz = int(cfg.data.max_nnz)
+
+    def step(tables, packed):
+        return predict_fn(tables, unpack_batch(packed, max_nnz), model, cfg)
+
+    return jit_predict(step, recorder=recorder, name=name, table_formats=table_formats)
 
 
 def parse_request(rows: list, dcfg) -> RowBlock:
@@ -263,6 +328,7 @@ class ServeRunner:
             self.rungs = (int(cfg.serve.max_batch),)
             self._predict_steps = {self.rungs[0]: self._predict_step}
             self._table_formats = None
+            self.packs_batches = False
         else:
             import jax
 
@@ -285,8 +351,13 @@ class ServeRunner:
                 names = {self.rungs[0]: "predict.serve"}
             else:
                 names = {r: f"predict.serve.b{r}" for r in self.rungs}
+            # a batch's host buffer in one native call that keeps the
+            # GIL, and one argument to its program (docs/SERVING.md
+            # "Request path"); numpy's dict where the library is missing
+            self.packs_batches = native_assembly_ready()
+            make = make_packed_predict_fn if self.packs_batches else make_predict_fn
             self._predict_steps = {
-                r: make_predict_fn(
+                r: make(
                     self.model, cfg, recorder=recorder, name=names[r],
                     table_formats=self._table_formats,
                 )
@@ -495,21 +566,36 @@ class ServeRunner:
             return None
 
     # ----------------------------------------------------------- predicting
-    def predict(self, arrays: dict) -> tuple[np.ndarray, Generation]:
+    def assemble(self, group: list, rung: int) -> tuple:
+        """A group of requests as one padded batch of `rung` rows, in the
+        form this runner's programs take: a `PackedBatch` where
+        `packs_batches`, else `assemble_batch`'s dict. -> (batch, spans)."""
+        make = assemble_packed if self.packs_batches else assemble_batch
+        return make(group, rung, self.cfg.data.max_nnz)
+
+    def predict(self, arrays) -> tuple[np.ndarray, Generation]:
         """One device batch: row-major {slots, fields, mask, row_mask}
-        -> (pctr [B] host array, the Generation that answered). The
-        generation is captured ONCE before dispatch so a concurrent
-        swap cannot split a batch across models."""
+        (a dict, or the `PackedBatch` `assemble` makes) -> (pctr [B]
+        host array, the Generation that answered). The generation is
+        captured ONCE before dispatch so a concurrent swap cannot split
+        a batch across models."""
         gen = self._gen
         if gen is None:
             raise RuntimeError("no checkpoint loaded; call load() first")
         # ladder dispatch: the batch's leading dim picks its rung's
         # compiled program; an off-ladder shape (direct predict()
         # callers) falls back to jit's own shape specialization
-        fn = self._predict_steps.get(
-            int(arrays["slots"].shape[0]), self._predict_step
-        )
-        p = fn(gen.tables, self._put(arrays))
+        if self.packs_batches:
+            batch = arrays if isinstance(arrays, PackedBatch) else pack_arrays(arrays)
+            fn = self._predict_steps.get(batch.rung, self._predict_step)
+            # the numpy vector straight into the call: its transfer and
+            # the dispatch are one C++ call
+            p = fn(gen.tables, batch.buf)
+        else:
+            fn = self._predict_steps.get(
+                int(arrays["slots"].shape[0]), self._predict_step
+            )
+            p = fn(gen.tables, self._put(dict(arrays)))
         return np.asarray(p), gen
 
     def warmup(self) -> int:
@@ -517,20 +603,17 @@ class ServeRunner:
         all-padding batch per rung through the real predict path, so
         the first real request at any rung never pays its compile.
         Returns the number of rungs warmed (serve_main logs it)."""
-        from xflow_tpu.serve.coalescer import assemble_batch
-
         if self._gen is None:
             raise RuntimeError("no checkpoint loaded; call load() first")
         for r in self.rungs:
-            arrays, _ = assemble_batch([], r, self.cfg.data.max_nnz)
-            self.predict(arrays)
+            self.predict(self.assemble([], r)[0])
         return len(self.rungs)
 
     def predict_rows(self, rows: list) -> tuple[np.ndarray, Generation]:
         """Convenience (C API / tests): parse + pad + predict a list of
         libffm feature rows, chunking by serve.max_batch so the compiled
         batch shape stays fixed. Returns (pctr [len(rows)], generation)."""
-        from xflow_tpu.serve.coalescer import PendingRequest, assemble_batch
+        from xflow_tpu.serve.coalescer import PendingRequest
 
         block = parse_request(rows, self.cfg.data)
         B = self.cfg.serve.max_batch
@@ -538,8 +621,8 @@ class ServeRunner:
         gen = None
         for lo in range(0, len(rows), B):
             req = PendingRequest(block=block.part(lo, lo + B))
-            arrays, _ = assemble_batch([req], B, self.cfg.data.max_nnz)
-            p, gen = self.predict(arrays)
+            batch, _ = self.assemble([req], B)
+            p, gen = self.predict(batch)
             out[lo : lo + req.num_rows] = p[: req.num_rows]
         return out, gen
 

@@ -6,8 +6,10 @@ Request path (docs/SERVING.md):
       (the training parser's hash path; native, off the GIL, where it
       builds) -> MicroBatcher.submit -> block on the request Future
     device worker thread: MicroBatcher.take (coalescing window) ->
-      assemble ONE padded batch -> ServeRunner.predict -> scatter pctr
-      slices + generation provenance back to every request's Future
+      assemble ONE padded batch (ServeRunner.assemble: one packed
+      vector by one native call that keeps the GIL, on one chip) ->
+      ServeRunner.predict -> scatter pctr slices + generation
+      provenance back to every request's Future
 
 One device batch per coalescing window, whatever the concurrency — the
 microbatching contract. The handler threads only parse and wait; the
@@ -40,8 +42,8 @@ from xflow_tpu.serve.autotune import AutotuneController, pick_rung
 from xflow_tpu.serve.coalescer import (
     BrownoutPolicy,
     MicroBatcher,
+    PackedBatch,
     RejectedRequest,
-    assemble_batch,
 )
 from xflow_tpu.serve.metrics import ServeMetrics
 from xflow_tpu.serve.runner import BadRequest, CheckpointWatcher, ServeRunner, parse_request
@@ -149,7 +151,6 @@ class ServeApp:
 
     # ------------------------------------------------------- device worker
     def _worker_loop(self) -> None:
-        cfg = self.cfg
         while True:
             group = self.batcher.take(timeout=0.1)
             if group is None:
@@ -173,13 +174,13 @@ class ServeApp:
             # unconfigured rung IS max_batch, the pre-ladder shape)
             rung = pick_rung(sum(r.num_rows for r in group), self._rungs)
             try:
-                arrays, spans = assemble_batch(
-                    group, rung, cfg.data.max_nnz
-                )
+                # in the runner's own form: one packed vector where
+                # it packs (ServeRunner.assemble), else the dict
+                batch, spans = self.runner.assemble(group, rung)
                 # predict's np.asarray readback IS the device sync: the
                 # worker (not the handler threads) pays the batch's
                 # device time, shared by all its requests
-                p, gen = self.runner.predict(arrays)
+                p, gen = self.runner.predict(batch)
             except Exception as e:  # noqa: BLE001 — fail THIS batch's
                 # futures, keep the worker alive for the next window
                 for req in group:
@@ -214,6 +215,7 @@ class ServeApp:
             self.metrics.observe_batch(
                 len(group), n_rows, queue_waits, device_s, totals,
                 batch_size=rung, native_rows=native_rows,
+                native_assembly=isinstance(batch, PackedBatch),
             )
             self._autotune(self.metrics.maybe_flush(
                 gen.gen, gen.step, freshness_s=_freshness(gen),
@@ -572,6 +574,10 @@ class _QuietDisconnects:
 
 class _TCPHTTPServer(_QuietDisconnects, ThreadingHTTPServer):
     daemon_threads = True
+    # socketserver's listen backlog is 5: a burst of more new
+    # connections than that overflows the accept queue and the kernel
+    # resets some of them mid-request
+    request_queue_size = 128
 
 
 def make_http_server(app: ServeApp, host: str, port: int) -> ThreadingHTTPServer:
@@ -589,6 +595,7 @@ class _UnixHTTPServer(
     address_family = socket.AF_UNIX
     allow_reuse_address = True
     daemon_threads = True
+    request_queue_size = _TCPHTTPServer.request_queue_size
 
     def server_bind(self):
         # a stale socket file from a dead server would EADDRINUSE
